@@ -7,7 +7,6 @@ namespace mirrors ``paddle.*``: tensor functions live here, layers under
 ``nn``, optimizers under ``optimizer``, parallelism under ``distributed``.
 """
 
-from .core import jax_compat as _jax_compat  # noqa: F401 — installs jax.shard_map shim
 from .core import dtype as _dtype_ns
 from .core.dtype import (bool_, uint8, int8, int16, int32, int64, float16,
                          bfloat16, float32, float64, complex64, complex128,

@@ -237,9 +237,12 @@ def build_fused_ce() -> BuiltGraph:
 
 def build_tp_fused_ce() -> BuiltGraph:
     """TP composition of the fused CE head on a dp=2 x tp=2 mesh: the
-    collective census contract — exactly one pmax + two psums over the tp
-    axis (global LSE + target logit), and NO all-gather (an implicit
-    GSPMD reshard re-materializing a vocab shard)."""
+    collective census contract — exactly one pmax + the two psums (global
+    LSE + target logit) over the tp axis, and NO all-gather (an implicit
+    GSPMD reshard re-materializing a vocab shard). On jax 0.9.0 XLA's
+    all-reduce combiner carries both psums — same dtype, same replica
+    groups, both ready once the local pass is done — in ONE tuple
+    all-reduce of two operands: two all-reduces, the same bytes."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -269,8 +272,9 @@ def build_tp_fused_ce() -> BuiltGraph:
         "tp_fused_ce",
         ban_rules=(BanRule(_VOCAB, B * S, label="global-logits"),),
         max_host_transfers=0,
-        expect_collectives={"all-reduce[tp]": 3},
-        notes="dp2xtp2 shard_map fused CE: pmax + 2 psum, 0 all-gather"),
+        expect_collectives={"all-reduce[tp]": 2},
+        notes="dp2xtp2 shard_map fused CE: pmax + one combined all-reduce "
+              "of the 2 psums, 0 all-gather"),
         mesh=hm)
 
 

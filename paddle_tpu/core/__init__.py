@@ -4,7 +4,3 @@ from . import compile_cache, dtype, flags, rng
 from .compile_cache import configure_compilation_cache
 from .flags import set_flags, get_flags, define_flag
 from .rng import seed, rng_tracker
-
-# opt-in persistent XLA compile cache: strict no-op unless
-# PT_COMPILE_CACHE_DIR is set in the environment (see compile_cache.py)
-configure_compilation_cache()

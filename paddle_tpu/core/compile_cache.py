@@ -20,9 +20,10 @@ opt-in and independently useful:
    normal compile — a stale artifact can never produce wrong numerics.
 
 3. **XLA persistent compilation cache** — ``configure_compilation_cache``
-   wires ``jax_compilation_cache_dir`` (env ``PT_COMPILE_CACHE_DIR`` or an
-   explicit path) so even the StableHLO→executable step is disk-cached
-   across processes. Strictly a no-op when no directory is configured.
+   turns it on for a process that owns one: at ``JAX_COMPILATION_CACHE_DIR``
+   when the environment sets it (jax reads that itself), else at one fixed
+   path inside the checkout, so even the StableHLO→executable step is
+   disk-cached across processes.
 
 Fingerprints are deliberately conservative: model class + config scalars +
 sublayer structure + optimizer class/hyperparameters + donation/accumulation
@@ -54,7 +55,8 @@ _LOCK = threading.Lock()
 _EXECUTABLES: "OrderedDict[str, Any]" = OrderedDict()
 _MAX_EXECUTABLES = 64
 
-_STATS = {"hits": 0, "misses": 0, "aot_hits": 0, "traces": 0}
+_STATS = {"hits": 0, "misses": 0, "aot_hits": 0, "traces": 0,
+          "persistent_hits": 0, "persistent_misses": 0}
 _PERSISTENT_DIR: Optional[str] = None
 # why the last stale AOT artifact was rejected (ISSUE 8: "a fingerprint
 # changed" is useless — operators need to know WHICH key drifted):
@@ -217,6 +219,8 @@ def acquire(fp: str, jitted, args, *, aot_dir: Optional[str] = None,
     drifted (model scalar, env escape, aval signature) instead of just
     "fingerprint mismatch".
     """
+    import jax
+
     with _LOCK:
         fn = _EXECUTABLES.get(fp)
         if fn is not None:
@@ -253,11 +257,13 @@ def acquire(fp: str, jitted, args, *, aot_dir: Optional[str] = None,
                            "trace+lower+XLA-compile wall time per "
                            "executable", "s").observe(
                 time.perf_counter() - t0, name=name)
+    except jax.errors.JaxRuntimeError:
+        raise    # the compiler refused the program: that is the result
     except Exception:
-        # exotic arg types: fall back to live dispatch WITHOUT caching —
-        # the jitted closure pins its Trainer's model/optimizer, and a
-        # process-global cache entry would leak that graph (and alias it
-        # into fingerprint-equal later Trainers)
+        # exotic arg types AOT lowering cannot take: live dispatch WITHOUT
+        # caching — the jitted closure pins its Trainer's model/optimizer,
+        # and a process-global cache entry would leak that graph (and
+        # alias it into fingerprint-equal later Trainers)
         with _LOCK:
             _STATS["misses"] += 1
         return jitted, "miss"
@@ -374,23 +380,45 @@ def load_aot(aot_dir: str, name: str, fp: str,
 
 # -- XLA persistent compilation cache ----------------------------------------
 
-def configure_compilation_cache(cache_dir: Optional[str] = None) -> bool:
-    """Opt-in wiring of jax's persistent compilation cache.
+#: where the cache lives when the environment names no directory: ONE fixed
+#: path inside the checkout (the path is part of jax's cache key, so a
+#: directory that moves — a temp name, a pid, a time — never hits)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    ``cache_dir`` defaults to env ``PT_COMPILE_CACHE_DIR``. When neither is
-    set this is a strict NO-OP (returns False, jax config untouched) —
-    guaranteed by test_superstep. When set, every XLA compile is disk-cached
-    so process restarts (preemption resume!) skip compilation entirely.
-    """
-    global _PERSISTENT_DIR
-    cache_dir = cache_dir or os.environ.get("PT_COMPILE_CACHE_DIR")
-    if not cache_dir:
-        return False
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_listening = False
+
+
+def _count_persistent(event: str, **_kw) -> None:
+    key = {_HIT_EVENT: "persistent_hits",
+           _MISS_EVENT: "persistent_misses"}.get(event)
+    if key is not None:
+        with _LOCK:
+            _STATS[key] += 1
+
+
+def configure_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    The one placement rule: if ``JAX_COMPILATION_CACHE_DIR`` is set, jax
+    reads it itself and this function sets NO directory; otherwise the
+    cache goes to :data:`DEFAULT_CACHE_DIR`. Called by every entry point
+    that owns a process (chip_smoke.py, bench.py, the tools that run on
+    the chip) before its first compile — never at import, so tests and
+    library users keep jax's own default. ``stats()`` then reports the
+    directory and the persistent hits/misses jax counts."""
+    global _PERSISTENT_DIR, _listening
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # default thresholds skip "cheap" compiles; a resume wants everything
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not _listening:
+        jax.monitoring.register_event_listener(_count_persistent)
+        _listening = True
     _PERSISTENT_DIR = cache_dir
-    return True
+    return cache_dir

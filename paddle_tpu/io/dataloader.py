@@ -97,6 +97,18 @@ _WORKER_STATE = {}
 _WORKER_ID_LOCK = threading.Lock()
 
 
+def _pin_worker_process_to_cpu():
+    """First thing a loader worker PROCESS does, before any dataset or
+    collate code runs: pin jax to the CPU platform. A chip belongs to one
+    process — the parent — and a worker that initialised the TPU backend
+    would fail or hang. (Worker threads share the parent's backend.)"""
+    import os
+
+    import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _worker_init(dataset, collate_fn, num_workers=0):
     _WORKER_STATE["dataset"] = dataset
     _WORKER_STATE["collate_fn"] = collate_fn
@@ -112,6 +124,11 @@ def _worker_init(dataset, collate_fn, num_workers=0):
                                 dataset=dataset))
 
 
+def _process_worker_init(dataset, collate_fn, num_workers=0):
+    _pin_worker_process_to_cpu()
+    _worker_init(dataset, collate_fn, num_workers)
+
+
 def _worker_fetch(indices):
     return _fetch_map(_WORKER_STATE["dataset"], indices,
                       _WORKER_STATE["collate_fn"])
@@ -123,6 +140,7 @@ def _shm_worker_loop(ring_name, index_queue, dataset, collate_fn):
     ShmRing (reference: the mmap-allocator path of dataloader_iter.py:358)."""
     import pickle
     from paddle_tpu.native import ShmRing
+    _pin_worker_process_to_cpu()
     ring = ShmRing.open(ring_name)
     try:
         while True:
@@ -306,7 +324,7 @@ class DataLoader:
             pool = ProcessPoolExecutor(
                 max_workers=self.num_workers,
                 mp_context=mp.get_context(self.multiprocessing_context),
-                initializer=_worker_init,
+                initializer=_process_worker_init,
                 initargs=(self.dataset, self.collate_fn, self.num_workers))
             fetch = _worker_fetch
             submit_args = lambda idx: (idx,)

@@ -160,7 +160,7 @@ def _proj(layer, x, name):
     weights do the plain dense matmul; int8 weights (detected by the
     ``<name>_scale`` twin) dispatch through the ops-registry
     "int8_matmul" op — fused Pallas dequant-in-VMEM on TPU gated by
-    TuneDB blocks + the lowering probe (the fused_vocab_ce pattern),
+    TuneDB blocks + the static shape gate (the fused_vocab_ce pattern),
     XLA convert+scale elsewhere, PT_DISABLE_PALLAS honored."""
     scale = getattr(layer, name + "_scale", None)
     if scale is not None:
@@ -438,8 +438,8 @@ class LlamaAttention(nn.Layer):
         b, s, _ = x.shape
         n_h, n_kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         q, k, v = self._qkv_rope(x, cos[:s], sin[:s])
-        from ..ops.attention import _sdpa_xla
-        out = _sdpa_xla(q, k, v, causal=True)
+        from ..ops.attention import flash_attention
+        out = flash_attention(q, k, v, causal=True)
         out = out.reshape(b, s, n_h * hd)
         out = _proj(self, out, "o_proj")
         k_cache = jnp.zeros((b, max_len, n_kv, hd), k.dtype).at[:, :s].set(k)
@@ -492,8 +492,12 @@ class LlamaAttention(nn.Layer):
                          cfg.head_dim)
         page = kv[0].shape[2]
         q, k, v = self._qkv_rope(x, cos[:s], sin[:s])
-        from ..ops.attention import _sdpa_xla
-        out = _sdpa_xla(q, k, v, causal=True)
+        # through the dispatcher, like forward(): the flash kernel on TPU.
+        # The dense XLA composition holds two f32 [h, s, s] score tensors
+        # (8.6 GB at 32 heads, s=6016) — a long prompt could not prefill
+        # beside the weights on one 16 GB chip
+        from ..ops.attention import flash_attention
+        out = flash_attention(q, k, v, causal=True)
         out = out.reshape(b, s, n_h * hd)
         out = _proj(self, out, "o_proj")
 
@@ -868,9 +872,9 @@ class LlamaForCausalLM(nn.Layer):
         the fused dequant-matmul epilogue on the vocab head: the int8
         [V, H] weight crosses HBM quantized and the registry's Pallas
         kernel widens it in VMEM and scales the f32 accumulator blockwise
-        (the PR 5 fused-CE template — TuneDB blocks + lowering probe gate
-        it identically). Tied embeddings keep the float gather table, so
-        the tied head stays a dense matmul."""
+        (the PR 5 fused-CE template — TuneDB blocks + static shape gate).
+        Tied embeddings keep the float gather table, so the tied head
+        stays a dense matmul."""
         if self.cfg.tie_word_embeddings:
             w = jnp.swapaxes(self.model.embed_tokens, 0, 1)
             return jnp.matmul(hidden, w.astype(hidden.dtype))

@@ -152,7 +152,7 @@ def weight_only_linear(x, weight, bias=None, weight_scale=None,
     if weight_dtype == "int8" and group_size == -1 and weight_scale is not None:
         # registry-routed path (ISSUE 17 dedupe): the ONE "int8_matmul"
         # op picks the fused Pallas kernel on TPU (TuneDB blocks +
-        # lowering probe + PT_DISABLE_PALLAS honored) or the XLA
+        # static shape gate + PT_DISABLE_PALLAS honored) or the XLA
         # convert+scale composition everywhere else
         scale = jnp.asarray(weight_scale, jnp.float32)
         if scale.ndim == 1:

@@ -1,15 +1,16 @@
 """Device capability tables for the cost observatory: peak FLOP/s, HBM
 bandwidth, inter-chip link bandwidth.
 
-One definition per number: bf16 peak FLOP/s comes from the trainer's
-``PEAK_FLOPS`` table (the MFU denominator every throughput report already
-uses) and the v5e/v5p HBM + v5p ICI constants come from
+One definition per number: bf16 peak FLOP/s and the v5e HBM come from the
+trainer's ``PEAK_FLOPS``/``PEAK_HBM`` tables (the MFU denominator every
+throughput report already uses), the v5p HBM + ICI constants from
 ``parallel/projection.py`` (cited public specs, asserted by
 tests/test_projection) — this module only ADDS the device kinds those
-tables don't carry, each with its source in a comment. Every lookup falls
-back to a nominal CPU tier so the observatory stays usable (and testable)
-on hosts with no accelerator: the absolute predictions are then
-meaningless, but the RATIOS the acceptance tests pin (K=1 vs K=4 step
+tables don't carry, each with its source in a comment. A device kind no
+table holds is a LookupError: a prediction divided by a guessed peak is
+worse than none. The ``cpu`` rows are NOMINAL — they keep the observatory
+testable on hosts with no accelerator, where absolute predictions are
+meaningless but the RATIOS the acceptance tests pin (K=1 vs K=4 step
 time, comm ∝ bytes) survive any constant scaling.
 """
 
@@ -25,7 +26,7 @@ __all__ = ["DeviceSpec", "device_spec", "current_device_kind"]
 _HBM_BW_EXTRA = {
     "tpu v4": 1228e9,        # v4: 32 GB @ 1228 GB/s
     "tpu v6 lite": 1640e9,   # v6e (trillium): 32 GB @ 1640 GB/s
-    "cpu": 50e9,             # nominal DRAM tier for smoke runs
+    "cpu": 50e9,             # NOMINAL DRAM tier (CPU tests only)
 }
 
 # bytes/s per chip, aggregate over ICI links (approximate: link count x
@@ -36,7 +37,7 @@ _LINK_BW_EXTRA = {
     "tpu v5 lite": 200e9,    # v5e: 1600 Gbit/s aggregate
     "tpu v5e": 200e9,
     "tpu v6 lite": 400e9,    # v6e: 3200 Gbit/s aggregate
-    "cpu": 10e9,             # nominal host-interconnect tier
+    "cpu": 10e9,             # NOMINAL host-interconnect tier (CPU tests only)
 }
 
 
@@ -53,34 +54,24 @@ class DeviceSpec:
 
 
 def _peak_table() -> Dict[str, float]:
-    # the trainer owns the MFU denominator; a jax-free environment
-    # (analyzing a saved .hlo dump) falls back to the nominal CPU tier
-    try:
-        from ...trainer.trainer import PEAK_FLOPS
-        return dict(PEAK_FLOPS)
-    except Exception:
-        return {"cpu": 1e12}
+    from ...trainer.trainer import PEAK_FLOPS
+    return dict(PEAK_FLOPS)
 
 
 def _hbm_table() -> Dict[str, float]:
+    from ...parallel.projection import HBM_BW
+    from ...trainer.trainer import PEAK_HBM
     out = dict(_HBM_BW_EXTRA)
-    try:
-        from ...parallel.projection import HBM_BW
-        out["tpu v5 lite"] = out["tpu v5e"] = HBM_BW["v5e"]
-        out["tpu v5"] = out["tpu v5p"] = HBM_BW["v5p"]
-    except Exception:
-        out.setdefault("tpu v5 lite", 819e9)
-        out.setdefault("tpu v5", 2765e9)
+    out.update({k: v["bytes_per_s"] for k, v in PEAK_HBM.items()})
+    out["tpu v5e"] = out["tpu v5 lite"]
+    out["tpu v5"] = out["tpu v5p"] = HBM_BW["v5p"]
     return out
 
 
 def _link_table() -> Dict[str, float]:
+    from ...parallel.projection import ICI_AGG
     out = dict(_LINK_BW_EXTRA)
-    try:
-        from ...parallel.projection import ICI_AGG
-        out["tpu v5"] = out["tpu v5p"] = ICI_AGG["v5p"]
-    except Exception:
-        out.setdefault("tpu v5", 600e9)
+    out["tpu v5"] = out["tpu v5p"] = ICI_AGG["v5p"]
     return out
 
 
@@ -94,24 +85,14 @@ def current_device_kind(default: str = "cpu") -> str:
         return default
 
 
-def _match(table: Dict[str, float], kind: str,
-           fallback: float) -> float:
-    kind = kind.lower()
-    # longest-substring match so "tpu v5 lite" beats "tpu v5"
-    best, best_len = None, -1
-    for k, v in table.items():
-        if k in kind and len(k) > best_len:
-            best, best_len = v, len(k)
-    return best if best is not None else fallback
-
-
 def device_spec(kind: Optional[str] = None) -> DeviceSpec:
-    """Spec for ``kind`` (defaults to the current jax device), with the
-    nominal CPU tier as the universal fallback."""
+    """Spec for ``kind`` (defaults to the current jax device);
+    LookupError when a table does not hold it."""
+    from ...trainer.trainer import peak_lookup
     kind = kind or current_device_kind()
     return DeviceSpec(
         kind=kind,
-        peak_flops=_match(_peak_table(), kind, 1e12),
-        hbm_bw=_match(_hbm_table(), kind, 50e9),
-        link_bw=_match(_link_table(), kind, 10e9),
+        peak_flops=peak_lookup(_peak_table(), kind),
+        hbm_bw=peak_lookup(_hbm_table(), kind),
+        link_bw=peak_lookup(_link_table(), kind),
     )

@@ -1,11 +1,7 @@
 """Pallas TPU kernels (flash attention, fused norms). Importing registers
 the TPU-backend kernels with the op registry."""
 
-from ...core.jax_compat import install_pallas_compat
-
-install_pallas_compat()    # pltpu.CompilerParams name on jax<0.6
-
-from . import flash_attention  # noqa: F401,E402
+from . import flash_attention  # noqa: F401
 from . import fused_norm  # noqa: F401
 from . import fused_vocab_ce  # noqa: F401
 from . import grouped_matmul  # noqa: F401

@@ -13,9 +13,10 @@ is a JSON database consulted at dispatch time:
     key = op | device_kind | dtype | bucketed shape signature
 
 Shapes bucket to powers of two so one sweep covers a family; lookups fall
-back to the nearest recorded bucket, then to the built-in defaults. A
-user-writable overlay (PT_TUNE_DB env or ~/.cache/paddle_tpu/) is merged
-over the shipped DB so `tools/tune_kernels.py --write` results win.
+back to the nearest recorded bucket, then to the built-in defaults. The
+shipped ``tune_db.json`` IS the database: block choice on the chip depends
+only on files git holds. ``tools/tune_kernels.py`` writes a sweep to the
+path it is given (``--out``) or into the shipped file (``--write-shipped``).
 """
 
 from __future__ import annotations
@@ -27,53 +28,37 @@ from typing import Dict, Optional, Tuple
 _SHIPPED = os.path.join(os.path.dirname(__file__), "tune_db.json")
 
 
-def _user_db_path() -> str:
-    env = os.environ.get("PT_TUNE_DB")
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                        "tune_db.json")
-
-
 class TuneDB:
-    """Merged shipped + user kernel-config database.
+    """One JSON file of kernel configs (default: the shipped DB).
 
-    ``shipped_path`` / ``user_path`` parameterize the two merge sources so
-    sibling databases (the cost observatory's :class:`OpCostDB`) share the
-    exact load/merge/corrupt-warning machinery instead of re-implementing
-    it; the defaults keep the original kernel-config behavior."""
+    ``path`` parameterizes the file so sibling databases (the cost
+    observatory's :class:`OpCostDB`) and tools writing a sweep elsewhere
+    share the exact load/save/corrupt-warning machinery."""
 
     #: human label used in the corrupt-file warning
     db_label = "kernel tune DB"
 
-    def __init__(self, shipped_path: Optional[str] = None,
-                 user_path: Optional[str] = None):
+    def __init__(self, path: Optional[str] = None):
         self._db: Dict[str, dict] = {}
         self._loaded = False
-        self._dirty = False
-        self._shipped_path = shipped_path or _SHIPPED
-        self._user_path = user_path
-
-    def user_path(self) -> str:
-        return self._user_path or _user_db_path()
+        self.path = path or _SHIPPED
 
     def _load(self):
         if self._loaded:
             return
-        for path in (self._shipped_path, self.user_path()):
-            try:
-                with open(path) as f:
-                    self._db.update(json.load(f))
-            except OSError:
-                pass      # absent DB is normal (no offline sweep run yet)
-            except ValueError as e:
-                # corrupt JSON: merging nothing SILENTLY would make
-                # offline-tuned configs vanish without a trace — say so once
-                import warnings
-                warnings.warn(
-                    f"ignoring corrupt {self.db_label} at {path} ({e}); "
-                    f"offline-tuned configs from that file will not be "
-                    f"applied", RuntimeWarning, stacklevel=2)
+        try:
+            with open(self.path) as f:
+                self._db.update(json.load(f))
+        except OSError:
+            pass      # absent DB is normal (no offline sweep run yet)
+        except ValueError as e:
+            # corrupt JSON: loading nothing SILENTLY would make
+            # offline-tuned configs vanish without a trace — say so once
+            import warnings
+            warnings.warn(
+                f"ignoring corrupt {self.db_label} at {self.path} ({e}); "
+                f"offline-tuned configs from that file will not be "
+                f"applied", RuntimeWarning, stacklevel=2)
         self._loaded = True
 
     @staticmethod
@@ -97,10 +82,9 @@ class TuneDB:
     def record(self, key: str, config: dict):
         self._load()
         self._db[key] = config
-        self._dirty = True
 
     def save(self, path: Optional[str] = None):
-        path = path or self.user_path()
+        path = path or self.path
         d = os.path.dirname(path)
         if d:
             os.makedirs(d, exist_ok=True)
@@ -116,7 +100,6 @@ class TuneDB:
         with open(tmp, "w") as f:
             json.dump(merged, f, indent=1, sort_keys=True)
         os.replace(tmp, path)
-        self._dirty = False
 
 
 _DB = TuneDB()
@@ -219,22 +202,15 @@ def get_db() -> TuneDB:
 _COST_SHIPPED = os.path.join(os.path.dirname(__file__), "op_cost_db.json")
 
 
-def _user_cost_db_path() -> str:
-    env = os.environ.get("PT_OP_COST_DB")
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                        "op_cost_db.json")
-
-
 class OpCostDB(TuneDB):
     """Measured-latency database the cost observatory calibrates
     (``tools/op_cost_probe.py``) and the sharding planner will read.
 
     Same persistence discipline as the kernel TuneDB it sits next to —
-    shipped + user overlay merge, atomic merge-over-existing save, and the
-    corrupt-file warning path (a corrupt calibration file must degrade to
-    analytical estimates loudly, never silently) — but keyed on MEASURED
+    one file (default: ``op_cost_db.json`` beside the tune DB), atomic
+    merge-over-existing save, and the corrupt-file warning path (a corrupt
+    calibration file must degrade to analytical estimates loudly, never
+    silently) — but keyed on MEASURED
     quantities: ``graph:<name>|<device_kind>|any|`` records a canonical
     graph's min-of-rounds execution seconds + its analytical flop/byte
     attribution, ``dot|<device_kind>|<dtype>|k=...,m=...,n=...`` records a
@@ -243,13 +219,8 @@ class OpCostDB(TuneDB):
 
     db_label = "op cost DB"
 
-    def __init__(self, user_path: Optional[str] = None):
-        super().__init__(shipped_path=_COST_SHIPPED, user_path=user_path)
-
-    def user_path(self) -> str:
-        # resolved LAZILY per call, matching TuneDB's PT_TUNE_DB
-        # discipline — a PT_OP_COST_DB set after import must still win
-        return self._user_path or _user_cost_db_path()
+    def __init__(self, path: Optional[str] = None):
+        super().__init__(path or _COST_SHIPPED)
 
     @staticmethod
     def graph_key(name: str, device_kind: str) -> str:

@@ -52,6 +52,9 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from .per_shard import active_axes, per_shard, qkv_layout
 
 try:  # pltpu only imports cleanly on TPU-enabled jaxlib builds
     from jax.experimental.pallas import tpu as pltpu
@@ -604,29 +607,6 @@ def pallas_supported(q, k, v, attn_mask, dropout_p, causal=False,
     return ok
 
 
-@functools.lru_cache(maxsize=1)
-def _tpu_lowering_ok() -> bool:
-    """One-shot compile probe on the real backend: if the representative
-    kernel fails Mosaic lowering (driver env drift, jax upgrade), dispatch
-    degrades to the XLA path instead of poisoning every downstream jit
-    (round-2: one lowering error zeroed the whole bench)."""
-    from ..registry import backend_kind
-    if backend_kind() != "tpu":
-        return False
-    try:
-        q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
-        jax.jit(functools.partial(
-            _flash_attention, dropout_p=0.0, scale=0.088, causal=True,
-            block_q=128, block_k=128, interpret=False)
-        ).lower(q, q, q, None, None, None).compile()
-        return True
-    except Exception as e:  # pragma: no cover - only on env drift
-        import warnings
-        warnings.warn(f"Pallas flash attention failed TPU lowering; "
-                      f"falling back to XLA attention: {e}")
-        return False
-
-
 def flash_attention_pallas(q, k, v, attn_mask=None, dropout_p: float = 0.0,
                            causal: bool = False, scale: Optional[float] = None,
                            segment_ids=None,
@@ -634,7 +614,8 @@ def flash_attention_pallas(q, k, v, attn_mask=None, dropout_p: float = 0.0,
                            block_k: Optional[int] = None,
                            interpret: bool = False,
                            dropout_seed=None):
-    """TPU flash attention; falls back to the XLA path when unsupported.
+    """TPU flash attention; shapes the static gate (pallas_supported)
+    rejects take the XLA path, supported ones compile or fail loudly.
 
     ``segment_ids`` ([b, s] ints, or a (q_seg, kv_seg) pair) restricts
     attention to equal-id positions — packed-sequence (varlen) and padding
@@ -657,12 +638,18 @@ def flash_attention_pallas(q, k, v, attn_mask=None, dropout_p: float = 0.0,
                                         str(q.dtype), causal)
         block_q = block_q if block_q is not None else tq
         block_k = block_k if block_k is not None else tk
-    supported = pallas_supported(q, k, v, attn_mask, dropout_p, causal,
-                                 block_q, block_k, segment_ids=segment_ids,
-                                 interpret=interpret)
-    if supported and not interpret:
-        supported = _tpu_lowering_ok()
-    if not supported:
+    # under a device mesh the kernel runs per shard (per_shard.py): batch
+    # over the data axes, heads over "tp" — when both divide
+    act = active_axes()
+    divides = True
+    if act is not None:
+        mesh, free, sizes = act
+        b_ax, h_ax, nb, nh = qkv_layout(free, sizes)
+        divides = not (q.shape[0] % nb or q.shape[2] % nh
+                       or k.shape[2] % nh)
+    if not divides or not pallas_supported(
+            q, k, v, attn_mask, dropout_p, causal, block_q, block_k,
+            segment_ids=segment_ids, interpret=interpret):
         if segment_ids is not None:
             # one shared segment->mask fold lives in _sdpa_xla
             segment_ids = _normalize_segments(segment_ids, q.shape[0],
@@ -684,8 +671,17 @@ def flash_attention_pallas(q, k, v, attn_mask=None, dropout_p: float = 0.0,
             seed = jax.random.randint(key, (1,), 0, 2**31 - 1, jnp.int32)
         else:
             seed = jnp.asarray(dropout_seed, jnp.int32).reshape((1,))
-    return _flash_attention(q, k, v, q_seg, kv_seg, seed, dropout_p, scale,
-                            causal, bq, bk, interpret)
+    def local(q, k, v, q_seg, kv_seg, seed):
+        return _flash_attention(q, k, v, q_seg, kv_seg, seed, dropout_p,
+                                scale, causal, bq, bk, interpret)
+
+    if act is None:
+        return local(q, k, v, q_seg, kv_seg, seed)
+    # one dropout seed for all shards: each draws the same mask pattern
+    # over its own rows
+    qkv, seg = P(b_ax, None, h_ax, None), P(b_ax, None)
+    return per_shard(local, mesh, free, (qkv, qkv, qkv, seg, seg, P(None)),
+                     qkv)(q, k, v, q_seg, kv_seg, seed)
 
 
 @register_kernel("flash_attention", "tpu")

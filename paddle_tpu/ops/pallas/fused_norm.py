@@ -17,10 +17,14 @@ Falls back to the XLA composition for ragged shapes / non-TPU backends.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from .per_shard import active_axes, batch_spec, per_shard, shards
 
 try:
     from jax.experimental.pallas import tpu as pltpu
@@ -145,28 +149,48 @@ def _pick_block_r(R: int, D: int, block_r: int = DEFAULT_BLOCK_R) -> int:
     return max(br, 8)
 
 
-def pallas_rms_supported(x, weight) -> bool:
-    from ..registry import pallas_disabled
-    if not _HAS_PLTPU or weight is None or pallas_disabled():
-        return False
-    D = x.shape[-1]
-    R = max(x.size // D, 1)
+def _shape_ok(shape) -> bool:
+    """Mosaic tiling rules for one (local) activation shape."""
+    D = shape[-1]
+    R = max(math.prod(shape) // D, 1)
     br = _pick_block_r(R, D)
     return D % 128 == 0 and R % br == 0 and br % 8 == 0
 
 
+def pallas_rms_supported(x, weight) -> bool:
+    from ..registry import pallas_disabled
+    if not _HAS_PLTPU or weight is None or pallas_disabled():
+        return False
+    return _shape_ok(x.shape)
+
+
 def rms_norm_pallas(x, weight, epsilon: float = 1e-6,
                     block_r: int = DEFAULT_BLOCK_R, interpret: bool = False):
-    """Fused RMS norm; XLA fallback when the shape doesn't tile."""
+    """Fused RMS norm; XLA composition when the shape doesn't tile. Under
+    a device mesh the kernel runs per shard (per_shard.py): rows split
+    with the batch dimension, the weight replicated."""
+    from ..norm import _rms_norm_xla
     if not pallas_rms_supported(x, weight):
-        from ..norm import _rms_norm_xla
         return _rms_norm_xla(x, weight, epsilon)
-    shape = x.shape
-    D = shape[-1]
-    x2d = x.reshape(-1, D)
-    out = _rms_norm_p(x2d, weight, float(epsilon),
-                      _pick_block_r(x2d.shape[0], D, block_r), interpret)
-    return out.reshape(shape)
+
+    def local(x, w):
+        D = x.shape[-1]
+        x2d = x.reshape(-1, D)
+        out = _rms_norm_p(x2d, w, float(epsilon),
+                          _pick_block_r(x2d.shape[0], D, block_r), interpret)
+        return out.reshape(x.shape)
+
+    act = active_axes()
+    if act is None:
+        return local(x, weight)
+    mesh, free, sizes = act
+    bs = batch_spec(free)
+    n = shards(bs, sizes)
+    if x.ndim < 2 or x.shape[0] % n or not _shape_ok(
+            (x.shape[0] // n,) + tuple(x.shape[1:])):
+        return _rms_norm_xla(x, weight, epsilon)
+    spec = P(bs, *([None] * (x.ndim - 1)))
+    return per_shard(local, mesh, free, (spec, P(None)), spec)(x, weight)
 
 
 from ..registry import register_kernel  # noqa: E402

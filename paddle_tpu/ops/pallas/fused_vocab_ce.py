@@ -44,14 +44,15 @@ weight is exactly 0 in the backward recompute).
 from __future__ import annotations
 
 import functools
-import math
-import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from .per_shard import active_axes, batch_spec, per_shard, shards
 
 try:  # pltpu only imports cleanly on TPU-enabled jaxlib builds
     from jax.experimental.pallas import tpu as pltpu
@@ -66,10 +67,22 @@ NEG_INF = -1e30  # large-negative instead of -inf: avoids inf-inf=nan in exp
 LANES = 8        # lane width for per-row scalars (lse/tgt/labels tiles)
 
 
+# Scoped-VMEM limit handed to Mosaic for all three kernels. The compiler's
+# default (16 MiB on v5e) is below what the backward kernels allocate at
+# real widths — 19.55 MiB at (H=4096, bn=256, bv=256), 20.00 MiB at
+# (H=1536, bn=256, bv=1024) — so the limit is explicit, and the block
+# chooser and support gate budget against it (kernel_vmem_bytes).
+VMEM_LIMIT = 48 * 2 ** 20
+# what kernel_vmem_bytes may reach: two thirds of the limit, the rest is
+# Mosaic's own (relayouts, spills) that the estimate does not itemize
+VMEM_BUDGET = 32 * 2 ** 20
+
+
 def _tpu_params(*semantics):
     if pltpu is None:
         return None
-    return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics),
+                                vmem_limit_bytes=VMEM_LIMIT)
 
 
 def _block_spec(shape, index_map):
@@ -90,7 +103,7 @@ def _pad_vocab(w, block_v: int):
 # XLA fallback: lax.scan over vocab blocks (same O(block_v) memory)
 # ---------------------------------------------------------------------------
 
-def _fwd_xla(h, w, labels, block_v, unroll=False):
+def _fwd_xla(h, w, labels, block_v):
     n, hd = h.shape
     v = w.shape[1]
     wp = _pad_vocab(w, block_v)
@@ -113,20 +126,13 @@ def _fwd_xla(h, w, labels, block_v, unroll=False):
 
     carry = (jnp.full((n,), NEG_INF, jnp.float32),
              jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32))
-    if unroll:
-        # Python loop (no while op): required inside partial-auto
-        # shard_map regions, whose SPMD partitioning rejects scan
-        for j in range(nb):
-            carry, _ = body(carry, jnp.int32(j))
-    else:
-        carry, _ = jax.lax.scan(body, carry,
+    (m, s, t), _ = jax.lax.scan(body, carry,
                                 jnp.arange(nb, dtype=jnp.int32))
-    m, s, t = carry
     safe = jnp.where(s == 0.0, 1.0, s)
     return m + jnp.log(safe), t
 
 
-def _bwd_xla(h, w, labels, lse, g_lse, g_tgt, block_v, unroll=False):
+def _bwd_xla(h, w, labels, lse, g_lse, g_tgt, block_v):
     n, hd = h.shape
     v = w.shape[1]
     wp = _pad_vocab(w, block_v)
@@ -152,16 +158,9 @@ def _bwd_xla(h, w, labels, lse, g_lse, g_tgt, block_v, unroll=False):
         return dh, dwj.astype(w.dtype)
 
     dh0 = jnp.zeros((n, hd), jnp.float32)
-    if unroll:
-        dh, blocks = dh0, []
-        for j in range(nb):
-            dh, dwj = body(dh, jnp.int32(j))
-            blocks.append(dwj)
-        dw = jnp.concatenate(blocks, axis=1)[:, :v]
-    else:
-        dh, dw_blocks = jax.lax.scan(body, dh0,
-                                     jnp.arange(nb, dtype=jnp.int32))
-        dw = jnp.moveaxis(dw_blocks, 0, 1).reshape(hd, nb * block_v)[:, :v]
+    dh, dw_blocks = jax.lax.scan(body, dh0,
+                                 jnp.arange(nb, dtype=jnp.int32))
+    dw = jnp.moveaxis(dw_blocks, 0, 1).reshape(hd, nb * block_v)[:, :v]
     return dh.astype(h.dtype), dw
 
 
@@ -368,7 +367,7 @@ def _bwd_pallas(h, w, labels, lse, g_lse, g_tgt, block_n, block_v, interpret):
 def _fwd_impl(h, w, labels, block_n, block_v, impl, interpret):
     if impl == "pallas":
         return _fwd_pallas(h, w, labels, block_n, block_v, interpret)
-    return _fwd_xla(h, w, labels, block_v, unroll=(impl == "xla_unroll"))
+    return _fwd_xla(h, w, labels, block_v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -396,8 +395,7 @@ def _lse_bwd_rule(block_n, block_v, impl, interpret, res, g):
         dh, dw = _bwd_pallas(h, w, labels, lse, g_lse, g_tgt,
                              block_n, block_v, interpret)
     else:
-        dh, dw = _bwd_xla(h, w, labels, lse, g_lse, g_tgt, block_v,
-                          unroll=(impl == "xla_unroll"))
+        dh, dw = _bwd_xla(h, w, labels, lse, g_lse, g_tgt, block_v)
     # int labels: symbolically-zero (float0) cotangent
     dlab = np.zeros(labels.shape, jax.dtypes.float0)
     return dh, dw, dlab
@@ -410,19 +408,22 @@ lse_and_target.defvjp(_lse_fwd_rule, _lse_bwd_rule)
 # support gates + public entry
 # ---------------------------------------------------------------------------
 
-VMEM_BUDGET = 14 * 2 ** 20
-
-
 def kernel_vmem_bytes(block_n, block_v, hd, itemsize) -> int:
-    """Worst-case per-kernel VMEM for one (block_n, block_v) config — the
-    dW backward kernel is the pacer. The ONE formula shared by the support
-    gate and the default block chooser (autotune.fused_vocab_ce_config):
-    two inconsistent estimates would let the chooser pick configs the gate
-    then rejects, silently routing every TPU call to the XLA fallback."""
-    return (hd * block_v * 4                  # dW accumulator (fp32)
-            + hd * block_v * itemsize         # W block
-            + block_n * hd * (itemsize + 4)   # h block + dh accumulator
-            + block_n * block_v * 4)          # dlog block
+    """Upper bound on the scoped VMEM Mosaic allocates for one
+    (block_n, block_v) config, over the two backward kernels (the forward
+    holds strictly less). Counts what the compiler holds, not just what
+    the kernel names: every blocked operand and output TWICE (the pipeline
+    double-buffers them), the fp32 scratch accumulator plus the matmul
+    result it is added to, the [block_n, block_v] fp32 temporaries of the
+    softmax recompute, and the per-row scalar tiles padded to 128 lanes.
+    The ONE formula shared by the support gate and the default block
+    chooser, so the chooser never picks what the gate rejects."""
+    io = 2 * (block_n * hd + hd * block_v) * itemsize   # h + W blocks
+    temps = 4 * block_n * block_v * 4         # logits, p, dlog, column iota
+    rows = 6 * 2 * block_n * 128 * 4          # labels/lse/g_lse/g_tgt tiles
+    dh = 2 * block_n * hd * itemsize + 2 * block_n * hd * 4
+    dw = 2 * hd * block_v * itemsize + 2 * hd * block_v * 4
+    return io + temps + rows + max(dh, dw)
 
 
 def default_blocks(n, hd, dtype_str) -> Tuple[Optional[int], int]:
@@ -464,47 +465,15 @@ def fused_ce_supported(n, hd, v, dtype, block_n, block_v,
             <= VMEM_BUDGET)
 
 
-@functools.lru_cache(maxsize=1)
-def _tpu_lowering_ok() -> bool:
-    """One-shot compile probe on the real backend (same rationale as
-    flash_attention: degrade to the XLA path on env drift instead of
-    poisoning every downstream jit)."""
-    from ..registry import backend_kind
-    if backend_kind() != "tpu":
-        return False
-    try:
-        h = jax.ShapeDtypeStruct((128, 128), jnp.bfloat16)
-        w = jax.ShapeDtypeStruct((128, 256), jnp.bfloat16)
-        lab = jax.ShapeDtypeStruct((128,), jnp.int32)
-
-        def probe(h, w, lab):
-            # grad probes BOTH directions: the backward dh/dW kernels use
-            # different grids (the dW grid is transposed) and larger
-            # scratch, so a forward-only probe could pass while the first
-            # train step still fails to lower
-            lse, tgt = lse_and_target(h, w, lab, block_n=128, block_v=128,
-                                      impl="pallas", interpret=False)
-            return jnp.sum(lse) + jnp.sum(tgt)
-
-        jax.jit(jax.grad(probe, argnums=(0, 1))).lower(h, w, lab).compile()
-        return True
-    except Exception as e:  # pragma: no cover - only on env drift
-        import warnings
-        warnings.warn(f"Pallas fused vocab-CE failed TPU lowering; "
-                      f"falling back to the XLA blockwise path: {e}")
-        return False
-
-
 def resolve_impl(n, hd, v, dtype, block_n, block_v,
                  interpret=False) -> str:
-    """'pallas' when the TPU kernel path is usable for these shapes (or
-    interpret mode is forced), else 'xla'."""
+    """'pallas' when the static gate accepts these shapes and the backend
+    is a TPU (or interpret mode is asked for), else 'xla'. A supported
+    kernel is compiled as-is: if Mosaic refuses it the jit fails."""
     from ..registry import backend_kind
     if not fused_ce_supported(n, hd, v, dtype, block_n, block_v, interpret):
         return "xla"
-    if interpret:
-        return "pallas"
-    if backend_kind() == "tpu" and _tpu_lowering_ok():
+    if interpret or backend_kind() == "tpu":
         return "pallas"
     return "xla"
 
@@ -533,15 +502,36 @@ def fused_linear_cross_entropy(hidden, w, labels, ignore_index: int = -100,
     lab = labels.reshape(n).astype(jnp.int32)
     valid = lab != ignore_index
     safe = jnp.where(valid, lab, -1)          # out of range -> tgt = 0
+    # under a device mesh the Pallas kernels run per shard (per_shard.py):
+    # rows split with the batch dimension, W whole on every device (its
+    # cotangent is summed over the data axes by the region's transpose)
+    act = active_axes()
+    n_local = n
+    if act is not None:
+        mesh, free, sizes = act
+        rows = batch_spec(free)
+        if lead and lead[0] % shards(rows, sizes) == 0:
+            n_local = n // shards(rows, sizes)
+        else:                        # rows do not divide: GSPMD's job
+            act, impl = None, impl or "xla"
     if block_n is None or block_v is None:
         from .autotune import fused_vocab_ce_config
-        tn, tv = fused_vocab_ce_config(n, hd, v, str(hidden.dtype))
+        tn, tv = fused_vocab_ce_config(n_local, hd, v, str(hidden.dtype))
         block_n = block_n if block_n is not None else tn
         block_v = block_v if block_v is not None else tv
     if impl is None:
-        impl = resolve_impl(n, hd, v, hidden.dtype, block_n, block_v,
+        impl = resolve_impl(n_local, hd, v, hidden.dtype, block_n, block_v,
                             interpret)
-    lse, tgt = lse_and_target(h2, w, safe, block_n, block_v, impl, interpret)
+
+    def local(h2, w, safe):
+        return lse_and_target(h2, w, safe, block_n, block_v, impl, interpret)
+
+    if act is not None and impl == "pallas":
+        lse, tgt = per_shard(
+            local, mesh, free, (P(rows, None), P(None, None), P(rows)),
+            (P(rows), P(rows)))(h2, w, safe)
+    else:
+        lse, tgt = local(h2, w, safe)
     nll = jnp.where(valid, lse - tgt, 0.0)
     if reduction == "none":
         return nll.reshape(lead)

@@ -18,16 +18,14 @@ gather — no masking in the kernel's hot loop.
 
 Gradients: the backward pass reuses the XLA fallback's vjp (ragged_dot
 is linear in both operands, so this is exact, and it guarantees the
-gradcheck parity the MoE tests pin). Dispatch is TuneDB-gated with a
-one-shot lowering probe and an XLA ``lax.ragged_dot`` fallback, exactly
-like fused_vocab_ce and int8_matmul; parallel/moe.py's ``_grouped_matmul``
-is the seam that routes here.
+gradcheck parity the MoE tests pin). Dispatch is gated by the TuneDB and
+the static shape gate, with ``lax.ragged_dot`` for what they reject;
+parallel/moe.py's ``_grouped_matmul`` is the seam that routes here.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import numpy as np
 import jax
@@ -47,27 +45,12 @@ DEFAULT_BLOCK_K = 128
 
 
 def xla_grouped_matmul(xs, w, group_sizes):
-    """XLA fallback: ``lax.ragged_dot`` when this jax ships it (XLA-
-    native; the round-5 v5e A/B measured it 1.7x faster than megablox
-    gmm with max|diff|=0 at e=64, d=2048, f=1408); otherwise the bundled
-    megablox Pallas kernel (interpret mode off-TPU). Returns f32 — the
-    accumulator dtype; callers cast back to the activation dtype."""
-    if hasattr(jax.lax, "ragged_dot"):
-        return jax.lax.ragged_dot(xs, w, group_sizes,
-                                  preferred_element_type=jnp.float32)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-    from ..registry import backend_kind
-
-    def tiling(m, kk, n):
-        # largest power-of-two tile <= 128 dividing each dim (gmm
-        # requires exact tiling; real configs are 128-multiples, tiny
-        # test shapes degrade gracefully)
-        g_ = lambda x: math.gcd(x, 128)
-        return (g_(m), g_(kk), g_(n))
-
-    return gmm(xs, w, group_sizes, preferred_element_type=jnp.float32,
-               tiling=tiling(xs.shape[0], w.shape[1], w.shape[2]),
-               interpret=backend_kind() != "tpu")
+    """XLA path: ``lax.ragged_dot`` (XLA-native; the round-5 v5e A/B
+    measured it 1.7x faster than megablox gmm with max|diff|=0 at e=64,
+    d=2048, f=1408). Returns f32 — the accumulator dtype; callers cast
+    back to the activation dtype."""
+    return jax.lax.ragged_dot(xs, w, group_sizes,
+                              preferred_element_type=jnp.float32)
 
 
 def _kernel(tg_ref, x_ref, w_ref, o_ref, acc_ref, *, nk):
@@ -220,52 +203,23 @@ def shapes_supported(x_shape, w_shape, *, block_m=DEFAULT_BLOCK_M,
     return n % bn == 0 and k % bk == 0 and bn >= 128 and bk >= 128
 
 
-@functools.lru_cache(maxsize=1)
-def _tpu_lowering_ok() -> bool:
-    """One-shot compile probe on the real backend (same rationale as
-    fused_vocab_ce/int8_matmul: degrade to the XLA path on env drift
-    instead of poisoning every downstream jit)."""
-    from ..registry import backend_kind
-    if backend_kind() != "tpu":
-        return False
-    try:
-        xs = jax.ShapeDtypeStruct((512, 256), jnp.bfloat16)
-        w = jax.ShapeDtypeStruct((4, 256, 256), jnp.bfloat16)
-        gs = jax.ShapeDtypeStruct((4,), jnp.int32)
-
-        def probe(xs, w, gs):
-            return grouped_matmul_pallas(xs, w, gs, block_m=128,
-                                         block_n=128, block_k=128)
-
-        jax.jit(probe).lower(xs, w, gs).compile()
-        return True
-    except Exception as e:  # pragma: no cover - only on env drift
-        import warnings
-        warnings.warn(f"Pallas grouped matmul failed TPU lowering; "
-                      f"falling back to XLA ragged_dot: {e}")
-        return False
-
-
 def _tpu_grouped(xs, w, group_sizes):
     """Registered TPU impl: the tile-aligned Pallas kernel when the
-    shape/env gates pass (TuneDB blocks + lowering probe), else the XLA
-    ragged_dot composition."""
+    static gates pass (TuneDB winner + blocks, shapes_supported), else
+    the XLA ragged_dot composition. A gated-in kernel compiles or the
+    jit fails — nothing here retries on another implementation."""
     from ..registry import pallas_disabled
     from ...core.flags import flag
     m, k = xs.shape
     g, _, n = w.shape
     if (pallas_disabled() or not flag("use_pallas_kernels")
-            or db_winner(m, n, k, g, xs.dtype) == "xla"
-            or not _tpu_lowering_ok()):
+            or db_winner(m, n, k, g, xs.dtype) == "xla"):
         return xla_grouped_matmul(xs, w, group_sizes)
     bm, bn, bk = tuned_blocks(m, n, k, g, xs.dtype)
     if not shapes_supported((m, k), tuple(w.shape), block_m=bm,
                             block_n=bn, block_k=bk, dtype=xs.dtype):
         return xla_grouped_matmul(xs, w, group_sizes)
-    try:
-        return _pallas_gmm(xs, w, group_sizes, bm, bn, bk, False)
-    except Exception:
-        return xla_grouped_matmul(xs, w, group_sizes)
+    return _pallas_gmm(xs, w, group_sizes, bm, bn, bk, False)
 
 
 def _register():

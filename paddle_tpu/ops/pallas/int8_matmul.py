@@ -144,36 +144,10 @@ def xla_weight_only(x, wq, scale):
     return (acc * scale).astype(x.dtype)
 
 
-@functools.lru_cache(maxsize=1)
-def _tpu_lowering_ok() -> bool:
-    """One-shot compile probe on the real backend (same rationale as
-    fused_vocab_ce: degrade to the XLA path on env drift instead of
-    poisoning every downstream jit)."""
-    from ..registry import backend_kind
-    if backend_kind() != "tpu":
-        return False
-    try:
-        x = jax.ShapeDtypeStruct((256, 512), jnp.bfloat16)
-        w = jax.ShapeDtypeStruct((256, 512), jnp.int8)
-        s = jax.ShapeDtypeStruct((256,), jnp.float32)
-
-        def probe(x, w, s):
-            return int8_matmul_pallas(x, w, s, block_m=256, block_n=256,
-                                      block_k=512)
-
-        jax.jit(probe).lower(x, w, s).compile()
-        return True
-    except Exception as e:  # pragma: no cover - only on env drift
-        import warnings
-        warnings.warn(f"Pallas int8 matmul failed TPU lowering; falling "
-                      f"back to the XLA dequant-matmul path: {e}")
-        return False
-
-
 def _tpu_weight_only(x, wq, scale):
-    """Registered TPU impl: the fused Pallas kernel when the shape/env
-    gates pass (TuneDB blocks + lowering probe, exactly the
-    fused_vocab_ce pattern), else the XLA composition."""
+    """Registered TPU impl: the fused Pallas kernel when the static
+    gates pass (TuneDB winner + blocks, shapes_supported), else the XLA
+    composition. A gated-in kernel compiles or the jit fails."""
     from ..registry import pallas_disabled
     from ...core.flags import flag
     scale = jnp.asarray(scale, jnp.float32)
@@ -183,20 +157,15 @@ def _tpu_weight_only(x, wq, scale):
         m *= d
     n = wq.shape[0]
     if (pallas_disabled() or not flag("use_pallas_kernels")
-            or scale.ndim > 1 or db_winner(m, n, k, x.dtype) == "xla"
-            or not _tpu_lowering_ok()):
+            or scale.ndim > 1 or db_winner(m, n, k, x.dtype) == "xla"):
         return xla_weight_only(x, wq, scale)
     bm, bn, bk = tuned_blocks(m, n, k, x.dtype)
     if not shapes_supported((m, k), tuple(wq.shape), block_m=bm,
                             block_n=bn, block_k=bk, dtype=x.dtype):
         return xla_weight_only(x, wq, scale)
-    try:
-        y = int8_matmul_pallas(x.reshape(m, k),
-                               wq, jnp.broadcast_to(scale.reshape(-1),
-                                                    (n,)),
-                               block_m=bm, block_n=bn, block_k=bk)
-    except Exception:
-        return xla_weight_only(x, wq, scale)
+    y = int8_matmul_pallas(x.reshape(m, k), wq,
+                           jnp.broadcast_to(scale.reshape(-1), (n,)),
+                           block_m=bm, block_n=bn, block_k=bk)
     return y.reshape(lead + (n,))
 
 

@@ -64,21 +64,33 @@ def _try_pallas_rope(q, k, cos, sin):
     EXACT table cotangents from the saved inputs (q, k, cos, sin are the
     residuals); when the tables are buffers — every model here — the
     table-grad computation and its residual use are dead and XLA's DCE
-    removes them under jit."""
+    removes them under jit. Under a device mesh the kernel runs per shard
+    (pallas/per_shard.py): batch over the data axes, heads over "tp"."""
     from .registry import backend_kind, pallas_disabled
     from ..core.flags import flag
     if (pallas_disabled() or not flag("use_pallas_kernels")
             or backend_kind() != "tpu" or q.ndim != 4):
         return None
-    from .pallas.fused_rope import (fused_rope_pallas, rope_supported,
-                                    tuned_block_s)
-    if not rope_supported(tuple(q.shape), tuple(k.shape)):
-        return None
+    from jax.sharding import PartitionSpec as P
+    from .pallas.fused_rope import rope_supported, tuned_block_s
+    from .pallas.per_shard import active_axes, per_shard, qkv_layout
     bs = tuned_block_s(q.shape[1], q.shape[3], q.dtype)
-    try:
-        return _rope_fwd_bwd(q, k, cos, sin, bs)
-    except Exception:
+    local = lambda q, k, cos, sin: _rope_fwd_bwd(q, k, cos, sin, bs)
+    act = active_axes()
+    if act is None:
+        if not rope_supported(tuple(q.shape), tuple(k.shape)):
+            return None
+        return local(q, k, cos, sin)
+    mesh, free, sizes = act
+    b_ax, h_ax, nb, nh = qkv_layout(free, sizes)
+    if q.shape[0] % nb or q.shape[2] % nh or k.shape[2] % nh:
         return None
+    cut = lambda sh: (sh[0] // nb, sh[1], sh[2] // nh, sh[3])
+    if not rope_supported(cut(q.shape), cut(k.shape)):
+        return None
+    qk, tab = P(b_ax, None, h_ax, None), P(None, None)
+    return per_shard(local, mesh, free, (qk, qk, tab, tab),
+                     (qk, qk))(q, k, cos, sin)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))

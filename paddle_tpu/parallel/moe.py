@@ -32,12 +32,9 @@ ep==1 meshes so pre-EP plans stay byte-identical). On an ep>1 mesh the
 dispatch/combine run as a shard_map'd ``lax.all_to_all`` over the "ep"
 axis in both capacity and DROPLESS variants — dropless keeps the exact
 per-expert counts as the (logical) a2a split sizes inside a statically
-bounded slot buffer, since this jax ships no ragged_all_to_all. On
-jaxlib <0.6 HYBRID meshes, where manual-subgroup collectives abort in
-the partial-manual shard_map lowering (the ring-attention gate,
-parallel/ring_attention.py), the layer falls back to pure-GSPMD
-dispatch: the dispatched [e, c, d] tensor is sharding-constrained to the
-expert axes and XLA materializes the global_scatter/global_gather
+bounded slot buffer, since this jax ships no ragged_all_to_all. On an
+ep==1 mesh the dispatched [e, c, d] tensor is sharding-constrained to
+the expert axes and XLA materializes the global_scatter/global_gather
 all-to-alls itself.
 """
 
@@ -255,8 +252,7 @@ class MoEMLP(Layer):
 def _constrain_experts(xe):
     """Shard the [e, c, d] dispatched tensor's expert dim over the
     ep×dp×fsdp submesh — this boundary is where GSPMD emits the
-    global_scatter/global_gather all-to-alls (and the whole of the
-    pure-GSPMD ep fallback on legacy jaxlib hybrid meshes)."""
+    global_scatter/global_gather all-to-alls."""
     hm = current_mesh()
     if hm is None or not isinstance(xe, jax.core.Tracer):
         return xe
@@ -278,7 +274,7 @@ def _grouped_matmul(xs, w, group_sizes):
     owns the implementation choice — the TuneDB-gated Pallas kernel on
     TPU, XLA ``lax.ragged_dot`` (the round-5 v5e A/B measured it 1.7x
     faster than megablox gmm with max|diff|=0 at e=64, d=2048, f=1408)
-    or megablox gmm elsewhere."""
+    elsewhere."""
     from ..ops.pallas.grouped_matmul import grouped_matmul
     return grouped_matmul(xs, w, group_sizes)
 
@@ -303,17 +299,6 @@ def _aux_loss_ep(probs, e):
     ce = jax.lax.pmean(
         jnp.mean(jax.nn.one_hot(top1, e, dtype=jnp.float32), axis=0), "ep")
     return jnp.sum(me * ce) * e
-
-
-def _ep_shard_map_ok(mesh) -> bool:
-    """Legacy jaxlib (< 0.6) cannot lower subgroup collectives inside a
-    partially-manual shard_map when ANOTHER mesh axis has size > 1 (the
-    ring-attention gate, parallel/ring_attention.py) — those hybrid
-    meshes take the pure-GSPMD dispatch instead."""
-    if jax.__version_info__ < (0, 6):
-        return not any(mesh.shape[a] > 1
-                       for a in mesh.axis_names if a != "ep")
-    return True
 
 
 class MoELayer(Layer):
@@ -354,13 +339,11 @@ class MoELayer(Layer):
         flat = x.reshape(t, d)
 
         # expert-parallel path: a real "ep" mesh axis routes through the
-        # shard_map'd all-to-all when the lowering supports it (pure-ep
-        # mesh, or modern jax); legacy hybrid meshes and ep==1 fall
-        # through to the GSPMD paths below
+        # shard_map'd all-to-all; ep==1 (and shapes ep does not divide)
+        # fall through to the GSPMD paths below
         hm = current_mesh()
         ep = hm.axis_size("ep") if hm is not None else 1
-        if (ep > 1 and t % ep == 0 and e % ep == 0 and (t // ep) > 0
-                and _ep_shard_map_ok(hm.mesh)):
+        if ep > 1 and t % ep == 0 and e % ep == 0 and (t // ep) > 0:
             if self.capacity_factor is None:
                 out, aux = self._forward_dropless_ep(flat, hm.mesh, ep)
             else:

@@ -232,6 +232,8 @@ def parallel_fused_linear_cross_entropy(hidden, w, labels, mesh=None,
     parallel_cross_entropy)."""
     from ..ops.pallas.fused_vocab_ce import (fused_linear_cross_entropy,
                                              lse_and_target, resolve_impl)
+    from ..ops.pallas.per_shard import (active_axes, batch_spec, per_shard,
+                                        shards)
     hm = current_mesh() if mesh is None else mesh
     if hm is None or hm.axis_size(axis) <= 1:
         return fused_linear_cross_entropy(
@@ -243,20 +245,27 @@ def parallel_fused_linear_cross_entropy(hidden, w, labels, mesh=None,
     if vocab % n_shards:
         raise ValueError(f"vocab {vocab} not divisible by {axis} degree "
                          f"{n_shards}")
+    # ONE region, manual over every mesh axis (a Mosaic kernel lowers only
+    # there — ops/pallas/per_shard.py): W's vocab over ``axis``, the rows
+    # over the data axes when they divide the batch (else every data rank
+    # holds all rows; the region's transpose still sums dW correctly)
+    mesh_, free, sizes = active_axes(hm)
+    rows = batch_spec(tuple(a for a in free if a != axis))
+    if not labels.ndim or labels.shape[0] % shards(rows, sizes):
+        rows = None
     shard_size = vocab // n_shards
     hd = hidden.shape[-1]
-    n_tok = int(np.prod(labels.shape))
+    n_tok = int(np.prod(labels.shape)) // shards(rows, sizes)
     if block_n is None or block_v is None:
         from ..ops.pallas.autotune import fused_vocab_ce_config
         tn, tv = fused_vocab_ce_config(n_tok, hd, shard_size,
                                        str(hidden.dtype))
         block_n = block_n if block_n is not None else tn
         block_v = block_v if block_v is not None else tv
-    # the block size must DIVIDE the per-shard vocab: the non-TP path pads
-    # W up to a block multiple, but a pad op inside this partial-auto
-    # manual region crashes the SPMD partitioner (IsManualSubgroup check).
-    # Fall back to one shard-sized block (== parallel_cross_entropy's
-    # per-shard working set) when nothing divides.
+    # a block size that DIVIDES the per-shard vocab spares the kernels'
+    # pad-W-to-a-block-multiple copy of the whole weight shard; one
+    # shard-sized block (== parallel_cross_entropy's per-shard working
+    # set) when nothing divides
     if shard_size % block_v:
         block_v = next((c for c in (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
                         if c <= shard_size and shard_size % c == 0),
@@ -264,16 +273,8 @@ def parallel_fused_linear_cross_entropy(hidden, w, labels, mesh=None,
     if impl is None:
         impl = resolve_impl(n_tok, hd, shard_size, hidden.dtype,
                             block_n, block_v, interpret)
-    if impl == "xla":
-        # the scan-based fallback lowers to a while loop, which the SPMD
-        # partitioner rejects inside this partial-auto manual region —
-        # unroll the (V/tp)/block_v vocab-block loop instead
-        impl = "xla_unroll"
-    batch_spec = P(*([None] * labels.ndim))
-    # each shard's vocab offset arrives as DATA (an axis-sharded [n_shards]
-    # array -> [1] per shard) instead of via lax.axis_index: the PartitionId
-    # lowering of axis_index is rejected by the SPMD partitioner when the
-    # manual region also contains the vocab-block scan
+    # each shard's vocab offset arrives as DATA: an axis-sharded [n_shards]
+    # array -> [1] per shard
     offsets = jnp.arange(n_shards, dtype=jnp.int32) * shard_size
 
     def local_fn(h_l, w_l, labels_l, off_l):
@@ -282,9 +283,8 @@ def parallel_fused_linear_cross_entropy(hidden, w, labels, mesh=None,
         valid = lab != ignore_index
         # ignored rows map below every shard's range (-1 - lo <= -1)
         local = jnp.where(valid, lab, -1) - lo
-        h2 = h_l.reshape(-1, hd)
-        lse_l, tgt_l = lse_and_target(h2, w_l, local, block_n, block_v,
-                                      impl, interpret)
+        lse_l, tgt_l = lse_and_target(h_l.reshape(-1, hd), w_l, local,
+                                      block_n, block_v, impl, interpret)
         gmax = jax.lax.stop_gradient(
             jax.lax.pmax(jax.lax.stop_gradient(lse_l), axis))
         gse = jax.lax.psum(jnp.exp(lse_l - gmax), axis)
@@ -293,11 +293,12 @@ def parallel_fused_linear_cross_entropy(hidden, w, labels, mesh=None,
         nll = jnp.where(valid, lse - tgt, 0.0)
         return nll.reshape(labels_l.shape)
 
-    fn = shard_map(
-        local_fn, mesh=hm.mesh, axis_names=frozenset({axis}),
-        in_specs=(P(*([None] * hidden.ndim)), P(None, axis), batch_spec,
-                  P(axis)),
-        out_specs=batch_spec)
+    lab_spec = P(rows, *([None] * (labels.ndim - 1)))
+    fn = per_shard(
+        local_fn, mesh_, free,
+        (P(rows, *([None] * (hidden.ndim - 1))), P(None, axis), lab_spec,
+         P(axis)),
+        lab_spec)
     return fn(hidden, w, labels, offsets)
 
 

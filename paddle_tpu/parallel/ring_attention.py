@@ -305,21 +305,6 @@ def ring_attention(q, k, v, causal: bool = True, axis: str = "sep",
     blocks = _flash_blocks_ok(sl, h, h_kv, d, has_seg=has_seg,
                               interpret=interpret)
 
-    # Legacy jaxlib (< 0.6) cannot lower collective-permute inside a
-    # partially-manual shard_map when ANOTHER mesh axis has size > 1
-    # (hlo_sharding_util manual-subgroup check aborts; all-reduce-style
-    # collectives are fine, which is why the tp paths work). On those
-    # builds a hybrid mesh falls back to pure GSPMD: q stays
-    # seq-sharded, XLA all-gathers K/V over the ring axis — the
-    # Megatron-SP communication pattern, exact numerics, no manual
-    # lowering. Modern jax (and any single-manual-axis mesh) keeps the
-    # real ring.
-    if jax.__version_info__ < (0, 6) and any(
-            mesh_.shape[a] > 1 for a in mesh_.axis_names if a != axis):
-        from ..ops.attention import _sdpa_xla
-        return _sdpa_xla(q, k, v, causal=causal, scale=scale,
-                         segment_ids=segment_ids)
-
     # each device's ring index as DATA (its [1, 1] shard of a [1, n]
     # arange over the ring axis): see _ring_flash's docstring for why
     # axis_index can't be used here. Rank 2 deliberately — a rank-1

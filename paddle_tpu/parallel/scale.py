@@ -26,12 +26,7 @@ _OPT_BYTES_PER_PARAM = 12  # AdamW fp32 master + m + v
 def abstract_mesh(axes: Dict[str, int]) -> AbstractMesh:
     """AbstractMesh from {'pp': 4, 'fsdp': 2, 'tp': 8} — no devices needed."""
     names = tuple(axes.keys())
-    sizes = tuple(int(axes[n]) for n in names)
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        # jax<0.6 spells it AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, sizes)))
+    return AbstractMesh(tuple(int(axes[n]) for n in names), names)
 
 
 def clean_spec(sharding: Optional[Tuple], axes: Dict[str, int]) -> PartitionSpec:

@@ -90,16 +90,6 @@ def ulysses_attention(q, k, v, causal: bool = True, axis: str = "sep",
         return _sdpa_xla(q, k, v, causal=causal, scale=scale)
 
     n = hm.axis_size(axis)
-    # Legacy jaxlib (< 0.6) aborts lowering all-to-all inside a
-    # partially-manual shard_map when another mesh axis has size > 1 —
-    # same manual-subgroup limitation as ring_attention's ppermute (see
-    # the comment there). Fall back to pure GSPMD on those builds: q
-    # stays seq-sharded, XLA gathers K/V over the axis.
-    if jax.__version_info__ < (0, 6) and any(
-            hm.mesh.shape[a] > 1 for a in hm.mesh.axis_names
-            if a != axis):
-        from ..ops.attention import _sdpa_xla
-        return _sdpa_xla(q, k, v, causal=causal, scale=scale)
     h, h_kv = q.shape[2], k.shape[2]
     if not ulysses_supported(h, h_kv, n):
         raise ValueError(

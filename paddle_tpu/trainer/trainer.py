@@ -53,7 +53,10 @@ from ..profiler import RecordEvent
 # neither is attached — same contract as SERVING_EVENTS)
 TRAINER_EVENTS = ("trainer::dispatch", "trainer::checkpoint")
 
-# bf16 peak TFLOP/s per chip
+# bf16 peak FLOP/s per chip, keyed by the (lower-cased) ``device_kind``
+# jax reports — a v5e chip reports "TPU v5 lite". Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s) and
+# the sibling per-generation pages.
 PEAK_FLOPS = {
     "tpu v4": 275e12,
     "tpu v5 lite": 197e12,   # v5e
@@ -61,17 +64,32 @@ PEAK_FLOPS = {
     "tpu v5": 459e12,        # v5p
     "tpu v5p": 459e12,
     "tpu v6 lite": 918e12,   # v6e (trillium)
-    "cpu": 1e12,             # nominal, for smoke runs
+    "cpu": 1e12,             # NOMINAL: keeps the MFU gauge defined in CPU
+    #                          tests; never a measurement of anything
+}
+# HBM per chip, same keys and source: capacity (bytes) and bandwidth
+# (bytes/s)
+PEAK_HBM = {
+    "tpu v5 lite": {"bytes": 16e9, "bytes_per_s": 819e9},
 }
 
 
+def peak_lookup(table: dict, kind: str):
+    """The entry of ``table`` whose key is the longest substring of the
+    lower-cased ``kind`` ("tpu v5 lite" beats "tpu v5"); LookupError for a
+    device the table does not hold — an unknown device is an error, never
+    a default to divide by."""
+    kind = kind.lower()
+    keys = [k for k in table if k in kind]
+    if not keys:
+        raise LookupError(
+            f"device kind {kind!r} is not in the peaks table "
+            f"({sorted(table)}); add it with its source")
+    return table[max(keys, key=len)]
+
+
 def device_peak_flops() -> float:
-    d = jax.devices()[0]
-    kind = getattr(d, "device_kind", "cpu").lower()
-    for k, v in PEAK_FLOPS.items():
-        if k in kind:
-            return v
-    return PEAK_FLOPS.get(d.platform, 1e12)
+    return peak_lookup(PEAK_FLOPS, jax.devices()[0].device_kind)
 
 
 @dataclass

@@ -1,0 +1,234 @@
+"""Main-path kernels compile for the chip at real widths — without the chip.
+
+The installed TPU compiler compiles for a DESCRIBED ``v5e:2x2`` topology
+(``on-chip-measurement`` guide, section 2.3). Interpret-mode tests cannot see
+what it refuses: a scoped-VMEM overrun (the fused vocab-CE backward asked for
+19.55 MiB against a 16 MiB default at Llama-3 widths), a tile that does not
+align, a Mosaic call GSPMD was asked to partition. One parametrised case per
+kernel and shape of the trainer's and the serving engine's compiled programs;
+nothing runs, so this says nothing about results or times.
+
+The file name sorts first on purpose: a guard the suite's clock never reaches
+guards nothing. Code that asks the backend sees the CPU here, so the mesh
+cases steer it in the test (``backend_kind``, ``_device_kind``) rather than
+through an option of the program.
+"""
+
+import json
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import autotune
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+KIND = "TPU v5 lite"                    # what a v5e chip reports
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:        # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache out of it
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _grad_sum(fn, argnums):
+    """fwd+bwd of ``fn``: gradient of the f32 sum of its outputs."""
+    def loss(*a):
+        return sum(jnp.sum(o.astype(F32)) for o in jax.tree.leaves(fn(*a)))
+    return jax.grad(loss, argnums=argnums)
+
+
+# -- one chip ----------------------------------------------------------------
+# each case: () -> (fn, [(shape, dtype), ...])
+
+def _flash(s, bq, bk, causal=True, d=128, h=32, h_kv=8, seg=False):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_pallas
+
+    def fn(q, k, v, *ids):
+        return flash_attention_pallas(
+            q, k, v, causal=causal, block_q=bq, block_k=bk,
+            segment_ids=ids[0] if ids else None)
+    args = [((1, s, h, d), BF16), ((1, s, h_kv, d), BF16),
+            ((1, s, h_kv, d), BF16)] + ([((1, s), I32)] if seg else [])
+    return _grad_sum(fn, (0, 1, 2)), args
+
+
+def _tuned_flash_cases():
+    """Every flash-attention entry of the shipped tune DB, at its blocks."""
+    with open(autotune._SHIPPED) as f:
+        db = json.load(f)
+    for key, cfg in sorted(db.items()):
+        if not key.startswith("flash_attention|"):
+            continue
+        dims = dict(kv.split("=") for kv in key.split("|")[3].split(","))
+        yield pytest.param(
+            lambda dims=dims, cfg=cfg: _flash(
+                int(dims["sq"]), cfg["block_q"], cfg["block_k"],
+                causal=bool(int(dims["causal"])), d=int(dims["d"]),
+                h=32 if dims["d"] == "128" else 16,
+                h_kv=8 if dims["d"] == "128" else 16),
+            id="flash_tuned[%s]" % key.split("|")[3])
+
+
+def _paged(page, dtype):
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+    pages, per_seq = 8 * (2048 // page) + 1, 2048 // page
+    quant = dtype == I8
+
+    def fn(q, kp, vp, tables, lens, *scales):
+        kw = dict(k_scales=scales[0], v_scales=scales[1]) if quant else {}
+        return paged_decode_attention(q, kp, vp, tables, lens, **kw)
+    pool = ((8, pages, page, 128), dtype)
+    args = [((8, 32, 128), BF16), pool, pool, ((8, per_seq), I32),
+            ((8,), I32)] + ([((pages,), F32)] * 2 if quant else [])
+    return fn, args
+
+
+def _rms_norm():
+    from paddle_tpu.ops.pallas.fused_norm import rms_norm_pallas
+    return (_grad_sum(lambda x, w: rms_norm_pallas(x, w, 1e-5), (0, 1)),
+            [((8, 2048, 4096), BF16), ((4096,), F32)])
+
+
+def _rope():
+    from paddle_tpu.ops.pallas.fused_rope import fused_rope_pallas
+    return (lambda q, k, c, s: fused_rope_pallas(q, k, c, s, block_s=128),
+            [((8, 2048, 32, 128), BF16), ((8, 2048, 8, 128), BF16),
+             ((2048, 128), F32), ((2048, 128), F32)])
+
+
+def _fused_ce(n, h, v):
+    """fwd+bwd with the blocks the chooser picks on a v5e (no DB entry for
+    this op: the VMEM-fitting defaults) — the train step's loss head."""
+    from paddle_tpu.ops.pallas.fused_vocab_ce import (fused_ce_supported,
+                                                      lse_and_target)
+    bn, bv = autotune.fused_vocab_ce_config(n, h, v, "bfloat16")
+    assert fused_ce_supported(n, h, v, BF16, bn, bv), (bn, bv)
+    assert bv >= 256, f"blocks shrunk until the kernel is pointless: {bn, bv}"
+
+    def fn(hid, w, lab):
+        return lse_and_target(hid, w, lab, bn, bv, "pallas", False)
+    return _grad_sum(fn, (0, 1)), [((n, h), BF16), ((h, v), BF16),
+                                   ((n,), I32)]
+
+
+def _grouped_matmul():
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
+    return grouped_matmul_pallas, [((32768, 2048), BF16),
+                                   ((64, 2048, 1024), BF16), ((64,), I32)]
+
+
+def _int8_matmul():
+    from paddle_tpu.ops.pallas.int8_matmul import int8_matmul_pallas
+    return int8_matmul_pallas, [((8, 4096), BF16), ((14336, 4096), I8),
+                                ((14336,), F32)]
+
+
+ONE_CHIP = [
+    pytest.param(lambda: _flash(2048, 128, 128), id="flash[s2048,128/128]"),
+    pytest.param(lambda: _flash(8192, 128, 128), id="flash[s8192,128/128]"),
+    pytest.param(lambda: _flash(2048, 512, 1024, seg=True),
+                 id="flash_segment_ids[s2048,512/1024]"),
+    pytest.param(lambda: _flash(8192, 1024, 1024, seg=True),
+                 id="flash_segment_ids[s8192,1024/1024]"),
+    *_tuned_flash_cases(),
+    pytest.param(lambda: _paged(128, BF16), id="paged_decode[bf16,page128]"),
+    pytest.param(lambda: _paged(16, BF16), id="paged_decode[bf16,page16]"),
+    pytest.param(lambda: _paged(128, I8), id="paged_decode[int8,page128]"),
+    pytest.param(_rms_norm, id="rms_norm[D4096]"),
+    pytest.param(_rope, id="rope[s2048,32/8]"),
+    pytest.param(lambda: _fused_ce(16384, 4096, 128256),
+                 id="fused_ce[16384x4096x128256]"),
+    pytest.param(lambda: _fused_ce(16384, 1536, 32000),
+                 id="fused_ce[16384x1536x32000]"),
+    pytest.param(_grouped_matmul, id="grouped_matmul[32768x2048,64x1024]"),
+    pytest.param(_int8_matmul, id="int8_matmul[8x4096x14336]"),
+]
+
+
+@pytest.mark.parametrize("case", ONE_CHIP)
+def test_kernel_compiles_for_v5e(topo, case, monkeypatch):
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    fn, shapes = case()
+    dev = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=dev) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+# -- the 2x2 mesh ------------------------------------------------------------
+# the kernels as the MODEL reaches them under a mesh: through dispatch and
+# per_shard (GSPMD cannot partition a Mosaic call), for both four-chip
+# layouts of chip_smoke.py --chips 4
+
+def _mesh_norm_rope_attention(hm):
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.ops import rope as rope_ops
+    act = NamedSharding(hm.mesh, P(("dp", "fsdp"), None, None))
+    rep = NamedSharding(hm.mesh, P())
+
+    def fn(x, w, wq, cos, sin):
+        h = F.rms_norm(x, w, 1e-5)
+        qkv = (h @ wq).reshape(4, 2048, 48, 128)
+        q, k, v = jnp.split(qkv, [32, 40], axis=2)
+        q, k = rope_ops.apply_rotary_pos_emb(q, k, cos, sin)
+        return F.scaled_dot_product_attention(q, k, v, is_causal=True,
+                                              training=False)
+    args = [jax.ShapeDtypeStruct((4, 2048, 4096), BF16, sharding=act),
+            jax.ShapeDtypeStruct((4096,), F32, sharding=rep),
+            jax.ShapeDtypeStruct((4096, 6144), BF16, sharding=NamedSharding(
+                hm.mesh, P("fsdp", "tp"))),
+            jax.ShapeDtypeStruct((2048, 128), F32, sharding=rep),
+            jax.ShapeDtypeStruct((2048, 128), F32, sharding=rep)]
+    return _grad_sum(fn, (0, 1, 2)), args, 4     # norm + rope + flash, f+b
+
+
+def _mesh_loss_head(hm):
+    """The fused loss head: vocab-parallel over tp where the mesh has one
+    (parallel_fused_linear_cross_entropy), data-parallel otherwise."""
+    from paddle_tpu.models.llama import fused_causal_lm_loss
+    args = [jax.ShapeDtypeStruct((4, 4096, 4096), BF16, sharding=NamedSharding(
+                hm.mesh, P(("dp", "fsdp"), None, None))),
+            jax.ShapeDtypeStruct((4096, 128256), BF16, sharding=NamedSharding(
+                hm.mesh, P("fsdp", "tp"))),
+            jax.ShapeDtypeStruct((4, 4096), I32, sharding=NamedSharding(
+                hm.mesh, P(("dp", "fsdp"), None)))]
+    return jax.grad(fused_causal_lm_loss, argnums=(0, 1)), args, 3
+
+
+@pytest.mark.parametrize("layout", [dict(fsdp=4), dict(fsdp=2, tp=2)],
+                         ids=["fsdp4", "fsdp2_tp2"])
+@pytest.mark.parametrize("build", [_mesh_norm_rope_attention,
+                                   _mesh_loss_head],
+                         ids=["norm_rope_attention", "fused_loss_head"])
+def test_kernels_compile_on_the_2x2_mesh(topo, layout, build, monkeypatch):
+    from paddle_tpu.ops import registry
+    from paddle_tpu.parallel import HybridMesh
+    monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    hm = HybridMesh.build(devices=topo.devices, **layout)
+    with hm:
+        fn, args, at_least = build(hm)
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= at_least
+    if hm.axis_size("tp") > 1 and build is _mesh_loss_head:
+        assert "all-reduce" in text      # the lse/target combine over tp

@@ -28,41 +28,38 @@ def test_key_buckets_seq_dims_only():
     assert k3 != k1  # d is not a seq dim: kept exact
 
 
-def test_record_save_load_roundtrip(tmp_path, monkeypatch):
+def test_record_save_load_roundtrip(tmp_path):
     path = str(tmp_path / "db.json")
-    monkeypatch.setenv("PT_TUNE_DB", path)
-    db = TuneDB()
+    db = TuneDB(path)
     key = TuneDB.key("flash_attention", "TPU v5e", "bfloat16",
                      sq=2048, sk=2048, d=128, causal=1)
     db.record(key, {"block_q": 256, "block_k": 512, "us": 123.4})
     db.save()
-    fresh = TuneDB()
+    fresh = TuneDB(path)
     hit = fresh.lookup(key)
     assert hit == {"block_q": 256, "block_k": 512, "us": 123.4}
     # merge-over: a second save with a different key keeps the first
-    db2 = TuneDB()
+    db2 = TuneDB(path)
     db2.record("other|key", {"block_q": 128, "block_k": 128})
     db2.save()
     data = json.load(open(path))
     assert key in data and "other|key" in data
 
 
-def test_corrupt_user_db_warns_with_path(tmp_path, monkeypatch):
-    """Satellite (ISSUE 2): a corrupt user DB must not silently merge
+def test_corrupt_db_warns_with_path(tmp_path):
+    """Satellite (ISSUE 2): a corrupt DB file must not silently load
     nothing — offline-tuned configs vanishing without a trace. One warning
-    naming the path, then lookups proceed on the shipped DB."""
+    naming the path."""
     import warnings
 
     path = str(tmp_path / "corrupt.json")
     with open(path, "w") as f:
         f.write("{not valid json")
-    monkeypatch.setenv("PT_TUNE_DB", path)
-    db = TuneDB()
+    db = TuneDB(path)
     with pytest.warns(RuntimeWarning, match="corrupt kernel tune DB"):
         db.lookup("whatever|key")
-    # a MISSING user DB stays silent (the common no-sweep-yet case)
-    monkeypatch.setenv("PT_TUNE_DB", str(tmp_path / "absent.json"))
-    fresh = TuneDB()
+    # a MISSING DB stays silent (the common no-sweep-yet case)
+    fresh = TuneDB(str(tmp_path / "absent.json"))
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         fresh.lookup("whatever|key")
@@ -74,12 +71,11 @@ def test_dispatch_uses_db_on_tpu(monkeypatch, tmp_path):
     from paddle_tpu.ops import registry
 
     path = str(tmp_path / "db.json")
-    monkeypatch.setenv("PT_TUNE_DB", path)
     key = TuneDB.key("flash_attention", "TPU v5e", "bfloat16",
                      sq=4096, sk=4096, d=128, causal=1)
     json.dump({key: {"block_q": 512, "block_k": 256}}, open(path, "w"))
 
-    fresh = TuneDB()
+    fresh = TuneDB(path)
     monkeypatch.setattr(autotune, "_DB", fresh)
     monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
 
@@ -141,7 +137,6 @@ def test_shipped_db_nonempty_and_consulted(monkeypatch):
                      sq=2048, sk=2048, d=128, causal=1)
     assert key in shipped, f"bench-shape key missing: {key}"
 
-    monkeypatch.setenv("PT_TUNE_DB", "/nonexistent/overlay.json")
     fresh = TuneDB()
     monkeypatch.setattr(autotune, "_DB", fresh)
     monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")

@@ -167,11 +167,11 @@ def test_predicted_vs_measured_ratio_k1_vs_k4():
 
 def test_opcostdb_roundtrip_and_reload_hits(tmp_path):
     path = str(tmp_path / "op_cost_db.json")
-    db = costs.OpCostDB(user_path=path)
+    db = costs.OpCostDB(path)
     key = costs.OpCostDB.graph_key("train_step_k1", "cpu")
     db.record(key, {"t_s": 0.005, "flops": 5.1e7})
     db.save()
-    fresh = costs.OpCostDB(user_path=path)
+    fresh = costs.OpCostDB(path)
     hit = fresh.lookup(key)
     assert hit is not None and hit["flops"] == 5.1e7
     # dot keys carry exact (unbucketed) shape dims
@@ -185,7 +185,7 @@ def test_opcostdb_corrupt_file_warns_like_tunedb(tmp_path):
     path = str(tmp_path / "corrupt_cost.json")
     with open(path, "w") as f:
         f.write("{not json")
-    db = costs.OpCostDB(user_path=path)
+    db = costs.OpCostDB(path)
     with pytest.warns(RuntimeWarning, match="corrupt op cost DB"):
         assert db.lookup("anything") is None
 

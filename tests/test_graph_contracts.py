@@ -229,16 +229,18 @@ def test_budget_floor_catches_donation_drop():
 # -- collective census -------------------------------------------------------
 
 def test_collective_census_tp_fused_ce():
-    """dp=2 x tp=2: the TP fused CE emits exactly one pmax + two psum
-    all-reduces over the tp axis and ZERO all-gathers — the implicit-
-    reshard regression the census exists to catch."""
+    """dp=2 x tp=2: the TP fused CE emits exactly one pmax + its two psums
+    over the tp axis and ZERO all-gathers — the implicit-reshard regression
+    the census exists to catch. The two psums (exp-sum and target logit,
+    each f32[16] per device) travel in ONE combined all-reduce on jax
+    0.9.0: two all-reduces in all, the psum one carrying both payloads."""
     g = A.build_graph("tp_fused_ce")
     rep = A.analyze(g.compiled, g.name, g.contract, mesh=g.mesh)
     assert A.check_contract(g.contract, rep) == []
-    assert rep.collectives["counts"] == {"all-reduce[tp]": 3}
-    ops = [c.op_name for c in rep.collectives["table"]]
-    assert sum("pmax" in o for o in ops) == 1
-    assert sum("psum" in o for o in ops) == 2
+    assert rep.collectives["counts"] == {"all-reduce[tp]": 2}
+    by_op = {("pmax" if "pmax" in c.op_name else "psum"): c.bytes
+             for c in rep.collectives["table"]}
+    assert by_op == {"pmax": 64, "psum": 128}
     # every collective classified to the tp axis, none over dp
     assert all(c.axis == "tp" for c in rep.collectives["table"])
     assert rep.collectives["bytes_by_op"].get("all-gather", 0) == 0

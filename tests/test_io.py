@@ -130,3 +130,32 @@ def test_collate_nested():
     assert out[0][0].shape == (4, 2)
     assert out[0][1].shape == (4,)
     assert out[1]["a"].shape == (4, 3)
+
+
+class _BackendProbeDataset:
+    """Each item reports which jax backend the process serving it sees."""
+
+    def __len__(self):
+        return 4
+
+    def __getitem__(self, i):
+        import jax
+        return np.asarray([i, jax.default_backend() == "cpu"], np.int64)
+
+
+@pytest.mark.parametrize("shm", [False, True])
+def test_worker_process_pinned_to_cpu(monkeypatch, shm):
+    """A chip belongs to one process: a loader worker process must come up
+    on the CPU platform even when its environment names the TPU (here the
+    variable is flipped for the children only — the parent's jax read it at
+    import). Unpinned, the child would die trying to initialise 'tpu'."""
+    from paddle_tpu import native
+    from paddle_tpu.io import DataLoader
+    if shm and not native.is_available():
+        pytest.skip("native shm ring not built")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    dl = DataLoader(_BackendProbeDataset(), batch_size=2, num_workers=1,
+                    multiprocessing_context="spawn", use_shared_memory=shm)
+    rows = np.concatenate(list(dl))
+    assert rows[:, 0].tolist() == [0, 1, 2, 3]
+    assert rows[:, 1].all(), "a worker process initialised a non-CPU backend"

@@ -17,8 +17,8 @@ Contract under test:
   no-op;
 * ``Trainer.fit`` ticks the installed sentry at log boundaries (the real
   wiring, not a hand call);
-* bench gate: r04-vs-r05 (tpu vs cpu) compares NOTHING and passes as
-  incomparable; baseline-vs-r05 (same backend) passes; a synthetically
+* bench gate: r04-vs-r06 (tpu vs cpu) compares NOTHING and passes as
+  incomparable; baseline-vs-r06 (same backend) passes; a synthetically
   degraded copy exits nonzero NAMING the scaled metric; the checked-in
   ``tools/bench_baseline.json`` matches what pinning the newest artifact
   produces.
@@ -628,15 +628,15 @@ def _bench_diff_main(argv):
         sys.path.pop(0)
 
 
-def test_r04_vs_r05_incomparable_backends_pass():
+def test_r04_vs_r06_incomparable_backends_pass():
     r04 = os.path.join(REPO, "BENCH_r04.json")
-    r05 = os.path.join(REPO, "BENCH_r05.json")
-    diff = bl.diff_records(bl.load_record(r04), bl.load_record(r05))
+    r06 = os.path.join(REPO, "BENCH_r06.json")
+    diff = bl.diff_records(bl.load_record(r04), bl.load_record(r06))
     assert diff.verdict() == "incomparable"
     assert diff.compared == 0
     assert diff.ok                          # no EVIDENCE of regression
     assert "backend mismatch" in diff.note
-    assert _bench_diff_main([r04, r05, "--quiet"]) == 0
+    assert _bench_diff_main([r04, r06, "--quiet"]) == 0
 
 
 def test_unknown_backend_never_bypasses_the_guard():
@@ -655,28 +655,28 @@ def test_unknown_backend_never_bypasses_the_guard():
         assert "backend unknown" in diff.note
 
 
-def test_baseline_vs_r05_no_regression():
+def test_baseline_vs_r06_no_regression():
     base = os.path.join(REPO, "tools", "bench_baseline.json")
-    r05 = os.path.join(REPO, "BENCH_r05.json")
-    diff = bl.diff_records(bl.load_record(base), bl.load_record(r05))
+    r06 = os.path.join(REPO, "BENCH_r06.json")
+    diff = bl.diff_records(bl.load_record(base), bl.load_record(r06))
     assert diff.verdict() == "ok"
     assert diff.compared >= 4
     assert diff.regressions == []
-    assert _bench_diff_main([base, r05, "--quiet"]) == 0
+    assert _bench_diff_main([base, r06, "--quiet"]) == 0
 
 
 def test_degraded_copy_exits_nonzero_naming_metric(tmp_path, capsys):
-    r05 = os.path.join(REPO, "BENCH_r05.json")
-    with open(r05) as f:
+    r06 = os.path.join(REPO, "BENCH_r06.json")
+    with open(r06) as f:
         d = json.load(f)
     d["parsed"]["detail"]["mfu"] *= 0.5     # past any 25% band
     degraded = str(tmp_path / "degraded.json")
     with open(degraded, "w") as f:
         json.dump(d, f)
-    diff = bl.diff_records(bl.load_record(r05), bl.load_record(degraded))
+    diff = bl.diff_records(bl.load_record(r06), bl.load_record(degraded))
     assert diff.verdict() == "regressed"
     assert diff.regressions == ["mfu"]
-    rc = _bench_diff_main([r05, degraded, "--quiet"])
+    rc = _bench_diff_main([r06, degraded, "--quiet"])
     assert rc == 1
     err = capsys.readouterr().err
     assert "mfu" in err                     # names the metric
@@ -766,7 +766,7 @@ def test_pin_roundtrip_and_band_override(tmp_path):
         pinned = json.load(f)
     assert pinned["backend"] == "tpu"
     assert pinned["metrics"]["mfu"] == pytest.approx(0.625, abs=0.01)
-    # a tiny --band makes r05's jitter-free self-diff still pass
+    # a tiny --band makes r04's jitter-free self-diff still pass
     rc = _bench_diff_main([out, os.path.join(REPO, "BENCH_r04.json"),
                            "--band", "0.001", "--quiet"])
     assert rc == 0

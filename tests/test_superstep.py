@@ -550,26 +550,6 @@ def test_aot_resume_preserves_donation(tmp_path):
     assert all(v.is_deleted() for v in before.values())
 
 
-def test_compile_cache_env_wiring_noop_when_unset(monkeypatch):
-    """CI guard (satellite): with no cache dir configured the wiring is a
-    strict no-op — jax config untouched, returns False."""
-    monkeypatch.delenv("PT_COMPILE_CACHE_DIR", raising=False)
-    before = jax.config.jax_compilation_cache_dir
-    assert compile_cache.configure_compilation_cache() is False
-    assert jax.config.jax_compilation_cache_dir == before
-
-
-def test_compile_cache_env_wiring_applies_when_set(tmp_path, monkeypatch):
-    before = jax.config.jax_compilation_cache_dir
-    try:
-        monkeypatch.setenv("PT_COMPILE_CACHE_DIR", str(tmp_path))
-        assert compile_cache.configure_compilation_cache() is True
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
-        compile_cache._PERSISTENT_DIR = None
-
-
 # -- satellites: key/LR hygiene, functional schedulers, stacking --------------
 
 def test_lr_scalar_transferred_only_on_change():

@@ -11,7 +11,7 @@ ratio is evidence would be worse than silence).
 Usage::
 
     # diff two artifacts (driver round files or raw bench payloads)
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_diff.py BENCH_r04.json BENCH_r06.json
 
     # gate a candidate against the checked-in pinned baseline
     python tools/bench_diff.py tools/bench_baseline.json new_round.json

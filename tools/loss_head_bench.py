@@ -44,7 +44,7 @@ def run_loss_head_bench(n=4096, h=512, v=32000, dtype="bfloat16",
     from paddle_tpu.nn import functional as F
     from paddle_tpu.ops.pallas.fused_vocab_ce import (
         fused_linear_cross_entropy)
-    from paddle_tpu.utils.hw_probe import force_host_sync as _sync
+    _sync = jax.block_until_ready
 
     dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
     rs = np.random.RandomState(0)
@@ -101,11 +101,7 @@ def main():
     ap.add_argument("--iters", type=int, default=2)
     ap.add_argument("--step-time-s", type=float, default=None,
                     help="full train-step time to compute loss_head_share")
-    ap.add_argument("--force-cpu", action="store_true")
     args = ap.parse_args()
-    if args.force_cpu:
-        from paddle_tpu.utils.hw_probe import force_cpu
-        force_cpu()
     out = run_loss_head_bench(args.n, args.h, args.v, args.dtype,
                               args.rounds, args.iters, args.step_time_s)
     print(json.dumps(out))

@@ -284,7 +284,7 @@ def _cost_leg(out_dir: str, errors: list) -> dict:
                     db_path=db_path)
     if not cal["recorded"]:
         errors.append("op_cost_probe recorded nothing")
-    fresh = OpCostDB(user_path=db_path)
+    fresh = OpCostDB(db_path)
     for key in cal["recorded"]:
         if fresh.lookup(key) is None:
             errors.append(f"OpCostDB reload missed {key}")

@@ -213,7 +213,7 @@ def calibrate(graphs=None, rounds: int = 3, iters: int = 4,
     keys, so callers can assert reload hits)."""
     from paddle_tpu.observability import costs
 
-    db = costs.OpCostDB(user_path=db_path) if db_path \
+    db = costs.OpCostDB(db_path) if db_path \
         else costs.get_op_cost_db()
     spec = costs.device_spec()
     measured = measure_graphs(graphs, rounds=rounds, iters=iters,
@@ -251,7 +251,7 @@ def calibrate(graphs=None, rounds: int = 3, iters: int = 4,
 
     if save:
         db.save()
-    return {"db_path": db.user_path(), "recorded": recorded,
+    return {"db_path": db.path, "recorded": recorded,
             "graphs": measured, "device_kind": spec.kind}
 
 
@@ -265,8 +265,8 @@ def main(argv=None) -> dict:
     ap.add_argument("--rounds", type=int, default=3)
     ap.add_argument("--iters", type=int, default=4)
     ap.add_argument("--db", default=None,
-                    help="OpCostDB path (default: PT_OP_COST_DB or "
-                         "~/.cache/paddle_tpu/op_cost_db.json)")
+                    help="OpCostDB path (default: op_cost_db.json "
+                         "beside the shipped tune DB)")
     args = ap.parse_args(argv)
     graphs = ([g.strip() for g in args.graphs.split(",") if g.strip()]
               if args.graphs else None)
