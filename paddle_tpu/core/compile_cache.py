@@ -34,6 +34,7 @@ contents, devices' wall clock) must not.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -44,11 +45,12 @@ from typing import Any, Dict, Optional, Tuple
 
 from ..observability.goodput import ledger as _goodput_ledger
 from ..observability.metrics import REGISTRY as _REG
+from ..profiler import RecordEvent
 
 __all__ = [
-    "acquire", "aval_signature", "fingerprint", "configure_compilation_cache",
-    "save_aot", "load_aot", "stats", "reset_stats", "clear", "note_trace",
-    "explain_fingerprint_change",
+    "acquire", "building", "aval_signature", "fingerprint",
+    "configure_compilation_cache", "save_aot", "load_aot", "stats",
+    "reset_stats", "clear", "note_trace", "explain_fingerprint_change",
 ]
 
 _LOCK = threading.Lock()
@@ -197,10 +199,37 @@ def _store(fp: str, fn) -> None:
             _EXECUTABLES.popitem(last=False)
 
 
+@contextlib.contextmanager
+def building(name: str, log: Optional[list] = None, **attrs):
+    """One program's build — trace + lower + compile, or the read from
+    jax's persistent cache — as a ``compile::<name>`` span (``attrs`` ride
+    on it) and, once it succeeds, one row appended to ``log``: ``name``,
+    ``t_s`` (``perf_counter`` at the start), ``seconds`` and ``cache``:
+    ``hit`` when jax read every executable of the build from its
+    persistent cache, ``miss`` when the backend compiled one, ``uncached``
+    when no persistent cache is in use. The engine's and the trainer's
+    ``build_log`` are such lists: which program compiled, when, for how
+    long."""
+    _listen()
+    with _LOCK:
+        hits, misses = _STATS["persistent_hits"], _STATS["persistent_misses"]
+    t0 = time.perf_counter()
+    with RecordEvent("compile::" + name, **attrs):
+        yield
+    seconds = time.perf_counter() - t0
+    if log is not None:
+        with _LOCK:
+            cache = ("miss" if _STATS["persistent_misses"] > misses else
+                     "hit" if _STATS["persistent_hits"] > hits else
+                     "uncached")
+        log.append(dict(attrs, name=name, t_s=t0, seconds=seconds,
+                        cache=cache))
+
+
 def acquire(fp: str, jitted, args, *, aot_dir: Optional[str] = None,
             name: str = "step", save_artifact: bool = False,
             donate_argnums: Tuple[int, ...] = (),
-            fp_parts=None):
+            fp_parts=None, build_log: Optional[list] = None):
     """Return ``(callable, outcome)`` for fingerprint ``fp``.
 
     Lookup order: in-process executable ("hit") → serialized AOT artifact
@@ -218,6 +247,11 @@ def acquire(fp: str, jitted, args, *, aot_dir: Optional[str] = None,
     rejection diffed against the stored parts so the log says WHICH key
     drifted (model scalar, env escape, aval signature) instead of just
     "fingerprint mismatch".
+
+    Whatever is not an in-process hit is a build: it runs inside
+    :func:`building` under the jitted function's own name (the name the
+    device trace prints after ``jit_``) and leaves its row in ``build_log``,
+    with ``how``: ``compile`` (lower + compile) or ``aot_artifact``.
     """
     import jax
 
@@ -239,18 +273,24 @@ def acquire(fp: str, jitted, args, *, aot_dir: Optional[str] = None,
             except Exception:
                 pass
         return hit, "hit"
+    program = getattr(jitted, "__name__", name)
     if aot_dir:
-        with _goodput_ledger().span("compile"):
+        row: list = []              # kept only if an artifact was read
+        with building(program, row, how="aot_artifact"), \
+                _goodput_ledger().span("compile"):
             fn = load_aot(aot_dir, name, fp, donate_argnums=donate_argnums,
                           expect_parts=fp_parts)
         if fn is not None:
             _store(fp, fn)
             with _LOCK:
                 _STATS["aot_hits"] += 1
+            if build_log is not None:
+                build_log.extend(row)
             return fn, "aot_hit"
     try:
         t0 = time.perf_counter()
-        with _goodput_ledger().span("compile"):
+        with building(program, build_log, how="compile"), \
+                _goodput_ledger().span("compile"):
             fn = jitted.lower(*args).compile()
         if _REG.enabled:
             _REG.histogram("pt_compile_seconds",
@@ -400,6 +440,15 @@ def _count_persistent(event: str, **_kw) -> None:
             _STATS[key] += 1
 
 
+def _listen() -> None:
+    """Count jax's persistent-cache hits and misses from here on."""
+    global _listening
+    if not _listening:
+        import jax
+        jax.monitoring.register_event_listener(_count_persistent)
+        _listening = True
+
+
 def configure_compilation_cache() -> str:
     """Turn on jax's persistent compilation cache; returns its directory.
 
@@ -410,15 +459,13 @@ def configure_compilation_cache() -> str:
     the chip) before its first compile — never at import, so tests and
     library users keep jax's own default. ``stats()`` then reports the
     directory and the persistent hits/misses jax counts."""
-    global _PERSISTENT_DIR, _listening
+    global _PERSISTENT_DIR
     import jax
 
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = DEFAULT_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-    if not _listening:
-        jax.monitoring.register_event_listener(_count_persistent)
-        _listening = True
+    _listen()
     _PERSISTENT_DIR = cache_dir
     return cache_dir

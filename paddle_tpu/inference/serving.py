@@ -124,6 +124,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import compile_cache
 from ..observability.metrics import REGISTRY as _REG
 from ..observability.sentry import sentry as _sentry
 from ..observability.tracing import TRACER as _TRACE
@@ -154,9 +155,17 @@ class _Request:
     generated: List[int] = field(default_factory=list)
     done: bool = False
     slot: int = -1                      # active slot, -1 = queued/finished
-    submit_t: float = 0.0               # perf_counter at submit
-    first_tok_t: float = 0.0            # TTFT timestamp (0 = none yet)
+    # the request's timeline, perf_counter stamps (0 = not yet). Each is
+    # set ONCE, at its first occurrence, so a preempted request's replay
+    # keeps submit <= admit <= prefill_start <= prefill_dispatched <=
+    # first token <= done; ``preemptions`` counts the replays.
+    submit_t: float = 0.0
+    admit_t: float = 0.0                # pages claimed, slot taken
+    prefill_start_t: float = 0.0        # first prefill program about to go
+    prefill_dispatched_t: float = 0.0   # last prefill program enqueued
+    first_tok_t: float = 0.0            # first token drained to the host
     done_t: float = 0.0                 # completion timestamp
+    preemptions: int = 0
     last_emit_t: float = 0.0            # previous tick's emit timestamp
     itl_gaps: List[float] = field(default_factory=list)  # per-TICK gaps
     prefilled: int = 0                  # KV tokens written (chunked mode)
@@ -181,6 +190,7 @@ class _InflightBlock:
     active: object                      # [B] device bool, post-block
     participants: List[Tuple[int, "_Request"]]
     K: int
+    seq: int = 0                        # dispatch number: pairs the spans
     # spec mode only: per-slot MAX possible commits this block (the
     # stride the host projected at dispatch) — drains subtract it back
     # out of the projection when the device committed fewer
@@ -207,6 +217,21 @@ def _entry_page_copy(entry, src, dst):
     bytes for free."""
     return tuple(a.at[:, dst].set(a[:, src]) if a.ndim == 4
                  else a.at[dst].set(a[src]) for a in entry)
+
+
+def _named_jit(fn, name: str, **jit_kw):
+    """``jax.jit(fn)`` compiled as the program ``jit_<name>``: the name the
+    device trace's ``XLA Modules`` line prints and, for a program built per
+    bucket, the only thing that tells two buckets apart there. Every name
+    is one of ``profiler.SERVING_PROGRAMS`` (plus ``_<bucket>``)."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kw)
+
+
+# the fields of one retired request's record, in the order it is stored
+_TIMELINE = ("rid", "submit_t", "admit_t", "prefill_start_t",
+             "prefill_dispatched_t", "first_tok_t", "done_t", "tokens",
+             "preemptions")
 
 
 class _PoolDry(Exception):
@@ -309,6 +334,12 @@ class ContinuousBatchingEngine:
         # for any K.
         self.decode_block = max(1, int(decode_block))
         self._decode_fns: Dict[tuple, object] = {}  # (K, sample, impl) -> fn
+        # one row per program this engine built (name, t_s, seconds,
+        # cache): which program compiled, when, for how long — the
+        # split of set-up time. ``_built`` holds what already has its row.
+        self.build_log: List[dict] = []
+        self._built: set = set()
+        self._block_seq = 0                 # decode blocks dispatched
         self.async_depth = max(1, int(async_depth))
         # token-level speculative decoding (ISSUE 6): each tick drafts
         # spec_k tokens (DraftProvider, n-gram prompt-lookup by default),
@@ -370,7 +401,7 @@ class ContinuousBatchingEngine:
         if self.prefill_chunk % page_size:
             raise ValueError(f"prefill_chunk ({self.prefill_chunk}) must "
                              f"be a multiple of page_size ({page_size})")
-        self._chunk_fn = None
+        self._chunk_fns: Dict[int, object] = {}  # ids width -> fn
         # device-resident scheduler state, created at first activation:
         #   state = (logits [B,V], pos [B], active [B], budget [B], gen [B])
         #   knobs = dict(rseed, eos, temp, topk, topp, dosample)  [B] each
@@ -385,7 +416,9 @@ class ContinuousBatchingEngine:
         self.pool_dry_drains = 0
         # bounded window (run() releases _Request objects for the same
         # reason — a long-lived engine must not grow per-request state)
-        self._latencies = deque(maxlen=10_000)  # (ttft_s, total_s, n_tok)
+        # — ONE record per retired request: its timeline stamps and
+        # token count; latency_stats() is computed from these
+        self._timelines: Deque[tuple] = deque(maxlen=10_000)
         # per-tick inter-token gaps of retired requests (incl. stalls a
         # preemption or a long peer prefill inflicted on them)
         self._itl_gaps = deque(maxlen=100_000)
@@ -513,7 +546,8 @@ class ContinuousBatchingEngine:
         ARRIVED this tick — with ``async_depth > 1`` a token is emitted
         the tick its block drains, one block behind its dispatch."""
         emitted: List[tuple] = []
-        with RecordEvent("serving::admit"):
+        with RecordEvent("serving::admit", queued=len(self._queue),
+                         free_pages=len(self._free)):
             self._admit()
         if self.chunked_prefill:
             self._prefill_tick()
@@ -704,7 +738,7 @@ class ContinuousBatchingEngine:
             return None
         toks = toks[:len(ids) * self.page_size]
         if self._gather_fn is None:
-            def run(pools, pids):
+            def gather_pages(pools, pids):
                 kv = jnp.stack(
                     [jnp.stack([e[0][:, pids], e[1][:, pids]], axis=0)
                      for e in pools], axis=0)
@@ -714,15 +748,16 @@ class ContinuousBatchingEngine:
                          for e in pools], axis=0)
                     return kv, sc
                 return kv, None
-            self._gather_fn = jax.jit(run)
+            self._gather_fn = jax.jit(gather_pages)
         # page count padded to a power-of-two bucket (extra rows read
         # the garbage page, sliced off below): the jit retraces per
         # page-count SHAPE, and unbucketed counts would pay a fresh
         # compile per distinct prompt length on the serving path
         b = self._handoff_bucket(len(ids))
-        kv, scales = self._gather_fn(
-            self.pools,
-            jnp.asarray(ids + [0] * (b - len(ids)), jnp.int32))
+        with self._building("gather_pages", pages=b):
+            kv, scales = self._gather_fn(
+                self.pools,
+                jnp.asarray(ids + [0] * (b - len(ids)), jnp.int32))
         kv = np.ascontiguousarray(np.asarray(kv)[:, :, :, :len(ids)])
         self.pages_exported += len(ids)
         payload = {"fmt": HANDOFF_FMT, "page_size": self.page_size,
@@ -819,7 +854,7 @@ class ContinuousBatchingEngine:
                 f"adopt_pages: pool cannot hold {n - k} more pages "
                 f"even after tree eviction; raise num_pages")
         if self._scatter_fn is None:
-            def run(pools, pids, data, sc):
+            def scatter_pages(pools, pids, data, sc):
                 out = []
                 for i, e in enumerate(pools):
                     ne = (e[0].at[:, pids].set(data[i, 0]),
@@ -829,7 +864,7 @@ class ContinuousBatchingEngine:
                                e[3].at[pids].set(sc[i, 1]))
                     out.append(ne)
                 return out
-            self._scatter_fn = jax.jit(run, donate_argnums=(0,))
+            self._scatter_fn = jax.jit(scatter_pages, donate_argnums=(0,))
         # same power-of-two bucketing as the gather: padded rows write
         # the garbage page (reserved junk — the designated sink)
         b = self._handoff_bucket(n - k)
@@ -840,10 +875,11 @@ class ContinuousBatchingEngine:
             sc_pad = np.zeros(scales.shape[:2] + (b,), np.float32)
             sc_pad[:, :, :n - k] = scales[:, :, k:]
             sc_pad = jnp.asarray(sc_pad)
-        self.pools = self._scatter_fn(
-            self.pools,
-            jnp.asarray(list(pages) + [0] * (b - (n - k)), jnp.int32),
-            jnp.asarray(kv_pad), sc_pad)
+        with self._building("scatter_pages", pages=b):
+            self.pools = self._scatter_fn(
+                self.pools,
+                jnp.asarray(list(pages) + [0] * (b - (n - k)), jnp.int32),
+                jnp.asarray(kv_pad), sc_pad)
         # insert walks the FULL run; the covered prefix needs page-id
         # placeholders that are never read (insert only consumes ids
         # from the first uncovered boundary on — and a coverage that
@@ -855,6 +891,19 @@ class ContinuousBatchingEngine:
         self._free.extend(p for p in pages if p not in taken)
         self.pages_adopted += len(donated)
         return donated
+
+    # -- program builds ------------------------------------------------------
+
+    def _building(self, program: str, **shape):
+        """Context for a CALL of ``program``: the first one per shape
+        variant is its build (trace + lower + compile or cache read), so it
+        runs as a ``compile::<program>`` span and leaves a ``build_log``
+        row; every later one is a set lookup."""
+        key = (program, *shape.values())
+        if key in self._built:
+            return _NULL
+        self._built.add(key)
+        return compile_cache.building(program, self.build_log, **shape)
 
     # -- metrics plane -------------------------------------------------------
 
@@ -1073,8 +1122,8 @@ class ContinuousBatchingEngine:
                        "dosample": jnp.zeros((B,), bool)}
 
     def _build_act_fn(self):
-        def run(state, knobs, slot, logits_row, pos0, budget0, gen0,
-                rseed0, eos0, temp0, topk0, topp0, dos0):
+        def activate_slot(state, knobs, slot, logits_row, pos0, budget0,
+                          gen0, rseed0, eos0, temp0, topk0, topp0, dos0):
             logits, pos, active, budget, gen = state
             state = (logits.at[slot].set(logits_row.astype(logits.dtype)),
                      pos.at[slot].set(pos0),
@@ -1091,12 +1140,16 @@ class ContinuousBatchingEngine:
 
         # no donation: in-flight blocks hold references to prior state
         # arrays for their async host drains
-        return jax.jit(run)
+        return jax.jit(activate_slot)
 
     def _activate(self, slot: int, req: _Request, logits_row):
         """Flip a slot live on device after its prefill finished: one
         small jitted dispatch setting the slot's row in every scheduler
         array (pos/active/budget/gen/knobs) + its first-token logits."""
+        with RecordEvent("serving::activate", rid=req.rid, slot=slot):
+            self._activate_slot(slot, req, logits_row)
+
+    def _activate_slot(self, slot: int, req: _Request, logits_row):
         if self._state is None:
             self._init_state(logits_row)
         if self._act_fn is None:
@@ -1104,15 +1157,17 @@ class ContinuousBatchingEngine:
         L = req.prefill_target
         eos = req.eos_token_id if req.eos_token_id is not None \
             else self.cfg.eos_token_id
-        self._state, self._knobs = self._act_fn(
-            self._state, self._knobs, np.int32(slot), logits_row,
-            np.int32(L), np.int32(req.max_new_tokens - len(req.generated)),
-            np.int32(len(req.generated)),
-            np.uint32((req.rid if req.rseed is None else req.rseed)
-                      & 0x7FFFFFFF),
-            np.int32(-1 if eos is None else eos),
-            np.float32(req.temperature), np.int32(req.top_k),
-            np.float32(req.top_p), np.bool_(req.do_sample))
+        with self._building("activate_slot"):
+            self._state, self._knobs = self._act_fn(
+                self._state, self._knobs, np.int32(slot), logits_row,
+                np.int32(L),
+                np.int32(req.max_new_tokens - len(req.generated)),
+                np.int32(len(req.generated)),
+                np.uint32((req.rid if req.rseed is None else req.rseed)
+                          & 0x7FFFFFFF),
+                np.int32(-1 if eos is None else eos),
+                np.float32(req.temperature), np.int32(req.top_k),
+                np.float32(req.top_p), np.bool_(req.do_sample))
         self.pos[slot] = L
         self._proj_pos[slot] = L
         self._proj_gen[slot] = len(req.generated)
@@ -1127,24 +1182,28 @@ class ContinuousBatchingEngine:
             # on device by each spec tick (the host is async_depth behind,
             # so drafting must read the carry, not host state)
             if self._hist_set_fn is None:
-                self._hist_set_fn = jax.jit(
-                    lambda h, slot, row: h.at[slot].set(row),
-                    donate_argnums=(0,))
+                def hist_set(h, slot, row):
+                    return h.at[slot].set(row)
+                self._hist_set_fn = jax.jit(hist_set, donate_argnums=(0,))
             row = np.zeros((self.max_len,), np.int32)
             row[:len(req.prompt)] = req.prompt
             if req.generated:
                 row[len(req.prompt):L] = req.generated
-            self._hist = self._hist_set_fn(self._hist, np.int32(slot), row)
+            with self._building("hist_set"):
+                self._hist = self._hist_set_fn(self._hist, np.int32(slot),
+                                               row)
 
     def _deactivate(self, slot: int):
         if self._state is None:
             return
         if self._deact_fn is None:
-            self._deact_fn = jax.jit(
-                lambda active, slot: active.at[slot].set(False))
+            def deactivate(active, slot):
+                return active.at[slot].set(False)
+            self._deact_fn = jax.jit(deactivate)
         logits, pos, active, budget, gen = self._state
-        self._state = (logits, pos, self._deact_fn(active, np.int32(slot)),
-                       budget, gen)
+        with self._building("deactivate"):
+            active = self._deact_fn(active, np.int32(slot))
+        self._state = (logits, pos, active, budget, gen)
 
     # -- admission / prefill ------------------------------------------------
 
@@ -1158,14 +1217,15 @@ class ContinuousBatchingEngine:
         core, model = self.core, self.model
         head = model.logits if hasattr(model, "logits") else (lambda h: h)
 
-        def run(params, ids, pools, tables1, last_idx):
+        def prefill_paged(params, ids, pools, tables1, last_idx):
             ctx = model._bind(params) if hasattr(model, "_bind") else None
             with ctx if ctx is not None else _null():
                 hidden, pools = core.prefill_paged(ids, pools, tables1)
                 logits = head(hidden[0, last_idx, :])
             return logits, pools
 
-        fn = jax.jit(run, donate_argnums=(2,))
+        fn = _named_jit(prefill_paged, f"prefill_paged_{bucket}",
+                        donate_argnums=(2,))
         self._prefill_cache[bucket] = fn
         return fn
 
@@ -1226,11 +1286,12 @@ class ContinuousBatchingEngine:
         jitted dispatch, page ids traced): the COW primitive for decode
         diverging into a shared page."""
         if self._cow_fn is None:
-            def run(pools, src, dst):
+            def cow_page(pools, src, dst):
                 return [_entry_page_copy(e, src, dst) for e in pools]
-            self._cow_fn = jax.jit(run, donate_argnums=(0,))
-        self.pools = self._cow_fn(self.pools, jnp.int32(src),
-                                  jnp.int32(dst))
+            self._cow_fn = jax.jit(cow_page, donate_argnums=(0,))
+        with self._building("cow_page"):
+            self.pools = self._cow_fn(self.pools, jnp.int32(src),
+                                      jnp.int32(dst))
         self.prefix_cow_copies += 1
 
     def _tail_logits_fn(self):
@@ -1244,7 +1305,7 @@ class ContinuousBatchingEngine:
             head = model.logits if hasattr(model, "logits") else \
                 (lambda h: h)
 
-            def run(params, tok, pos, pools, tables1, src, dst):
+            def tail_logits(params, tok, pos, pools, tables1, src, dst):
                 pools = [_entry_page_copy(e, src, dst) for e in pools]
                 ctx = model._bind(params) if hasattr(model, "_bind") \
                     else None
@@ -1254,7 +1315,7 @@ class ContinuousBatchingEngine:
                     logits = head(h[0, 0, :])
                 return logits, pools
 
-            self._tail_fn = jax.jit(run, donate_argnums=(3,))
+            self._tail_fn = jax.jit(tail_logits, donate_argnums=(3,))
         return self._tail_fn
 
     def _admit(self):
@@ -1341,6 +1402,8 @@ class ContinuousBatchingEngine:
             self._tables_dirty = True
             self._slots[slot] = req
             req.slot = slot
+            if not req.admit_t:
+                req.admit_t = time.perf_counter()
             if req.tspans is not None:
                 ts = req.tspans
                 q = ts.pop("queue", None)
@@ -1363,13 +1426,16 @@ class ContinuousBatchingEngine:
                 assert src is not None, "matched tail page vanished"
                 self.prefix_cow_copies += 1
                 psp = self._prefill_span(req, "cow")
-                with RecordEvent("serving::prefill"):
+                with self._prefill_event(req, slot, 1, "cow"), \
+                        self._building("tail_logits"):
                     logits, self.pools = self._tail_logits_fn()(
                         self._params,
                         jnp.asarray(toks[L - 1:L].reshape(1, 1)),
                         jnp.full((1,), L - 1, jnp.int32), self.pools,
                         jnp.asarray(self.tables[slot:slot + 1]),
                         jnp.int32(src), jnp.int32(pages[0]))
+                req.prefill_dispatched_t = (req.prefill_dispatched_t
+                                            or time.perf_counter())
                 if psp is not None:
                     psp.end()
                 req.prefilled = L
@@ -1388,7 +1454,8 @@ class ContinuousBatchingEngine:
             off = n_lock * self.page_size
             req.prefilled = L
             psp = self._prefill_span(req, "suffix" if off else "full")
-            with RecordEvent("serving::prefill"):
+            with self._prefill_event(req, slot, bucket - off,
+                                     "suffix" if off else "full"):
                 if off:
                     # suffix-only prefill from the page-aligned offset:
                     # the existing chunked-prefill extend attends over
@@ -1399,20 +1466,23 @@ class ContinuousBatchingEngine:
                     # cold-prefill cache already lives with.
                     ids = np.zeros((1, bucket - off), np.int32)
                     ids[0, :L - off] = toks[off:]
-                    if self._chunk_fn is None:
-                        self._chunk_fn = self._build_chunk_fn()
-                    logits, self.pools = self._chunk_fn(
-                        self._params, jnp.asarray(ids), jnp.int32(off),
-                        self.pools,
-                        jnp.asarray(self.tables[slot:slot + 1]),
-                        jnp.int32(L - 1))
+                    with self._building("prefill_chunk",
+                                        width=bucket - off):
+                        logits, self.pools = self._chunk_fn(bucket - off)(
+                            self._params, jnp.asarray(ids), jnp.int32(off),
+                            self.pools,
+                            jnp.asarray(self.tables[slot:slot + 1]),
+                            jnp.int32(L - 1))
                 else:
                     ids = np.zeros((1, bucket), np.int32)
                     ids[0, :L] = toks
-                    logits, self.pools = self._prefill_fn(bucket)(
-                        self._params, jnp.asarray(ids), self.pools,
-                        jnp.asarray(self.tables[slot:slot + 1]),
-                        jnp.int32(L - 1))
+                    with self._building("prefill_paged", bucket=bucket):
+                        logits, self.pools = self._prefill_fn(bucket)(
+                            self._params, jnp.asarray(ids), self.pools,
+                            jnp.asarray(self.tables[slot:slot + 1]),
+                            jnp.int32(L - 1))
+            req.prefill_dispatched_t = (req.prefill_dispatched_t
+                                        or time.perf_counter())
             if psp is not None:
                 psp.end()
             self._activate(slot, req, logits)
@@ -1430,14 +1500,31 @@ class ContinuousBatchingEngine:
         return ts["tr"].start("replica::prefill", parent=parent,
                               tags={"kind": kind})
 
+    @staticmethod
+    def _prefill_event(req: _Request, slot: int, bucket: int, kind: str):
+        """The ``serving::prefill`` span of one prefill program for
+        ``req`` (``bucket``: the width of ids it takes; ``kind``: full,
+        suffix, cow or chunk), stamping the start of the request's
+        first."""
+        if not req.prefill_start_t:
+            req.prefill_start_t = time.perf_counter()
+        return RecordEvent("serving::prefill", rid=req.rid, slot=slot,
+                           bucket=bucket, kind=kind)
+
     def _decode_ready(self, req) -> bool:
         return req is not None and req.prefilled >= req.prefill_target
 
-    def _build_chunk_fn(self):
+    def _chunk_fn(self, width: int):
+        """The prefill-extend program for ``width`` ids (a page multiple:
+        the chunk in chunked mode, bucket minus shared prefix for a suffix
+        prefill), one jit per width so each has its own name."""
+        fn = self._chunk_fns.get(width)
+        if fn is not None:
+            return fn
         core, model = self.core, self.model
         head = model.logits if hasattr(model, "logits") else (lambda h: h)
 
-        def run(params, ids, offset, pools, tables1, last_idx):
+        def prefill_chunk(params, ids, offset, pools, tables1, last_idx):
             ctx = model._bind(params) if hasattr(model, "_bind") else None
             with ctx if ctx is not None else _null():
                 hidden, pools = core.prefill_chunk_paged(
@@ -1448,7 +1535,9 @@ class ContinuousBatchingEngine:
                 logits = head(hidden[0, last_idx - offset, :])
             return logits, pools
 
-        return jax.jit(run, donate_argnums=(3,))
+        fn = self._chunk_fns[width] = _named_jit(
+            prefill_chunk, f"prefill_chunk_{width}", donate_argnums=(3,))
+        return fn
 
     def _prefill_tick(self):
         """Advance the oldest in-prefill slot by ONE chunk."""
@@ -1465,12 +1554,11 @@ class ContinuousBatchingEngine:
         ids = np.zeros((1, C), np.int32)
         chunk = toks[off:off + C]
         ids[0, :len(chunk)] = chunk
-        if self._chunk_fn is None:
-            self._chunk_fn = self._build_chunk_fn()
         last_idx = req.prefill_target - 1
         psp = self._prefill_span(req, "chunk")
-        with RecordEvent("serving::prefill"):
-            logits, self.pools = self._chunk_fn(
+        with self._prefill_event(req, slot, C, "chunk"), \
+                self._building("prefill_chunk", width=C):
+            logits, self.pools = self._chunk_fn(C)(
                 self._params, jnp.asarray(ids), jnp.int32(off), self.pools,
                 jnp.asarray(self.tables[slot:slot + 1]),
                 jnp.int32(min(last_idx, off + C - 1)))
@@ -1478,6 +1566,8 @@ class ContinuousBatchingEngine:
             psp.tag(off=off).end()
         req.prefilled = min(off + C, self._bucket(req.prefill_target))
         if req.prefilled >= req.prefill_target:
+            req.prefill_dispatched_t = (req.prefill_dispatched_t
+                                        or time.perf_counter())
             self._activate(slot, req, logits)
             if self._prefix is not None:
                 self._insert_prefix(slot, req)
@@ -1502,6 +1592,9 @@ class ContinuousBatchingEngine:
         head = model.logits if hasattr(model, "logits") else (lambda h: h)
         from ..ops.pallas.paged_attention import force_decode_impl
 
+        # ``run`` is the decode tick's name in the device trace, and the
+        # only program of the engine with that name: the benchmark's
+        # decode_tick_roofline finds the tick as ``^jit_run\(``
         def run(params, pools, tables, base_key, state, knobs):
             ctx = model._bind(params) if hasattr(model, "_bind") else None
             with ctx if ctx is not None else _null(), \
@@ -1568,7 +1661,8 @@ class ContinuousBatchingEngine:
         head = model.logits if hasattr(model, "logits") else (lambda h: h)
         provider = self._draft
 
-        def run(params, pools, tables, base_key, state, knobs, hist):
+        def spec_decode_block(params, pools, tables, base_key, state, knobs,
+                              hist):
             ctx = model._bind(params) if hasattr(model, "_bind") else None
             with ctx if ctx is not None else _null():
                 logits, pos, active, budget, gen = state
@@ -1653,7 +1747,7 @@ class ContinuousBatchingEngine:
         # so the [B, max_len] buffer updates in place (nothing else holds
         # the old history — in-flight blocks only reference toks/kept/
         # pos/active)
-        return jax.jit(run, donate_argnums=(1, 6))
+        return jax.jit(spec_decode_block, donate_argnums=(1, 6))
 
     def _participants(self) -> List[Tuple[int, _Request]]:
         """Slots the NEXT block decodes for: prefill done and not yet
@@ -1768,6 +1862,7 @@ class ContinuousBatchingEngine:
                 victim = max(cands, key=lambda i: self._slots[i].rid)
             self.preemptions += 1
             vreq = self._slots[victim]
+            vreq.preemptions += 1
             self._deactivate(victim)
             # donate the victim's completed pages (prefix mode): its
             # replay re-maps them instead of re-prefilling, and at ref 0
@@ -1836,22 +1931,27 @@ class ContinuousBatchingEngine:
         # tables upload BEFORE executable resolution: the cost-observatory
         # eager compile below lowers on the concrete args of this dispatch
         if self._tables_dirty:
-            self._tables_dev = jnp.asarray(self.tables)
+            with RecordEvent("serving::tables_upload"):
+                self._tables_dev = jnp.asarray(self.tables)
             self._tables_dirty = False
+        seq = self._block_seq
+        self._block_seq += 1
         fn = self._decode_fns.get(fkey)
-        if fn is None:
-            jfn = (self._build_spec_decode(self.spec_k, any_sample)
-                   if spec else self._build_decode(K, any_sample,
-                                                   attn_impl))
-            fn = self._decode_fns[fkey] = \
-                self._maybe_compile_with_costs(jfn, spec)
-        with RecordEvent("serving::dispatch"):
+        with RecordEvent("serving::dispatch", block=seq, K=K,
+                         active=len(parts)), \
+                self._building("spec_decode_block" if spec else "run",
+                               key="/".join(map(str, fkey))):
+            if fn is None:
+                jfn = (self._build_spec_decode(self.spec_k, any_sample)
+                       if spec else self._build_decode(K, any_sample,
+                                                       attn_impl))
+                fn = self._decode_fns[fkey] = \
+                    self._maybe_compile_with_costs(jfn, spec)
+            out = fn(*self._decode_args(spec))
             if spec:
-                toks, kept, self._state, self.pools, self._hist = fn(
-                    *self._decode_args(True))
+                toks, kept, self._state, self.pools, self._hist = out
             else:
-                toks, kept, self._state, self.pools = fn(
-                    *self._decode_args(False))
+                toks, kept, self._state, self.pools = out
             # start the device→host copies NOW so reconciliation (one or
             # more blocks later) finds the bytes already on host
             for arr in (toks, kept, self._state[1], self._state[2]):
@@ -1871,7 +1971,7 @@ class ContinuousBatchingEngine:
             self._proj_gen[s] += steps
             self._proj_pos[s] += steps
         self._inflight.append(_InflightBlock(
-            toks, kept, self._state[1], self._state[2], parts, K,
+            toks, kept, self._state[1], self._state[2], parts, K, seq=seq,
             steps=stride))
         return True
 
@@ -1892,11 +1992,18 @@ class ContinuousBatchingEngine:
         the device already moved past: append kept tokens, retire slots
         whose done flag came back, record arrival-time latency metrics."""
         blk = self._inflight.popleft()
-        with RecordEvent("serving::drain"):
+        with RecordEvent("serving::drain", block=blk.seq):
             toks = np.asarray(blk.toks)            # [K, B]
             kept = np.asarray(blk.kept)            # [K, B] prefix mask
             pos_after = np.asarray(blk.pos)
             active_after = np.asarray(blk.active)
+        with RecordEvent("serving::reconcile", block=blk.seq):
+            return self._reconcile_host(blk, toks, kept, pos_after,
+                                        active_after)
+
+    def _reconcile_host(self, blk: _InflightBlock, toks, kept, pos_after,
+                        active_after) -> List[tuple]:
+        """The host bookkeeping of one drained block."""
         emitted: List[tuple] = []
         # TTFT/ITL stamp at token-ARRIVAL time: under pipelining a
         # block's tokens only exist on host once its drain completes, so
@@ -1962,10 +2069,11 @@ class ContinuousBatchingEngine:
                 req.done = True
                 req.done_t = now
                 self._requests_retired += 1
-                self._latencies.append(
-                    (req.first_tok_t - req.submit_t,
-                     req.done_t - req.submit_t,
-                     len(req.generated)))
+                self._timelines.append((
+                    req.rid, req.submit_t, req.admit_t,
+                    req.prefill_start_t, req.prefill_dispatched_t,
+                    req.first_tok_t, req.done_t, len(req.generated),
+                    req.preemptions))          # _TIMELINE's order
                 self._itl_gaps.extend(req.itl_gaps)
                 # cache=True: donate the whole conversation's completed
                 # pages to the prefix tree before the slot's lock
@@ -2022,8 +2130,17 @@ class ContinuousBatchingEngine:
     def reset_latency_stats(self) -> None:
         """Drop the retired-request latency window (e.g. after a warmup
         phase whose TTFTs include one-time jit compiles)."""
-        self._latencies.clear()
+        self._timelines.clear()
         self._itl_gaps.clear()
+
+    def request_timelines(self) -> List[dict]:
+        """One record per retired request in the window (most recent
+        10,000): ``rid``, the ``perf_counter`` stamps ``submit_t``,
+        ``admit_t``, ``prefill_start_t``, ``prefill_dispatched_t``,
+        ``first_tok_t``, ``done_t`` (each the FIRST occurrence, so they
+        never run backwards through a preemption), ``tokens`` and
+        ``preemptions``."""
+        return [dict(zip(_TIMELINE, r)) for r in self._timelines]
 
     def latency_stats(self) -> Dict[str, float]:
         """TTFT / end-to-end latency percentiles over a sliding window of
@@ -2032,19 +2149,31 @@ class ContinuousBatchingEngine:
         the serving SLO numbers (reference: PaddleNLP llm serving
         benchmarks report the same trio: throughput, TTFT, p99).
         Timestamps are token-ARRIVAL times (post-drain), so pipelined
-        dispatch cannot flatter the percentiles."""
-        if not self._latencies:
+        dispatch cannot flatter the percentiles. Computed from
+        ``request_timelines()``; beside ``ttft``/``latency``/``itl`` it
+        splits a request's wait for its first token into ``queue_wait``
+        (submit → admitted), ``prefill`` (first prefill program starting →
+        last one dispatched; host time, the device may lag) and
+        ``first_drain_wait`` (→ first token drained), p50 and p99 each."""
+        if not self._timelines:
             return {}
-        arr = np.asarray(self._latencies, np.float64)
-        ttft, total = arr[:, 0], arr[:, 1]
-        out = {
-            "requests": int(arr.shape[0]),
-            "tokens": int(arr[:, 2].sum()),
-            "ttft_p50_s": float(np.percentile(ttft, 50)),
-            "ttft_p99_s": float(np.percentile(ttft, 99)),
-            "latency_p50_s": float(np.percentile(total, 50)),
-            "latency_p99_s": float(np.percentile(total, 99)),
-        }
+        arr = np.asarray(self._timelines, np.float64)
+        col = {k: arr[:, i] for i, k in enumerate(_TIMELINE)}
+        out = {"requests": int(arr.shape[0]),
+               "tokens": int(col["tokens"].sum())}
+        # the request's wait split where the engine's layers hand it on:
+        # queued until admitted, its prefill programs' dispatch, then the
+        # wait for the decode block that drains its first token
+        for key, a, b in (
+                ("ttft", "submit_t", "first_tok_t"),
+                ("latency", "submit_t", "done_t"),
+                ("queue_wait", "submit_t", "admit_t"),
+                ("prefill", "prefill_start_t", "prefill_dispatched_t"),
+                ("first_drain_wait", "prefill_dispatched_t",
+                 "first_tok_t")):
+            d = col[b] - col[a]
+            out[f"{key}_p50_s"] = float(np.percentile(d, 50))
+            out[f"{key}_p99_s"] = float(np.percentile(d, 99))
         if self._itl_gaps:
             gaps = np.asarray(self._itl_gaps, np.float64)
             # per-TOKEN gaps: a multi-token drain (decode_block>1 or an
@@ -2064,6 +2193,9 @@ class _null:
 
     def __exit__(self, *exc):
         return False
+
+
+_NULL = _null()
 
 
 __all__ = ["ContinuousBatchingEngine", "HANDOFF_FMT", "HANDOFF_FMT_V1"]

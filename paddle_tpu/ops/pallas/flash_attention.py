@@ -283,6 +283,7 @@ def _fwd(q, k, v, q_seg, kv_seg, seed, dropout_p, scale, causal,
         compiler_params=_tpu_params("parallel", "parallel", "parallel",
                                     "arbitrary"),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*inputs)
     return jnp.swapaxes(out_t, 1, 2), lse4[..., 0]   # [b,sq,h,d], [b,h,sq]
 
@@ -471,6 +472,7 @@ def _bwd(dropout_p, scale, causal, block_q, block_k, interpret, res, dout):
         compiler_params=_tpu_params("parallel", "parallel", "parallel",
                                     "arbitrary"),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*dq_inputs)[0]
 
     # dk/dv accumulated at kv-head resolution: grid (b, h_kv, nk, nq*group);
@@ -512,6 +514,7 @@ def _bwd(dropout_p, scale, causal, block_q, block_k, interpret, res, dout):
         compiler_params=_tpu_params("parallel", "parallel", "parallel",
                                     "arbitrary"),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*dkv_inputs)
 
     dq = jnp.swapaxes(dq_t, 1, 2)

@@ -97,6 +97,7 @@ def _rms_fwd(x2d, w, eps, block_r, interpret):
         compiler_params=(pltpu.CompilerParams(
             dimension_semantics=("parallel",)) if pltpu else None),
         interpret=interpret,
+        name="fused_rmsnorm_fwd",
     )(x2d, w.reshape(1, D))
     return out, rstd
 
@@ -125,6 +126,7 @@ def _rms_bwd_rule(eps, block_r, interpret, res, dy):
         compiler_params=(pltpu.CompilerParams(
             dimension_semantics=("parallel",)) if pltpu else None),
         interpret=interpret,
+        name="fused_rmsnorm_bwd",
     )(x2d, w.reshape(1, D), rstd, dy)
     dw = jnp.sum(dwp, axis=0).astype(w.dtype)
     return dx, dw

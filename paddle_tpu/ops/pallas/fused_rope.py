@@ -85,6 +85,7 @@ def fused_rope_pallas(q, k, cos, sin, *, block_s: int = DEFAULT_BLOCK_S,
             dimension_semantics=("parallel", "parallel"))
             if not interpret else None),
         interpret=interpret,
+        name="fused_rope",
     )(q, k, cos.astype(cf), sin.astype(cf))
     return qo, ko
 

@@ -241,6 +241,7 @@ def _fwd_pallas(h, w, labels, block_n, block_v, interpret):
                         pltpu.VMEM((block_n, 128), jnp.float32)],
         compiler_params=_tpu_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="fused_vocab_ce_fwd",
     )(h, wp, lab2)
     return out[0][:, 0], out[1][:, 0]
 
@@ -335,6 +336,7 @@ def _bwd_pallas(h, w, labels, lse, g_lse, g_tgt, block_n, block_v, interpret):
         scratch_shapes=[pltpu.VMEM((block_n, hd), jnp.float32)],
         compiler_params=_tpu_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="fused_vocab_ce_bwd_dh",
     )(h, wp, lab2, lse2, glse2, gtgt2)[0]
 
     # dW: grid transposed (vocab blocks parallel, rows sequential) so the
@@ -356,6 +358,7 @@ def _bwd_pallas(h, w, labels, lse, g_lse, g_tgt, block_n, block_v, interpret):
         scratch_shapes=[pltpu.VMEM((hd, block_v), jnp.float32)],
         compiler_params=_tpu_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="fused_vocab_ce_bwd_dw",
     )(h, wp, lab2, lse2, glse2, gtgt2)[0]
     return dh, dwp[:, :v]
 

@@ -150,6 +150,7 @@ def grouped_matmul_pallas(xs, w, group_sizes, *,
             dimension_semantics=("arbitrary", "parallel", "arbitrary"))
             if not interpret else None),
         interpret=interpret,
+        name="grouped_matmul",
     )(tile_group, xp, w)
     return yp[dest]
 

@@ -96,6 +96,7 @@ def int8_matmul_pallas(x, wq, scale, *, block_m: int = DEFAULT_BLOCK_M,
             dimension_semantics=("parallel", "parallel", "arbitrary"))
             if not interpret else None),
         interpret=interpret,
+        name="int8_matmul",
     )(x, wq, scale2)
     return out
 

@@ -188,6 +188,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
         out_shape=jax.ShapeDtypeStruct((B, H_kv, group, D), q.dtype),
         compiler_params=_tpu_params(),
         interpret=interpret,
+        name="paged_attention_decode",
     )(*prefetch, qg, *([k_pages] * n_fetch), *([v_pages] * n_fetch))
     return out.reshape(B, H, D)
 

@@ -11,6 +11,13 @@ spans per thread); the device side is XLA's own profiler (jax.profiler →
 xplane/TensorBoard trace, the CUPTI slot). The scheduler state machine,
 RecordEvent instrumentation API, chrome-trace export, and summary stats keep
 the reference's shape so profiling code ports 1:1.
+
+One span primitive, two clocks: ``RecordEvent`` stamps ``perf_counter_ns``
+for the in-process collector (chrome JSON, ``summary()``, the flight ring)
+and, whenever ANY jax profiler trace is running, also enters a
+``jax.profiler.TraceAnnotation`` — so the same span lands on the
+``/host:CPU`` plane of the ``.xplane.pb`` that holds the device's ops, on
+the profiler's clock, with its keyword attributes as stats.
 """
 
 from __future__ import annotations
@@ -23,21 +30,35 @@ from collections import defaultdict
 from enum import Enum, IntEnum
 from typing import Callable, Iterable, Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 __all__ = [
     "ProfilerTarget", "ProfilerState", "make_scheduler", "RecordEvent",
-    "Profiler", "export_chrome_tracing", "export_protobuf", "load_profiler_result",
+    "Profiler", "export_chrome_tracing", "load_profiler_result",
     "SummaryView", "SortedKeys", "benchmark", "SERVING_EVENTS",
-    "serving_trace",
+    "SERVING_PROGRAMS", "serving_trace",
 ]
 
 # tick-level spans the async ContinuousBatchingEngine emits through
-# RecordEvent (near-zero cost unless a Profiler is recording): request
-# admission, per-slot prefill (full or chunked), decode-block dispatch,
-# and the async device→host drain/reconcile. A chrome trace of one
-# serving run shows dispatch N+1 opening before drain N closes — the
-# overlap the engine's in-flight window exists to create.
-SERVING_EVENTS = ("serving::admit", "serving::prefill",
-                  "serving::dispatch", "serving::drain")
+# RecordEvent (a TraceMe activity check unless something records), each
+# with the ids that tie them together: admission (queued, free_pages),
+# per-request prefill (rid, slot, bucket, kind) and activation (rid,
+# slot), the page-table upload, decode-block dispatch and its drain (the
+# same ``block`` number, so dispatch N+1 is seen opening before drain N
+# closes — the overlap the in-flight window exists to create), and the
+# host bookkeeping after the drain's copies.
+SERVING_EVENTS = ("serving::admit", "serving::prefill", "serving::activate",
+                  "serving::tables_upload", "serving::dispatch",
+                  "serving::drain", "serving::reconcile")
+
+# names of the compiled programs the engine runs, as the device trace's
+# ``XLA Modules`` line prints them after ``jit_`` (bucketed programs end in
+# ``_<bucket>``). ``run`` is the decode tick and nothing else: the
+# benchmark's ``decode_tick_roofline`` reads ``^jit_run\(``.
+SERVING_PROGRAMS = ("run", "spec_decode_block", "prefill_paged",
+                    "prefill_chunk", "tail_logits", "cow_page",
+                    "activate_slot", "deactivate", "hist_set",
+                    "gather_pages", "scatter_pages")
 
 
 class ProfilerTarget(Enum):
@@ -91,14 +112,15 @@ def _default_scheduler(step: int) -> ProfilerState:
 # ---------------------------------------------------------------------------
 
 class _HostEvent:
-    __slots__ = ("name", "start_ns", "end_ns", "tid", "event_type")
+    __slots__ = ("name", "start_ns", "end_ns", "tid", "event_type", "attrs")
 
-    def __init__(self, name, start_ns, end_ns, tid, event_type):
+    def __init__(self, name, start_ns, end_ns, tid, event_type, attrs=None):
         self.name = name
         self.start_ns = start_ns
         self.end_ns = end_ns
         self.tid = tid
         self.event_type = event_type
+        self.attrs = attrs
 
 
 class _Collector:
@@ -140,28 +162,48 @@ def set_flight_sink(sink) -> None:
 class RecordEvent:
     """Instrumentation span (reference: paddle.profiler.RecordEvent; C++
     platform/profiler RecordEvent). Usable as context manager or
-    begin()/end() pair; near-zero overhead when no profiler is recording."""
+    begin()/end() pair. Keyword attributes travel with the span: into the
+    chrome export's ``args`` and, while a jax profiler trace is running,
+    into a ``TraceAnnotation`` on the trace's own clock (a
+    ``StepTraceAnnotation`` when ``step_num`` is among them, which is what
+    xprof's step view reads). With no trace, no recording Profiler and no
+    flight sink a span costs one TraceMe activity check and reads no
+    clock."""
 
-    def __init__(self, name: str, event_type: str = "UserDefined"):
+    __slots__ = ("name", "event_type", "attrs", "_start_ns", "_annotation")
+
+    def __init__(self, name: str, event_type: str = "UserDefined", **attrs):
         self.name = name
         self.event_type = event_type
+        self.attrs = attrs
         self._start_ns = None
+        self._annotation = None
 
     def begin(self):
-        self._start_ns = time.perf_counter_ns()
+        if TraceAnnotation.is_enabled():
+            cls = (StepTraceAnnotation if "step_num" in self.attrs
+                   else TraceAnnotation)
+            self._annotation = cls(self.name, **self.attrs)
+            self._annotation.__enter__()
+        if _collector.enabled or _flight_sink is not None:
+            self._start_ns = time.perf_counter_ns()
 
     def end(self):
-        if self._start_ns is None:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        start = self._start_ns
+        if start is None:
             return
+        self._start_ns = None
+        end = time.perf_counter_ns()
+        tid = threading.get_ident()
         if _collector.enabled:
-            _collector.add(_HostEvent(self.name, self._start_ns,
-                                      time.perf_counter_ns(),
-                                      threading.get_ident(), self.event_type))
+            _collector.add(_HostEvent(self.name, start, end, tid,
+                                      self.event_type, self.attrs or None))
         sink = _flight_sink
         if sink is not None:
-            sink.append((self.name, self._start_ns, time.perf_counter_ns(),
-                         threading.get_ident(), self.event_type))
-        self._start_ns = None
+            sink.append((self.name, start, end, tid, self.event_type))
 
     def __enter__(self):
         self.begin()
@@ -185,12 +227,15 @@ class ProfilerResult:
     def chrome_trace(self) -> dict:
         items = []
         for ev in self.events:
-            items.append({
+            item = {
                 "name": ev.name, "ph": "X", "cat": ev.event_type,
                 "pid": os.getpid(), "tid": ev.tid,
                 "ts": ev.start_ns / 1000.0,
                 "dur": (ev.end_ns - ev.start_ns) / 1000.0,
-            })
+            }
+            if ev.attrs:
+                item["args"] = ev.attrs
+            items.append(item)
         return {"traceEvents": items,
                 "metadata": {"framework": "paddle_tpu",
                              "steps": list(self.step_range)}}
@@ -213,13 +258,6 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
         return path
 
     return handler
-
-
-def export_protobuf(dir_name: str, worker_name: Optional[str] = None):
-    """Parity shim for the reference's protobuf exporter: the device side is
-    already written as xplane protos by jax.profiler into the trace dir; the
-    host side exports chrome JSON next to it."""
-    return export_chrome_tracing(dir_name, worker_name)
 
 
 def load_profiler_result(path: str) -> dict:

@@ -53,6 +53,11 @@ from ..profiler import RecordEvent
 # neither is attached — same contract as SERVING_EVENTS)
 TRAINER_EVENTS = ("trainer::dispatch", "trainer::checkpoint")
 
+# names of the trainer's compiled programs as the device trace's
+# ``XLA Modules`` line prints them after ``jit_`` (the benchmark's
+# flash_attn_roofline reads ``^jit_one_step``)
+TRAINER_PROGRAMS = ("one_step", "superstep")
+
 # bf16 peak FLOP/s per chip, keyed by the (lower-cased) ``device_kind``
 # jax reports — a v5e chip reports "TPU v5 lite". Source: Google Cloud
 # documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s) and
@@ -164,6 +169,9 @@ class Trainer:
         self._lr_cache = None          # (host float, device f32 scalar)
         self._base_key_data = None
         self._aot_dir: Optional[str] = None
+        #: one row per program this trainer built (compile_cache.building:
+        #: name, t_s, seconds, cache, how) — the split of set-up time
+        self.build_log: list = []
         #: host-side dispatch accounting: `dispatch_host_s` is the wall time
         #: spent ENQUEUEING compiled programs (not waiting on them) — the
         #: per-step host overhead the superstep amortizes (bench.py reports
@@ -442,13 +450,16 @@ class Trainer:
                 fn, _ = compile_cache.acquire(
                     fp, jitted, args, aot_dir=self._aot_dir, name=kind,
                     donate_argnums=(0, 1) if self._donate else (),
-                    fp_parts=parts)
+                    fp_parts=parts, build_log=self.build_log)
                 exec_cache[sig] = fn
             if fast is not None:
                 self._fast_exec[fast] = fn
         self._last_exec = fn
         self._last_exec_kind = kind
-        with RecordEvent("trainer::dispatch"):
+        # a step span: ``step_num`` makes it a StepTraceAnnotation, which
+        # xprof's step view groups the device's work by
+        with RecordEvent("trainer::dispatch", step_num=self._step,
+                         kind=kind):
             out = fn(*args)
         self.dispatch_stats["dispatches"] += 1
         self.dispatch_stats["dispatch_host_s"] += time.perf_counter() - t0
@@ -520,7 +531,7 @@ class Trainer:
             save_artifact=self._aot_dir is not None,
             donate_argnums=(0, 1) if self._donate else (),
             fp_parts={"static": self._fp_parts(), "kind": kind,
-                      "avals": sig})
+                      "avals": sig}, build_log=self.build_log)
         exec_cache[sig] = fn
         return {"kind": kind, "outcome": outcome, "fingerprint": fp,
                 "aot_dir": self._aot_dir}

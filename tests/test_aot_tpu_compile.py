@@ -16,6 +16,7 @@ through an option of the program.
 
 import json
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
 
@@ -25,7 +26,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from paddle_tpu.ops.pallas import autotune
+from paddle_tpu.ops.pallas import KERNEL_NAMES, autotune
 
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 KIND = "TPU v5 lite"                    # what a v5e chip reports
@@ -173,6 +174,12 @@ def test_kernel_compiles_for_v5e(topo, case, monkeypatch):
     args = [jax.ShapeDtypeStruct(s, d, sharding=dev) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    # the compiler names a Mosaic call after the kernel's own name, forward
+    # (``%flash_attention_fwd.1``) and under jvp/transpose alike: that name
+    # is the kernel's event text in the device trace (PERF.md section 3)
+    calls = re.findall(r"^\s*(?:ROOT )?%(\S+) = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    assert calls and all(any(k in c for k in KERNEL_NAMES) for c in calls), calls
 
 
 # -- the 2x2 mesh ------------------------------------------------------------
