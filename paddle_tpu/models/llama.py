@@ -230,8 +230,19 @@ def _kv_scatter_tokens(kv, phys, off, k_new, v_new):
     pages onto the new grid, then write the new codes."""
     if not _kv_quantized(kv):
         kp, vp = kv
-        return (kp.at[:, phys, off].set(k_new.astype(kp.dtype)),
-                vp.at[:, phys, off].set(v_new.astype(vp.dtype)))
+        n_kv, num_pages, page, hd = kp.shape
+        # one [hd] ROW per (kv head, token) of the pool seen as rows: a
+        # scatter over [:, phys, off] has an [n_kv, hd] window, for which
+        # XLA:TPU re-lays the whole pool out and back on every call (two
+        # 134 MB copies a pool a layer at the serving shape, 27 ms a tick)
+        row = ((jnp.arange(n_kv, dtype=jnp.int32).reshape(
+            (n_kv,) + (1,) * phys.ndim) * num_pages + phys[None]) * page
+            + off[None])
+
+        def write(pool, new):
+            return pool.reshape(-1, hd).at[row].set(
+                new.astype(pool.dtype)).reshape(pool.shape)
+        return write(kp, k_new), write(vp, v_new)
     kp, vp, ks, vs = kv
 
     def one(pool, scale, new):
@@ -585,9 +596,10 @@ class LlamaAttention(nn.Layer):
         """One-token step over the page pools: writes the new K/V into the
         page slot for position ``pos`` and attends via the Pallas paged
         kernel (XLA gather fallback off-TPU). A ``force_decode_impl``
-        scope ("dense") routes the attention through the XLA gather path —
-        the serving engine's context-aware dense/paged dispatch uses it
-        below the measured crossover length."""
+        scope ("dense") routes the attention through the XLA gather path
+        (K/V read in the stored dtype, per KV head) — the serving
+        engine's context-aware dense/paged dispatch uses it at or below
+        the measured crossover length, which on v5e is 0: no context."""
         from ..ops.pallas.paged_attention import (forced_decode_impl,
                                                  paged_decode_attention,
                                                  paged_decode_supported,

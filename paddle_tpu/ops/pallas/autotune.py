@@ -173,13 +173,19 @@ def fused_vocab_ce_config(n: int, h: int, v: int,
     return default_blocks(n, h, dtype)
 
 
-def paged_decode_crossover(default: int = 4096) -> int:
+def paged_decode_crossover(default: int = 0) -> int:
     """Context length (tokens) above which the Pallas paged-decode kernel
     beats the dense XLA gather path for one decode step. Measured on v5e
-    (bench paged_decode_us_ctx* sweep): dense marginally ahead at ctx 2048,
-    paged 1.45x ahead at 8192 and 3.6x at 16K — so the default crossover
-    sits between them. A tuned value (op "paged_decode_crossover", config
-    key "ctx") in the TuneDB wins; the serving engine consults this per
+    at the serving shape (B=32, 32 query / 8 KV heads of 128, pages of
+    128; tools/tune_kernels.py --paged-decode: write-then-attend steps
+    chained in one program): the kernel is ahead at EVERY context, 0.22 /
+    0.27 / 0.34 / 0.43 ms a call at 256 / 512 / 1024 / 2048 tokens of a
+    2048-token table span against 1.04-1.30 ms dense, and 0.57-1.60 ms
+    against 4.46-5.13 ms over an 8192-token span — so the default is 0
+    and every tick of an engine takes one executable. (The 4096 it
+    replaces was read off single host-timed dispatches, which sit on one
+    ~3 ms floor.) A tuned value (op "paged_decode_crossover", config key
+    "ctx") in the TuneDB wins; the serving engine consults this per
     dispatched decode block (inference/serving.py)."""
     key = TuneDB.key("paged_decode_crossover", _device_kind(), "any")
     hit = _DB.lookup(key)

@@ -323,6 +323,30 @@ def test_a_pallas_entry_point_names_its_kernels(entry, expect):
     assert set(got) <= set(pallas_ops.KERNEL_NAMES)
 
 
+def test_the_decode_attention_share_reads_the_paged_kernel_alone():
+    """``decode_attn_share.decode`` (PR 25) finds the decode tick's
+    attention by the kernel's name, in the forms the TPU compiler gives a
+    named Pallas call, and no other kernel nor an operation that only
+    consumes the kernel's result."""
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "layer_metrics",
+                           "decode_attn_share.decode.json")) as f:
+        spec = json.load(f)
+    assert spec["reducer"] == "device_op_share" and spec["unit"] == "%"
+    rx = re.compile(spec["pattern"])
+    tail = (" = bf16[32,8,4,128]{3,2,1,0:T(4,128)(2,1)} custom-call(s32[32,16]"
+            "{1,0} %fusion.3), custom_call_target=\"tpu_custom_call\"")
+    for kernel in pallas_ops.KERNEL_NAMES:
+        for text in (f"%{kernel}.3", f"%jvp_{kernel}_.1", f"{kernel}.7"):
+            assert bool(rx.search(text + tail)) == (
+                kernel == "paged_attention_decode"), text
+    assert not rx.search("%fusion.2 = bf16[32,4096]{1,0} fusion(bf16[32,8,4,"
+                         "128]{3,2,1,0} %paged_attention_decode.14), "
+                         "kind=kLoop")
+
+
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
     assert len(names) == len(set(names)) == 12

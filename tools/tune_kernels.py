@@ -11,15 +11,18 @@ On the chip:
     headline shapes and records the fastest config per (shape, dtype,
     device) — into the file named by --out, or into the in-repo DB with
     --write-shipped (nothing is written otherwise);
-  - microbenches pallas-vs-XLA for flash attention and paged decode,
-    printing one JSON line per case, so regressions are diffable (the
-    in-repo analogue of ci_op_benchmark.sh).
+  - microbenches pallas-vs-XLA for flash attention and paged decode
+    (--paged-decode: that comparison alone, per context length at the
+    serving cell's shape), printing one JSON line per case, so
+    regressions are diffable (the in-repo analogue of
+    ci_op_benchmark.sh).
 
 Without a TPU it fails. --interpret validates the sweep machinery on any
 backend with one tiny case in Pallas interpret mode (no timings recorded).
 
 Usage:
     python tools/tune_kernels.py [--quick] [--out PATH] [--write-shipped]
+    python tools/tune_kernels.py --paged-decode
     python tools/tune_kernels.py --interpret
 """
 
@@ -144,56 +147,78 @@ def sweep_flash(shapes, candidates, interpret, record_db, quick=False):
     return results
 
 
-def bench_paged_decode(interpret):
+def bench_paged_decode(interpret, steps=128, per_seq=16,
+                       contexts=(256, 512, 1024, 2048, "ragged")):
+    """The Pallas paged-decode kernel against the XLA fallback at the
+    serving cell's shape (32 rows, 32 query / 8 KV heads of 128, tables of
+    ``per_seq`` pages of 128, bf16), per context length. ``steps`` calls
+    are chained inside ONE program (each step's output is the next one's
+    query and its new K/V row), so the reading is device time a call; a
+    single host-timed dispatch sits on a ~3 ms floor and ranks nothing.
+    "ragged" is the batch-decode cell's mix: lengths log-uniform over
+    160-1280 of a 2048-token span. The reading that sets
+    ``autotune.paged_decode_crossover``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+    from paddle_tpu.models.llama import _kv_scatter_tokens
+    from paddle_tpu.ops.pallas.paged_attention import (paged_decode_attention,
+                                                       paged_decode_xla)
 
     kind = getattr(jax.devices()[0], "device_kind", "cpu")
     rs = np.random.RandomState(0)
-    B, H, H_kv, D = 8, 8, 2, 128
-    page, npages, per_seq = 128, 256, 16   # up to 2048 ctx
+    if interpret:
+        B, H, H_kv, D, page, per_seq = 2, 4, 2, 32, 16, 4
+        contexts, steps = (24, "ragged"), 2
+    else:
+        B, H, H_kv, D, page = 32, 32, 8, 128, 128
+    npages = B * per_seq
+    span = page * per_seq
     dt = jnp.bfloat16
     q = jnp.asarray(rs.normal(0, 1, (B, H, D)), dt)
     # head-major pools [H_kv, num_pages, page_size, D]
     kp = jnp.asarray(rs.normal(0, 1, (H_kv, npages, page, D)), dt)
     vp = jnp.asarray(rs.normal(0, 1, (H_kv, npages, page, D)), dt)
-    tables = jnp.asarray(rs.permutation(npages)[:B * per_seq]
-                         .reshape(B, per_seq).astype(np.int32))
-    lens = jnp.full((B,), page * per_seq - 2, jnp.int32)
+    impls = {"pallas": functools.partial(paged_decode_attention,
+                                         interpret=interpret),
+             "xla": paged_decode_xla}
 
-    pfn = jax.jit(functools.partial(paged_decode_attention,
-                                    interpret=interpret))
-    pdt = _time_fn(pfn, q, kp, vp, tables, lens,
-                   iters=2 if interpret else 20, warmup=1 if interpret else 3,
-                   reps=1 if interpret else 3)
+    def chained(fn):
+        # the decode tick's own order: write the step's K/V into its page
+        # slot, then attend. The pools are carried, or XLA hoists the
+        # fallback's gather out of the loop and only its products are timed
+        def run(q, kp, vp, tables, lens):
+            phys, off = tables[jnp.arange(B), lens // page], lens % page
 
-    def xla(q, kp, vp, tables, lens):
-        T = per_seq * page
-        ks = jnp.moveaxis(
-            kp[:, jnp.maximum(tables, 0)].reshape(H_kv, B, T, D), 0, 2)
-        vs = jnp.moveaxis(
-            vp[:, jnp.maximum(tables, 0)].reshape(H_kv, B, T, D), 0, 2)
-        ks = jnp.repeat(ks, H // H_kv, axis=2)
-        vs = jnp.repeat(vs, H // H_kv, axis=2)
-        lg = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
-                        ks.astype(jnp.float32)) / np.sqrt(D)
-        lg = jnp.where(jnp.arange(T)[None, None, :] <= lens[:, None, None],
-                       lg, -jnp.inf)
-        p = jax.nn.softmax(lg, axis=-1)
-        return jnp.einsum("bht,bthd->bhd", p, vs.astype(jnp.float32))
+            def body(carry, _):
+                q, kp, vp = carry
+                new = jnp.swapaxes(q[:, :H_kv], 0, 1)
+                kp, vp = _kv_scatter_tokens((kp, vp), phys, off, new, new)
+                return (fn(q, kp, vp, tables, lens), kp, vp), None
+            return jax.lax.scan(body, (q, kp, vp), None, length=steps)[0][0]
+        return jax.jit(run)
 
-    xfn = jax.jit(xla)
-    xdt = _time_fn(xfn, q, kp, vp, tables, lens,
-                   iters=2 if interpret else 20, warmup=1 if interpret else 3,
-                   reps=1 if interpret else 3)
-    line = {"bench": "paged_decode", "device": kind,
-            "shape": f"b{B}_h{H}x{H_kv}_d{D}_ctx{page * per_seq}",
-            "pallas_us": round(pdt * 1e6, 1), "xla_us": round(xdt * 1e6, 1),
-            "speedup": round(xdt / pdt, 3)}
-    print(json.dumps(line))
-    return [line]
+    fns = {name: chained(fn) for name, fn in impls.items()}
+    results = []
+    for ctx in contexts:
+        if ctx == "ragged":
+            lens = np.exp(rs.uniform(np.log(span * 5 / 64),
+                                     np.log(span * 5 / 8), B)).astype(np.int32)
+        else:
+            lens = np.full((B,), ctx - 1, np.int32)
+        used = lens // page + 1
+        tables = rs.permutation(npages)[:B * per_seq].reshape(B, per_seq)
+        tables = np.where(np.arange(per_seq)[None] < used[:, None], tables, -1)
+        args = (q, kp, vp, jnp.asarray(tables, jnp.int32), jnp.asarray(lens))
+        line = {"bench": "paged_decode", "device": kind, "ctx": ctx,
+                "shape": f"b{B}_h{H}x{H_kv}_d{D}_pages{per_seq}x{page}",
+                "live_tokens": int(lens.sum() + B), "steps": steps}
+        for name, fn in fns.items():
+            t = _time_fn(fn, *args, iters=1, warmup=1, reps=3)
+            line[f"{name}_us"] = round(t / steps * 1e6, 1)
+        print(json.dumps(line), flush=True)
+        results.append(line)
+    return results
 
 
 def main():
@@ -203,6 +228,8 @@ def main():
                     help="write results into the in-repo tune_db.json")
     ap.add_argument("--out", default=None,
                     help="write results into this JSON file instead")
+    ap.add_argument("--paged-decode", action="store_true",
+                    help="only the paged-decode kernel-vs-XLA comparison")
     ap.add_argument("--interpret", action="store_true",
                     help="validate the sweep machinery in Pallas interpret "
                          "mode (any backend, nothing recorded)")
@@ -214,6 +241,14 @@ def main():
         from paddle_tpu.ops.registry import require_tpu
         configure_compilation_cache()
         require_tpu()
+
+    if args.paged_decode:
+        results = bench_paged_decode(interpret)
+        if not interpret:       # and a table four times as long
+            results += bench_paged_decode(interpret, per_seq=64,
+                                          contexts=(1024, 2048, 8192))
+        print(json.dumps({"tuned": False, "cases": len(results)}))
+        return
 
     import jax.numpy as jnp
     if interpret or args.quick:
