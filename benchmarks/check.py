@@ -1,5 +1,6 @@
 """What decides ``correct``: the timed path's output against the plain
-references, each number beside its limit (the limits and the readings they
+reference of the configuration's family (``families/``), each number beside
+its limit (the limits and the readings they
 were set from: the configuration's ``check`` group, and PERF.md section 2).
 
 Serving compares, for a seeded sample of the requests the window finished
@@ -17,8 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import traffic, weights
-from .refs import decoder
+from . import adamw, families, traffic, weights
 
 
 def number(name, value, limit):
@@ -61,9 +61,10 @@ def reference_gaps(config, seed, sample, quant=None, rows_per_block=2,
         blocks.append((jnp.asarray(ids), np.asarray(rows), np.asarray(cols),
                        np.asarray(toks)))
     get = lambda names: weights.make_some(seed, config, names)
+    logits_at = families.of(config).logits_at
     with jax.default_matmul_precision("highest"):
-        ref = decoder.logits_at(config, get, [b[:3] for b in blocks], None)
-        low = (decoder.logits_at(config, get, [b[:3] for b in blocks], quant)
+        ref = logits_at(config, get, [b[:3] for b in blocks], None)
+        low = (logits_at(config, get, [b[:3] for b in blocks], quant)
                if quant else None)
     served_gap, control_gap = [], []
     for i, (_, _, _, toks) in enumerate(blocks):
@@ -123,16 +124,17 @@ def reference_training(config, mix, seed, steps, quant=None, first_grads=None):
     leaf of (``first_grads`` - the reference's first gradient)).
     ``first_grads`` maps leaf names to host arrays, or is a callable that
     takes the reference's first gradients (the control's come that way)."""
-    names = list(weights.leaf_shapes(config))
+    family, shapes = families.of(config), weights.leaf_shapes(config)
+    names = list(shapes)
     batch = config["trainer"]["rows_per_chip"] * config.get("chips", 1)
     with jax.default_matmul_precision("highest"):
         leaves = dict(weights.make_some(seed, config, names))
-        state = decoder.adamw_init(leaves)
+        state = adamw.adamw_init(leaves)
         losses, grad_norms, diff = [], None, None
         # the configuration STORES its matrices in ``dtype`` and updates
         # float32 master weights: each step multiplies with the stored
         # values (float32 arithmetic on them), the update goes to the master
-        dtype, shapes = jnp.dtype(config["dtype"]), weights.leaf_shapes(config)
+        dtype = jnp.dtype(config["dtype"])
         stored = jax.jit(lambda t: {
             k: v.astype(dtype).astype(jnp.float32) if shapes[k][1] == "matrix"
             else v for k, v in t.items()})
@@ -141,7 +143,7 @@ def reference_training(config, mix, seed, steps, quant=None, first_grads=None):
         for k in range(steps):
             rows = traffic.training_rows(mix, seed, k * batch, batch,
                                          config["vocab_size"])
-            loss, grads = decoder.loss_and_grads(
+            loss, grads = family.loss_and_grads(
                 config, stored(leaves), jnp.asarray(rows["input_ids"]),
                 jnp.asarray(rows["labels"]), quant,
                 config["check"].get("rows_per_block", 1))
@@ -154,8 +156,8 @@ def reference_training(config, mix, seed, steps, quant=None, first_grads=None):
                     diff = {n: float(jnp.sqrt(jnp.sum(jnp.square(
                         jnp.asarray(theirs[n], jnp.float32) - grads[n]))))
                         for n in names}
-            leaves, state = decoder.adamw_step(leaves, grads, state,
-                                               config["optimizer"])
+            leaves, state = adamw.adamw_step(leaves, grads, state,
+                                             config["optimizer"])
         del state, grads
         change = {}
         for n in names:         # against the seeded start, a leaf at a time
@@ -174,7 +176,7 @@ def training_numbers(config, program_side, reference_side):
     out = [number(f"loss_step{i}_abs_diff", abs(a - b), lim["loss_abs_diff"][i])
            for i, (a, b) in enumerate(zip(pl, rl))]
     out.append(number("loss0_vs_seeded_init", abs(
-        pl[0] - decoder.loss0_expected(config, weights.INIT_STD)),
+        pl[0] - families.of(config).loss0_expected(config, weights.INIT_STD)),
         lim["loss0_vs_seeded_init"]))
     out.append(number("grad_norm_worst_leaf", worst_leaf(pg, rg),
                        lim["grad_norm_worst_leaf"]))

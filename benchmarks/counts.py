@@ -7,7 +7,12 @@ memory are the implementation's cost and show as a LOWER share. So no share
 can pass 100% by a miscount here; tests/test_counts.py holds each function
 to hand-worked numbers for the three configurations.
 
-``model`` is a configuration's ``model`` group.
+These are the counts of ONE family, ``families/gqa_decoder.py``, which is
+the only module that imports this one: the harness reaches a count through
+the configuration's family and never directly.
+
+``model`` is a configuration's ``model`` group; ``shapes`` what a run's
+traffic fixed (``rows_per_chip``, ``seq_len``).
 """
 
 from __future__ import annotations
@@ -73,6 +78,37 @@ def flash_bytes(model, batch: int, seq_len: int, itemsize: int = 2) -> dict:
     q = batch * seq_len * n_q * hd * itemsize
     kv = batch * seq_len * n_kv * hd * itemsize
     return {"fwd": 2 * q + 2 * kv, "bwd": 4 * q + 4 * kv}
+
+
+def flash_attention(model, shapes) -> dict:
+    """``flash_flops`` and ``flash_bytes`` as a ``work: "kernel"`` roofline
+    takes them: per pass, for one training step (every layer)."""
+    b, s, layers = shapes["rows_per_chip"], shapes["seq_len"], model["num_hidden_layers"]
+    f, by = flash_flops(model, b, s), flash_bytes(model, b, s)
+    return {k: {"flops": layers * f[k], "bytes": layers * by[k]}
+            for k in ("fwd", "bwd")}
+
+
+def expert_gemm(model, shapes, itemsize: int = 2) -> dict:
+    """The per-expert matrix products of one training step of a routed
+    model (every layer): each token's row goes through the gate, up and
+    down projections ([H, F], [H, F], [F, H]) of its ``top_k`` experts, so
+    forward is 2 * tokens * top_k * 3 H F FLOPs a layer; backward takes the
+    gradient of both operands of each product, twice that. Least bytes:
+    every expert's weights once a pass (backward writes their gradient
+    once too) and the routed rows [tokens * top_k, H] in and out (backward:
+    the rows, the output's gradient in, the rows' gradient out). The
+    [tokens * top_k, 2 F] intermediate need not leave the chip's memory."""
+    h, f = model["hidden_size"], model["intermediate_size"]
+    routed = shapes["rows_per_chip"] * shapes["seq_len"] * model["num_experts_per_tok"]
+    layers = model["num_hidden_layers"]
+    fwd = 2.0 * routed * 3 * h * f
+    weights = model["num_experts"] * 3 * h * f * itemsize
+    rows = routed * h * itemsize
+    return {"fwd": {"flops": layers * fwd,
+                    "bytes": layers * (weights + 2 * rows)},
+            "bwd": {"flops": layers * 2 * fwd,
+                    "bytes": layers * (2 * weights + 3 * rows)}}
 
 
 def weight_bytes(model, itemsize: int = 2) -> int:

@@ -2,8 +2,9 @@
 
 From the program the benchmark takes the system (model classes, the serving
 engine, the trainer, the mesh) and nothing that decides a number: weights
-come from ``weights.py``, traffic from ``traffic.py``, time from the
-harness's clock, correctness from ``refs/``. Which class to build and how
+come from ``weights.py`` (which leaves: the configuration's family), traffic
+from ``traffic.py``, time from the harness's clock, correctness from the
+family's reference. Which class to build and how
 its parameters are called is data in the configuration's ``program`` group.
 """
 
@@ -77,6 +78,15 @@ def build_trainer(config: dict, seed: int):
     o = dict(config["optimizer"])
     opt = getattr(opt_mod, o.pop("class"))(parameters=model, **o)
     return Trainer(model, opt), names, model
+
+
+def trainer_counters(tr) -> dict:
+    """The counters the trainer keeps: its ``stats()`` where it has one,
+    else its public ``dispatch_stats`` (steps, dispatches, the host's
+    seconds spent enqueueing) and the programs its ``build_log`` lists."""
+    if callable(getattr(tr, "stats", None)):
+        return dict(tr.stats())
+    return dict(tr.dispatch_stats, programs_built=len(tr.build_log))
 
 
 def make_loader(rows_fn, batch: int):

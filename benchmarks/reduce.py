@@ -18,12 +18,17 @@ import glob
 import os
 import re
 
-from . import counts
+from . import families
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
+# a span: ``<prefix>::<name>`` as the harness (``bm::``) and the program
+# (``serving::``, ``trainer::``, ``compile::``, whatever a new mechanism
+# adds) name theirs; the runtime's own C++ events (``Class::Method``,
+# ``tpu::System::Execute``) start with a capital or nest further
+SPAN = re.compile(r"^[a-z][a-z0-9_]*::[^:]+$")
 COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
                         r"collective-permute)(-start|-done)?\b")
 
@@ -47,6 +52,7 @@ class Event:
     name: str
     start_ns: float
     dur_ns: float
+    stats: dict = dataclasses.field(default_factory=dict, compare=False)
 
     @property
     def end_ns(self):
@@ -54,7 +60,10 @@ class Event:
 
 
 def read_xplane(path: str) -> list:
-    """All events of a trace directory (or one ``.xplane.pb``)."""
+    """The events of a trace directory (or one ``.xplane.pb``) a reducer
+    can read: everything on the device planes, and from the host plane the
+    spans (``SPAN``) with their stats (``block``, ``rid``, ``step_num``,
+    ``bucket``...)."""
     from jax.profiler import ProfileData
     if os.path.isdir(path):
         found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
@@ -69,10 +78,23 @@ def read_xplane(path: str) -> list:
             continue
         for line in plane.lines:
             for e in line.events:
-                if keep_all or e.name.startswith("bm::"):
+                if keep_all:
                     out.append(Event(plane.name, line.name, e.name,
                                      float(e.start_ns), float(e.duration_ns)))
+                elif SPAN.match(e.name):
+                    out.append(Event(plane.name, line.name, e.name,
+                                     float(e.start_ns), float(e.duration_ns),
+                                     dict(e.stats)))
     return out
+
+
+def host_spans(events) -> dict:
+    """name -> [(start, end), ...] of the host plane's spans."""
+    spans = {}
+    for e in events:
+        if e.plane == HOST_PLANE:
+            spans.setdefault(e.name, []).append((e.start_ns, e.end_ns))
+    return spans
 
 
 def device_ops(events, device: int | None = None) -> list:
@@ -208,18 +230,16 @@ def exposed_collective_seconds(events, device: int) -> float:
 
 
 def idle_gaps_by_span(events, device: int = 0, n: int = 10) -> list:
-    """Idle time of the device inside the window, shared out to the harness
-    span (``bm::...``) that covers each stretch of it; what no span covers
-    is ``outside_spans``."""
+    """Idle time of the device inside the window, shared out to the span of
+    the harness (``bm::...``) or of the program (``serving::admit``,
+    ``compile::<program>``...) that covers each stretch of it; what no span
+    covers is ``outside_spans``."""
     window = window_of(events)
     if window is None:
         return []
     busy = union((e.start_ns, e.end_ns) for e in device_ops(events, device))
     idle = subtract([window], busy)
-    spans = {}
-    for e in events:
-        if e.plane == HOST_PLANE and e.name.startswith("bm::"):
-            spans.setdefault(e.name, []).append((e.start_ns, e.end_ns))
+    spans = host_spans(events)
     out, covered = {}, []
     # innermost spans first: a nested span takes its time from its parent
     for name in sorted(spans, key=lambda k: _length(union(spans[k]))):
@@ -270,6 +290,16 @@ def _counter(ctx, spec):
     return None if c is None else c * spec.get("scale", 1.0)
 
 
+def _counter_ratio(ctx, spec):
+    """A quotient of two counters of the window (a rate of acceptance, of
+    hits): nothing where either is missing or nothing was counted below."""
+    num = ctx["counters"].get(spec["numerator"])
+    den = ctx["counters"].get(spec["denominator"])
+    if num is None or not den:
+        return None
+    return num / den * spec.get("scale", 1.0)
+
+
 def _idle_share(ctx, spec):
     s = device_summary(ctx.get("events") or [])
     if s is None:
@@ -284,6 +314,19 @@ def _device_op_share(ctx, spec):
     if s is None:
         return None
     return 100.0 * op_seconds(ev, spec["pattern"], 0) / s["window_s"]
+
+
+def _span_share(ctx, spec):
+    """The host spans ``pattern`` names: their union inside the traced
+    window, as a share of it."""
+    ev = ctx.get("events") or []
+    window = window_of(ev)
+    rx = re.compile(spec["pattern"])
+    ivs = [iv for name, got in host_spans(ev).items() if rx.search(name)
+           for iv in got]
+    if window is None or not ivs:
+        return None
+    return 100.0 * _length(_clip(union(ivs), *window)) / (window[1] - window[0])
 
 
 def _exposed_collective(ctx, spec):
@@ -304,33 +347,40 @@ def _mfu(ctx, spec):
     tok = ctx["e2e"].get(spec["rate"])
     if tok is None:
         return None
-    flops = counts.train_flops_per_token(ctx["model"], ctx["shapes"]["seq_len"])
+    flops = families.of(ctx["model"]).train_flops_per_token(
+        ctx["model"], ctx["shapes"]["seq_len"])
     return 100.0 * tok * flops / ctx["peaks"]["bf16_flops"]
 
 
 def _roofline(ctx, spec):
-    """Least time by the chip's peaks over device time from the trace."""
+    """Least time by the chip's peaks over device time from the trace. What
+    the work requires is the family's to say (``families/``)."""
     ev = ctx.get("events") or []
-    model, shapes, peaks = ctx["model"], ctx["shapes"], ctx["peaks"]
+    model, peaks = ctx["model"], ctx["peaks"]
     if spec["work"] == "decode_tick":
         # bytes a tick needs over the device time of one decode program
         tick = most_run_module(ev, spec["module"])
         if not tick or not ctx["counters"].get("live_tokens_mean"):
             return None
-        need = counts.decode_tick_bytes(model, ctx["counters"]["live_tokens_mean"])
+        need = families.of(model).decode_tick_bytes(
+            model, ctx["counters"]["live_tokens_mean"])
         return 100.0 * (need / peaks["hbm_bytes_per_s"]) / (tick[1] / tick[0])
-    if spec["work"] == "flash":
-        # causal attention's need, all layers, forward and backward, over
-        # the device time of the kernels ``pattern`` names, per step
+    if spec["work"] in ("kernel", "flash"):
+        # what the family's function ``counts`` says one run of ``module``
+        # requires of a kernel, pass by pass, over the device time of the
+        # operations ``pattern`` names, per run. "flash" is the kernel whose
+        # function is ``flash_attention`` (causal attention, every layer,
+        # forward and backward)
         step = most_run_module(ev, spec["module"])
         secs = op_seconds(ev, spec["pattern"], 0)
         if not step or not secs:
             return None
-        b, s = shapes["rows_per_chip"], shapes["seq_len"]
-        f, by = counts.flash_flops(model, b, s), counts.flash_bytes(model, b, s)
-        least = sum(max(f[k] / peaks["bf16_flops"],
-                        by[k] / peaks["hbm_bytes_per_s"]) for k in ("fwd", "bwd"))
-        return 100.0 * least * model["num_hidden_layers"] * step[0] / secs
+        name = "flash_attention" if spec["work"] == "flash" else spec["counts"]
+        need = families.kernel_work(model, name, ctx["shapes"])
+        least = sum(max(p["flops"] / peaks["bf16_flops"],
+                        p["bytes"] / peaks["hbm_bytes_per_s"])
+                    for p in need.values())
+        return 100.0 * least * step[0] / secs
     raise ValueError(f"unknown work {spec['work']!r}")
 
 
@@ -339,8 +389,10 @@ REDUCERS = {
     "sample_mean": _sample_mean,
     "counter": _counter,
     "counter_rate": _counter_rate,
+    "counter_ratio": _counter_ratio,
     "idle_share": _idle_share,
     "device_op_share": _device_op_share,
+    "span_share": _span_share,
     "exposed_collective": _exposed_collective,
     "memory_peak": _memory_peak,
     "mfu": _mfu,

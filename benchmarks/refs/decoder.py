@@ -4,9 +4,15 @@ Straightforward ``jax.numpy`` in float32 under
 ``jax.default_matmul_precision("highest")`` (the callers set it): RMSNorm,
 rotary embedding (half-rotation, as Hugging Face's Llama/Mistral/OLMoE),
 causal softmax attention with the KV heads repeated, a SwiGLU MLP or the
-routed block of ``refs/olmoe.py``, cross entropy, and AdamW. No kernels, no
+routed block of ``refs/olmoe.py``, and cross entropy (AdamW, which knows
+nothing of the architecture, is ``benchmarks/adamw.py``). No kernels, no
 cache, no batching tricks. It imports nothing of the program and takes its
 weights from ``benchmarks/weights.py`` by canonical name.
+
+Another family's reference reuses the pieces (``mm``, ``rms_norm``,
+``attention``, ``dense_mlp``) and the two drivers: ``logits_at`` and
+``loss_and_grads`` walk the layers through ``arch.layer_names`` and
+``arch.layer``, which are this module's own unless a family passes its.
 
 ``quant`` is the control of "How correct is decided": the same mathematics
 with every matrix multiplication's inputs rounded to the next precision
@@ -18,10 +24,10 @@ from __future__ import annotations
 
 import functools
 import math
+import sys
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import olmoe
 
@@ -119,25 +125,30 @@ def layer(model, w, x, quant=None, route=None):
     return h + y, aux, counts
 
 
-def _short(model, i, leaves):
+def _short(model, i, leaves, arch):
     p = f"layers.{i}."
-    return {n[len(p):]: leaves[n] for n in layer_names(model, i)}
+    return {n[len(p):]: leaves[n] for n in arch.layer_names(model, i)}
+
+
+def _arch(arch):
+    return arch or sys.modules[__name__]
 
 
 # -- serving: logits of a few rows, one layer's weights at a time -------------
 
-def logits_at(model, get, blocks, quant=None):
+def logits_at(model, get, blocks, quant=None, arch=None):
     """For each block (ids [n, s], rows, cols): logits [len(rows), V] at
     positions (rows[j], cols[j]). Rows are padded on the right (causal, so
     padding cannot reach back). ``get(names)`` returns those leaves in
     float32; it is called once per layer, so only one layer's weights are
     alive, and each block is a batch of its own, so the rest fits."""
-    step = jax.jit(lambda w, x: layer(model, w, x, quant)[0])
+    arch = _arch(arch)
+    step = jax.jit(lambda w, x: arch.layer(model, w, x, quant)[0])
     embed = get(["embed"])["embed"]
     xs = [jnp.take(embed, ids, axis=0) for ids, _, _ in blocks]
     del embed
     for i in range(model["num_hidden_layers"]):
-        w = _short(model, i, get(layer_names(model, i)))
+        w = _short(model, i, get(arch.layer_names(model, i)), arch)
         xs = [step(w, x) for x in xs]
     tail = get(["final_norm", "head"])
     return [mm(rms_norm(x[rows, cols], tail["final_norm"],
@@ -145,15 +156,15 @@ def logits_at(model, get, blocks, quant=None):
             for x, (_, rows, cols) in zip(xs, blocks)]
 
 
-# -- training: loss, gradients, AdamW ----------------------------------------
+# -- training: loss and gradients ---------------------------------------------
 
-def _forward_rows(model, leaves, ids, quant, routes):
+def _forward_rows(model, leaves, ids, quant, routes, arch):
     """(final hidden states, load-balance term, per-layer routing sums)."""
     x = jnp.take(leaves["embed"], ids, axis=0)
     aux, seen = jnp.zeros((), jnp.float32), []
-    block = jax.checkpoint(lambda w, x_, r: layer(model, w, x_, quant, r))
+    block = jax.checkpoint(lambda w, x_, r: arch.layer(model, w, x_, quant, r))
     for i in range(model["num_hidden_layers"]):
-        x, a, counts = block(_short(model, i, leaves), x,
+        x, a, counts = block(_short(model, i, leaves, arch), x,
                              None if routes is None else routes[i])
         aux = aux + a
         seen.append(counts)
@@ -166,12 +177,15 @@ def _nll_sum(model, leaves, hidden, labels, quant):
     return -jnp.sum(jnp.take_along_axis(logp, labels[..., None], -1))
 
 
-def loss_and_grads(model, leaves, ids, labels, quant=None, rows_per_block=1):
+def loss_and_grads(model, leaves, ids, labels, quant=None, rows_per_block=1,
+                   arch=None):
     """Mean next-token cross entropy (+ the routed blocks' load-balance
     term times ``aux_loss_weight``) over the whole batch, and its gradient,
     accumulated over blocks of rows so that it fits. The load-balance term
     couples all tokens of the batch, so a first pass without gradients takes
-    each routed layer's batch-wide routing shares (olmoe.route_counts)."""
+    each routed layer's batch-wide routing shares (olmoe.merge_counts); a
+    layer that routes nothing reports no counts and gets no shares."""
+    arch = _arch(arch)
     n, s = ids.shape
     blocks = [(a, min(a + rows_per_block, n))
               for a in range(0, n, rows_per_block)]
@@ -179,13 +193,14 @@ def loss_and_grads(model, leaves, ids, labels, quant=None, rows_per_block=1):
     routes = None
     if routed:
         first = jax.jit(lambda lv, x: _forward_rows(model, lv, x, quant,
-                                                    None)[2])
+                                                    None, arch)[2])
         per_block = [first(leaves, ids[a:b]) for a, b in blocks]
-        routes = [olmoe.merge_counts([pb[i] for pb in per_block], n * s)
+        routes = [None if per_block[0][i] is None else
+                  olmoe.merge_counts([pb[i] for pb in per_block], n * s)
                   for i in range(model["num_hidden_layers"])]
 
     def block_loss(lv, x, y, rt):
-        hidden, aux, _ = _forward_rows(model, lv, x, quant, rt)
+        hidden, aux, _ = _forward_rows(model, lv, x, quant, rt, arch)
         return (_nll_sum(model, lv, hidden, y, quant) / (n * s)
                 + model.get("aux_loss_weight", 0.0) * aux)
 
@@ -199,38 +214,6 @@ def loss_and_grads(model, leaves, ids, labels, quant=None, rows_per_block=1):
         l, grads = step(leaves, grads, ids[a:b], labels[a:b], routes)
         loss = loss + l
     return loss, grads
-
-
-def adamw_init(leaves):
-    """Both moments, kept on the HOST between steps so that the reference
-    fits beside nothing but its own weights and gradients."""
-    return {"step": 0,
-            "m": {k: np.zeros(v.shape, np.float32) for k, v in leaves.items()},
-            "v": {k: np.zeros(v.shape, np.float32) for k, v in leaves.items()}}
-
-
-def adamw_step(leaves, grads, state, opt):
-    """Decoupled weight decay on every leaf, bias-corrected moments: the
-    textbook AdamW the configuration's ``optimizer`` group parametrises.
-    One leaf at a time; ``leaves`` and ``grads`` are used up."""
-    t = state["step"] + 1
-    b1, b2, eps = opt["beta1"], opt["beta2"], opt["epsilon"]
-    lr, wd = opt["learning_rate"], opt["weight_decay"]
-
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def one(p, g, m, v):
-        m = b1 * m + (1 - b1) * g
-        v = b2 * v + (1 - b2) * g * g
-        upd = (m / (1 - b1 ** t)) / (jnp.sqrt(v / (1 - b2 ** t)) + eps)
-        return p - lr * (upd + wd * p), m, v
-
-    new = {}
-    for k in list(leaves):
-        p, m, v = one(leaves.pop(k), grads.pop(k), state["m"][k], state["v"][k])
-        new[k] = p
-        state["m"][k], state["v"][k] = np.asarray(m), np.asarray(v)
-    state["step"] = t
-    return new, state
 
 
 def loss0_expected(model, init_std):

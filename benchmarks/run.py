@@ -4,11 +4,12 @@
     python benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell is made of is found by name: the cell in BENCHMARK.json,
-its configuration's file, ``traffic/<traffic>.json`` and, in a traced run,
-every ``layer_metrics/<name>.json`` that lists the cell. One process per
-run: load, warm this cell's shapes, measure, check, print. Without the chips
-the cell asks for, or on a device missing from ``peaks.json``, it exits 2
-and prints no result.
+its configuration's file and through it the configuration's family
+(``families/``: leaves, reference, required work), ``traffic/<traffic>.json``
+and, in a traced run, every ``layer_metrics/<name>.json`` that lists the
+cell. One process per run: load, warm this cell's shapes, measure, check,
+print. Without the chips the cell asks for, or on a device missing from
+``peaks.json``, it exits 2 and prints no result.
 """
 
 from __future__ import annotations
@@ -40,7 +41,7 @@ def load_json(path):
 
 def resolve(workload: str, benchmark_file: str):
     """(cell, configuration, traffic mix, per-layer metric files)."""
-    from benchmarks import traffic
+    from benchmarks import families, traffic
     bench = load_json(benchmark_file)
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -50,6 +51,7 @@ def resolve(workload: str, benchmark_file: str):
     config = load_json(os.path.join(ROOT,
                                     entry["file"]))
     config["chips"] = cell["chips"]
+    families.of(config)     # a missing or unknown family fails here, loudly
     mix = traffic.load(cell["traffic"])
     metrics = []
     for path in sorted(glob.glob(os.path.join(HERE, "layer_metrics", "*.json"))):
@@ -88,6 +90,16 @@ class Context:
             import jax
             return jax.profiler.TraceAnnotation(name)
         return contextlib.nullcontext()
+
+    def count_program(self, prefix, opened, closed):
+        """The program's own counters over the window, as
+        ``<prefix>.<key>``: every numeric key of its ``stats()``, at the
+        window's close less at its opening (a gauge such as ``queued``
+        means little as a difference: PERF.md section 3)."""
+        for k, v in closed.items():
+            if (isinstance(v, (int, float)) and not isinstance(v, bool)
+                    and isinstance(opened.get(k), (int, float))):
+                self.counters[f"{prefix}.{k}"] = v - opened[k]
 
     def on_compile(self, event, secs, **_):
         if self._in_window and event.endswith("backend_compile_duration"):

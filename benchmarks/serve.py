@@ -138,10 +138,12 @@ def measure(ctx, eng, sched, seconds):
     while True:
         now = time.perf_counter() - t_open
         if at_open is None and now >= 0.0:
-            at_open = (_ticks(eng), eng.preemptions)
+            at_open = (_ticks(eng), eng.preemptions, eng.stats())
             ctx.open_window()
         if at_close is None and now >= seconds:
-            at_close = (_ticks(eng), eng.preemptions, eng.stats()["queued"])
+            stats = eng.stats()
+            at_close = (_ticks(eng), eng.preemptions, stats["queued"])
+            ctx.count_program("engine", at_open[2], stats)
             ctx.close_window()
             if closed:
                 # the closed loop's window ends with the step during which

@@ -1,7 +1,9 @@
 """The harness end to end at tiny presets on the CPU, through run.py's own
 entry: the run line's keys, resolution by name, no chip means failure, a
-broken timed path means ``correct: false``, and a new configuration,
-traffic mix, per-layer metric and cell are each one new file."""
+broken timed path means ``correct: false``, a new configuration, traffic
+mix, per-layer metric and cell are each one new file, and so is a new
+FAMILY: its leaves, reference and counts, a kernel's roofline, a ratio of
+the program's counters and a share of one of its spans."""
 
 import json
 import os
@@ -291,3 +293,120 @@ def test_a_configuration_a_mix_a_metric_and_a_cell_are_each_one_new_file(tmp_pat
     assert traced["metrics"]["throwaway_ticks_s"]["value"] > 0
     for path, data in before.items():
         assert path.read_bytes() == data, f"{path} was edited"
+
+
+NEW_FAMILY = os.path.join(ROOT, "benchmarks", "tests", "data", "new_family")
+
+# The CPU has no device plane and no entry in peaks.json, so the run below
+# is given both: a v5e's peaks, and over the span of the host's real events a
+# step program with a grouped matmul in each run. Everything else is the
+# harness's own: the family found by name, its leaves in the program, its
+# reference deciding ``correct``, the trainer's counters and spans.
+_DRIVE_NEW_FAMILY = """
+import json, sys
+sys.path.insert(0, '.')
+from benchmarks import reduce, run
+
+load, events = run.load_json, run.Context.events
+
+def load_json(path):
+    out = load(path)
+    if path.endswith('peaks.json'):
+        out['cpu'] = out['TPU v5 lite']
+    return out
+
+def with_a_device(self):
+    ev = events(self)
+    host = [e for e in ev if e.plane == reduce.HOST_PLANE]
+    if host:
+        lo, hi = min(e.start_ns for e in host), max(e.end_ns for e in host)
+        run_ns = (hi - lo) / 4
+        for i in range(4):
+            ev.append(reduce.Event('/device:TPU:0', reduce.MODULES_LINE,
+                                   'jit_one_step(1)', lo + i * run_ns, run_ns))
+            ev.append(reduce.Event('/device:TPU:0', reduce.OPS_LINE,
+                                   '%jvp_grouped_matmul_.2 = f32[64,64]{1,0} custom-call()',
+                                   lo + i * run_ns, run_ns / 2))
+    return ev
+
+run.load_json, run.Context.events = load_json, with_a_device
+print(json.dumps([run.run_cell('tiny.shared-moe-train', 3, 1.0, t, require_chip=False)
+                  for t in (False, True)]))
+"""
+
+
+def test_a_family_is_new_files_only(tmp_path):
+    """In a temporary copy: a family the harness has never seen (a dense
+    first layer and a shared expert beside the routed ones, which
+    ``gqa_decoder`` cannot say and ``MoEForCausalLM`` builds), with its
+    reference, its counts, a configuration, a cell and one metric of each
+    new kind, every one a new file; nothing that exists is edited."""
+    shutil.copytree(os.path.join(ROOT, "benchmarks"), tmp_path / "benchmarks",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    os.symlink(os.path.join(ROOT, "paddle_tpu"), tmp_path / "paddle_tpu")
+    before = {p: p.read_bytes() for p in (tmp_path / "benchmarks").rglob("*") if p.is_file()}
+    added = []
+    for root, _, files in os.walk(NEW_FAMILY):
+        for f in files:
+            if f.endswith((".py", ".json")):
+                rel = os.path.relpath(os.path.join(root, f), NEW_FAMILY)
+                dest = tmp_path / "benchmarks" / rel
+                assert not dest.exists(), rel
+                shutil.copy(os.path.join(root, f), dest)
+                added.append(rel)
+    assert sorted(added) == [
+        "configs/tiny-shared-moe-train.json", "families/shared_expert_moe.py",
+        "layer_metrics/tiny_dispatch_span_share.json",
+        "layer_metrics/tiny_dispatches_per_step.json",
+        "layer_metrics/tiny_expert_gemm_roofline.json", "refs/shared_expert_moe.py"]
+    bench = json.load(open(TINY))
+    bench["configs"].append({"name": "tiny-shared-moe-train",
+                             "source": "https://example.invalid/tiny",
+                             "file": "benchmarks/configs/tiny-shared-moe-train.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "tiny.shared-moe-train",
+                               "config": "tiny-shared-moe-train",
+                               "traffic": "test-train", "chips": 1, "why": "test"})
+    for m in bench["end_to_end"]:
+        if "workloads" in m and "tiny.moe-train" in m["workloads"]:
+            m["workloads"].append("tiny.shared-moe-train")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    p = subprocess.run([sys.executable, "-c", _DRIVE_NEW_FAMILY], cwd=tmp_path,
+                       text=True, capture_output=True, timeout=900,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=""))
+    assert p.returncode == 0, p.stderr[-3000:]
+    plain, traced = json.loads(p.stdout.strip().splitlines()[-1])
+    assert plain["correct"] is True and traced["correct"] is True, p.stdout[-3000:]
+    assert set(plain["metrics"]) == {"train_tok_s_chip", "setup_s"}
+    got = {k: v["value"] for k, v in traced["metrics"].items()}
+    assert set(got) == {"tiny_expert_gemm_roofline", "tiny_dispatches_per_step",
+                        "tiny_dispatch_span_share"}
+    assert all(v == v and abs(v) < float("inf") for v in got.values())
+    assert 0 < got["tiny_expert_gemm_roofline"] < 100
+    assert got["tiny_dispatches_per_step"] == 1.0     # one program a step
+    assert 0 < got["tiny_dispatch_span_share"] <= 100
+    assert "trainer::dispatch" in {k for k, _ in traced["breakdown"]["idle_gaps"]}
+    for path, data in before.items():
+        assert path.read_bytes() == data, f"{path} was edited"
+
+
+def test_a_configuration_without_a_family_or_with_an_unknown_one_fails_loudly(tmp_path):
+    from benchmarks import families
+    bench = json.load(open(TINY))
+    cfg = json.load(open(os.path.join(ROOT, bench["configs"][0]["file"])))
+    assert families.of(cfg).__name__ == "benchmarks.families.gqa_decoder"
+    with pytest.raises(KeyError, match="names no \"family\""):
+        families.of({k: v for k, v in cfg.items() if k != "family"})
+    with pytest.raises(ModuleNotFoundError):
+        families.of(dict(cfg, family="benchmarks.families.no_such_family"))
+    with pytest.raises(AttributeError, match="lacks leaf_shapes"):
+        families.of(dict(cfg, family="benchmarks.counts"))
+    with pytest.raises(AttributeError, match="no count function"):
+        families.kernel_work(cfg, "no_such_kernel", {})
+    # and through run.py's own resolution, before anything is built
+    cfg.pop("family")
+    (tmp_path / "c.json").write_text(json.dumps(cfg))
+    bench["configs"][0]["file"] = str(tmp_path / "c.json")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(KeyError, match="family"):
+        run.resolve("tiny.closed", str(tmp_path / "BENCHMARK.json"))

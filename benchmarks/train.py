@@ -69,6 +69,7 @@ def run(ctx):
     loss.block_until_ready()
 
     ctx.open_window()
+    at_open = program.trainer_counters(tr)
     t_open = time.perf_counter()
     steps, pending = 0, []
     while True:
@@ -87,6 +88,7 @@ def run(ctx):
     with ctx.span("bm::sync"):
         last = float(pending[-1])
     elapsed = time.perf_counter() - t_open
+    ctx.count_program("trainer", at_open, program.trainer_counters(tr))
     ctx.close_window()
     mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
               for d in jax.devices())
