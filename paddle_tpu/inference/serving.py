@@ -283,6 +283,19 @@ class ContinuousBatchingEngine:
                 f"spec_k={spec_k} needs a model whose core implements "
                 f"decode_verify_paged (multi-token paged verify); "
                 f"{type(self.core).__name__} does not")
+        # a model that lacks the prefill-extend or the multi-token verify
+        # program (a latent-cache model has neither yet) cannot run the
+        # modes built on them: refused here, by name, not inside a trace
+        for mode, on, needs in (
+                ("chunked_prefill", chunked_prefill, ("prefill_chunk_paged",)),
+                ("prefix_cache", prefix_cache, ("prefill_chunk_paged",
+                                                "decode_verify_paged"))):
+            lacks = [m for m in needs if not hasattr(self.core, m)]
+            if on and lacks:
+                raise ValueError(
+                    f"{mode}=True needs a model whose core implements "
+                    f"{' and '.join(lacks)}; "
+                    f"{type(self.core).__name__} does not")
         self.cfg = generation_config or GenerationConfig()
         self.max_batch = max_batch
         self.page_size = page_size
@@ -300,6 +313,21 @@ class ContinuousBatchingEngine:
         # decode/prefill write paths quantize inside the model
         self.kv_quant = len(pools[0]) == 4
         self.kv_quant_ticks = 0             # decode ticks on an int8 pool
+        # "gqa": K and V pages per KV head; "mla": one latent row a token.
+        # Nothing below reads a pool entry's arrays as K and V except the
+        # handoff (serialize_pages / adopt_pages), which refuses the rest
+        self.attention_kind = getattr(self.core, "attention_kind", "gqa")
+        # bytes of cache a token takes over all layers, as ALLOCATED (a
+        # gauge): the 4-D arrays of every entry, a token's share of a page,
+        # a latent row's padding to whole lane tiles included
+        self.kv_bytes_per_token = sum(
+            a.shape[0] * a.shape[3] * a.dtype.itemsize
+            for entry in pools for a in entry if a.ndim == 4)
+        # counters a model's decode tick adds up on the device
+        # (``core.tick_counters``: a routed model's expert load); they ride
+        # to the host inside the block's token array
+        self._tick_counters = tuple(getattr(self.core, "tick_counters", ()))
+        self.tick_counts = dict.fromkeys(self._tick_counters, 0)
         self._total_pages = total - 1
         self._free: List[int] = list(range(total - 1, 0, -1))  # stack; 0 kept
         self.tables = np.zeros((max_batch, self.pages_per_seq), np.int32)
@@ -608,7 +636,9 @@ class ContinuousBatchingEngine:
                "preemptions": self.preemptions,
                "inflight": len(self._inflight),
                "attn_dense_ticks": self.attn_path_ticks["dense"],
-               "attn_paged_ticks": self.attn_path_ticks["paged"]}
+               "attn_paged_ticks": self.attn_path_ticks["paged"],
+               "kv_bytes_per_token": self.kv_bytes_per_token,
+               **self.tick_counts}
         if self.spec_k:
             out["spec_tokens_proposed"] = self.spec_tokens_proposed
             out["spec_tokens_accepted"] = self.spec_tokens_accepted
@@ -708,6 +738,14 @@ class ContinuousBatchingEngine:
 
     # -- KV-page handoff (serving-fabric disaggregation, ISSUE 12) -----------
 
+    def _refuse_latent_handoff(self) -> None:
+        if self.attention_kind != "gqa":
+            raise ValueError(
+                f"KV-page handoff ({HANDOFF_FMT}) carries K and V pages "
+                f"per KV head; this engine's pool holds "
+                f"{self.attention_kind!r} pages, which the format cannot "
+                f"say")
+
     @staticmethod
     def _handoff_bucket(n: int) -> int:
         """Next power of two ≥ n: the gather/scatter executable count
@@ -730,6 +768,7 @@ class ContinuousBatchingEngine:
         touching its own pool; the wire codec (base64 over TCP) lives in
         ``serving_fabric.transport``, this dict is the in-process
         form."""
+        self._refuse_latent_handoff()
         if self._prefix is None:
             raise RuntimeError("serialize_pages needs prefix_cache=True "
                                "(the radix tree owns the exportable "
@@ -787,6 +826,7 @@ class ContinuousBatchingEngine:
 
         Validation is strictly first: a corrupt, truncated or
         mis-shaped payload raises ValueError before anything mutates."""
+        self._refuse_latent_handoff()
         if self._prefix is None:
             raise RuntimeError("adopt_pages needs prefix_cache=True")
         fmt = payload.get("fmt") if isinstance(payload, dict) else None
@@ -1502,16 +1542,17 @@ class ContinuousBatchingEngine:
         return ts["tr"].start("replica::prefill", parent=parent,
                               tags={"kind": kind})
 
-    @staticmethod
-    def _prefill_event(req: _Request, slot: int, bucket: int, kind: str):
+    def _prefill_event(self, req: _Request, slot: int, bucket: int,
+                       kind: str):
         """The ``serving::prefill`` span of one prefill program for
         ``req`` (``bucket``: the width of ids it takes; ``kind``: full,
-        suffix, cow or chunk), stamping the start of the request's
-        first."""
+        suffix, cow or chunk; ``attn``: the model's attention kind),
+        stamping the start of the request's first."""
         if not req.prefill_start_t:
             req.prefill_start_t = time.perf_counter()
         return RecordEvent("serving::prefill", rid=req.rid, slot=slot,
-                           bucket=bucket, kind=kind)
+                           bucket=bucket, kind=kind,
+                           attn=self.attention_kind)
 
     def _decode_ready(self, req) -> bool:
         return req is not None and req.prefilled >= req.prefill_target
@@ -1593,6 +1634,7 @@ class ContinuousBatchingEngine:
         core, model = self.core, self.model
         head = model.logits if hasattr(model, "logits") else (lambda h: h)
         from ..ops.pallas.paged_attention import force_decode_impl
+        n_counts = len(self._tick_counters)
 
         # ``run`` is the decode tick's name in the device trace, and the
         # only program of the engine with that name: the benchmark's
@@ -1620,17 +1662,32 @@ class ContinuousBatchingEngine:
                     # slots HOLD real pages, stopped slots' speculative
                     # writes must be unreachable — one mask serves both
                     tbl = tables * active[:, None].astype(tables.dtype)
-                    h, pools = core.decode_step_paged(tok, pos, pools, tbl)
+                    if n_counts:
+                        h, pools, counts = core.decode_step_paged(
+                            tok, pos, pools, tbl, counters=True)
+                    else:
+                        h, pools = core.decode_step_paged(tok, pos, pools,
+                                                          tbl)
+                        counts = None
                     new_logits = head(h[:, 0, :])
                     new_active, budget = decode_stop_update(
                         tok, active, budget, knobs["eos"])
                     adv = active.astype(jnp.int32)
                     new_state = (new_logits, pos + adv, new_active,
                                  budget, gen + adv)
-                    return (new_state, pools), (tok, active)
+                    return (new_state, pools), (tok, active, counts)
 
-                (state, pools), (toks, kept) = jax.lax.scan(
+                (state, pools), (toks, kept, counts) = jax.lax.scan(
                     body, (state, pools), None, length=K)
+                if n_counts:
+                    # the block's counters ride behind its tokens, as
+                    # whole rows of the one array the host drains anyway
+                    rows = -(-n_counts // toks.shape[1])
+                    tail = jnp.zeros((rows * toks.shape[1],), toks.dtype)
+                    tail = tail.at[:n_counts].set(
+                        jnp.sum(counts, axis=0).astype(toks.dtype))
+                    toks = jnp.concatenate(
+                        [toks, tail.reshape(rows, toks.shape[1])])
             return toks, kept, state, pools
 
         return jax.jit(run, donate_argnums=(1,))
@@ -1996,6 +2053,11 @@ class ContinuousBatchingEngine:
         with RecordEvent("serving::drain", block=blk.seq):
             toks = np.asarray(blk.toks)            # [K, B]
             kept = np.asarray(blk.kept)            # [K, B] prefix mask
+            if self._tick_counters and not self.spec_k:
+                tail = toks[blk.K:].reshape(-1)    # the tick's counters
+                for name, n in zip(self._tick_counters, tail):
+                    self.tick_counts[name] += int(n)
+                toks = toks[:blk.K]
             pos_after = np.asarray(blk.pos)
             active_after = np.asarray(blk.active)
         with RecordEvent("serving::reconcile", block=blk.seq):

@@ -295,6 +295,21 @@ def _kv_gather_ctx(kv, tables):
     return one(kp), one(vp)
 
 
+def alloc_layer_pools(layers, batch: int, max_len: int, page_size: int):
+    """(pools, tables) of a paged model: one pool entry a layer, laid out
+    by the layer's attention (``self_attn.alloc_pool``), and the shared
+    block table with pages assigned contiguously per sequence (the
+    allocator is the caller's concern at serving scale; reference:
+    block_multi_head_attention's table-driven pool)."""
+    pages_per_seq = -(-max_len // page_size)
+    num_pages = batch * pages_per_seq
+    pools = [layer.self_attn.alloc_pool(num_pages, page_size)
+             for layer in layers]
+    tables = jnp.arange(num_pages, dtype=jnp.int32).reshape(
+        batch, pages_per_seq)
+    return pools, tables
+
+
 def _token_mean(nll, labels, ignore_index: int = -100):
     """Token-weighted mean over per-token nll (ignored rows already 0) —
     the ONE reduction both loss heads share; a drifting copy here is a
@@ -487,6 +502,22 @@ class LlamaAttention(nn.Layer):
 
 
     # -- paged-KV (vLLM-style) inference paths ------------------------------
+
+    def alloc_pool(self, num_pages: int, page_size: int):
+        """This layer's page pool entry: head-major K and V pools
+        [H_kv, num_pages, page_size, hd], (kp, vp) native or (kp, vp,
+        kscale, vscale) under ``kv_dtype="int8"``: int8 pages + one fp32
+        absmax scale per physical page, per K/V side (ISSUE 17). Scales
+        start at 0 = "page holds nothing": dequant of an unwritten page is
+        exactly the all-zeros page a native pool starts with."""
+        cfg = self.cfg
+        shape = (cfg.num_key_value_heads, num_pages, page_size, cfg.head_dim)
+        if getattr(cfg, "kv_dtype", "native") == "int8":
+            return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
+                    jnp.zeros((num_pages,), jnp.float32),
+                    jnp.zeros((num_pages,), jnp.float32))
+        dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+        return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
     def prefill_paged(self, x, cos, sin, kv, tables):
         """Prompt pass writing K/V into head-major page pools
@@ -788,32 +819,8 @@ class LlamaModel(nn.Layer):
 
     def alloc_paged_caches(self, batch: int, max_len: int,
                            page_size: int = 128):
-        """Per-layer head-major page pools + the shared block table.
-        Pages are assigned contiguously per sequence (the allocator is the
-        caller's concern at serving scale; reference:
-        block_multi_head_attention's table-driven pool)."""
-        cfg = self.cfg
-        pages_per_seq = -(-max_len // page_size)
-        num_pages = batch * pages_per_seq
-        shape = (cfg.num_key_value_heads, num_pages, page_size,
-                 cfg.head_dim)
-        if getattr(cfg, "kv_dtype", "native") == "int8":
-            # int8 pages + one fp32 absmax scale per physical page, per
-            # K/V side (ISSUE 17). Scales start at 0 = "page holds
-            # nothing": dequant of an unwritten page is exactly the
-            # all-zeros page a native pool starts with.
-            pools = [
-                (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                 jnp.zeros((num_pages,), jnp.float32),
-                 jnp.zeros((num_pages,), jnp.float32))
-                for _ in range(cfg.num_hidden_layers)]
-        else:
-            dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-            pools = [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
-                     for _ in range(cfg.num_hidden_layers)]
-        tables = jnp.arange(num_pages, dtype=jnp.int32).reshape(
-            batch, pages_per_seq)
-        return pools, tables
+        """Per-layer head-major page pools + the shared block table."""
+        return alloc_layer_pools(self.layers, batch, max_len, page_size)
 
     def prefill_paged(self, input_ids, pools, tables):
         x = jnp.take(self.embed_tokens, input_ids, axis=0)

@@ -12,9 +12,20 @@ module defines the architecture from the published papers' shapes:
 - Qwen2-MoE: same skeleton (shared_expert + routed), top-4 routing, with a
   sigmoid shared-expert gate.
 
+- DeepSeek-V2/V3's layout, as GLM-4.7-Flash publishes it (``attention=
+  "mla"``): latent attention (``LatentAttention``: low-rank queries, ONE
+  cached row a token shared by all heads) in front of sigmoid-routed
+  experts with a selection bias, a routing scale and a shared expert.
+
 TPU-first: reuses LlamaAttention (fused QKV, flash attention) and the
 dense-layout MoE block (one batched einsum on the MXU; all-to-all dispatch
 appears from GSPMD sharding — parallel/moe.py).
+
+Served through ``inference.ContinuousBatchingEngine`` by the paged trio
+(``alloc_paged_caches`` / ``prefill_paged`` / ``decode_step_paged``), whose
+loop takes each attention layer's own ``alloc_pool`` / ``prefill_paged`` /
+``decode_paged``: per-head K and V pages under GQA attention, one latent
+row under MLA.
 """
 
 from __future__ import annotations
@@ -31,7 +42,8 @@ from ..nn import functional as F
 from ..nn import initializer as I
 from ..ops import rope as rope_ops
 from ..parallel.moe import MoELayer
-from .llama import LlamaAttention, LlamaConfig, LlamaMLP, _normal
+from .llama import (LlamaAttention, LlamaConfig, LlamaMLP, _normal,
+                    alloc_layer_pools)
 
 
 @dataclass
@@ -58,19 +70,47 @@ class MoEConfig:
     dtype: str = "float32"
     recompute: str = "none"
     sequence_parallel: bool = False
+    # attention kind: "gqa" (LlamaAttention) or "mla" (LatentAttention,
+    # which needs the five sizes below; num_key_value_heads is not read)
+    attention: str = "gqa"
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    # the router (parallel/moe.py MoELayer): the defaults are the GShard
+    # router (softmax, renormalised whenever top-k > 1, no bias, no scale)
+    scoring_func: str = "softmax"
+    router_bias: bool = False              # selection bias (noaux_tc)
+    norm_topk_prob: Optional[bool] = None
+    routed_scaling_factor: float = 1.0
+
+    def __post_init__(self):
+        if self.attention not in ("gqa", "mla"):
+            raise ValueError(f"attention must be 'gqa' or 'mla', got "
+                             f"{self.attention!r}")
+        if self.attention == "mla":
+            missing = [k for k in ("q_lora_rank", "kv_lora_rank",
+                                   "qk_nope_head_dim", "qk_rope_head_dim",
+                                   "v_head_dim") if not getattr(self, k)]
+            if missing:
+                raise ValueError(f"attention='mla' needs {missing}")
 
     @property
     def head_dim(self):
         return self.hidden_size // self.num_attention_heads
 
     def _as_llama(self) -> LlamaConfig:
-        """Attention/MLP sublayers are config-compatible with Llama's."""
+        """Attention/MLP sublayers are config-compatible with Llama's
+        (under latent attention only the dense MLP reads it, and the head
+        count, which need not divide the hidden size there, is left out)."""
+        mla = self.attention == "mla"
         return LlamaConfig(
             vocab_size=self.vocab_size, hidden_size=self.hidden_size,
             intermediate_size=self.intermediate_size,
             num_hidden_layers=self.num_hidden_layers,
-            num_attention_heads=self.num_attention_heads,
-            num_key_value_heads=self.num_key_value_heads,
+            num_attention_heads=1 if mla else self.num_attention_heads,
+            num_key_value_heads=1 if mla else self.num_key_value_heads,
             max_position_embeddings=self.max_position_embeddings,
             rms_norm_eps=self.rms_norm_eps, rope_theta=self.rope_theta,
             initializer_range=self.initializer_range,
@@ -104,6 +144,188 @@ class MoEConfig:
                          num_experts_per_tok=2, num_shared_experts=1,
                          first_k_dense_replace=1,
                          max_position_embeddings=256, **kw)
+
+
+def _rope_at(positions, dim: int, theta: float):
+    """(cos, sin) [..., dim] float32 at whole-number ``positions``, in the
+    rotate-half convention. Computed where it is used: a table over
+    GLM-4.7-Flash's 202,752 positions would be 104 MB of constants in
+    every compiled program."""
+    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    ang = jnp.concatenate([ang, ang], axis=-1)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _rotate(x, cos, sin):
+    """Rotate-half RoPE in float32; x [b, s, h, dim], cos/sin [b|1, s, dim]."""
+    xf = x.astype(jnp.float32)
+    return (xf * cos[:, :, None, :]
+            + rope_ops._rotate_half(xf) * sin[:, :, None, :]).astype(x.dtype)
+
+
+class LatentAttention(nn.Layer):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), as
+    GLM-4.7-Flash configures it. Queries through a low-rank bottleneck
+    (``q_a_proj``, RMSNorm, ``q_b_proj``), per head [nope | rope]. Keys and
+    values from ONE latent a token: ``kv_a_proj`` gives [c | k_r]; ``c`` is
+    normalised, ``k_r`` rotated and shared by all heads; ``kv_b_proj``
+    expands ``c`` to per-head [k_nope | v].
+
+    Two forms of the same mathematics. Expanded (``forward``,
+    ``prefill_paged``): per-head keys [k_nope | k_r] and values through the
+    flash kernel. Absorbed (``decode_paged``): ``kv_b_proj``'s key half is
+    multiplied into the query and its value half applied after the
+    softmax, so attention runs over the cached rows ``[c | k_r]``
+    themselves: the cache is one row of kv_lora_rank + qk_rope_head_dim
+    numbers a token (padded to whole lane tiles), and a decode step never
+    expands it."""
+
+    attention_kind = "mla"
+
+    def __init__(self, cfg: MoEConfig):
+        super().__init__()
+        self.cfg = cfg
+        d, n_h, std = (cfg.hidden_size, cfg.num_attention_heads,
+                       cfg.initializer_range)
+        self.rank, self.rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        self.nope, self.v_dim = cfg.qk_nope_head_dim, cfg.v_head_dim
+        self.scale = 1.0 / math.sqrt(self.nope + self.rope)
+        # a cached row [c_kv | k_r] is padded with zeros to whole lane
+        # tiles (576 -> 640 at GLM-4.7-Flash's sizes). The chip pads the
+        # minor dim of a bf16 array to 128 anyway, and left at 576 it lays
+        # a [.., 128, 576] pool out PAGE-minor by default, which fed the
+        # decode kernel through two 250 MB copies a layer a tick (10.3 ms
+        # of a 24.3 ms tick: chip run, PR 27)
+        self.row = -(-(self.rank + self.rope) // 128) * 128
+
+        def proj(shape, sharding):
+            return self.create_parameter(shape, dtype=cfg.dtype,
+                                         initializer=_normal(std),
+                                         sharding=sharding)
+        self.q_a_proj = proj([d, cfg.q_lora_rank], ("fsdp", None))
+        self.q_a_layernorm = nn.RMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps,
+                                        dtype="float32")
+        self.q_b_proj = proj([cfg.q_lora_rank,
+                              n_h * (self.nope + self.rope)], ("fsdp", "tp"))
+        self.kv_a_proj = proj([d, self.rank + self.rope], ("fsdp", None))
+        self.kv_a_layernorm = nn.RMSNorm(self.rank, cfg.rms_norm_eps,
+                                         dtype="float32")
+        self.kv_b_proj = proj([self.rank, n_h * (self.nope + self.v_dim)],
+                              ("fsdp", "tp"))
+        self.o_proj = proj([n_h * self.v_dim, d], ("tp", "fsdp"))
+
+    def _mm(self, x, name):
+        return jnp.matmul(x, getattr(self, name).astype(x.dtype))
+
+    def _latents(self, x, positions):
+        """x [b, s, d] at ``positions`` [b|1, s] -> (q_nope [b, s, h, nope],
+        q_rope [b, s, h, rope], rows [b, s, row]): the queries and the
+        cache rows ``[c_kv | k_r | 0..]``, rotary applied."""
+        cfg = self.cfg
+        b, s, _ = x.shape
+        q = self._mm(self.q_a_layernorm(self._mm(x, "q_a_proj")), "q_b_proj")
+        q = q.reshape(b, s, cfg.num_attention_heads, self.nope + self.rope)
+        q_nope, q_rope = q[..., :self.nope], q[..., self.nope:]
+        ckr = self._mm(x, "kv_a_proj")
+        c = self.kv_a_layernorm(ckr[..., :self.rank]).astype(x.dtype)
+        cos, sin = _rope_at(positions, self.rope, cfg.rope_theta)
+        k_r = _rotate(ckr[..., None, self.rank:], cos, sin)[:, :, 0]
+        pad = jnp.zeros((b, s, self.row - self.rank - self.rope), x.dtype)
+        return (q_nope, _rotate(q_rope, cos, sin),
+                jnp.concatenate([c, k_r, pad], axis=-1))
+
+    def _kv_b(self, dtype):
+        """kv_b_proj per head: (w_uk [rank, h, nope], w_uv [rank, h, v])."""
+        w = self.kv_b_proj.astype(dtype).reshape(
+            self.rank, self.cfg.num_attention_heads, self.nope + self.v_dim)
+        return w[..., :self.nope], w[..., self.nope:]
+
+    def _expanded(self, q_nope, q_rope, rows):
+        """Causal attention in the expanded form, rows starting at 0."""
+        from ..ops.attention import flash_attention
+        b, s, n_h, _ = q_nope.shape
+        w_uk, w_uv = self._kv_b(rows.dtype)
+        c = rows[..., :self.rank]
+        k_r = rows[..., self.rank:self.rank + self.rope]
+        k = jnp.concatenate(
+            [jnp.einsum("bsr,rhn->bshn", c, w_uk),
+             jnp.broadcast_to(k_r[:, :, None, :], (b, s, n_h, self.rope))],
+            axis=-1)
+        v = jnp.einsum("bsr,rhv->bshv", c, w_uv)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        out = flash_attention(q, k, v, causal=True, scale=self.scale)
+        return self._mm(out.reshape(b, s, n_h * self.v_dim), "o_proj")
+
+    def forward(self, x, cos=None, sin=None):
+        """``cos``/``sin`` are taken for the decoder layer's sake and not
+        read: the rotary angles are worked out from the positions."""
+        q_nope, q_rope, rows = self._latents(
+            x, jnp.arange(x.shape[1])[None])
+        return self._expanded(q_nope, q_rope, rows)
+
+    # -- paged serving path --------------------------------------------------
+
+    def alloc_pool(self, num_pages: int, page_size: int):
+        """This layer's page pool: ONE array [1, num_pages, page_size,
+        row] (pages on axis 1, as the engine's page copy expects), ``row``
+        being rank + rope padded to whole lane tiles."""
+        dt = jnp.bfloat16 if self.cfg.dtype == "bfloat16" else jnp.float32
+        return (jnp.zeros((1, num_pages, page_size, self.row), dt),)
+
+    def prefill_paged(self, x, cos, sin, kv, tables):
+        """Prompt pass: expanded attention, and the rows ``[c_kv | k_r]``
+        written into the pool's pages in the pool's dtype. Rows past the
+        prompt's own length (the engine pads ids to a bucket) lie beyond
+        seq_len and are overwritten by decode steps before they are ever
+        unmasked."""
+        (pool,) = kv
+        b, s, _ = x.shape
+        page = pool.shape[2]
+        q_nope, q_rope, rows = self._latents(x, jnp.arange(s)[None])
+        out = self._expanded(q_nope, q_rope, rows)
+        # row by row into the pool seen as rows, as decode_paged writes:
+        # a scatter of whole [page, width] tiles makes XLA:TPU re-lay the
+        # pool out and back (two 250 MB copies a layer a prefill)
+        at = (tables[:, jnp.arange(s) // page] * page
+              + jnp.arange(s) % page)                         # [b, s]
+        pool = pool.reshape(-1, pool.shape[3]).at[at.reshape(-1)].set(
+            rows.reshape(b * s, -1).astype(pool.dtype)).reshape(pool.shape)
+        return out, (pool,)
+
+    def decode_paged(self, x, cos, sin, pos, kv, tables):
+        """One-token step, absorbed: the new row goes to its page slot and
+        every head attends over the cached rows, read once in the dtype
+        they are stored in (the Pallas kernel on a TPU, its XLA twin
+        elsewhere or under ``force_decode_impl("dense")``)."""
+        from ..ops.pallas.latent_attention import (latent_decode_attention,
+                                                   latent_decode_supported,
+                                                   latent_decode_xla)
+        from ..ops.pallas.paged_attention import forced_decode_impl
+        from ..ops.registry import backend_kind
+        (pool,) = kv
+        b = x.shape[0]
+        _, _, page, width = pool.shape
+        q_nope, q_rope, rows = self._latents(x, pos.reshape(b, 1))
+        # one [width] ROW a token of the pool seen as rows (a windowed
+        # scatter makes XLA:TPU re-lay the whole pool out: llama.py)
+        row = tables[jnp.arange(b), pos // page] * page + pos % page
+        pool = pool.reshape(-1, width).at[row].set(
+            rows[:, 0].astype(pool.dtype)).reshape(pool.shape)
+        w_uk, w_uv = self._kv_b(x.dtype)
+        q = jnp.concatenate(
+            [jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk), q_rope[:, 0],
+             jnp.zeros((b, q_rope.shape[2], width - self.rank - self.rope),
+                       x.dtype)], axis=-1)             # [b, h, row]
+        if (forced_decode_impl() != "dense" and backend_kind() == "tpu"
+                and latent_decode_supported(q, pool, self.rank)):
+            ctx = latent_decode_attention(q, pool, tables, pos, self.rank,
+                                          self.scale)
+        else:
+            ctx = latent_decode_xla(q, pool, tables, pos, self.rank,
+                                    self.scale)
+        out = jnp.einsum("bhr,rhv->bhv", ctx.astype(x.dtype), w_uv)
+        return self._mm(out.reshape(b, 1, -1), "o_proj"), (pool,)
 
 
 class SharedExpertMLP(nn.Layer):
@@ -147,7 +369,8 @@ class MoEDecoderLayer(nn.Layer):
         lcfg = cfg._as_llama()
         self.input_layernorm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                           dtype="float32")
-        self.self_attn = LlamaAttention(lcfg)
+        self.self_attn = (LatentAttention(cfg) if cfg.attention == "mla"
+                          else LlamaAttention(lcfg))
         self.post_attention_layernorm = nn.RMSNorm(cfg.hidden_size,
                                                    cfg.rms_norm_eps,
                                                    dtype="float32")
@@ -160,7 +383,10 @@ class MoEDecoderLayer(nn.Layer):
             self.moe = MoELayer(cfg.hidden_size, cfg.moe_intermediate_size,
                                 cfg.num_experts, top_k=cfg.num_experts_per_tok,
                                 capacity_factor=cfg.capacity_factor,
-                                dtype=cfg.dtype)
+                                dtype=cfg.dtype, scoring=cfg.scoring_func,
+                                select_bias=cfg.router_bias,
+                                norm_topk_prob=cfg.norm_topk_prob,
+                                routed_scaling_factor=cfg.routed_scaling_factor)
             if cfg.num_shared_experts > 0:
                 self.shared_experts = SharedExpertMLP(cfg)
             else:
@@ -175,6 +401,17 @@ class MoEDecoderLayer(nn.Layer):
         if self.shared_experts is not None:
             routed = routed + self.shared_experts(z)
         return h + routed, aux
+
+    def mlp_inference(self, z):
+        """The block after attention on the serving path: (y, load), load
+        being the rows each routed expert was sent ([e] int32; None for a
+        dense layer). No auxiliary loss, no token dropped."""
+        if self.dense:
+            return self.mlp(z), None
+        routed, load = self.moe.forward_inference(z)
+        if self.shared_experts is not None:
+            routed = routed + self.shared_experts(z)
+        return routed, load
 
 
 class MoEForCausalLM(nn.Layer):
@@ -197,17 +434,73 @@ class MoEForCausalLM(nn.Layer):
             [cfg.hidden_size, cfg.vocab_size], dtype=cfg.dtype,
             initializer=_normal(cfg.initializer_range),
             sharding=("fsdp", "tp"))
-        cos, sin = rope_ops.rope_freqs(cfg.head_dim,
-                                       cfg.max_position_embeddings,
-                                       cfg.rope_theta)
-        self.register_buffer("rope_cos", cos, persistable=False)
-        self.register_buffer("rope_sin", sin, persistable=False)
+        self.attention_kind = cfg.attention
+        # what a decode tick counts on the device beside its tokens (the
+        # engine adds them up into ``stats()``): rows x top-k routed, and
+        # the most rows one expert got, summed over the routed layers
+        self.tick_counters = (("moe_assignments", "moe_peak_load")
+                              if cfg.first_k_dense_replace
+                              < cfg.num_hidden_layers else ())
+        if cfg.attention == "mla":
+            # LatentAttention works its rotary angles out from positions
+            self.rope_cos = self.rope_sin = None
+        else:
+            cos, sin = rope_ops.rope_freqs(cfg.head_dim,
+                                           cfg.max_position_embeddings,
+                                           cfg.rope_theta)
+            self.register_buffer("rope_cos", cos, persistable=False)
+            self.register_buffer("rope_sin", sin, persistable=False)
+
+    def logits(self, hidden):
+        return jnp.matmul(hidden, self.lm_head.astype(hidden.dtype))
+
+    # -- paged-KV serving path (inference.ContinuousBatchingEngine) ---------
+
+    def alloc_paged_caches(self, batch: int, max_len: int,
+                           page_size: int = 128):
+        """One pool entry a layer, laid out by the layer's attention
+        (``alloc_pool``), and the shared block table."""
+        return alloc_layer_pools(self.layers, batch, max_len, page_size)
+
+    def prefill_paged(self, input_ids, pools, tables):
+        x = jnp.take(self.embed_tokens, input_ids, axis=0)
+        new_pools = []
+        for layer, kv in zip(self.layers, pools):
+            a, kv = layer.self_attn.prefill_paged(
+                layer.input_layernorm(x), self.rope_cos, self.rope_sin,
+                kv, tables)
+            h = x + a
+            x = h + layer.mlp_inference(layer.post_attention_layernorm(h))[0]
+            new_pools.append(kv)
+        return self.norm(x), new_pools
+
+    def decode_step_paged(self, token_ids, pos, pools, tables,
+                          counters: bool = False):
+        """token_ids [b] -> (hidden [b, 1, d], pools) and, with
+        ``counters``, the tick's ``tick_counters`` as [2] int32."""
+        x = jnp.take(self.embed_tokens, token_ids[:, None], axis=0)
+        new_pools, routed, peak = [], 0, 0
+        for layer, kv in zip(self.layers, pools):
+            a, kv = layer.self_attn.decode_paged(
+                layer.input_layernorm(x), self.rope_cos, self.rope_sin,
+                pos, kv, tables)
+            h = x + a
+            y, load = layer.mlp_inference(layer.post_attention_layernorm(h))
+            x = h + y
+            new_pools.append(kv)
+            if load is not None:
+                routed, peak = routed + jnp.sum(load), peak + jnp.max(load)
+        if counters:
+            return self.norm(x), new_pools, jnp.stack(
+                [routed, peak]).astype(jnp.int32)
+        return self.norm(x), new_pools
 
     def forward(self, input_ids, labels=None):
         cfg = self.cfg
         s = input_ids.shape[1]
         x = jnp.take(self.embed_tokens, input_ids, axis=0)
-        cos, sin = self.rope_cos[:s], self.rope_sin[:s]
+        cos, sin = ((None, None) if self.rope_cos is None
+                    else (self.rope_cos[:s], self.rope_sin[:s]))
         aux_total = jnp.zeros((), jnp.float32)
         if cfg.recompute == "full":
             def run(layer, h):

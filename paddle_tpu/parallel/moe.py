@@ -308,21 +308,95 @@ class MoELayer(Layer):
 
     ``capacity_factor=None`` selects DROPLESS routing via grouped matmul
     (megablox gmm): exact per-expert counts, no token ever dropped.
+
+    The router's variants (the defaults are the GShard router this layer
+    has always had, so a model that passes none runs the program it ran):
+    ``scoring`` "softmax" | "sigmoid" (DeepSeek-V3's, arXiv:2412.19437:
+    each expert scored on its own); ``select_bias`` adds a float32
+    parameter ``gate_bias`` [e] to the scores for the CHOICE of experts
+    only (the weights stay the unbiased scores: the auxiliary-loss-free
+    balancing of that paper); ``norm_topk_prob`` None renormalises the
+    chosen weights whenever top_k > 1, True/False says so outright;
+    ``routed_scaling_factor`` multiplies them. The variants run on the
+    dropless and the inference paths; the capacity and expert-parallel
+    paths refuse them by name.
+
+    ``forward_inference`` (what ``forward`` runs once the layer is in eval
+    mode) computes no auxiliary loss and is dropless at any load.
     """
+
+    # rows at or below which the inference path runs EVERY expert over
+    # every row and weights the outputs (0 where an expert was not chosen)
+    # instead of sorting rows to their experts, once the rows' choices
+    # outnumber the experts: a decode tick's few rows then hit nearly every
+    # expert anyway (64 rows x top-4 over 64 experts: 62.97 expected), the
+    # products are bound by the experts' weights streaming from HBM either
+    # way (e x 6 d f bytes against t x e x 6 d f FLOPs: level at t =
+    # 197e12 / 819e9 = 240 rows on a v5e), and a plain batched matmul needs
+    # no sort, no gather and no per-group tiles: 1.67 ms a layer against
+    # ragged_dot's 2.36 at GLM-4.7-Flash's sizes (chip run, PR 27)
+    DENSE_ROWS = 128
 
     def __init__(self, hidden_size: int, ffn_size: int, num_experts: int,
                  top_k: int = 2, capacity_factor: Optional[float] = 1.25,
-                 dtype=None, gate: str = "gshard"):
+                 dtype=None, gate: str = "gshard",
+                 scoring: str = "softmax", select_bias: bool = False,
+                 norm_topk_prob: Optional[bool] = None,
+                 routed_scaling_factor: float = 1.0):
         super().__init__()
         if top_k > num_experts:
             raise ValueError(f"top_k={top_k} > num_experts={num_experts}")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
+                             f"got {scoring!r}")
         self.num_experts = num_experts
         self.top_k = 1 if gate == "switch" else top_k
         self.capacity_factor = capacity_factor
+        self.scoring = scoring
+        self.renormalize = (self.top_k > 1 if norm_topk_prob is None
+                            else bool(norm_topk_prob))
+        self.routed_scaling_factor = float(routed_scaling_factor)
         self.gate_weight = self.create_parameter(
             [hidden_size, num_experts], dtype="float32",
             initializer=I.Normal(0.0, 0.02))
+        if select_bias:
+            self.gate_bias = self.create_parameter(
+                [num_experts], dtype="float32", initializer=I.Constant(0.0))
+        else:
+            self.add_parameter("gate_bias", None)
+        self._gshard_router = (scoring == "softmax" and not select_bias
+                               and self.renormalize == (self.top_k > 1)
+                               and self.routed_scaling_factor == 1.0)
+        if not self._gshard_router and capacity_factor is not None:
+            raise ValueError(
+                "scoring / select_bias / norm_topk_prob / "
+                "routed_scaling_factor run on the dropless path only: pass "
+                "capacity_factor=None")
         self.experts = MoEMLP(num_experts, hidden_size, ffn_size, dtype=dtype)
+
+    def _choose(self, logits):
+        """(scores [t, e], the chosen experts' scores [t, k], ids [t, k]):
+        float32 scores by ``scoring``; the choice is the top-k of the
+        scores plus ``gate_bias`` where there is one, the weights are the
+        scores themselves."""
+        scores = (jax.nn.softmax(logits, axis=-1) if self.scoring == "softmax"
+                  else jax.nn.sigmoid(logits))
+        if self.gate_bias is None:
+            gates, ids = jax.lax.top_k(scores, self.top_k)
+        else:
+            _, ids = jax.lax.top_k(scores + self.gate_bias, self.top_k)
+            gates = jnp.take_along_axis(scores, ids, axis=-1)
+        return scores, gates, ids
+
+    def _weights(self, gates, axis: int):
+        """The chosen experts' weights from their scores (k along
+        ``axis``): renormalised and scaled as the layer was told."""
+        if self.renormalize:
+            gates = gates / jnp.maximum(
+                jnp.sum(gates, axis, keepdims=True), 1e-9)
+        if self.routed_scaling_factor != 1.0:
+            gates = gates * self.routed_scaling_factor
+        return gates
 
     def routing_histogram(self, x):
         """Measured per-expert token counts for ``x`` — the histogram
@@ -343,7 +417,14 @@ class MoELayer(Layer):
         # fall through to the GSPMD paths below
         hm = current_mesh()
         ep = hm.axis_size("ep") if hm is not None else 1
+        if not self.training and ep == 1 and self.capacity_factor is None:
+            out, _ = self.forward_inference(x)
+            return out, jnp.zeros((), jnp.float32)
         if ep > 1 and t % ep == 0 and e % ep == 0 and (t // ep) > 0:
+            if not self._gshard_router:
+                raise NotImplementedError(
+                    "the expert-parallel paths route with the GShard "
+                    "router only (softmax, no selection bias, no scale)")
             if self.capacity_factor is None:
                 out, aux = self._forward_dropless_ep(flat, hm.mesh, ep)
             else:
@@ -478,8 +559,7 @@ class MoELayer(Layer):
         as fallback."""
         t, d = flat.shape
         e, k = self.num_experts, self.top_k
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, ids = jax.lax.top_k(probs, k)                  # [t, k]
+        probs, gates, ids = self._choose(logits)              # [t, k]
         flat_e = ids.T.reshape(-1)                            # [k*t]
         order = jnp.argsort(flat_e, stable=True)
         sorted_e = flat_e[order]
@@ -496,8 +576,46 @@ class MoELayer(Layer):
 
         # unsort to choice-major, weight, reduce over k
         y_cm = jnp.zeros_like(ys).at[order].set(ys).reshape(k, t, d)
-        g_km = gates.T                                        # [k, t]
-        if k > 1:
-            g_km = g_km / jnp.maximum(jnp.sum(g_km, 0, keepdims=True), 1e-9)
+        g_km = self._weights(gates.T, 0)                      # [k, t]
         out = jnp.sum(g_km[..., None].astype(ys.dtype) * y_cm, axis=0)
         return out, _aux_loss(probs, e)
+
+    def forward_inference(self, x):
+        """The routed block without a loss: x [b, s, d] -> (out [b, s, d],
+        load [e] int32: the rows each expert was sent). Dropless at any
+        load. At most ``DENSE_ROWS`` rows whose choices outnumber the
+        experts run every expert over every row as one batched matmul; the
+        rest are sorted to their experts and go through XLA's
+        ``ragged_dot`` (no tile of a Pallas grid per expert, which a few
+        rows an expert would leave nearly empty)."""
+        b, s, d = x.shape
+        t, e, k = b * s, self.num_experts, self.top_k
+        flat = x.reshape(t, d)
+        logits = jnp.matmul(flat.astype(jnp.float32), self.gate_weight)
+        _, gates, ids = self._choose(logits)
+        gates = self._weights(gates, -1)                      # [t, k]
+        load = jnp.bincount(ids.reshape(-1), length=e).astype(jnp.int32)
+        w_gu = self.experts.w_gate_up.astype(flat.dtype)      # [e, d, 2f]
+        w_dn = self.experts.w_down.astype(flat.dtype)         # [e, f, d]
+        if t <= self.DENSE_ROWS and t * k >= e:
+            # weight [t, e]: an expert's share of a row, 0 if not chosen
+            weight = jnp.zeros((t, e), jnp.float32).at[
+                jnp.arange(t)[:, None], ids].add(gates)
+            gu = jnp.einsum("etd,edf->etf",
+                            jnp.broadcast_to(flat[None], (e, t, d)), w_gu,
+                            preferred_element_type=jnp.float32)
+            g, u = jnp.split(gu, 2, axis=-1)
+            h = (F.silu(g) * u * weight.T[..., None]).astype(flat.dtype)
+            out = jnp.einsum("etf,efd->td", h, w_dn,
+                             preferred_element_type=jnp.float32)
+            return out.astype(x.dtype).reshape(b, s, d), load
+        from ..ops.pallas.grouped_matmul import xla_grouped_matmul
+        flat_e = ids.T.reshape(-1)                            # [k*t]
+        order = jnp.argsort(flat_e, stable=True)
+        xs = flat[order % t]                                  # [k*t, d]
+        gu = xla_grouped_matmul(xs, w_gu, load).astype(flat.dtype)
+        g, u = jnp.split(gu, 2, axis=-1)
+        ys = xla_grouped_matmul(F.silu(g) * u, w_dn, load)    # f32
+        y_cm = jnp.zeros_like(ys).at[order].set(ys).reshape(k, t, d)
+        out = jnp.sum(gates.T[..., None] * y_cm, axis=0)
+        return out.astype(x.dtype).reshape(b, s, d), load

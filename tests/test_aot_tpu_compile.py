@@ -61,7 +61,8 @@ def _grad_sum(fn, argnums):
 # -- one chip ----------------------------------------------------------------
 # each case: () -> (fn, [(shape, dtype), ...])
 
-def _flash(s, bq, bk, causal=True, d=128, h=32, h_kv=8, seg=False):
+def _flash(s, bq, bk, causal=True, d=128, h=32, h_kv=8, seg=False,
+           grad=True):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_pallas
 
     def fn(q, k, v, *ids):
@@ -70,7 +71,7 @@ def _flash(s, bq, bk, causal=True, d=128, h=32, h_kv=8, seg=False):
             segment_ids=ids[0] if ids else None)
     args = [((1, s, h, d), BF16), ((1, s, h_kv, d), BF16),
             ((1, s, h_kv, d), BF16)] + ([((1, s), I32)] if seg else [])
-    return _grad_sum(fn, (0, 1, 2)), args
+    return (_grad_sum(fn, (0, 1, 2)) if grad else fn), args
 
 
 def _tuned_flash_cases():
@@ -102,6 +103,17 @@ def _paged(page, dtype):
     args = [((8, 32, 128), BF16), pool, pool, ((8, per_seq), I32),
             ((8,), I32)] + ([((pages,), F32)] * 2 if quant else [])
     return fn, args
+
+
+def _latent_decode():
+    """GLM-4.7-Flash's decode tick: 64 rows of 20 absorbed query heads over
+    a pool of 1,536 + 1 pages of 128 rows of 576 = 512 + 64 numbers."""
+    from paddle_tpu.ops.pallas.latent_attention import latent_decode_attention
+
+    def fn(q, pages, tables, lens):
+        return latent_decode_attention(q, pages, tables, lens, 512, 1 / 16)
+    return fn, [((64, 20, 576), BF16), ((1, 1537, 128, 576), BF16),
+                ((64, 24), I32), ((64,), I32)]
 
 
 def _rms_norm():
@@ -152,6 +164,16 @@ ONE_CHIP = [
     pytest.param(lambda: _flash(8192, 1024, 1024, seg=True),
                  id="flash_segment_ids[s8192,1024/1024]"),
     *_tuned_flash_cases(),
+    # latent attention's expanded prefill: 20 heads of 256 (192 nope + 64
+    # rope; values 256 too), forward only, at the blocks the chooser gives
+    # the serving buckets on a v5e (512/1024, 256/256, 128/128)
+    pytest.param(lambda: _flash(2048, 512, 1024, d=256, h=20, h_kv=20,
+                                grad=False), id="flash_fwd[s2048,512/1024,d256]"),
+    pytest.param(lambda: _flash(1792, 256, 256, d=256, h=20, h_kv=20,
+                                grad=False), id="flash_fwd[s1792,256/256,d256]"),
+    pytest.param(lambda: _flash(1920, 128, 128, d=256, h=20, h_kv=20,
+                                grad=False), id="flash_fwd[s1920,128/128,d256]"),
+    pytest.param(_latent_decode, id="latent_decode[bf16,64x20x576,page128]"),
     pytest.param(lambda: _paged(128, BF16), id="paged_decode[bf16,page128]"),
     pytest.param(lambda: _paged(16, BF16), id="paged_decode[bf16,page16]"),
     pytest.param(lambda: _paged(128, I8), id="paged_decode[int8,page128]"),

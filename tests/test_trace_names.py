@@ -302,6 +302,15 @@ def _paged():
         jnp.zeros((2, 4, 128)),)
 
 
+def _latent():
+    from paddle_tpu.ops.pallas.latent_attention import latent_decode_attention
+    pages, bt = jnp.zeros((1, 8, 16, 160)), jnp.zeros((2, 4), jnp.int32)
+    sl = jnp.array([3, 5], jnp.int32)
+    return (lambda q: latent_decode_attention(q, pages, bt, sl, 128, 0.1,
+                                              interpret=True)), (
+        jnp.zeros((2, 4, 160)),)
+
+
 @pytest.mark.parametrize("entry,expect", [
     (_flash, ["flash_attention_fwd", "flash_attention_bwd_dq",
               "flash_attention_bwd_dkv"]),
@@ -313,6 +322,7 @@ def _paged():
     (_grouped, ["grouped_matmul"]),
     (_int8, ["int8_matmul"]),
     (_paged, ["paged_attention_decode"]),
+    (_latent, ["latent_attention_decode"]),
 ], ids=lambda v: v.__name__.strip("_") if callable(v) else None)
 def test_a_pallas_entry_point_names_its_kernels(entry, expect):
     """Forward and gradient: every pallas_call in the traced program carries
@@ -349,7 +359,7 @@ def test_the_decode_attention_share_reads_the_paged_kernel_alone():
 
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
-    assert len(names) == len(set(names)) == 12
+    assert len(names) == len(set(names)) == 13
 
 
 # -- request timelines --------------------------------------------------------
