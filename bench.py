@@ -1675,10 +1675,7 @@ def _moe_ep_probe(on_tpu):
     measured`` — the priced census's per-a2a seconds ÷ a wall-clock
     shard_map all-to-all of the same dispatch buffer on the same mesh
     (cost-model drift for the NEW collective, healthy ~1.0 on TPU,
-    nominal on CPU). ``moe_grouped_matmul_speedup`` — XLA ragged_dot ÷
-    Pallas grouped-matmul wall time, interleaved min-of-rounds
-    (interpret mode off-TPU, so the CPU row only proves the kernel
-    path runs; the TPU row is the one the kernel must win)."""
+    nominal on CPU)."""
     out = {}
     import numpy as np
     import jax
@@ -1738,33 +1735,6 @@ def _moe_ep_probe(on_tpu):
         out["moe_ep_backend"] = "inline"
     except Exception as e:
         out["moe_ep_error"] = f"{type(e).__name__}: {str(e)[:150]}"
-    try:
-        from paddle_tpu.ops.pallas import grouped_matmul as gmm
-        m, k, n, g = 512, 128, 128, 4
-        dt = jnp.bfloat16 if on_tpu else jnp.float32
-        rs = np.random.RandomState(0)
-        xs = jnp.asarray(rs.randn(m, k), dt)
-        w = jnp.asarray(rs.randn(g, k, n), dt)
-        gs = jnp.full((g,), m // g, jnp.int32)
-        xla_fn = jax.jit(gmm.xla_grouped_matmul)
-        interp = not on_tpu
-        pal_fn = jax.jit(lambda a, b, s: gmm.grouped_matmul_pallas(
-            a, b, s, interpret=interp))
-        xla_fn(xs, w, gs).block_until_ready()
-        pal_fn(xs, w, gs).block_until_ready()
-        t_xla, t_pal = float("inf"), float("inf")
-        for _ in range(5):       # interleaved min-of-rounds
-            t0 = time.perf_counter()
-            xla_fn(xs, w, gs).block_until_ready()
-            t_xla = min(t_xla, time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            pal_fn(xs, w, gs).block_until_ready()
-            t_pal = min(t_pal, time.perf_counter() - t0)
-        out["moe_grouped_matmul_speedup"] = round(t_xla / t_pal, 4)
-        out["moe_grouped_matmul_backend"] = ("pallas-tpu" if on_tpu
-                                             else "pallas-interpret")
-    except Exception as e:
-        out["moe_gmm_error"] = f"{type(e).__name__}: {str(e)[:150]}"
     return out
 
 

@@ -162,14 +162,9 @@ RATIO_METRICS: Dict[str, RatioMetric] = {m.name: m for m in [
     # regressed; rides host noise, wide band), the priced-census
     # per-a2a seconds ÷ a wall-clock shard_map all-to-all (cost-model
     # drift for the NEW collective; CPU constants are nominal, so only
-    # the drift-of-the-ratio is gated, either direction), and XLA
-    # ragged_dot ÷ Pallas grouped matmul (within-run A/B; the CPU leg
-    # runs interpret mode — structurally stable but not a perf claim,
-    # hence the wider cpu band)
+    # the drift-of-the-ratio is gated, either direction)
     RatioMetric("moe_ep_step_speedup", "lower", band=0.35),
     RatioMetric("moe_ep_a2a_pred_over_measured", "either", band=0.5),
-    RatioMetric("moe_grouped_matmul_speedup", "lower", band=0.35,
-                cpu_band=0.6),
 ]}
 
 

@@ -4,20 +4,19 @@ the TPU-backend kernels with the op registry."""
 from . import flash_attention  # noqa: F401
 from . import fused_norm  # noqa: F401
 from . import fused_vocab_ce  # noqa: F401
-from . import grouped_matmul  # noqa: F401
 from . import latent_attention  # noqa: F401
 from . import paged_attention  # noqa: F401
 
 # every ``pl.pallas_call`` here passes one of these as ``name=``: jax puts
 # it on the call's name stack, and the TPU compiler names the custom call
-# after it (``%flash_attention_fwd.3``, ``%jvp_grouped_matmul_.1``,
+# after it (``%flash_attention_fwd.3``, ``%jvp_flash_attention_fwd_.1``,
 # ``%transpose_jvp_fused_vocab_ce_bwd_dw__.2``), which is the text of the
 # kernel's event on the device trace's ``XLA Ops`` line. The benchmark's
 # ``*_share`` metrics find kernels by these names (PERF.md section 3).
 KERNEL_NAMES = (
     "flash_attention_fwd", "flash_attention_bwd_dq",
-    "flash_attention_bwd_dkv", "grouped_matmul", "fused_vocab_ce_fwd",
-    "fused_vocab_ce_bwd_dh", "fused_vocab_ce_bwd_dw",
-    "paged_attention_decode", "fused_rmsnorm_fwd", "fused_rmsnorm_bwd",
-    "fused_rope", "int8_matmul", "latent_attention_decode",
+    "flash_attention_bwd_dkv", "fused_vocab_ce_fwd", "fused_vocab_ce_bwd_dh",
+    "fused_vocab_ce_bwd_dw", "paged_attention_decode", "fused_rmsnorm_fwd",
+    "fused_rmsnorm_bwd", "fused_rope", "int8_matmul",
+    "latent_attention_decode",
 )

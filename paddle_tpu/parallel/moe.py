@@ -20,10 +20,11 @@ TPU analogue of the reference's exact-count global_scatter path
 (moe/utils.py count_by_gate). Round-5 on-chip A/B at DeepSeekMoE scale
 (e=64, d=2048, f=1408, k=6, v5e): XLA's native ``lax.ragged_dot`` runs the
 same grouped matmul 1.7x faster than the bundled megablox Pallas gmm with
-bit-identical output, so ragged_dot is the primary path (gmm remains the
-fallback for jax builds without ragged_dot); the capacity-factor dense
-path is ~4x faster still at this scale but DROPS overflow tokens — the
-measured trade is recorded in ops/pallas/tune_db.json (moe_grouped_mm).
+bit-identical output. Since PR 28 ``lax.ragged_dot`` is the only
+implementation of that product, forward and backward, on every platform
+(``grouped_matmul`` below); the capacity-factor dense path is ~4x faster
+still at this scale but DROPS overflow tokens — the measured trade is
+recorded in ops/pallas/tune_db.json (moe_grouped_mm).
 
 Expert parallelism (ISSUE 20): expert weights shard their expert dim over
 the ("ep","dp","fsdp") submesh — "ep" is a REAL mesh axis carved out of
@@ -266,17 +267,53 @@ def _constrain_experts(xe):
         xe, NamedSharding(hm.mesh, P(axes, *([P.UNCONSTRAINED] * (xe.ndim - 1)))))
 
 
-def _grouped_matmul(xs, w, group_sizes):
-    """Ragged grouped matmul: rows of ``xs`` [m, k] are split by
-    ``group_sizes`` [g] and each run multiplies its own ``w[g]`` [k, n].
+def xla_grouped_matmul(xs, w, group_sizes):
+    """Ragged grouped matmul: rows of ``xs`` [m, k] are split into runs
+    by ``group_sizes`` [g] and run ``i`` multiplies its own ``w[i]``
+    [k, n], through XLA's ``lax.ragged_dot``. Returns f32, the
+    accumulator dtype; callers cast back to the activation dtype."""
+    return jax.lax.ragged_dot(xs, w, group_sizes,
+                              preferred_element_type=jnp.float32)
 
-    This is the dispatch SEAM (ISSUE 20): ops/pallas/grouped_matmul
-    owns the implementation choice — the TuneDB-gated Pallas kernel on
-    TPU, XLA ``lax.ragged_dot`` (the round-5 v5e A/B measured it 1.7x
-    faster than megablox gmm with max|diff|=0 at e=64, d=2048, f=1408)
-    elsewhere."""
-    from ..ops.pallas.grouped_matmul import grouped_matmul
-    return grouped_matmul(xs, w, group_sizes)
+
+@jax.custom_vjp
+def grouped_matmul(xs, w, group_sizes):
+    """The grouped matmul of a differentiated program: the one entry of
+    every per-expert product of a training step (dropless routing, the
+    EP shard_map body). XLA's ``ragged_dot`` forward and backward since
+    PR 28 (the Pallas kernel that ran the forward on TPU took 8x its
+    time on the chip and is gone).
+
+    Returns ``xs.dtype``: the product's accumulator is rounded to the
+    activation dtype by ``ragged_dot`` itself, not by a cast after it. A
+    float32 result of OLMoE's 262,144 routed rows is 2 GB a product, and
+    with it beside its bf16 copy the step program needs 17.49 GB of a
+    v5e's 15.75 (the compiler's count, PR 28); the backward takes the
+    float32 path of ``xla_grouped_matmul`` as it always has.
+
+    custom_vjp because jax's ragged_dot ad rules choke on symbolic-Zero
+    tangents inside a shard_map transpose (the dropless-EP body):
+    custom_vjp instantiates the cotangent before bwd runs, and the
+    product is linear in each operand, so the vjp below is the exact
+    gradient."""
+    return jax.lax.ragged_dot(xs, w, group_sizes,
+                              preferred_element_type=xs.dtype)
+
+
+def _gmm_fwd(xs, w, group_sizes):
+    return grouped_matmul(xs, w, group_sizes), (xs, w, group_sizes)
+
+
+def _gmm_bwd(res, gy):
+    xs, w, group_sizes = res
+    _, vjp = jax.vjp(
+        lambda a, b: xla_grouped_matmul(a, b, group_sizes), xs, w)
+    dxs, dw = vjp(gy.astype(jnp.float32))
+    return (dxs.astype(xs.dtype), dw.astype(w.dtype),
+            np.zeros(group_sizes.shape, dtype=jax.dtypes.float0))
+
+
+grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def _expert_ffn(xe, w_gu, w_dn):
@@ -307,7 +344,8 @@ class MoELayer(Layer):
     forward(x: [b, s, d]) -> (out [b, s, d], aux_loss scalar)
 
     ``capacity_factor=None`` selects DROPLESS routing via grouped matmul
-    (megablox gmm): exact per-expert counts, no token ever dropped.
+    (``lax.ragged_dot``, forward and backward since PR 28): exact
+    per-expert counts, no token ever dropped.
 
     The router's variants (the defaults are the GShard router this layer
     has always had, so a model that passes none runs the program it ran):
@@ -494,9 +532,8 @@ class MoELayer(Layer):
         ever dropped); the exact per-expert counts are the a2a split
         sizes in the logical sense — they define slot occupancy inside
         the bound, because this jax ships no ragged_all_to_all. The
-        grouped matmul then runs over the received slot blocks through
-        the ops/pallas seam, and the reverse all-to-all + unsort
-        restores token order."""
+        grouped matmul then runs over the received slot blocks, and the
+        reverse all-to-all + unsort restores token order."""
         t, d = flat.shape
         e, k = self.num_experts, self.top_k
         t_l = t // ep
@@ -524,10 +561,9 @@ class MoELayer(Layer):
                                      tiled=True)          # [e_l, ep*cap, d]
             rows = buf.reshape(e_l * ep * cap, d)
             gsz = jnp.full((e_l,), ep * cap, jnp.int32)
-            gu = _grouped_matmul(rows, wgu, gsz).astype(xl.dtype)
+            gu = grouped_matmul(rows, wgu, gsz)
             g, u = jnp.split(gu, 2, axis=-1)
-            ys = _grouped_matmul(F.silu(g) * u, wdn,
-                                 gsz).astype(xl.dtype)
+            ys = grouped_matmul(F.silu(g) * u, wdn, gsz)
             ybuf = jax.lax.all_to_all(ys.reshape(e_l, ep * cap, d), "ep",
                                       split_axis=1, concat_axis=0,
                                       tiled=True)             # [e, cap, d]
@@ -553,10 +589,9 @@ class MoELayer(Layer):
     def _forward_dropless(self, flat, logits):
         """Grouped-matmul experts over exact per-expert counts — the
         dropless path (reference analogue: global_scatter's exact
-        count_by_gate split sizes). Grouped matmul = lax.ragged_dot
-        (XLA-native; measured 1.7x faster than megablox gmm at
-        DeepSeekMoE-64 scale on v5e, identical numerics), megablox gmm
-        as fallback."""
+        count_by_gate split sizes). Both products are
+        ``grouped_matmul``: XLA's ``lax.ragged_dot`` forward and
+        backward since PR 28."""
         t, d = flat.shape
         e, k = self.num_experts, self.top_k
         probs, gates, ids = self._choose(logits)              # [t, k]
@@ -569,10 +604,10 @@ class MoELayer(Layer):
         w_gu = self.experts.w_gate_up.astype(flat.dtype)      # [e, d, 2f]
         w_dn = self.experts.w_down.astype(flat.dtype)         # [e, f, d2]
 
-        gu = _grouped_matmul(xs, w_gu, group_sizes).astype(flat.dtype)
+        gu = grouped_matmul(xs, w_gu, group_sizes)
         g, u = jnp.split(gu, 2, axis=-1)
         h = F.silu(g) * u
-        ys = _grouped_matmul(h, w_dn, group_sizes).astype(flat.dtype)
+        ys = grouped_matmul(h, w_dn, group_sizes)
 
         # unsort to choice-major, weight, reduce over k
         y_cm = jnp.zeros_like(ys).at[order].set(ys).reshape(k, t, d)
@@ -586,8 +621,7 @@ class MoELayer(Layer):
         load. At most ``DENSE_ROWS`` rows whose choices outnumber the
         experts run every expert over every row as one batched matmul; the
         rest are sorted to their experts and go through XLA's
-        ``ragged_dot`` (no tile of a Pallas grid per expert, which a few
-        rows an expert would leave nearly empty)."""
+        ``ragged_dot`` (``xla_grouped_matmul``)."""
         b, s, d = x.shape
         t, e, k = b * s, self.num_experts, self.top_k
         flat = x.reshape(t, d)
@@ -609,7 +643,6 @@ class MoELayer(Layer):
             out = jnp.einsum("etf,efd->td", h, w_dn,
                              preferred_element_type=jnp.float32)
             return out.astype(x.dtype).reshape(b, s, d), load
-        from ..ops.pallas.grouped_matmul import xla_grouped_matmul
         flat_e = ids.T.reshape(-1)                            # [k*t]
         order = jnp.argsort(flat_e, stable=True)
         xs = flat[order % t]                                  # [k*t, d]

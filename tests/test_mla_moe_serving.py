@@ -205,12 +205,33 @@ def test_the_batched_and_the_sorted_inference_paths_agree(monkeypatch):
     assert int(np.asarray(load_a).sum()) == 2 * 9 * 2
 
 
+@pytest.mark.parametrize("router", [
+    dict(),
+    dict(scoring="sigmoid", select_bias=True, norm_topk_prob=True,
+         routed_scaling_factor=1.8)], ids=["gshard", "sigmoid_bias_scale"])
+def test_the_training_and_the_sorted_inference_paths_agree(router):
+    """More rows than ``DENSE_ROWS``: a layer in train mode
+    (``_forward_dropless``: ``grouped_matmul``) and the same layer in eval
+    mode (``forward_inference``: ``xla_grouped_matmul``) run the one
+    ``ragged_dot`` over the same sorted rows, so the same weights give the
+    same output."""
+    layer = _router_layer(**router)
+    x = jax.random.normal(jax.random.key(6), (2, 80, 16))
+    assert x.shape[0] * x.shape[1] > MoELayer.DENSE_ROWS
+    layer.train()
+    trained, aux = layer(x)
+    layer.eval()
+    served, load = layer.forward_inference(x)
+    assert np.abs(np.asarray(trained) - np.asarray(served)).max() < 1e-5
+    assert float(aux) > 0 and int(np.asarray(load).sum()) == 2 * 80 * 2
+
+
 def test_a_layer_with_no_new_argument_runs_the_parents_program():
     """OLMoE's layer (dropless, no router argument): bit for bit what the
     parent's ``_forward_dropless`` computed, written out here as it stood
     (softmax, top-k, renormalised since k > 1, no bias, no scale)."""
     from paddle_tpu.nn import functional as F
-    from paddle_tpu.parallel.moe import _aux_loss, _grouped_matmul
+    from paddle_tpu.parallel.moe import _aux_loss, grouped_matmul
     layer = MoELayer(16, 8, 6, top_k=2, capacity_factor=None, dtype="float32")
     assert layer._gshard_router and layer.gate_bias is None
     assert [n for n, _ in layer.named_parameters()] == [
@@ -225,11 +246,9 @@ def test_a_layer_with_no_new_argument_runs_the_parents_program():
     flat_e = ids.T.reshape(-1)
     order = jnp.argsort(flat_e, stable=True)
     sizes = jnp.bincount(flat_e[order], length=6).astype(jnp.int32)
-    gu = _grouped_matmul(flat[order % 14], layer.experts.w_gate_up,
-                         sizes).astype(flat.dtype)
+    gu = grouped_matmul(flat[order % 14], layer.experts.w_gate_up, sizes)
     g, u = jnp.split(gu, 2, axis=-1)
-    ys = _grouped_matmul(F.silu(g) * u, layer.experts.w_down,
-                         sizes).astype(flat.dtype)
+    ys = grouped_matmul(F.silu(g) * u, layer.experts.w_down, sizes)
     y_cm = jnp.zeros_like(ys).at[order].set(ys).reshape(2, 14, 16)
     g_km = gates.T
     g_km = g_km / jnp.maximum(jnp.sum(g_km, 0, keepdims=True), 1e-9)

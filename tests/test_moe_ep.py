@@ -20,9 +20,10 @@ What is pinned here, on the conftest 8-virtual-device CPU mesh:
 * satellite regression: ``accumulate_steps>1`` keeps grads
   fsdp-sharded through the accumulation scan — the compiled census
   shows ZERO extra all-gather rows vs accumulate_steps=1;
-* the Pallas grouped matmul matches the XLA ragged_dot fallback in
-  interpret mode (fwd + grad, uneven/empty groups) and its
-  ``shapes_supported`` gate refuses what the kernel can't tile.
+* the grouped matmul (``lax.ragged_dot`` forward and backward since
+  PR 28) matches a per-expert Python loop in value and gradient
+  (uneven/empty groups, float32 and bfloat16), and a dropless layer's
+  step is six ``ragged_dot``s and no ``pallas_call``.
 
 The heavy pieces share ONE compiled dp2_ep2 build (module fixture);
 everything else is analytic or tiny-layer compiles — tier-1 budget is
@@ -266,63 +267,101 @@ def test_accumulate_steps_keeps_grads_fsdp_sharded():
 
 
 # ---------------------------------------------------------------------------
-# Pallas grouped matmul vs the XLA ragged_dot fallback (interpret mode)
+# the grouped matmul: lax.ragged_dot forward and backward (PR 28)
 # ---------------------------------------------------------------------------
 
-def test_grouped_matmul_pallas_matches_xla():
-    from paddle_tpu.ops.pallas.grouped_matmul import (
-        grouped_matmul_pallas, xla_grouped_matmul)
+def _loop_grouped_matmul(xs, w, counts):
+    """The oracle: one plain float32 matmul per expert over its run of
+    rows, in Python. Nothing of ``parallel/moe.py`` in it."""
+    xs, w = np.asarray(xs, np.float32), np.asarray(w, np.float32)
+    out, row = np.zeros((xs.shape[0], w.shape[2]), np.float32), 0
+    for e, c in enumerate(counts):
+        out[row:row + c] = xs[row:row + c] @ w[e]
+        row += c
+    return out
+
+
+def _loop_grouped_matmul_grads(xs, w, counts, gy):
+    """Its gradients for a cotangent ``gy``: dx = gy @ w[e].T and
+    dw[e] = x_run.T @ gy_run, run by run."""
+    xs, w = np.asarray(xs, np.float32), np.asarray(w, np.float32)
+    dx, dw, row = np.zeros_like(xs), np.zeros_like(w), 0
+    for e, c in enumerate(counts):
+        dx[row:row + c] = gy[row:row + c] @ w[e].T
+        dw[e] = xs[row:row + c].T @ gy[row:row + c]
+        row += c
+    return dx, dw
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("counts", [[12, 12, 12, 12], [10, 3, 22, 13],
+                                    [10, 0, 25, 13], [0, 0, 48, 0]],
+                         ids=["even", "uneven", "one_empty", "all_in_one"])
+def test_grouped_matmul_value_and_grad_match_a_per_expert_loop(counts, dtype,
+                                                               tol):
+    """Value and both gradients (all in the inputs' dtype) against the
+    loop, over the inputs as stored: bf16 inputs are rounded once, on both
+    sides alike, and a bf16 result is the loop's float32 one rounded."""
+    from paddle_tpu.parallel.moe import grouped_matmul
     rs = np.random.RandomState(0)
-    m, k, n, g = 48, 16, 24, 4
-    xs = jnp.asarray(rs.randn(m, k).astype(np.float32))
-    w = jnp.asarray(rs.randn(g, k, n).astype(np.float32) * 0.1)
-    for counts in ([12, 12, 12, 12], [10, 0, 25, 13], [0, 0, 48, 0]):
-        gs = jnp.asarray(counts, jnp.int32)
-        ref = xla_grouped_matmul(xs, w, gs)
-        out = grouped_matmul_pallas(xs, w, gs, block_m=8, block_n=8,
-                                    block_k=8, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5), counts
-    # bf16 inputs: both paths accumulate in f32, so they stay close
-    xb, wb = xs.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
-    gs = jnp.asarray([10, 0, 25, 13], jnp.int32)
-    ref = xla_grouped_matmul(xb, wb, gs)
-    out = grouped_matmul_pallas(xb, wb, gs, block_m=8, block_n=8,
-                                block_k=8, interpret=True)
+    xs = jnp.asarray(rs.randn(48, 16), dtype)
+    w = jnp.asarray(rs.randn(4, 16, 24) * 0.1, dtype)
+    gy = np.asarray(jnp.asarray(rs.randn(48, 24), dtype), np.float32)
+    gs = jnp.asarray(counts, jnp.int32)
+    out, vjp = jax.vjp(lambda a, b: grouped_matmul(a, b, gs), xs, w)
+    assert out.dtype == xs.dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=2e-2, atol=2e-2)
+                               _loop_grouped_matmul(xs, w, counts),
+                               rtol=tol, atol=tol)
+    dx, dw = vjp(jnp.asarray(gy, dtype))
+    assert (dx.dtype, dw.dtype) == (xs.dtype, w.dtype)
+    rx, rw = _loop_grouped_matmul_grads(xs, w, counts, gy)
+    np.testing.assert_allclose(np.asarray(dx, np.float32), rx,
+                               rtol=tol, atol=tol)
+    # a dw entry sums up to 48 products: bf16 rounds the result to 2^-8 of it
+    np.testing.assert_allclose(np.asarray(dw, np.float32), rw,
+                               rtol=tol, atol=tol * max(1.0, np.abs(rw).max()))
 
 
-def test_grouped_matmul_grad_matches_xla():
-    """The public dispatcher is a custom_vjp whose bwd is the vjp of
-    the (linear) XLA fallback — grads through either forward are the
-    same function, so they must agree exactly."""
-    from paddle_tpu.ops.pallas.grouped_matmul import (
-        grouped_matmul, xla_grouped_matmul)
-    rs = np.random.RandomState(1)
-    xs = jnp.asarray(rs.randn(32, 8).astype(np.float32))
-    w = jnp.asarray(rs.randn(4, 8, 12).astype(np.float32) * 0.1)
-    gs = jnp.asarray([7, 9, 0, 16], jnp.int32)
-    f = lambda fn: lambda x, ww: jnp.sum(fn(x, ww, gs) ** 2)
-    gx, gw = jax.grad(f(grouped_matmul), argnums=(0, 1))(xs, w)
-    rx, rw = jax.grad(f(xla_grouped_matmul), argnums=(0, 1))(xs, w)
-    np.testing.assert_allclose(np.asarray(gx), np.asarray(rx),
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(gw), np.asarray(rw),
-                               rtol=1e-6, atol=1e-6)
+def _live_primitives(jaxpr, counts=None):
+    """Primitive name -> count over the equations ``jaxpr``'s outputs
+    depend on. ``make_jaxpr`` keeps dead equations, and the backward's
+    ``jax.vjp`` traces the forward product once more only to drop it."""
+    counts = {} if counts is None else counts
+    live = {v for v in jaxpr.outvars if not hasattr(v, "val")}
+    for eqn in reversed(jaxpr.eqns):
+        if not any(v in live for v in eqn.outvars):
+            continue
+        live.update(v for v in eqn.invars if not hasattr(v, "val"))
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _live_primitives(sub, counts)
+    return counts
 
 
-def test_grouped_matmul_shapes_supported_gate():
-    from paddle_tpu.ops.pallas.grouped_matmul import shapes_supported
-    ok = shapes_supported((512, 256), (4, 256, 256), block_m=128,
-                          block_n=128, block_k=128,
-                          dtype=jnp.bfloat16)
-    assert ok
-    # k not divisible by the clamped block -> refuse, fall back to XLA
-    assert not shapes_supported((512, 100), (4, 100, 256), block_m=128,
-                                block_n=128, block_k=128,
-                                dtype=jnp.bfloat16)
+def test_a_dropless_layers_step_is_six_ragged_dots_and_no_pallas_call():
+    """A routed layer's two expert products are ``lax.ragged_dot`` forward
+    (2) and backward (dx and dw of each: 4), on every platform: no
+    ``pallas_call`` anywhere in the layer's forward + backward."""
+    moe = MoELayer(hidden_size=16, ffn_size=32, num_experts=4, top_k=2,
+                   capacity_factor=None)
+    x = jnp.asarray(np.random.RandomState(8).randn(1, 16, 16), jnp.float32)
+
+    def loss(p, xb):
+        o, a = moe.functional_call(p, xb)
+        return jnp.sum(o ** 2) + 0.01 * a
+    params = moe.raw_parameters()
+    fwd = _live_primitives(jax.make_jaxpr(loss)(params, x).jaxpr)
+    both = _live_primitives(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr)
+    assert fwd["ragged_dot_general"] == 2
+    assert both["ragged_dot_general"] == 2 + 4
+    assert "pallas_call" not in fwd and "pallas_call" not in both
 
 
 # ---------------------------------------------------------------------------

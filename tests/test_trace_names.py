@@ -278,14 +278,6 @@ def _vocab_ce():
                                          jnp.zeros((128, 512)))
 
 
-def _grouped():
-    from paddle_tpu.ops.pallas.grouped_matmul import _pallas_gmm
-    gs = jnp.full((4,), 64, jnp.int32)
-    f = lambda a, b: _pallas_gmm(a, b, gs, 128, 128, 128, True).sum()
-    return jax.grad(f, argnums=(0, 1)), (jnp.zeros((256, 128)),
-                                         jnp.zeros((4, 128, 128)))
-
-
 def _int8():
     from paddle_tpu.ops.pallas.int8_matmul import int8_matmul_pallas
     wq, sc = jnp.zeros((128, 128), jnp.int8), jnp.ones((128,))
@@ -318,8 +310,6 @@ def _latent():
     (_rope, ["fused_rope"]),
     (_vocab_ce, ["fused_vocab_ce_fwd", "fused_vocab_ce_bwd_dh",
                  "fused_vocab_ce_bwd_dw"]),
-    # its backward is XLA's ragged_dot: one Pallas call, the forward's
-    (_grouped, ["grouped_matmul"]),
     (_int8, ["int8_matmul"]),
     (_paged, ["paged_attention_decode"]),
     (_latent, ["latent_attention_decode"]),
@@ -359,7 +349,7 @@ def test_the_decode_attention_share_reads_the_paged_kernel_alone():
 
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
-    assert len(names) == len(set(names)) == 13
+    assert len(names) == len(set(names)) == 12
 
 
 # -- request timelines --------------------------------------------------------
