@@ -11,17 +11,19 @@ a benchmark and no number it prints is a performance claim.
                                       tiny shapes on the CPU, Pallas kernels
                                       in interpret mode — control flow only
 
-* ``train``  — ``Trainer.train_step`` fed by ``io.DataLoader`` wired as
-  ``bench.py:_train_bench`` wires them, at ``bench._HEADLINE_TPU_CFG``
-  (b=8, s=2048). NOT a published model: it is the one training shape that
-  has run on a chip before, so a failure points at the toolchain and not at
-  size. Llama-3-8B widths cannot train on one 16 GB chip at any depth
-  (embedding + head alone are 1.05 B parameters = 12.6 GB of bf16 weight and
-  gradient plus two fp32 moments); that is ``train4``.
+* ``train``  — ``Trainer.train_step`` fed by ``io.DataLoader`` (worker
+  threads, collate, device prefetch) at ``TRAIN_SHAPE`` (b=8, s=2048). NOT
+  a published model: it is the one training shape that has run on a chip
+  before, so a failure points at the toolchain and not at size. Llama-3-8B
+  widths cannot train on one 16 GB chip at any depth (embedding + head
+  alone are 1.05 B parameters = 12.6 GB of bf16 weight and gradient plus
+  two fp32 moments); that is ``train4``.
 * ``serve``  — ``ContinuousBatchingEngine`` with its defaults over
   ``LlamaConfig.llama3_8b()`` widths in bf16, DEPTH CUT to 16 of 32 layers
   and nothing else; 8 greedy requests of 120–6000 prompt tokens in two
-  waves, so both sides of the engine's dense/paged decode crossover run.
+  waves. Which decode paths must run follows the engine's own crossover:
+  its default is 0 since PR 25 (every tick paged); the rehearsal sets one,
+  so both sides run there.
   Prefill-then-decode through the cache is held against the model's plain
   full forward over prompt + generated tokens.
 * ``train4`` — Llama-3-8B widths, depth cut to 4 layers, s=4096, through
@@ -62,6 +64,12 @@ LOSS_RTOL = 1e-2
 #: how far step-0 loss may sit from what random init predicts (loss0_expected)
 LOSS0_ATOL = 0.1
 
+#: the ``train`` phase's model (why this one: the docstring)
+TRAIN_SHAPE = dict(vocab_size=32000, hidden_size=1536,
+                   intermediate_size=4608, num_hidden_layers=12,
+                   num_attention_heads=12, num_key_value_heads=4,
+                   max_position_embeddings=2048, dtype="bfloat16")
+
 REHEARSAL = False
 _COMPILE_S = [0.0]          # seconds jax spent tracing, lowering, compiling
 
@@ -94,7 +102,6 @@ def loss0_expected(cfg) -> float:
 
 def _sizes(rehearse: bool) -> dict:
     """Real sizes, or the tiny ones of a rehearsal."""
-    import bench
     from paddle_tpu.models import LlamaConfig
     if rehearse:
         tiny = functools.partial(LlamaConfig.tiny, dtype="bfloat16")
@@ -121,8 +128,8 @@ def _sizes(rehearse: bool) -> dict:
                       waves=[[128, 120, 1500, 1480, 3000],
                              [4200, 6000, 5990]],
                       new=64, max_len=6144, num_pages=256, engine_kw={}),
-        "train": dict(cfg=LlamaConfig(**bench._HEADLINE_TPU_CFG),
-                      config="bench._HEADLINE_TPU_CFG", batch=8, seq=2048,
+        "train": dict(cfg=LlamaConfig(**TRAIN_SHAPE),
+                      config="chip_smoke.TRAIN_SHAPE", batch=8, seq=2048,
                       steps=5, warmup=2),
         # 4 layers = 1.92 B parameters = 26.9 GiB of train state over four
         # chips; compiled for a described v5e:2x2 at 10.1 (fsdp=4) and
@@ -204,8 +211,28 @@ def _peaks():
 
 # -- train ------------------------------------------------------------------
 
+def synthetic_loader(cfg, batch_size, seq_len, steps):
+    """Synthetic LM batches through the real input pipeline (worker
+    threads, collate, device prefetch); four batches more than the steps
+    ask for, so the prefetcher never runs dry."""
+    import numpy as np
+    from paddle_tpu.io import DataLoader, Dataset
+
+    class SyntheticLM(Dataset):
+        def __len__(self):
+            return batch_size * (steps + 4)
+
+        def __getitem__(self, i):
+            rs = np.random.RandomState(i)
+            ids = rs.randint(0, cfg.vocab_size, (seq_len + 1,), np.int32)
+            return {"input_ids": ids[:-1], "labels": ids[1:]}
+
+    return DataLoader(SyntheticLM(), batch_size=batch_size, num_workers=2,
+                      prefetch_factor=4, prefetch_to_device=True,
+                      drop_last=True)
+
+
 def phase_train(sz, seed):
-    import bench
     import jax
     import paddle_tpu as pt
     from paddle_tpu.models import LlamaForCausalLM
@@ -227,7 +254,7 @@ def phase_train(sz, seed):
     model = LlamaForCausalLM(cfg)
     opt = AdamW(learning_rate=1e-4, weight_decay=0.01, parameters=model)
     tr = Trainer(model, opt)
-    it = iter(bench._make_loader(cfg, b, s, steps + warmup))
+    it = iter(synthetic_loader(cfg, b, s, steps + warmup))
 
     losses = [float(tr.train_step(next(it))) for _ in range(warmup)]
     t_warm = time.perf_counter() - t0
@@ -331,8 +358,10 @@ def phase_serve(sz, seed):
          total_s=round(t_serve, 2),
          compile_s=round(_COMPILE_S[0] - c0, 2), attn_path_ticks=ticks,
          prefill_buckets=sorted(eng._prefill_cache), cache=_cache_counts())
-    check(ticks["dense"] > 0 and ticks["paged"] > 0,
-          f"one side of the dense/paged crossover never ran: {ticks}")
+    sides = ("dense", "paged") if eng.attn_crossover else ("paged",)
+    check(all(ticks[side] > 0 for side in sides),
+          f"of {sides} (crossover {eng.attn_crossover}) one never ran: "
+          f"{ticks}")
 
     # the reference: the model's plain full forward over prompt +
     # generated tokens (padded to whole kernel blocks; causal, so the pad
@@ -386,8 +415,9 @@ def phase_serve(sz, seed):
               f"no flash kernel in the prefill program: {kernels}")
         check(kernels["decode_paged"].get("paged_attention", 0) > 0,
               f"no paged kernel in the paged decode program: {kernels}")
-        check(kernels["decode_dense"]["tpu_custom_call"] > 0,
-              f"no Pallas kernel in the dense decode program: {kernels}")
+        if "dense" in sides:
+            check(kernels["decode_dense"]["tpu_custom_call"] > 0,
+                  f"no Pallas kernel in the dense decode program: {kernels}")
     del eng, model, reference, programs
     return {"tokens": out}
 

@@ -455,9 +455,9 @@ def configure_compilation_cache() -> str:
     The one placement rule: if ``JAX_COMPILATION_CACHE_DIR`` is set, jax
     reads it itself and this function sets NO directory; otherwise the
     cache goes to :data:`DEFAULT_CACHE_DIR`. Called by every entry point
-    that owns a process (chip_smoke.py, bench.py, the tools that run on
-    the chip) before its first compile — never at import, so tests and
-    library users keep jax's own default. ``stats()`` then reports the
+    that owns a process (chip_smoke.py, the tools that run on the chip)
+    before its first compile — never at import, so tests and library
+    users keep jax's own default. ``stats()`` then reports the
     directory and the persistent hits/misses jax counts."""
     global _PERSISTENT_DIR
     import jax

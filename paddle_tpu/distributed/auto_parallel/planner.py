@@ -875,9 +875,10 @@ def validate_rank_order(report: PlanReport, *, rounds: int = 4,
                         iters: int = 2) -> Dict:
     """Execute every ranked config's OWN priced program and compare the
     predicted ordering with the measured one. Requires
-    ``plan(keep_builds=True)``. Returns the verdict dict the bench row
-    and the dryrun print: pairwise agreement, whether the predicted
-    winner lands in the measured top 2, and the per-config table.
+    ``plan(keep_builds=True)``. Returns the verdict dict that
+    ``tools/plan.py --validate`` and the dryrun print: pairwise agreement,
+    whether the predicted winner lands in the measured top 2, and the
+    per-config table.
 
     Rounds INTERLEAVE across configs (the op_cost_probe discipline): a
     host-contention spike then taxes every config's round equally

@@ -82,9 +82,8 @@ def apply_overlap_flags(enable: bool = True, *, target: str = "tpu") -> str:
 
     Must run BEFORE jax backend initialization — libtpu reads the variable
     once, when it loads; after that this warns and returns the current
-    value unchanged. ``PT_NO_OVERLAP=1`` forces them off (the A/B lever
-    for measuring the overlap win on hardware). A non-TPU ``target``
-    installs nothing."""
+    value unchanged. ``PT_NO_OVERLAP=1`` forces them off. A non-TPU
+    ``target`` installs nothing."""
     if os.environ.get("PT_NO_OVERLAP"):
         enable = False
     cur = os.environ.get(FLAGS_ENV, "")

@@ -343,8 +343,7 @@ def causal_lm_loss(logits, labels, ignore_index: int = -100):
 
 def fused_loss_enabled(cfg) -> bool:
     """The fused loss head is the default; ``cfg.loss_impl='naive'`` or env
-    ``PT_NAIVE_LOSS_HEAD=1`` (the bench A/B lever) fall back to the
-    materialized-logits path."""
+    ``PT_NAIVE_LOSS_HEAD=1`` fall back to the materialized-logits path."""
     import os
     return (getattr(cfg, "loss_impl", "fused") == "fused"
             and not os.environ.get("PT_NAIVE_LOSS_HEAD"))
@@ -951,8 +950,9 @@ class LlamaForCausalLM(nn.Layer):
 
         ``causal=True`` halves the attention term to count only the FLOPs a
         causal kernel actually executes (avg context (s+1)/2 per query):
-        the honest-utilization convention. Both are reported by bench.py;
-        the PaLM (non-causal) number is the cross-paper-comparable one."""
+        the honest-utilization convention; the PaLM (non-causal) number is
+        the cross-paper-comparable one. The benchmark's ``mfu_pct`` uses
+        neither: its family module counts the required FLOPs itself."""
         cfg = self.cfg
         n = self.num_params()
         if not cfg.tie_word_embeddings:

@@ -1,8 +1,8 @@
 """Analytical flop/byte attribution over a compiled graph's optimized HLO.
 
-THE one flop formula (ISSUE 9 acceptance): bench's ``mfu_analytical``, the
-live ``pt_model_flops_utilization`` gauge and graph_lint's flop-floor
-budget all call :func:`attribute_costs` over the PR 8 ``HloModule`` — there
+THE one flop formula (ISSUE 9 acceptance): the live
+``pt_model_flops_utilization`` gauge and graph_lint's flop-floor budget
+both call :func:`attribute_costs` over the PR 8 ``HloModule`` — there
 is no second, hand-maintained per-model formula to drift from the program
 XLA actually runs. (``model.flops_per_token`` remains the PaLM-convention
 closed form the HEADLINE MFU quotes for cross-paper comparability; the two

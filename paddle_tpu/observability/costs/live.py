@@ -20,8 +20,8 @@ publishes, through the PR 4 registry:
   windows (a sync-lowered backend is trivially 100% exposed and would
   page a sentry on every CPU run for a structural non-event).
 * ``pt_model_flops_utilization{component}`` — HLO-attributed flops ÷
-  (measured time × device peak): the MFU definition shared with bench's
-  ``mfu_analytical`` and graph_lint's flop floor.
+  (measured time × device peak): the MFU definition shared with
+  graph_lint's flop floor.
 * ``pt_hbm_bw_utilization{component}`` — attributed HBM bytes ÷
   (measured time × HBM bandwidth).
 * ``pt_step_time_predicted_over_measured{component}`` — the cost model

@@ -1,5 +1,5 @@
 """paddle_tpu.observability.sentry — declarative SLOs over the metrics
-plane, correlated incident capture, noise-aware bench regression gating.
+plane and correlated incident capture.
 
 The closing third of the observability loop (ISSUE 10): PR 4's registry
 records, PR 9's cost observatory attributes, this package *watches*.
@@ -15,17 +15,10 @@ Quickstart::
     engine.run()              # ticks at drain boundaries
     for inc in sn.active().incidents:
         print(inc.rule, inc.severity, inc.context["goodput"])
-
-The bench half (:mod:`baselines` + ``tools/bench_diff.py``) applies the
-same watch-the-ratios discipline to the checked-in bench artifacts.
 """
 
 from __future__ import annotations
 
-from . import baselines as baselines  # noqa: F401 (re-export module)
-from .baselines import (RATIO_METRICS, BenchDiff, RatioMetric, backend_of,
-                        diff_records, load_record, pin_baseline,
-                        ratio_metrics_of)
 from .rules import (EwmaSpike, RatioBand, SloRule, Staleness, Threshold,
                     default_rules, elastic_rules, fabric_rules,
                     frontdoor_rules, moe_rules, serving_rules,
@@ -39,7 +32,4 @@ __all__ = [
     "elastic_rules", "moe_rules", "default_rules",
     "Incident", "SloSentry", "install", "uninstall", "active",
     "maybe_tick",
-    "baselines", "RatioMetric", "RATIO_METRICS", "BenchDiff",
-    "load_record", "backend_of", "ratio_metrics_of", "pin_baseline",
-    "diff_records",
 ]

@@ -8,10 +8,10 @@ TPU design: rope is pure VPU work and HBM-bandwidth-bound. The fusion win
 over XLA is structural: one pallas_call reads cos/sin ONCE per sequence
 block and rotates BOTH q and k tiles while they sit in VMEM, instead of
 two elementwise fusions each re-reading the tables. Whether that beats
-XLA's fusion on real hardware is an empirical question — bench.py records
-pallas-vs-XLA timings (rope_pallas_us / rope_xla_us) and the dispatch
-keeps the XLA path unless the kernel is enabled and eligible (training
-layout, contiguous positions).
+XLA's fusion on real hardware is an empirical question that no benchmark
+cell has answered yet (the kernel has a name in the trace, ``fused_rope``,
+and no share metric); the dispatch keeps the XLA path unless the kernel is
+enabled and eligible (training layout, contiguous positions).
 
 Layout: q,k [b, s, h, d] (d = head_dim, lane-aligned at 128/64); cos/sin
 [s, d]. Grid over (b, s/block_s). position_ids path (gathered tables)

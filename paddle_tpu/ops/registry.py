@@ -21,14 +21,14 @@ _KERNELS: Dict[Tuple[str, str], Callable] = {}
 
 def device_is_tpu(d) -> bool:
     """True if a jax Device is a TPU. The single source of truth for
-    is-this-a-TPU; framework.is_compiled_with_tpu and bench use it too."""
+    is-this-a-TPU; framework.is_compiled_with_tpu uses it too."""
     return getattr(d, "platform", "") == "tpu"
 
 
 def require_tpu():
     """The first jax device, or RuntimeError when it is not a TPU — for
     entry points whose numbers mean nothing anywhere else (chip_smoke.py,
-    bench.py, the tools/ that measure). Never falls back."""
+    the tools/ that measure). Never falls back."""
     d = jax.devices()[0]
     if not device_is_tpu(d):
         raise RuntimeError(
@@ -46,8 +46,8 @@ def backend_kind() -> str:
 
 def pallas_disabled() -> bool:
     """Global Pallas kill-switch (PT_DISABLE_PALLAS): one predicate shared
-    by every kernel-family support gate so the bench's degrade-to-XLA
-    retry covers all of them."""
+    by every kernel-family support gate, so one switch turns all of them
+    off."""
     import os
     return bool(os.environ.get("PT_DISABLE_PALLAS"))
 

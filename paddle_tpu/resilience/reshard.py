@@ -34,8 +34,8 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 from ..observability.metrics import REGISTRY as _REG
 
 __all__ = ["PLAN_NAME", "ReshardError", "write_plan", "read_plan",
-           "effective_axes", "plans_equivalent", "check_feasible",
-           "load_resharded", "place_tree"]
+           "effective_axes", "plans_equivalent", "saved_tree",
+           "check_feasible", "load_resharded", "place_tree"]
 
 PLAN_NAME = "_PLAN.json"
 _PLAN_SCHEMA = "pt-ckpt-plan-v1"
@@ -100,6 +100,15 @@ def plans_equivalent(a, b) -> bool:
 
 
 # -- feasibility --------------------------------------------------------------
+
+def saved_tree(step_dir: str) -> Dict[str, Any]:
+    """The checkpoint's own tree, each leaf the array's metadata (``shape``,
+    ``dtype``) and no payload read: what ``check_feasible`` walks offline.
+    orbax hands the tree over inside ``StepMetadata.item_metadata``; the
+    wrapper itself is ONE leaf, over which the walk finds nothing."""
+    import orbax.checkpoint as ocp
+    return ocp.StandardCheckpointer().metadata(step_dir).item_metadata.tree
+
 
 def _iter_spec_leaves(tree: Dict[str, Any], param_specs: Dict[str, Any]
                       ) -> Iterator[Tuple[str, Any, Tuple[int, ...]]]:

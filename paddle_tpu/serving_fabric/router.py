@@ -14,7 +14,7 @@ through a :class:`~.transport.FabricTransport`. Per scheduler pass
    picks the replica: ``affinity`` routes to the longest
    digest-matched prefix (ties and cold prompts fall back to
    least-loaded = free slots × free pages), ``least-loaded`` and
-   ``round-robin`` are the baselines the bench compares against.
+   ``round-robin`` are the policies it is held against.
    Dispatch is capacity-gated (a replica is only handed requests while
    it has free slots), so the global queue — where fairness and SLO
    policy live — stays the ONE place requests wait.

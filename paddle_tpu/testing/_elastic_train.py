@@ -124,7 +124,7 @@ def pick_plan(args, mcfg, devices):
 
 
 def reshard_probe() -> dict:
-    """Timed mini elastic cycle for the bench detail rows: llama-micro
+    """Timed mini elastic cycle (``--probe-reshard``): llama-micro
     state checkpointed every 4 steps under the largest feasible dp×tp
     plan, a SIGKILL-shape death at step 6, resharded restore onto HALF
     the devices. ``elastic_reshard_seconds`` is the verify+reshard+place

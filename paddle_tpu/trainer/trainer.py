@@ -174,8 +174,8 @@ class Trainer:
         self.build_log: list = []
         #: host-side dispatch accounting: `dispatch_host_s` is the wall time
         #: spent ENQUEUEING compiled programs (not waiting on them) — the
-        #: per-step host overhead the superstep amortizes (bench.py reports
-        #: dispatch_overhead_s_per_step = dispatch_host_s / steps).
+        #: per-step host overhead the superstep amortizes (per trained step:
+        #: dispatch_host_s / steps).
         self.dispatch_stats = {"steps": 0, "dispatches": 0,
                                "dispatch_host_s": 0.0}
         # cost observatory (ISSUE 9): lazily attached at the first log

@@ -10,6 +10,7 @@ and every worker a test spawns inherits it) and jax.config (already-imported
 jax). The chip is reached through chip_smoke.py, never through pytest.
 """
 
+import functools
 import os
 
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
@@ -62,6 +63,7 @@ def _shared_engine_executables():
     orig = ContinuousBatchingEngine.__init__
     cache = {}
 
+    @functools.wraps(orig)       # inspect.signature still sees the knobs
     def patched(self, model, *args, **kwargs):
         orig(self, model, *args, **kwargs)
         key = (id(model), repr(getattr(model, "cfg", None)),

@@ -9,6 +9,7 @@ All meshes are virtual CPU devices (conftest forces 8)."""
 import hashlib
 import json
 import os
+import re
 import sys
 
 import numpy as np
@@ -440,7 +441,10 @@ def test_reshard_cli_dry_run_and_write(tmp_path, plans, capsys):
     CheckpointManager(root, save_interval_steps=1, plan=plan8).save(4, tree)
 
     assert _cli(["--from", root, "--mesh", "2x2", "--dry-run"]) == 0
-    assert "feasible" in capsys.readouterr().out
+    said = capsys.readouterr().out
+    assert "feasible" in said
+    # a walk that matched no leaf would approve any target
+    assert int(re.search(r"(\d+) sharded leaves", said).group(1)) > 0
 
     out = str(tmp_path / "dst")
     assert _cli(["--from", root, "--mesh", "2x2", "--out", out]) == 0

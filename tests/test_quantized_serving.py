@@ -222,6 +222,40 @@ def test_quant_engine_chunked_prefill_and_metrics(quant, prompts,
                                   "kv_dtype": "native"}
 
 
+def test_int8_pages_keep_more_slots_resident_at_equal_bytes(bf16, quant):
+    """What int8 KV pages buy, counted through the engine's own allocator:
+    one byte budget gives each pool dtype the pages it affords; eight
+    requests of three pages each overfill the native pool (it preempts)
+    and fit the int8 pool (it does not), and both finish every stream."""
+    def page_bytes(model):
+        sizes = []
+        for n in (1, 2):
+            pools, _ = model.model.alloc_paged_caches(1, n * PAGE, PAGE)
+            sizes.append(sum(a.size * a.dtype.itemsize
+                             for entry in pools for a in entry))
+        return sizes[1] - sizes[0]
+
+    per_page = {"native": page_bytes(bf16), "int8": page_bytes(quant)}
+    # int8 values + one float32 scale per row: under half the native bytes
+    assert per_page["native"] > 2 * per_page["int8"]
+    budget = 13 * per_page["native"]     # 1 reserved + 4 slots x 3 pages
+    rs = np.random.RandomState(8)
+    reqs = [rs.randint(0, bf16.cfg.vocab_size, (2 * PAGE,)).astype(np.int32)
+            for _ in range(8)]
+    preempted = {}
+    for name, model in (("native", bf16), ("int8", quant)):
+        eng = ContinuousBatchingEngine(
+            model, max_batch=8, page_size=PAGE, max_len=4 * PAGE,
+            num_pages=budget // per_page[name],
+            generation_config=GenerationConfig(max_new_tokens=PAGE,
+                                               do_sample=False))
+        rids = [eng.submit(p) for p in reqs]
+        out = eng.run()
+        assert all(len(out[r]) == PAGE for r in rids)
+        preempted[name] = eng.preemptions
+    assert preempted["native"] > 0 and preempted["int8"] == 0, preempted
+
+
 # ---------------------------------------------------------------------------
 # BanRule dtype narrowing (the quant graph contract's mechanism)
 # ---------------------------------------------------------------------------

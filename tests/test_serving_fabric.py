@@ -535,6 +535,7 @@ def test_serve_fabric_cli_smoke():
     assert out["roles"] == ["prefill", "both"]
     assert out["tenant_admitted"] == {"shared": 4, "cold": 1}
     assert out["handoffs"] == 1 and out["handoff_failures"] == 0
+    assert out["handoff_bytes"] > 0
     assert sum(out["routed"].values()) >= 5
 
 

@@ -1,5 +1,5 @@
 """SLO sentry (ISSUE 10): declarative rules over the metrics plane,
-correlated incident capture, noise-aware bench regression gate.
+correlated incident capture.
 
 Contract under test:
 
@@ -16,17 +16,10 @@ Contract under test:
   snapshots the registry; ``maybe_tick`` with no sentry installed is a
   no-op;
 * ``Trainer.fit`` ticks the installed sentry at log boundaries (the real
-  wiring, not a hand call);
-* bench gate: r04-vs-r06 (tpu vs cpu) compares NOTHING and passes as
-  incomparable; baseline-vs-r06 (same backend) passes; a synthetically
-  degraded copy exits nonzero NAMING the scaled metric; the checked-in
-  ``tools/bench_baseline.json`` matches what pinning the newest artifact
-  produces.
+  wiring, not a hand call).
 """
 
 import json
-import os
-import sys
 import warnings
 
 import numpy as np
@@ -35,8 +28,6 @@ import pytest
 import paddle_tpu.observability as obs
 from paddle_tpu.observability import sentry as sn
 from paddle_tpu.observability.metrics import REGISTRY
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -610,194 +601,6 @@ def test_trainer_fit_ticks_sentry_at_log_boundaries(tmp_path):
     assert sentry.incidents[0].rule == "train_loss_always"
     recs = sn.SloSentry.load_incidents(path)
     assert len(recs) == 1
-
-
-# ---------------------------------------------------------------------------
-# bench regression gate
-# ---------------------------------------------------------------------------
-
-from paddle_tpu.observability.sentry import baselines as bl  # noqa: E402
-
-
-def _bench_diff_main(argv):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_diff
-        return bench_diff.main(argv)
-    finally:
-        sys.path.pop(0)
-
-
-def test_r04_vs_r06_incomparable_backends_pass():
-    r04 = os.path.join(REPO, "BENCH_r04.json")
-    r06 = os.path.join(REPO, "BENCH_r06.json")
-    diff = bl.diff_records(bl.load_record(r04), bl.load_record(r06))
-    assert diff.verdict() == "incomparable"
-    assert diff.compared == 0
-    assert diff.ok                          # no EVIDENCE of regression
-    assert "backend mismatch" in diff.note
-    assert _bench_diff_main([r04, r06, "--quiet"]) == 0
-
-
-def test_unknown_backend_never_bypasses_the_guard():
-    """An artifact predating the detail.backend field loads as backend
-    "unknown" — that must read as "can't prove same backend" (compare
-    nothing), not as a wildcard that matches any backend and lets a
-    TPU-vs-CPU MFU ratio produce a fake verdict."""
-    known = {"detail": {"backend": "tpu", "mfu": 0.5}}
-    legacy = {"detail": {"mfu": 0.1}}         # no backend field anywhere
-    for base, cand in ((known, legacy), (legacy, known),
-                       (legacy, legacy)):
-        diff = bl.diff_records(base, cand)
-        assert diff.verdict() == "incomparable"
-        assert diff.compared == 0
-        assert all(r["reason"] == "backend unknown" for r in diff.rows)
-        assert "backend unknown" in diff.note
-
-
-def test_baseline_vs_r06_no_regression():
-    base = os.path.join(REPO, "tools", "bench_baseline.json")
-    r06 = os.path.join(REPO, "BENCH_r06.json")
-    diff = bl.diff_records(bl.load_record(base), bl.load_record(r06))
-    assert diff.verdict() == "ok"
-    assert diff.compared >= 4
-    assert diff.regressions == []
-    assert _bench_diff_main([base, r06, "--quiet"]) == 0
-
-
-def test_degraded_copy_exits_nonzero_naming_metric(tmp_path, capsys):
-    r06 = os.path.join(REPO, "BENCH_r06.json")
-    with open(r06) as f:
-        d = json.load(f)
-    d["parsed"]["detail"]["mfu"] *= 0.5     # past any 25% band
-    degraded = str(tmp_path / "degraded.json")
-    with open(degraded, "w") as f:
-        json.dump(d, f)
-    diff = bl.diff_records(bl.load_record(r06), bl.load_record(degraded))
-    assert diff.verdict() == "regressed"
-    assert diff.regressions == ["mfu"]
-    rc = _bench_diff_main([r06, degraded, "--quiet"])
-    assert rc == 1
-    err = capsys.readouterr().err
-    assert "mfu" in err                     # names the metric
-
-
-def test_checked_in_baseline_matches_newest_artifact_pin():
-    """The committed tools/bench_baseline.json must be exactly what
-    pinning the newest round artifact produces — a drifted baseline
-    gates against history nobody can reproduce."""
-    newest = bl.newest_round_artifact(REPO)
-    assert newest is not None
-    pinned = bl.pin_baseline(bl.load_record(newest),
-                             source=os.path.basename(newest))
-    with open(os.path.join(REPO, "tools", "bench_baseline.json")) as f:
-        checked_in = json.load(f)
-    assert checked_in == pinned
-
-
-def test_newest_round_artifact_orders_numerically(tmp_path):
-    """Lexicographic order would pin r9 over r10 (and r99 over r100)
-    forever — "newest" must mean the numeric round."""
-    for name in ("BENCH_r9.json", "BENCH_r10.json", "BENCH_r100.json"):
-        with open(tmp_path / name, "w") as f:
-            json.dump({"parsed": {"detail": {"backend": "cpu",
-                                             "mfu": 0.5}}}, f)
-    (tmp_path / "BENCH_r101_notes.json").write_text("{}")  # non-round file
-    assert os.path.basename(
-        bl.newest_round_artifact(str(tmp_path))) == "BENCH_r100.json"
-
-
-def test_diff_direction_semantics():
-    base = {"schema": bl.BASELINE_SCHEMA, "backend": "tpu",
-            "metrics": {"mfu": 0.5, "obs_overhead_ratio": 1.0,
-                        "step_time_predicted_over_measured": 1.0}}
-
-    def cand(**kw):
-        det = {"backend": "tpu", "mfu": 0.5, "obs_overhead_ratio": 1.0,
-               "step_time_predicted_over_measured": 1.0}
-        det.update(kw)
-        return {"detail": det}
-
-    # lower-is-worse: mfu UP past the band is an improvement, not a fail
-    assert bl.diff_records(base, cand(mfu=0.9)).ok
-    assert "mfu" in bl.diff_records(base, cand(mfu=0.9)).improvements
-    assert bl.diff_records(base, cand(mfu=0.3)).regressions == ["mfu"]
-    # higher-is-worse: overhead ratio UP fails, DOWN is fine
-    assert bl.diff_records(
-        base, cand(obs_overhead_ratio=1.3)).regressions == [
-        "obs_overhead_ratio"]
-    assert bl.diff_records(base, cand(obs_overhead_ratio=0.9)).ok
-    # either: the drift self-ratio fails in BOTH directions
-    assert bl.diff_records(
-        base,
-        cand(step_time_predicted_over_measured=2.0)).regressions == [
-        "step_time_predicted_over_measured"]
-    assert bl.diff_records(
-        base,
-        cand(step_time_predicted_over_measured=0.4)).regressions == [
-        "step_time_predicted_over_measured"]
-    # cpu tier: MFU/vs_baseline are absolute-derived (host weather, the
-    # documented ±40% swings) — the band widens to cpu_band, so a 0.6
-    # ratio passes while a catastrophic 0.5 collapse still fails; the
-    # within-run overhead ratio keeps its tight band on cpu
-    cbase = {"schema": bl.BASELINE_SCHEMA, "backend": "cpu",
-             "metrics": {"mfu": 0.5, "obs_overhead_ratio": 1.0}}
-
-    def ccand(**kw):
-        det = {"backend": "cpu", "mfu": 0.5, "obs_overhead_ratio": 1.0}
-        det.update(kw)
-        return {"detail": det}
-
-    assert bl.diff_records(cbase, ccand(mfu=0.3)).ok            # 0.6
-    assert bl.diff_records(cbase, ccand(mfu=0.25)).regressions == [
-        "mfu"]                                                   # 0.5
-    assert bl.diff_records(
-        cbase, ccand(obs_overhead_ratio=1.3)).regressions == [
-        "obs_overhead_ratio"]
-
-
-def test_pin_roundtrip_and_band_override(tmp_path):
-    out = str(tmp_path / "pinned.json")
-    rc = _bench_diff_main(["--pin", out,
-                           os.path.join(REPO, "BENCH_r04.json"),
-                           "--quiet"])
-    assert rc == 0
-    with open(out) as f:
-        pinned = json.load(f)
-    assert pinned["backend"] == "tpu"
-    assert pinned["metrics"]["mfu"] == pytest.approx(0.625, abs=0.01)
-    # a tiny --band makes r04's jitter-free self-diff still pass
-    rc = _bench_diff_main([out, os.path.join(REPO, "BENCH_r04.json"),
-                           "--band", "0.001", "--quiet"])
-    assert rc == 0
-
-
-# ---------------------------------------------------------------------------
-# review fixes
-# ---------------------------------------------------------------------------
-
-def test_zero_collapsed_ratio_metric_regresses_not_skips():
-    """A ratio metric collapsing to exactly 0.0 is the most extreme
-    regression — it must fail the gate, not skip as 'absent'."""
-    base = {"schema": bl.BASELINE_SCHEMA, "backend": "cpu",
-            "metrics": {"prefix_hit_rate": 0.95}}
-    cand = {"detail": {"backend": "cpu", "prefix_hit_rate": 0.0}}
-    diff = bl.diff_records(base, cand)
-    assert diff.regressions == ["prefix_hit_rate"]
-    # while zeros are never PINNED as baselines (no ratio can anchor
-    # on them), and a zero base in an artifact-vs-artifact diff skips
-    # with the reason named rather than dividing by zero
-    pinned = bl.pin_baseline(
-        {"detail": {"backend": "cpu", "prefix_hit_rate": 0.0,
-                    "mfu": 0.5}})
-    assert "prefix_hit_rate" not in pinned["metrics"]
-    assert pinned["metrics"]["mfu"] == 0.5
-    zdiff = bl.diff_records(
-        {"detail": {"backend": "cpu", "mfu": 0.0}},
-        {"detail": {"backend": "cpu", "mfu": 0.5}})
-    assert zdiff.regressions == []
-    assert [r for r in zdiff.rows if r["metric"] == "mfu"][0][
-        "reason"] == "zero baseline value"
 
 
 def test_window_mean_spike_fires_on_transient():

@@ -173,7 +173,7 @@ def test_superstep_dispatch_count(monkeypatch):
 
 def test_superstep_host_dispatch_overhead_amortized():
     """The host time spent enqueueing per trained step must drop with K>1
-    (the bench.py acceptance metric). Interleaved min-of-rounds so a
+    (``dispatch_stats``: dispatch_host_s / steps). Interleaved min-of-rounds so a
     loaded CI machine's scheduling spikes can't flip the verdict."""
     tr = build()
     batches = make_batches(8)

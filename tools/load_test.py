@@ -1,34 +1,19 @@
-"""Front-door load test: goodput vs offered load through the full
-client → FrontDoor → ServingFabric → replica stack (ISSUE 16).
+"""Front-door load test through the full client → FrontDoor →
+ServingFabric → replica stack (ISSUE 16).
 
-Three entry points:
-
-* ``--smoke`` — the tier-1 CI leg (tests/test_load_smoke.py runs it
-  in-process): ~20 concurrent streaming FabricClients against a
-  2-replica fabric with the shed ladder, tenant weights, and a circuit
-  breaker armed, plus one slow-loris client and one injected
-  hang-then-recover mid-run. Asserts the acceptance contract: every
-  rejection is TYPED and carries ``retry_after_ms``, every admitted
-  stream completes exactly, the slow client is evicted (and its
-  capacity reused), the hung replica trips/fails-over/readmits, and
-  admitted p99 TTFT stays under the ``frontdoor_rules()`` ceiling.
-* ``overload_leg()`` — offered load at a multiple of pool capacity,
-  shed ladder on vs off; goodput = deadline-met tokens per second.
-  The shed-off leg admits everything and burns slot-time on requests
-  the deadline then cancels; the shed-on leg refuses the excess at
-  admission (typed, with a retry hint) and finishes what it admits.
-  bench.py's ``frontdoor_goodput_under_overload`` ratio row is
-  on ÷ off from this leg.
-* ``hang_leg()`` — p99 TTFT with a replica hung mid-run, breaker
-  budgets tight vs loose. "Breaker off" is approximated with an 8x
-  budget, NOT no budget — an unbounded poll on a hung replica wedges
-  the driver forever. bench.py's ``frontdoor_p99_ttft_with_breaker_
-  ratio`` row is tight ÷ loose from this leg.
+One leg, the tier-1 CI leg (tests/test_load_smoke.py runs it
+in-process): ~20 concurrent streaming FabricClients against a 2-replica
+fabric with the shed ladder, tenant weights, and a circuit breaker
+armed, plus one slow-loris client and one injected hang-then-recover
+mid-run. Asserts the acceptance contract: every rejection is TYPED and
+carries ``retry_after_ms``, every admitted stream completes exactly, the
+slow client is evicted (and its capacity reused), the hung replica
+trips/fails-over/readmits, and admitted p99 TTFT stays under the
+``frontdoor_rules()`` ceiling.
 
 Usage::
 
     JAX_PLATFORMS=cpu python tools/load_test.py --smoke
-    JAX_PLATFORMS=cpu python tools/load_test.py --offered 2.0
 
 Prints one JSON summary line; exit 0 = pass. ``main(argv)`` is
 importable.
@@ -359,203 +344,18 @@ def smoke(ttft_ceiling_s: float = 30.0) -> dict:
     return summary
 
 
-# -- bench legs (imported by bench.py) ---------------------------------------
-
-def overload_leg(model, *, shed: bool, offered: int = 20,
-                 max_new: int = 16, deadline_mult: float = 3.5,
-                 deadline_ms=None, rounds: int = 2,
-                 seed: int = 5) -> dict:
-    """Offered load well beyond pool capacity (2 replicas x 2 slots),
-    every request deadline-bound at ``deadline_mult`` x the measured
-    UNLOADED request latency, one shot each (no client retries: the
-    leg measures the SERVER's admission discipline). The deadline is
-    sized so the first couple of scheduling waves meet it and deeper
-    queue positions cannot — shed OFF admits those anyway, pays their
-    prefill and partial decode, then the deadline cancels them
-    (slot-time burned for zero delivered tokens); shed ON refuses them
-    at admission with a typed ``Overloaded`` and finishes what it
-    admits. Goodput counts only deadline-met tokens. Pass the first
-    leg's returned ``deadline_ms`` into the second so the A/B shares
-    ONE deadline; best-of-``rounds`` absorbs scheduler jitter."""
-    from paddle_tpu.serving_fabric import (FabricClient, LoadShedder,
-                                           TenantFairPolicy)
-    shedder = LoadShedder(queue_depth_hi=3, queue_depth_lo=1,
-                          queue_cap=4, breach_ticks=1,
-                          recover_ticks=3) if shed else None
-    tag = "sh" if shed else "un"
-    door, fab, _br = build_stack(
-        model, shedder=shedder, fair=TenantFairPolicy(),
-        door_kwargs=dict(outbox_max=64),
-        names=[f"{tag}0", f"{tag}1"])
-    door.start()
-    try:
-        _warmup(door, fab)
-        if deadline_ms is None:
-            # calibrate: one unloaded request end-to-end
-            cal = FabricClient(door.host, door.port, max_attempts=2,
-                               io_timeout_s=300.0)
-            t0 = time.perf_counter()
-            cal.generate(_prompts(1, seed=seed)[0], max_new,
-                         request_id=f"cal-{tag}")
-            deadline_ms = (deadline_mult
-                           * (time.perf_counter() - t0) * 1000.0)
-
-        best = None
-        for rnd in range(rounds):
-            done, rejected = [], []
-            lock = threading.Lock()
-            go = threading.Barrier(offered)
-            prompts = _prompts(offered, seed=seed + 1)
-
-            def one(i):
-                c = FabricClient(door.host, door.port, max_attempts=1,
-                                 io_timeout_s=300.0)
-                go.wait(timeout=60.0)
-                try:
-                    r = c.generate(prompts[i], max_new,
-                                   deadline_ms=deadline_ms,
-                                   request_id=f"ov-{tag}-{rnd}-{i}")
-                    with lock:
-                        done.append(len(r.tokens))
-                except Exception as e:   # noqa: BLE001 — typed/deadline
-                    with lock:
-                        rejected.append(type(e).__name__)
-
-            ts = [threading.Thread(target=one, args=(i,), daemon=True)
-                  for i in range(offered)]
-            t1 = time.perf_counter()
-            for t in ts:
-                t.start()
-            for t in ts:
-                t.join(timeout=300.0)
-            dt = time.perf_counter() - t1
-            lat = fab.latency_stats()
-            res = {"goodput_tps": sum(done) / max(dt, 1e-9),
-                   "completed": len(done), "rejected": len(rejected),
-                   "wall_s": dt, "deadline_ms": deadline_ms,
-                   "ttft_p99_s": lat.get("ttft_p99_s", 0.0)}
-            if best is None or res["goodput_tps"] > best["goodput_tps"]:
-                best = res
-        return best
-    finally:
-        door.stop()
-
-
-def hang_leg(model, *, poll_budget_s: float, n_requests: int = 4,
-             max_new: int = 6, seed: int = 11) -> dict:
-    """p99 TTFT for requests admitted while one replica is HUNG: with
-    a tight poll budget the breaker converts the hang into a fast
-    failover; with a loose one every step stalls the full budget
-    first. Driven at the router (the layer the breaker guards)."""
-    from paddle_tpu.serving_fabric import (BreakerTransport,
-                                           InProcTransport,
-                                           ServingFabric,
-                                           build_replicas)
-    from paddle_tpu.inference.generation import GenerationConfig
-    from paddle_tpu.testing.chaos import hang_replica, unhang_replica
-    tag = f"hg{int(poll_budget_s * 10)}"
-    reps = build_replicas(
-        model, 2, page_size=8, max_len=96, max_batch=2,
-        names=[f"{tag}a", f"{tag}b"],
-        generation_config=GenerationConfig(max_new_tokens=max_new,
-                                           do_sample=False))
-    br = BreakerTransport(InProcTransport(reps),
-                          open_cooldown_s=60.0,  # no readmission mid-leg
-                          probe_timeout_s=0.2)
-    fab = ServingFabric(br, policy="round-robin")
-    # warm every bucket incl. the failover re-prefill one, under the
-    # LOOSE default budgets (first polls pay jit compiles); the leg's
-    # budget applies only once the hang is armed
-    for p in _prompts(4, seed=seed) + _prompts(2, length=14, seed=seed):
-        fab.submit(p, max_new)
-    fab.run()
-    victim = f"{tag}a"
-    hang_replica(br, victim)
-    br.op_timeouts["poll"] = poll_budget_s
-    br.op_timeouts["submit"] = poll_budget_s
-    try:
-        fab.reset_latency_stats()
-        fids = [fab.submit(p, max_new)
-                for p in _prompts(n_requests, seed=seed + 1)]
-        out = fab.run()
-        assert all(len(out[f]) == max_new for f in fids)
-        return {"ttft_p99_s": fab.latency_stats()["ttft_p99_s"],
-                "trips": br.trips}
-    finally:
-        unhang_replica(br, victim)
-
-
-def trace_overhead_legs(model, *, rounds: int = 3, n_requests: int = 6,
-                        max_new: int = 8, seed: int = 13) -> dict:
-    """Wall time of one fabric wave with request tracing ON vs OFF,
-    interleaved min-of-rounds on the SAME warmed fabric (same discipline
-    as the bench's obs_overhead_ratio). The ratio prices the span
-    machinery end-to-end — router queue/route/submit spans, engine
-    queue/resident/prefill/decode spans — against the disabled path's
-    attribute-load-plus-branch contract."""
-    from paddle_tpu.inference.generation import GenerationConfig
-    from paddle_tpu.observability.tracing import TRACER
-    from paddle_tpu.serving_fabric import (InProcTransport, ServingFabric,
-                                           build_replicas)
-    reps = build_replicas(
-        model, 2, page_size=8, max_len=96, max_batch=2,
-        names=["tro0", "tro1"],
-        generation_config=GenerationConfig(max_new_tokens=max_new,
-                                           do_sample=False))
-    fab = ServingFabric(InProcTransport(reps), policy="round-robin")
-    prompts = _prompts(n_requests, seed=seed)
-
-    def wave():
-        fids = [fab.submit(p, max_new) for p in prompts]
-        got = fab.run()
-        assert all(len(got[f]) == max_new for f in fids)
-
-    wave()                                    # pay the jit compiles once
-    legs = {"off": float("inf"), "on": float("inf")}
-    n_traces = 0
-    try:
-        for _ in range(rounds):
-            TRACER.disable()
-            t0 = time.perf_counter()
-            wave()
-            legs["off"] = min(legs["off"], time.perf_counter() - t0)
-            TRACER.enable()
-            t0 = time.perf_counter()
-            wave()
-            legs["on"] = min(legs["on"], time.perf_counter() - t0)
-            n_traces += len(TRACER.take_completed())
-    finally:
-        TRACER.disable()
-    return {"wall_on_s": legs["on"], "wall_off_s": legs["off"],
-            "ratio": legs["on"] / max(legs["off"], 1e-9),
-            "traces": n_traces}
-
-
 # -- CLI ---------------------------------------------------------------------
 
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
-                    help="tier-1 acceptance leg (~20 clients, one "
-                         "slow, one hang)")
-    ap.add_argument("--ttft-ceiling", type=float, default=None,
-                    help="frontdoor_rules p99 TTFT ceiling in seconds "
-                         "(smoke default 30 on CPU, else 2.0)")
-    ap.add_argument("--offered", type=int, default=20,
-                    help="concurrent clients for the overload A/B")
+                    help="the acceptance leg (~20 clients, one slow, "
+                         "one hang); the only leg, so the flag is "
+                         "optional")
+    ap.add_argument("--ttft-ceiling", type=float, default=30.0,
+                    help="frontdoor_rules p99 TTFT ceiling in seconds")
     args = ap.parse_args(argv)
-    if args.smoke:
-        return smoke(ttft_ceiling_s=args.ttft_ceiling or 30.0)
-    model = _tiny_model()
-    legs = {"shed_on": overload_leg(model, shed=True,
-                                    offered=args.offered)}
-    legs["shed_off"] = overload_leg(
-        model, shed=False, offered=args.offered,
-        deadline_ms=legs["shed_on"]["deadline_ms"])
-    ratio = (legs["shed_on"]["goodput_tps"]
-             / max(legs["shed_off"]["goodput_tps"], 1e-9))
-    return {"ok": True, "legs": legs,
-            "goodput_under_overload": round(ratio, 3)}
+    return smoke(ttft_ceiling_s=args.ttft_ceiling)
 
 
 if __name__ == "__main__":

@@ -24,8 +24,8 @@ Usage::
     python tools/op_cost_probe.py --calibrate --db /tmp/op_cost_db.json
 
 Prints one JSON summary line. ``calibrate()`` / ``measure_graphs()`` are
-importable — tools/obs_smoke.py's cost leg and bench.py's cost probe
-drive them in-process.
+importable — tools/obs_smoke.py's cost leg and the cost observatory's
+tests drive them in-process.
 """
 
 import argparse

@@ -22,8 +22,7 @@ Usage::
 
 ``--validate`` additionally EXECUTES every ranked config (interleaved
 min-of-rounds) and reports predicted-vs-measured rank agreement + the
-top1-in-measured-top2 verdict — the bench planner rows and the
-acceptance bar ride this mode. ``main(argv)`` is importable and returns
+top1-in-measured-top2 verdict — the acceptance bar rides this mode. ``main(argv)`` is importable and returns
 the exit code (the tier-1 smoke test drives it in-process).
 """
 

@@ -134,8 +134,7 @@ def main(argv=None) -> int:
               f"exist", file=sys.stderr)
         return 2
 
-    import orbax.checkpoint as ocp
-    md = ocp.StandardCheckpointer().metadata(sdir)
+    md = rs.saved_tree(sdir)
     try:
         rs.check_feasible(md, target)
     except rs.ReshardError as e:

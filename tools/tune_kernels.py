@@ -256,7 +256,7 @@ def main():
         candidates = [(128, 128), (128, 256)]
     else:
         shapes = [
-            (8, 2048, 12, 4, 128, jnp.bfloat16, True),    # bench.py shape
+            (8, 2048, 12, 4, 128, jnp.bfloat16, True),    # chip_smoke train shape
             (4, 4096, 12, 4, 128, jnp.bfloat16, True),
             (1, 8192, 32, 8, 128, jnp.bfloat16, True),    # Llama-3-8B @ 8K
             (8, 2048, 16, 16, 64, jnp.bfloat16, True),
