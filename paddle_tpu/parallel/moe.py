@@ -276,6 +276,11 @@ def xla_grouped_matmul(xs, w, group_sizes):
                               preferred_element_type=jnp.float32)
 
 
+def _int_zero(a):
+    """The cotangent of an integer argument of a ``custom_vjp``."""
+    return np.zeros(a.shape, dtype=jax.dtypes.float0)
+
+
 @jax.custom_vjp
 def grouped_matmul(xs, w, group_sizes):
     """The grouped matmul of a differentiated program: the one entry of
@@ -288,8 +293,11 @@ def grouped_matmul(xs, w, group_sizes):
     activation dtype by ``ragged_dot`` itself, not by a cast after it. A
     float32 result of OLMoE's 262,144 routed rows is 2 GB a product, and
     with it beside its bf16 copy the step program needs 17.49 GB of a
-    v5e's 15.75 (the compiler's count, PR 28); the backward takes the
-    float32 path of ``xla_grouped_matmul`` as it always has.
+    v5e's 15.75 (the compiler's count, PR 28). The backward's products
+    do the same since PR 30: the cotangent goes in as it arrives, ``dxs``
+    comes back in ``xs.dtype`` and ``dw`` in ``w.dtype`` (all four of
+    OLMoE's, at its size on the chip: bit for bit the float32 products
+    cast after, in 19.1 + 37.9 ms against 32.5 + 59.8; chip run, PR 30).
 
     custom_vjp because jax's ragged_dot ad rules choke on symbolic-Zero
     tangents inside a shard_map transpose (the dropless-EP body):
@@ -306,14 +314,75 @@ def _gmm_fwd(xs, w, group_sizes):
 
 def _gmm_bwd(res, gy):
     xs, w, group_sizes = res
+    # jax's transpose rule hands ``preferred_element_type`` on to both
+    # products of the backward
     _, vjp = jax.vjp(
-        lambda a, b: xla_grouped_matmul(a, b, group_sizes), xs, w)
-    dxs, dw = vjp(gy.astype(jnp.float32))
-    return (dxs.astype(xs.dtype), dw.astype(w.dtype),
-            np.zeros(group_sizes.shape, dtype=jax.dtypes.float0))
+        lambda a, b: jax.lax.ragged_dot(
+            a, b, group_sizes, preferred_element_type=xs.dtype), xs, w)
+    dxs, dw = vjp(gy)
+    return dxs, dw, _int_zero(group_sizes)
 
 
 grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def inverse_permutation(order):
+    """``inv`` with ``inv[order[i]] == i`` for a permutation ``order`` of
+    ``arange(n)``: an int32 scatter of n indices (1 MB at OLMoE's 262,144
+    routed rows), told that no two of them collide."""
+    n = order.shape[0]
+    return jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+
+
+# The routed rows of a differentiated program move by GATHERS alone (PR
+# 30). ``order`` (the stable argsort of the rows' experts) is a permutation,
+# which XLA cannot know: left to jax's own rules, ``zeros.at[order].set``
+# compiles on TPU to passes over a u32 copy of the rows with a mask and an
+# index sort, and the transpose of ``flat[order % t]`` to a scatter-add
+# with another sort (140 ms of OLMoE's 532 ms step, chip run, PR 28).
+# Stated as what they are, a permutation's transpose is the gather by its
+# inverse and the dispatch's transpose a gather and a sum over k.
+
+@jax.custom_vjp
+def permute_rows(x, idx, idx_inv):
+    """``x[idx]`` for a permutation ``idx`` whose inverse is ``idx_inv``."""
+    return x[idx]
+
+
+def _permute_fwd(x, idx, idx_inv):
+    return permute_rows(x, idx, idx_inv), (idx, idx_inv)
+
+
+def _permute_bwd(res, g):
+    idx, idx_inv = res
+    return g[idx_inv], _int_zero(idx), _int_zero(idx_inv)
+
+
+permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+
+@jax.custom_vjp
+def dispatch_rows(flat, order, inv):
+    """The rows of ``flat`` [t, d] in the order of their k*t choice-major
+    assignments sorted by ``order``: ``flat[order % t]`` [k*t, d].
+    ``inv`` is ``order``'s inverse."""
+    return flat[order % flat.shape[0]]
+
+
+def _dispatch_fwd(flat, order, inv):
+    return dispatch_rows(flat, order, inv), (order, inv, flat.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    order, inv, t = res
+    # a token's k cotangents, summed in float32 and rounded once
+    dflat = jnp.sum(g[inv].reshape(-1, t, g.shape[-1]), axis=0,
+                    dtype=jnp.float32).astype(g.dtype)
+    return dflat, _int_zero(order), _int_zero(inv)
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 def _expert_ffn(xe, w_gu, w_dn):
@@ -591,15 +660,17 @@ class MoELayer(Layer):
         dropless path (reference analogue: global_scatter's exact
         count_by_gate split sizes). Both products are
         ``grouped_matmul``: XLA's ``lax.ragged_dot`` forward and
-        backward since PR 28."""
+        backward since PR 28. The rows go to their experts and come back
+        by gathers, forward and backward (``dispatch_rows``,
+        ``permute_rows``)."""
         t, d = flat.shape
         e, k = self.num_experts, self.top_k
         probs, gates, ids = self._choose(logits)              # [t, k]
         flat_e = ids.T.reshape(-1)                            # [k*t]
-        order = jnp.argsort(flat_e, stable=True)
-        sorted_e = flat_e[order]
-        group_sizes = jnp.bincount(sorted_e, length=e).astype(jnp.int32)
-        xs = flat[order % t]                                  # [k*t, d]
+        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+        inv = inverse_permutation(order)
+        group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
+        xs = dispatch_rows(flat, order, inv)                  # [k*t, d]
 
         w_gu = self.experts.w_gate_up.astype(flat.dtype)      # [e, d, 2f]
         w_dn = self.experts.w_down.astype(flat.dtype)         # [e, f, d2]
@@ -610,7 +681,7 @@ class MoELayer(Layer):
         ys = grouped_matmul(h, w_dn, group_sizes)
 
         # unsort to choice-major, weight, reduce over k
-        y_cm = jnp.zeros_like(ys).at[order].set(ys).reshape(k, t, d)
+        y_cm = permute_rows(ys, inv, order).reshape(k, t, d)
         g_km = self._weights(gates.T, 0)                      # [k, t]
         out = jnp.sum(g_km[..., None].astype(ys.dtype) * y_cm, axis=0)
         return out, _aux_loss(probs, e)

@@ -202,14 +202,11 @@ def test_kernel_compiles_for_v5e(topo, case, monkeypatch):
     assert calls and all(any(k in c for k in KERNEL_NAMES) for c in calls), calls
 
 
-def test_the_expert_products_compile_to_ragged_dots_alone(topo):
+@pytest.fixture(scope="module")
+def olmoe_routed_layer(topo):
     """OLMoE's routed layer as ``olmoe.pretrain-4k`` runs it (8 x 4096
     tokens, 64 experts of 2048 x 1024, top-8, dropless, bf16), forward and
-    backward: both expert products, their two ``dx`` and their two ``dw``
-    are XLA's ``ragged-dot`` instructions and no kernel of this repo stands
-    among them (PR 28: the Pallas grouped matmul took 8x their time on the
-    chip). XLA:TPU emits a ``ragged-dot`` as a Mosaic call of its own, so
-    ``tpu_custom_call`` does occur: under the compiler's names alone."""
+    backward, compiled for one described chip."""
     from paddle_tpu.parallel.moe import MoELayer
     moe = MoELayer(hidden_size=2048, ffn_size=1024, num_experts=64, top_k=8,
                    capacity_factor=None, dtype="bfloat16")
@@ -222,13 +219,46 @@ def test_the_expert_products_compile_to_ragged_dots_alone(topo):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
         moe.raw_parameters())
     x = jax.ShapeDtypeStruct((8, 4096, 2048), BF16, sharding=dev)
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, x).compile().as_text()
-    calls = _mosaic_calls(text)
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+
+
+def test_the_expert_products_compile_to_ragged_dots_alone(olmoe_routed_layer):
+    """Both expert products, their two ``dx`` and their two ``dw``
+    are XLA's ``ragged-dot`` instructions and no kernel of this repo stands
+    among them (PR 28: the Pallas grouped matmul took 8x their time on the
+    chip). XLA:TPU emits a ``ragged-dot`` as a Mosaic call of its own, so
+    ``tpu_custom_call`` does occur: under the compiler's names alone."""
+    calls = _mosaic_calls(olmoe_routed_layer.as_text())
     products = [c for c in calls if not c.startswith("ragged-dot-metadata")]
     assert len(products) == 6, calls
     assert all(c.startswith("ragged-dot") for c in calls), calls
     assert not any(k in c for k in KERNEL_NAMES for c in calls), calls
+
+
+def test_the_routed_rows_move_by_gathers_in_the_activation_dtype(
+        olmoe_routed_layer):
+    """The same compiled layer (PR 30): its 262,144 routed rows of 2048 go
+    to their experts and come back by gathers, forward and backward, as
+    bf16. No scatter over them (on TPU a scatter whose indices XLA cannot
+    know to be a permutation is two passes over a ``u32[262144,2048]``
+    copy, a ``pred`` mask and an index sort), no float32 copy of them
+    around a backward product, and so under 6 GiB of temporaries (4.53;
+    9.52 with the scatter form and float32 cotangents)."""
+    text = olmoe_routed_layer.as_text()
+    # the entry computation's instructions are the buffers the program
+    # holds; inside a fusion a float32 value lives in registers (v5e's
+    # vector unit has no bf16 arithmetic)
+    results = re.findall(r"^\s*(?:ROOT )?%(\S+) = (.*?) [a-z][\w\-]*\(",
+                         text[text.index("\nENTRY "):], re.M)
+    assert len(results) > 100
+    wide = [(name, shape) for name, shape in results if re.search(
+        r"u32\[262144,|pred\[262144,2048\]|f32\[262144,", shape)]
+    assert not wide, wide
+    scatters = [(name, shape) for name, shape in results
+                if "scatter" in name and "[262144,2048]" in shape]
+    assert not scatters, scatters
+    temp = olmoe_routed_layer.memory_analysis().temp_size_in_bytes
+    assert temp < 6 * 2 ** 30, temp / 2 ** 30
 
 
 # -- the 2x2 mesh ------------------------------------------------------------

@@ -347,6 +347,39 @@ def test_the_decode_attention_share_reads_the_paged_kernel_alone():
                          "kind=kLoop")
 
 
+def test_xla_own_share_reads_what_is_neither_a_kernel_nor_an_expert_product():
+    """``xla_own_share.train`` (PR 30) is the training step's device time
+    under XLA's own names: fusions, converts, copies, gathers. Neither a
+    ``ragged-dot`` (the expert products and their metadata) nor a named
+    kernel of this repo in any of the forms the compiler gives one."""
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "layer_metrics",
+                           "xla_own_share.train.json")) as f:
+        spec = json.load(f)
+    assert spec["reducer"] == "device_op_share" and spec["unit"] == "%"
+    assert spec["workloads"] == ["olmoe.pretrain-4k"]
+    rx = re.compile(spec["pattern"])
+    tail = "bf16[262144,2048]{1,0:T(8,128)(2,1)} fusion(bf16[32768,2048] %p)"
+    for own in ("%fusion.7 = ", "%convert_element_type.26 = ", "%copy.11 = ",
+                "%gather.3 = ", "%pad_maximum_fusion = ", "fusion.2 = ",
+                # an operation that only CONSUMES a kernel's result is XLA's
+                "%fusion.9 = bf16[8,8]{1,0} fusion(%ragged-dot-none.2), x = "):
+        assert rx.search(own + tail), own
+    for not_own in ("%ragged-dot-none.2 = ", "%ragged-dot-metadata = ",
+                    "%jvp_fused_vocab_ce_fwd_.1 = ",
+                    "%transpose_jvp_flash_attention_fwd__.3 = ",
+                    "%fused_rmsnorm_bwd.2 = ", "%fused_rope.1 = ",
+                    "ragged-dot-none = "):
+        assert not rx.search(not_own + tail), not_own
+    # every kernel a training step can run is named in the pattern
+    for kernel in pallas_ops.KERNEL_NAMES:
+        served_only = kernel in ("paged_attention_decode", "int8_matmul",
+                                 "latent_attention_decode")
+        assert bool(rx.search(f"%jvp_{kernel}_.1 = " + tail)) == served_only
+
+
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
     assert len(names) == len(set(names)) == 12
