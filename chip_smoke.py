@@ -335,7 +335,7 @@ def phase_serve(sz, seed):
         bucket = eng._bucket(len(p))
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :len(p)] = p
-        logits, eng.pools = eng._prefill_fn(bucket)(
+        logits, eng.pools, _ = eng._prefill_fn(bucket)(
             eng._params, jnp.asarray(ids), eng.pools,
             jnp.zeros((1, eng.pages_per_seq), jnp.int32),
             jnp.int32(len(p) - 1))

@@ -278,6 +278,31 @@ class ContinuousBatchingEngine:
         # replica's spans landing in the router's singleton
         self._tracer = None
         self.core = getattr(model, "model", model)
+        # per-slot state (``core.alloc_slot_state``): what a sequence
+        # carries from token to token OUTSIDE its pages (a convolution's
+        # last inputs, a recurrence's state): a pytree whose leaves lead
+        # with the slot. It lives beside the pools, is donated with them,
+        # written by the slot's prefill and rewritten by every tick; a
+        # freed, reused or preempted slot needs no copy, because its next
+        # prefill overwrites it. None for a model whose state is all in
+        # its pages: an empty pytree, which adds no input to a program,
+        # so the engine builds the programs it built. What would need a
+        # SNAPSHOT of the state at a position other than a sequence's
+        # end is refused here, by name
+        alloc_state = getattr(self.core, "alloc_slot_state", None)
+        self.slot_state = alloc_state(max_batch) if alloc_state else None
+        if self.slot_state is not None:
+            for mode, on in (("chunked_prefill", chunked_prefill),
+                             ("prefix_cache", prefix_cache),
+                             ("spec_k", spec_k)):
+                if on:
+                    raise ValueError(
+                        f"{mode}={on!r} needs a snapshot of the per-slot "
+                        f"state {type(self.core).__name__} keeps beside "
+                        f"its pages (alloc_slot_state), which the engine "
+                        f"does not take yet")
+        self.slot_state_bytes = sum(
+            a.size * a.dtype.itemsize for a in jax.tree.leaves(self.slot_state))
         if spec_k and not hasattr(self.core, "decode_verify_paged"):
             raise ValueError(
                 f"spec_k={spec_k} needs a model whose core implements "
@@ -638,6 +663,7 @@ class ContinuousBatchingEngine:
                "attn_dense_ticks": self.attn_path_ticks["dense"],
                "attn_paged_ticks": self.attn_path_ticks["paged"],
                "kv_bytes_per_token": self.kv_bytes_per_token,
+               "slot_state_bytes": self.slot_state_bytes,
                **self.tick_counts}
         if self.spec_k:
             out["spec_tokens_proposed"] = self.spec_tokens_proposed
@@ -739,6 +765,11 @@ class ContinuousBatchingEngine:
     # -- KV-page handoff (serving-fabric disaggregation, ISSUE 12) -----------
 
     def _refuse_latent_handoff(self) -> None:
+        if self.slot_state is not None:
+            raise ValueError(
+                f"KV-page handoff ({HANDOFF_FMT}) carries pages alone; "
+                f"this engine's model keeps per-slot state beside them "
+                f"(alloc_slot_state), which the format cannot say")
         if self.attention_kind != "gqa":
             raise ValueError(
                 f"KV-page handoff ({HANDOFF_FMT}) carries K and V pages "
@@ -968,7 +999,7 @@ class ContinuousBatchingEngine:
         signature change can't leave the two silently diverged."""
         args = (self._params, self.pools, self._tables_dev,
                 self._base_key, self._state, self._knobs)
-        return args + (self._hist,) if spec_mode else args
+        return args + ((self._hist,) if spec_mode else (self.slot_state,))
 
     def _maybe_compile_with_costs(self, jfn, spec_mode: bool):
         """Resolve a freshly built decode tick for dispatch. With the
@@ -1259,15 +1290,20 @@ class ContinuousBatchingEngine:
         core, model = self.core, self.model
         head = model.logits if hasattr(model, "logits") else (lambda h: h)
 
-        def prefill_paged(params, ids, pools, tables1, last_idx):
+        def prefill_paged(params, ids, pools, tables1, last_idx,
+                          slot_state=None, slot=None):
             ctx = model._bind(params) if hasattr(model, "_bind") else None
             with ctx if ctx is not None else _null():
-                hidden, pools = core.prefill_paged(ids, pools, tables1)
+                if slot_state is None:
+                    hidden, pools = core.prefill_paged(ids, pools, tables1)
+                else:
+                    hidden, pools, slot_state = core.prefill_paged(
+                        ids, pools, tables1, slot_state, slot, last_idx)
                 logits = head(hidden[0, last_idx, :])
-            return logits, pools
+            return logits, pools, slot_state
 
         fn = _named_jit(prefill_paged, f"prefill_paged_{bucket}",
-                        donate_argnums=(2,))
+                        donate_argnums=(2, 5))
         self._prefill_cache[bucket] = fn
         return fn
 
@@ -1519,10 +1555,12 @@ class ContinuousBatchingEngine:
                     ids = np.zeros((1, bucket), np.int32)
                     ids[0, :L] = toks
                     with self._building("prefill_paged", bucket=bucket):
-                        logits, self.pools = self._prefill_fn(bucket)(
-                            self._params, jnp.asarray(ids), self.pools,
-                            jnp.asarray(self.tables[slot:slot + 1]),
-                            jnp.int32(L - 1))
+                        logits, self.pools, self.slot_state = \
+                            self._prefill_fn(bucket)(
+                                self._params, jnp.asarray(ids), self.pools,
+                                jnp.asarray(self.tables[slot:slot + 1]),
+                                jnp.int32(L - 1), self.slot_state,
+                                np.int32(slot))
             req.prefill_dispatched_t = (req.prefill_dispatched_t
                                         or time.perf_counter())
             if psp is not None:
@@ -1639,13 +1677,14 @@ class ContinuousBatchingEngine:
         # ``run`` is the decode tick's name in the device trace, and the
         # only program of the engine with that name: the benchmark's
         # decode_tick_roofline finds the tick as ``^jit_run\(``
-        def run(params, pools, tables, base_key, state, knobs):
+        def run(params, pools, tables, base_key, state, knobs,
+                slot_state=None):
             ctx = model._bind(params) if hasattr(model, "_bind") else None
             with ctx if ctx is not None else _null(), \
                     force_decode_impl(attn_impl):
                 def body(carry, _):
                     logits, pos, active, budget, gen = carry[0]
-                    pools = carry[1]
+                    pools, slots = carry[1:]
                     lf = logits.astype(jnp.float32)
                     if any_sample:
                         # key = f(seed, request, token index): sampled
@@ -1662,23 +1701,28 @@ class ContinuousBatchingEngine:
                     # slots HOLD real pages, stopped slots' speculative
                     # writes must be unreachable — one mask serves both
                     tbl = tables * active[:, None].astype(tables.dtype)
-                    if n_counts:
-                        h, pools, counts = core.decode_step_paged(
-                            tok, pos, pools, tbl, counters=True)
-                    else:
-                        h, pools = core.decode_step_paged(tok, pos, pools,
-                                                          tbl)
-                        counts = None
+                    # what the model returns follows what it is asked for:
+                    # (h, pools), then the tick's counters, then the
+                    # slots' next state
+                    kw = dict(counters=True) if n_counts else {}
+                    if slots is not None:
+                        kw["slot_state"] = slots
+                    h, pools, *more = core.decode_step_paged(
+                        tok, pos, pools, tbl, **kw)
+                    counts = more.pop(0) if n_counts else None
+                    if slots is not None:
+                        (slots,) = more
                     new_logits = head(h[:, 0, :])
                     new_active, budget = decode_stop_update(
                         tok, active, budget, knobs["eos"])
                     adv = active.astype(jnp.int32)
                     new_state = (new_logits, pos + adv, new_active,
                                  budget, gen + adv)
-                    return (new_state, pools), (tok, active, counts)
+                    return (new_state, pools, slots), (tok, active, counts)
 
-                (state, pools), (toks, kept, counts) = jax.lax.scan(
-                    body, (state, pools), None, length=K)
+                (state, pools, slot_state), (toks, kept, counts) = \
+                    jax.lax.scan(body, (state, pools, slot_state), None,
+                                 length=K)
                 if n_counts:
                     # the block's counters ride behind its tokens, as
                     # whole rows of the one array the host drains anyway
@@ -1688,9 +1732,9 @@ class ContinuousBatchingEngine:
                         jnp.sum(counts, axis=0).astype(toks.dtype))
                     toks = jnp.concatenate(
                         [toks, tail.reshape(rows, toks.shape[1])])
-            return toks, kept, state, pools
+            return toks, kept, state, pools, slot_state
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(run, donate_argnums=(1, 6))
 
     def _build_spec_decode(self, k: int, any_sample: bool):
         """One speculative tick, fully on device: draft k tokens from the
@@ -2009,7 +2053,7 @@ class ContinuousBatchingEngine:
             if spec:
                 toks, kept, self._state, self.pools, self._hist = out
             else:
-                toks, kept, self._state, self.pools = out
+                toks, kept, self._state, self.pools, self.slot_state = out
             # start the device→host copies NOW so reconciliation (one or
             # more blocks later) finds the bytes already on host
             for arr in (toks, kept, self._state[1], self._state[2]):

@@ -295,6 +295,43 @@ def _kv_gather_ctx(kv, tables):
     return one(kp), one(vp)
 
 
+def _kv_write_prompt(kv, tables, k, v):
+    """Whole prompts' K and V [b, s, n_kv, hd] into the pages ``tables``
+    [b, max_pages] maps: padded up to whole pages and written head-major
+    [n_kv, b * pages, page, hd] (``_kv_scatter_pages``)."""
+    b, s, n_kv, hd = k.shape
+    page = kv[0].shape[2]
+    np_ = -(-s // page)                       # pages holding the prompt
+    pad = np_ * page - s
+
+    def tiles(new):
+        padded = jnp.pad(new, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        # [b, np_, page, n_kv, hd] -> [n_kv, b*np_, page, hd]
+        return jnp.transpose(
+            padded.reshape(b, np_, page, n_kv, hd), (3, 0, 1, 2, 4)
+        ).reshape(n_kv, b * np_, page, hd)
+    return _kv_scatter_pages(kv, tables[:, :np_].reshape(-1),
+                             tiles(k), tiles(v))
+
+
+def _paged_decode_attention(q2, kv, tables, pos):
+    """One new token a row, q2 [b, n_h, hd], over the page pools ``kv``:
+    the Pallas paged kernel on a TPU, its XLA twin (K/V read in the stored
+    dtype, per KV head) elsewhere or under ``force_decode_impl("dense")``."""
+    from ..ops.pallas.paged_attention import (forced_decode_impl,
+                                             paged_decode_attention,
+                                             paged_decode_supported,
+                                             paged_decode_xla)
+    from ..ops.registry import backend_kind
+    scales = ({"k_scales": kv[2], "v_scales": kv[3]}
+              if _kv_quantized(kv) else {})
+    if (forced_decode_impl() != "dense" and backend_kind() == "tpu"
+            and paged_decode_supported(q2, kv[0])):
+        return paged_decode_attention(q2, kv[0], kv[1], tables, pos,
+                                      **scales)
+    return paged_decode_xla(q2, kv[0], kv[1], tables, pos, **scales)
+
+
 def alloc_layer_pools(layers, batch: int, max_len: int, page_size: int):
     """(pools, tables) of a paged model: one pool entry a layer, laid out
     by the layer's attention (``self_attn.alloc_pool``), and the shared
@@ -529,9 +566,7 @@ class LlamaAttention(nn.Layer):
         unmasked before being overwritten by decode steps."""
         cfg = self.cfg
         b, s, _ = x.shape
-        n_h, n_kv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                         cfg.head_dim)
-        page = kv[0].shape[2]
+        n_h, hd = cfg.num_attention_heads, cfg.head_dim
         q, k, v = self._qkv_rope(x, cos[:s], sin[:s])
         # through the dispatcher, like forward(): the flash kernel on TPU.
         # The dense XLA composition holds two f32 [h, s, s] score tensors
@@ -541,18 +576,7 @@ class LlamaAttention(nn.Layer):
         out = flash_attention(q, k, v, causal=True)
         out = out.reshape(b, s, n_h * hd)
         out = _proj(self, out, "o_proj")
-
-        np_ = -(-s // page)                       # pages holding the prompt
-        pad = np_ * page - s
-        def tiles(new):
-            padded = jnp.pad(new, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            # [b, np_, page, n_kv, hd] -> [n_kv, b*np_, page, hd]
-            return jnp.transpose(
-                padded.reshape(b, np_, page, n_kv, hd), (3, 0, 1, 2, 4)
-            ).reshape(n_kv, b * np_, page, hd)
-        kv = _kv_scatter_pages(kv, tables[:, :np_].reshape(-1),
-                               tiles(k), tiles(v))
-        return out, kv
+        return out, _kv_write_prompt(kv, tables, k, v)
 
     def _paged_ctx_attention(self, q, positions, kv, tables):
         """Full-table-span paged attention read: queries ``q``
@@ -630,15 +654,9 @@ class LlamaAttention(nn.Layer):
         (K/V read in the stored dtype, per KV head) — the serving
         engine's context-aware dense/paged dispatch uses it at or below
         the measured crossover length, which on v5e is 0: no context."""
-        from ..ops.pallas.paged_attention import (forced_decode_impl,
-                                                 paged_decode_attention,
-                                                 paged_decode_supported,
-                                                 paged_decode_xla)
-        from ..ops.registry import backend_kind
         cfg = self.cfg
         b = x.shape[0]
-        n_h, n_kv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                         cfg.head_dim)
+        n_h, hd = cfg.num_attention_heads, cfg.head_dim
         page = kv[0].shape[2]
         q, k, v = self._qkv_rope(x, cos, sin, pos.reshape(b, 1))
         b_idx = jnp.arange(b)
@@ -647,15 +665,7 @@ class LlamaAttention(nn.Layer):
         kv = _kv_scatter_tokens(kv, phys, off,
                                 jnp.swapaxes(k[:, 0], 0, 1),
                                 jnp.swapaxes(v[:, 0], 0, 1))
-        quant = _kv_quantized(kv)
-        scales = {"k_scales": kv[2], "v_scales": kv[3]} if quant else {}
-        q2 = q[:, 0]                               # [b, n_h, hd]
-        if (forced_decode_impl() != "dense" and backend_kind() == "tpu"
-                and paged_decode_supported(q2, kv[0])):
-            out = paged_decode_attention(q2, kv[0], kv[1], tables, pos,
-                                         **scales)
-        else:
-            out = paged_decode_xla(q2, kv[0], kv[1], tables, pos, **scales)
+        out = _paged_decode_attention(q[:, 0], kv, tables, pos)
         out = out.reshape(b, 1, n_h * hd).astype(x.dtype)
         return _proj(self, out, "o_proj"), kv
 

@@ -21,11 +21,19 @@ TPU-first: reuses LlamaAttention (fused QKV, flash attention) and the
 dense-layout MoE block (one batched einsum on the MXU; all-to-all dispatch
 appears from GSPMD sharding — parallel/moe.py).
 
+- ZAYA1's layout (``attention="cca"``): compressed convolutional attention
+  (``CompressedConvAttention``: GQA in a compressed space behind two short
+  causal convolutions and a value shift, which make a fixed-size state a
+  sequence carries from token to token), top-1 experts behind an MLP router
+  whose state is handed from layer to layer, residual merges scaled per
+  channel, the embedding tied to the head.
+
 Served through ``inference.ContinuousBatchingEngine`` by the paged trio
 (``alloc_paged_caches`` / ``prefill_paged`` / ``decode_step_paged``), whose
 loop takes each attention layer's own ``alloc_pool`` / ``prefill_paged`` /
 ``decode_paged``: per-head K and V pages under GQA attention, one latent
-row under MLA.
+row under MLA, K and V pages plus a per-slot state (``alloc_slot_state``)
+under CCA.
 """
 
 from __future__ import annotations
@@ -41,9 +49,10 @@ from .. import nn
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..ops import rope as rope_ops
-from ..parallel.moe import MoELayer
-from .llama import (LlamaAttention, LlamaConfig, LlamaMLP, _normal,
-                    alloc_layer_pools)
+from ..parallel.moe import ROUTER_NORM_EPS, MoELayer
+from .llama import (LlamaAttention, LlamaConfig, LlamaMLP,
+                    _kv_scatter_tokens, _kv_write_prompt, _normal,
+                    _paged_decode_attention, alloc_layer_pools)
 
 
 @dataclass
@@ -70,9 +79,13 @@ class MoEConfig:
     dtype: str = "float32"
     recompute: str = "none"
     sequence_parallel: bool = False
-    # attention kind: "gqa" (LlamaAttention) or "mla" (LatentAttention,
+    # attention kind: "gqa" (LlamaAttention), "mla" (LatentAttention,
     # which needs the five sizes below; num_key_value_heads is not read)
+    # or "cca" (CompressedConvAttention: heads of ``head_dim`` in the
+    # compressed space, two causal convolutions of 2 taps)
     attention: str = "gqa"
+    head_dim: Optional[int] = None         # None: hidden_size / heads
+    partial_rotary_factor: float = 1.0
     q_lora_rank: Optional[int] = None
     kv_lora_rank: Optional[int] = None
     qk_nope_head_dim: Optional[int] = None
@@ -84,11 +97,25 @@ class MoEConfig:
     router_bias: bool = False              # selection bias (noaux_tc)
     norm_topk_prob: Optional[bool] = None
     routed_scaling_factor: float = 1.0
+    router: str = "linear"                 # or "mlp" (router_hidden_size)
+    router_hidden_size: Optional[int] = None
+    router_skip_choice: bool = False       # the MLP router's no-expert output
+    # h = s_r * x + s_o * branch + b_o per channel at both residual merges
+    residual_scaling: bool = False
+    tie_word_embeddings: bool = False
 
     def __post_init__(self):
-        if self.attention not in ("gqa", "mla"):
-            raise ValueError(f"attention must be 'gqa' or 'mla', got "
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_attention_heads
+        if self.attention not in ("gqa", "mla", "cca"):
+            raise ValueError(f"attention must be 'gqa', 'mla' or 'cca', got "
                              f"{self.attention!r}")
+        if self.router == "mlp" and self.rms_norm_eps != ROUTER_NORM_EPS:
+            raise ValueError(
+                f"router='mlp' normalises its state at eps "
+                f"{ROUTER_NORM_EPS} (parallel/moe.py); a model that "
+                f"normalises at rms_norm_eps={self.rms_norm_eps} would "
+                f"differ from its reference there")
         if self.attention == "mla":
             missing = [k for k in ("q_lora_rank", "kv_lora_rank",
                                    "qk_nope_head_dim", "qk_rope_head_dim",
@@ -96,21 +123,18 @@ class MoEConfig:
             if missing:
                 raise ValueError(f"attention='mla' needs {missing}")
 
-    @property
-    def head_dim(self):
-        return self.hidden_size // self.num_attention_heads
-
     def _as_llama(self) -> LlamaConfig:
         """Attention/MLP sublayers are config-compatible with Llama's
-        (under latent attention only the dense MLP reads it, and the head
-        count, which need not divide the hidden size there, is left out)."""
-        mla = self.attention == "mla"
+        (under latent or compressed attention only the dense MLP reads it,
+        and the head count, which need not divide the hidden size there,
+        is left out)."""
+        own = self.attention != "gqa"      # an attention with sizes of its own
         return LlamaConfig(
             vocab_size=self.vocab_size, hidden_size=self.hidden_size,
             intermediate_size=self.intermediate_size,
             num_hidden_layers=self.num_hidden_layers,
-            num_attention_heads=1 if mla else self.num_attention_heads,
-            num_key_value_heads=1 if mla else self.num_key_value_heads,
+            num_attention_heads=1 if own else self.num_attention_heads,
+            num_key_value_heads=1 if own else self.num_key_value_heads,
             max_position_embeddings=self.max_position_embeddings,
             rms_norm_eps=self.rms_norm_eps, rope_theta=self.rope_theta,
             initializer_range=self.initializer_range,
@@ -328,6 +352,189 @@ class LatentAttention(nn.Layer):
         return self._mm(out.reshape(b, 1, -1), "o_proj"), (pool,)
 
 
+def _prev_row(a):
+    """a[:, t-1] at t and zeros at t = 0, for a [b, s, ...]."""
+    return jnp.pad(a, ((0, 0), (1, 0)) + ((0, 0),) * (a.ndim - 2))[:, :-1]
+
+
+class CompressedConvAttention(nn.Layer):
+    """Compressed convolutional attention (CCA, arXiv:2510.04476) as
+    ZAYA1-8B configures it: GQA of ``num_attention_heads`` over
+    ``num_key_value_heads`` heads of ``head_dim`` in a COMPRESSED space
+    (Cq = heads x head_dim, Ck = kv heads x head_dim, both narrower than the
+    hidden size), one up-projection behind it.
+
+    In front of the attention (``_front``): ``q_down`` / ``k_down`` give
+    q0, k0; c = [q0 | k0] goes through a depthwise convolution over time
+    (2 taps: the previous token and this one) and a block-diagonal one (2
+    taps, one [d, d] block a head); q and k are its output plus the mean of
+    q0 and k0 per head; the values are half this token's projection and
+    half the PREVIOUS token's; q and k are L2-normalised to sqrt(d) per
+    head, k times a learned temperature, and rotated on the first
+    ``partial_rotary_factor`` of each head's dims.
+
+    What a token takes from its predecessor is a fixed-size state, not a
+    row of a page: the pre-conv row c, the first convolution's output c1
+    and the shifted value half, 2 (Cq + Ck) + Ck / 2 numbers a sequence a
+    layer (``alloc_slot_state``). ``forward`` and ``prefill_paged`` see the
+    whole sequence and shift it; ``decode_paged`` reads the state of its
+    rows and returns the next. Behind the front, K and V live in the GQA
+    pool layout [H_kv, pages, page, d] and attention is the flash kernel
+    (prefill) and ``paged_decode_attention`` (decode), as
+    ``LlamaAttention`` calls them."""
+
+    attention_kind = "cca"
+
+    def __init__(self, cfg: MoEConfig):
+        super().__init__()
+        self.cfg = cfg
+        h, d, std = cfg.hidden_size, cfg.head_dim, cfg.initializer_range
+        self.n_q, self.n_kv, self.d = (cfg.num_attention_heads,
+                                       cfg.num_key_value_heads, d)
+        self.cq, self.ck = self.n_q * d, self.n_kv * d
+        self.rot = int(d * cfg.partial_rotary_factor)
+        c, heads = self.cq + self.ck, self.n_q + self.n_kv
+
+        def matrix(shape, sharding=None):
+            return self.create_parameter(shape, dtype=cfg.dtype,
+                                         initializer=_normal(std),
+                                         sharding=sharding)
+
+        def vector(shape, value):
+            return self.create_parameter(shape, dtype="float32",
+                                         initializer=I.Constant(value))
+        self.q_down = matrix([h, self.cq], ("fsdp", None))
+        self.k_down = matrix([h, self.ck], ("fsdp", None))
+        self.v_down = matrix([h, self.ck], ("fsdp", None))    # [Wv1 | Wv2]
+        self.conv0_weight = vector([2, c], 1.0)       # taps: previous, this
+        self.conv0_bias = vector([c], 0.0)
+        self.conv1_weight = matrix([2, heads, d, d])  # tap, head, in, out
+        self.conv1_bias = vector([c], 0.0)
+        self.temp = vector([self.n_kv], 1.0)
+        self.o_proj = matrix([self.cq, h], (None, "fsdp"))
+
+    def _down(self, u):
+        """u [b, s, H] -> (c [b, s, Cq + Ck] = [q0 | k0], the two halves of
+        the value projection [b, s, Ck / 2] each)."""
+        c = jnp.concatenate(
+            [jnp.matmul(u, self.q_down.astype(u.dtype)),
+             jnp.matmul(u, self.k_down.astype(u.dtype))], axis=-1)
+        v = jnp.matmul(u, self.v_down.astype(u.dtype))
+        return c, v[..., :self.ck // 2], v[..., self.ck // 2:]
+
+    def _conv0(self, c, c_prev):
+        w = self.conv0_weight
+        return (w[0] * c_prev.astype(jnp.float32)
+                + w[1] * c.astype(jnp.float32)
+                + self.conv0_bias).astype(c.dtype)
+
+    def _front(self, c, c1, c1_prev, v_own, v_prev, positions):
+        """(q [b, s, Hq, d], k, v [b, s, Hkv, d]) from this token's c and c1
+        and its predecessor's c1 and value half, at ``positions`` [b|1, s]."""
+        b, s, _ = c.shape
+        n_q, n_kv, d = self.n_q, self.n_kv, self.d
+        w = self.conv1_weight.astype(c.dtype)
+
+        def heads(a):
+            return a.reshape(b, s, n_q + n_kv, d)
+        c2 = (jnp.einsum("bshd,hde->bshe", heads(c1_prev), w[0],
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bshd,hde->bshe", heads(c1), w[1],
+                           preferred_element_type=jnp.float32)
+              + self.conv1_bias.reshape(n_q + n_kv, d))
+        c = heads(c).astype(jnp.float32)
+        m_q = (c[:, :, :n_q] + jnp.repeat(c[:, :, n_q:], n_q // n_kv,
+                                          axis=2)) * 0.5
+        m_k = jnp.mean(m_q.reshape(b, s, n_kv, n_q // n_kv, d), axis=3)
+
+        def unit(x):
+            return x * (math.sqrt(d) * jax.lax.rsqrt(jnp.maximum(
+                jnp.sum(x * x, -1, keepdims=True), 1e-12)))
+        cos, sin = _rope_at(positions, self.rot, self.cfg.rope_theta)
+
+        def rotate(x):
+            return jnp.concatenate(
+                [_rotate(x[..., :self.rot], cos, sin), x[..., self.rot:]],
+                axis=-1)
+        q = rotate(unit(c2[:, :, :n_q] + m_q))
+        k = rotate(unit(c2[:, :, n_q:] + m_k) * self.temp[:, None])
+        v = jnp.concatenate([v_own, v_prev], axis=-1).reshape(b, s, n_kv, d)
+        return q.astype(v.dtype), k.astype(v.dtype), v
+
+    def _sequence(self, u):
+        """The whole-sequence form: (attention output [b, s, H], k, v, and
+        (c, c1, the value half the next token takes), each [b, s, ..])."""
+        from ..ops.attention import flash_attention
+        b, s, _ = u.shape
+        c, v_own, v_next = self._down(u)
+        c1 = self._conv0(c, _prev_row(c))
+        q, k, v = self._front(c, c1, _prev_row(c1), v_own, _prev_row(v_next),
+                              jnp.arange(s)[None])
+        out = flash_attention(q, k, v, causal=True,
+                              scale=1.0 / math.sqrt(self.d))
+        out = jnp.matmul(out.reshape(b, s, self.cq),
+                         self.o_proj.astype(u.dtype))
+        return out, k, v, (c, c1, v_next)
+
+    def forward(self, x, cos=None, sin=None):
+        """``cos``/``sin`` are taken for the decoder layer's sake and not
+        read: the rotary angles are worked out from the positions."""
+        return self._sequence(x)[0]
+
+    # -- paged serving path --------------------------------------------------
+
+    def alloc_pool(self, num_pages: int, page_size: int):
+        """K and V page pools [H_kv, num_pages, page_size, d], the GQA
+        layout, in the compressed space."""
+        dt = jnp.bfloat16 if self.cfg.dtype == "bfloat16" else jnp.float32
+        shape = (self.n_kv, num_pages, page_size, self.d)
+        return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+
+    def alloc_slot_state(self, slots: int):
+        """What each of ``slots`` sequences carries from its last token to
+        its next: (c [slots, Cq + Ck], c1 [slots, Cq + Ck], the shifted
+        value half [slots, Ck / 2])."""
+        dt = jnp.bfloat16 if self.cfg.dtype == "bfloat16" else jnp.float32
+        c = self.cq + self.ck
+        return (jnp.zeros((slots, c), dt), jnp.zeros((slots, c), dt),
+                jnp.zeros((slots, self.ck // 2), dt))
+
+    def prefill_paged(self, x, cos, sin, kv, tables, state, slot, last_idx):
+        """Prompt pass of ONE sequence into slot ``slot``: K and V pages
+        written whole (rows past the prompt lie beyond seq_len and are
+        overwritten by decode steps before they are unmasked), and the
+        slot's state taken at the prompt's true last position
+        ``last_idx``, not at the bucket's padded end."""
+        out, k, v, carried = self._sequence(x)
+        kv = _kv_write_prompt(kv, tables, k, v)
+        state = tuple(st.at[slot].set(new[0, last_idx].astype(st.dtype))
+                      for st, new in zip(state, carried))
+        return out, kv, state
+
+    def decode_paged(self, x, cos, sin, pos, kv, tables, state):
+        """One-token step of every row: the front from this token and the
+        row's state, the new K and V into their page slot, attention by
+        the paged kernel (its XLA twin off the TPU or under
+        ``force_decode_impl("dense")``). Returns the rows' next state."""
+        b = x.shape[0]
+        page = kv[0].shape[2]
+        c_prev, c1_prev, v_prev = state
+        c, v_own, v_next = self._down(x)
+        c1 = self._conv0(c, c_prev[:, None])
+        q, k, v = self._front(c, c1, c1_prev[:, None], v_own,
+                              v_prev[:, None].astype(x.dtype),
+                              pos.reshape(b, 1))
+        kv = _kv_scatter_tokens(kv, tables[jnp.arange(b), pos // page],
+                                pos % page, jnp.swapaxes(k[:, 0], 0, 1),
+                                jnp.swapaxes(v[:, 0], 0, 1))
+        out = _paged_decode_attention(q[:, 0], kv, tables, pos)
+        out = jnp.matmul(out.reshape(b, 1, self.cq).astype(x.dtype),
+                         self.o_proj.astype(x.dtype))
+        state = tuple(new[:, 0].astype(st.dtype)
+                      for st, new in zip(state, (c, c1, v_next)))
+        return out, kv, state
+
+
 class SharedExpertMLP(nn.Layer):
     """DeepSeekMoE's always-on shared expert(s): one SwiGLU MLP of width
     num_shared * moe_ffn; Qwen2-MoE adds a sigmoid gate on its output."""
@@ -361,6 +568,26 @@ class SharedExpertMLP(nn.Layer):
         return out
 
 
+class ResidualMerge(nn.Layer):
+    """``s_r * x + s_o * branch + b_o`` per channel, float32 (ZAYA1's
+    ``scale_residual_merge``), in place of ``x + branch``."""
+
+    def __init__(self, hidden_size: int):
+        super().__init__()
+        for name, value in (("res_scale", 1.0), ("out_scale", 1.0),
+                            ("out_bias", 0.0)):
+            setattr(self, name, self.create_parameter(
+                [hidden_size], dtype="float32",
+                initializer=I.Constant(value)))
+
+    def forward(self, x, branch):
+        return (self.res_scale * x + self.out_scale * branch
+                + self.out_bias).astype(x.dtype)
+
+
+_ATTENTION = {"mla": LatentAttention, "cca": CompressedConvAttention}
+
+
 class MoEDecoderLayer(nn.Layer):
     def __init__(self, cfg: MoEConfig, dense: bool = False):
         super().__init__()
@@ -369,7 +596,8 @@ class MoEDecoderLayer(nn.Layer):
         lcfg = cfg._as_llama()
         self.input_layernorm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                           dtype="float32")
-        self.self_attn = (LatentAttention(cfg) if cfg.attention == "mla"
+        self.self_attn = (_ATTENTION[cfg.attention](cfg)
+                          if cfg.attention in _ATTENTION
                           else LlamaAttention(lcfg))
         self.post_attention_layernorm = nn.RMSNorm(cfg.hidden_size,
                                                    cfg.rms_norm_eps,
@@ -386,32 +614,61 @@ class MoEDecoderLayer(nn.Layer):
                                 dtype=cfg.dtype, scoring=cfg.scoring_func,
                                 select_bias=cfg.router_bias,
                                 norm_topk_prob=cfg.norm_topk_prob,
-                                routed_scaling_factor=cfg.routed_scaling_factor)
+                                routed_scaling_factor=cfg.routed_scaling_factor,
+                                **({} if cfg.router == "linear" else dict(
+                                    router=cfg.router,
+                                    router_hidden_size=cfg.router_hidden_size,
+                                    skip_choice=cfg.router_skip_choice)))
             if cfg.num_shared_experts > 0:
                 self.shared_experts = SharedExpertMLP(cfg)
             else:
                 self.add_sublayer("shared_experts", None)
+        if cfg.residual_scaling:
+            self.attn_merge = ResidualMerge(cfg.hidden_size)
+            self.mlp_merge = ResidualMerge(cfg.hidden_size)
+        else:
+            self.add_sublayer("attn_merge", None)
+            self.add_sublayer("mlp_merge", None)
 
-    def forward(self, x, cos, sin):
-        h = x + self.self_attn(self.input_layernorm(x), cos, sin)
+    def merge(self, which: str, x, branch):
+        """A residual merge (``which``: "attn" or "mlp"): the sum, or the
+        model's per-channel scaled one."""
+        scaled = getattr(self, which + "_merge")
+        return x + branch if scaled is None else scaled(x, branch)
+
+    def _router_state(self, z, prev):
+        """The MLP router's state of this layer (``prev``: the layer
+        before's); None under a one-matrix router or in a dense layer."""
+        if self.dense or self.moe.router == "linear":
+            return None
+        return self.moe.router_state(z, prev)
+
+    def forward(self, x, cos, sin, router_state=None):
+        """(x, aux, this layer's router state for the next layer)."""
+        h = self.merge("attn", x,
+                       self.self_attn(self.input_layernorm(x), cos, sin))
         z = self.post_attention_layernorm(h)
         if self.dense:
-            return h + self.mlp(z), jnp.zeros((), jnp.float32)
-        routed, aux = self.moe(z)
+            return (self.merge("mlp", h, self.mlp(z)),
+                    jnp.zeros((), jnp.float32), None)
+        r = self._router_state(z, router_state)
+        routed, aux = self.moe(z, r)
         if self.shared_experts is not None:
             routed = routed + self.shared_experts(z)
-        return h + routed, aux
+        return self.merge("mlp", h, routed), aux, r
 
-    def mlp_inference(self, z):
-        """The block after attention on the serving path: (y, load), load
-        being the rows each routed expert was sent ([e] int32; None for a
-        dense layer). No auxiliary loss, no token dropped."""
+    def mlp_inference(self, z, router_state=None):
+        """The block after attention on the serving path: (y, load, r),
+        load being the rows each routed expert was sent ([e] int32; None
+        for a dense layer) and r this layer's router state (None without
+        one). No auxiliary loss, no token dropped."""
         if self.dense:
-            return self.mlp(z), None
-        routed, load = self.moe.forward_inference(z)
+            return self.mlp(z), None, None
+        r = self._router_state(z, router_state)
+        routed, load = self.moe.forward_inference(z, r)
         if self.shared_experts is not None:
             routed = routed + self.shared_experts(z)
-        return routed, load
+        return routed, load, r
 
 
 class MoEForCausalLM(nn.Layer):
@@ -430,19 +687,27 @@ class MoEForCausalLM(nn.Layer):
             for i in range(cfg.num_hidden_layers)])
         self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                dtype="float32")
-        self.lm_head = self.create_parameter(
-            [cfg.hidden_size, cfg.vocab_size], dtype=cfg.dtype,
-            initializer=_normal(cfg.initializer_range),
-            sharding=("fsdp", "tp"))
+        if cfg.tie_word_embeddings:
+            self.add_parameter("lm_head", None)
+        else:
+            self.lm_head = self.create_parameter(
+                [cfg.hidden_size, cfg.vocab_size], dtype=cfg.dtype,
+                initializer=_normal(cfg.initializer_range),
+                sharding=("fsdp", "tp"))
         self.attention_kind = cfg.attention
         # what a decode tick counts on the device beside its tokens (the
-        # engine adds them up into ``stats()``): rows x top-k routed, and
-        # the most rows one expert got, summed over the routed layers
-        self.tick_counters = (("moe_assignments", "moe_peak_load")
+        # engine adds them up into ``stats()``): rows x top-k routed (the
+        # rows that chose no expert included), the most rows one expert
+        # got, summed over the routed layers, and, where the router has a
+        # skip choice, the rows that took it
+        self.tick_counters = ((("moe_assignments", "moe_peak_load")
+                               + (("moe_skipped",) if cfg.router_skip_choice
+                                  else ()))
                               if cfg.first_k_dense_replace
                               < cfg.num_hidden_layers else ())
-        if cfg.attention == "mla":
-            # LatentAttention works its rotary angles out from positions
+        if cfg.attention != "gqa":
+            # LatentAttention and CompressedConvAttention work their
+            # rotary angles out from positions
             self.rope_cos = self.rope_sin = None
         else:
             cos, sin = rope_ops.rope_freqs(cfg.head_dim,
@@ -451,8 +716,14 @@ class MoEForCausalLM(nn.Layer):
             self.register_buffer("rope_cos", cos, persistable=False)
             self.register_buffer("rope_sin", sin, persistable=False)
 
+    def _head(self):
+        """The output head [H, V]: ``lm_head``, or the embedding
+        transposed where the two are tied."""
+        return (jnp.swapaxes(self.embed_tokens, 0, 1)
+                if self.cfg.tie_word_embeddings else self.lm_head)
+
     def logits(self, hidden):
-        return jnp.matmul(hidden, self.lm_head.astype(hidden.dtype))
+        return jnp.matmul(hidden, self._head().astype(hidden.dtype))
 
     # -- paged-KV serving path (inference.ContinuousBatchingEngine) ---------
 
@@ -462,38 +733,78 @@ class MoEForCausalLM(nn.Layer):
         (``alloc_pool``), and the shared block table."""
         return alloc_layer_pools(self.layers, batch, max_len, page_size)
 
-    def prefill_paged(self, input_ids, pools, tables):
+    def alloc_slot_state(self, slots: int):
+        """What a sequence carries from token to token OUTSIDE its pages,
+        one entry a layer, every leaf leading with the slot: CCA's
+        conv/shift state. None for a model whose whole state is its pages
+        (the engine then keeps nothing and builds the programs it built)."""
+        if self.cfg.attention != "cca":
+            return None
+        return [layer.self_attn.alloc_slot_state(slots)
+                for layer in self.layers]
+
+    def prefill_paged(self, input_ids, pools, tables, slot_state=None,
+                      slot=None, last_idx=None):
+        """The prompt of one sequence: (hidden, pools) and, given
+        ``slot_state``, the state with slot ``slot`` set from the prompt's
+        position ``last_idx``."""
         x = jnp.take(self.embed_tokens, input_ids, axis=0)
-        new_pools = []
-        for layer, kv in zip(self.layers, pools):
-            a, kv = layer.self_attn.prefill_paged(
-                layer.input_layernorm(x), self.rope_cos, self.rope_sin,
-                kv, tables)
-            h = x + a
-            x = h + layer.mlp_inference(layer.post_attention_layernorm(h))[0]
+        new_pools, new_state, r = [], [], None
+        for i, (layer, kv) in enumerate(zip(self.layers, pools)):
+            u = layer.input_layernorm(x)
+            if slot_state is None:
+                a, kv = layer.self_attn.prefill_paged(
+                    u, self.rope_cos, self.rope_sin, kv, tables)
+            else:
+                a, kv, st = layer.self_attn.prefill_paged(
+                    u, self.rope_cos, self.rope_sin, kv, tables,
+                    slot_state[i], slot, last_idx)
+                new_state.append(st)
+            h = layer.merge("attn", x, a)
+            y, _, r = layer.mlp_inference(layer.post_attention_layernorm(h), r)
+            x = layer.merge("mlp", h, y)
             new_pools.append(kv)
-        return self.norm(x), new_pools
+        if slot_state is None:
+            return self.norm(x), new_pools
+        return self.norm(x), new_pools, new_state
 
     def decode_step_paged(self, token_ids, pos, pools, tables,
-                          counters: bool = False):
-        """token_ids [b] -> (hidden [b, 1, d], pools) and, with
-        ``counters``, the tick's ``tick_counters`` as [2] int32."""
+                          counters: bool = False, slot_state=None):
+        """token_ids [b] -> (hidden [b, 1, d], pools), then, with
+        ``counters``, the tick's ``tick_counters`` as int32 and, given
+        ``slot_state`` (its leaves [b, ..]: row i is sequence i's), the
+        rows' next state."""
         x = jnp.take(self.embed_tokens, token_ids[:, None], axis=0)
-        new_pools, routed, peak = [], 0, 0
-        for layer, kv in zip(self.layers, pools):
-            a, kv = layer.self_attn.decode_paged(
-                layer.input_layernorm(x), self.rope_cos, self.rope_sin,
-                pos, kv, tables)
-            h = x + a
-            y, load = layer.mlp_inference(layer.post_attention_layernorm(h))
-            x = h + y
+        new_pools, new_state, r = [], [], None
+        routed, peak, skipped = 0, 0, 0
+        for i, (layer, kv) in enumerate(zip(self.layers, pools)):
+            u = layer.input_layernorm(x)
+            if slot_state is None:
+                a, kv = layer.self_attn.decode_paged(
+                    u, self.rope_cos, self.rope_sin, pos, kv, tables)
+            else:
+                a, kv, st = layer.self_attn.decode_paged(
+                    u, self.rope_cos, self.rope_sin, pos, kv, tables,
+                    slot_state[i])
+                new_state.append(st)
+            h = layer.merge("attn", x, a)
+            y, load, r = layer.mlp_inference(
+                layer.post_attention_layernorm(h), r)
+            x = layer.merge("mlp", h, y)
             new_pools.append(kv)
             if load is not None:
                 routed, peak = routed + jnp.sum(load), peak + jnp.max(load)
+                if self.cfg.router_skip_choice:
+                    # the rows x top-k that no expert was sent
+                    skipped = skipped + (load.dtype.type(
+                        x.shape[0] * self.cfg.num_experts_per_tok)
+                        - jnp.sum(load))
+        out = (self.norm(x), new_pools)
         if counters:
-            return self.norm(x), new_pools, jnp.stack(
-                [routed, peak]).astype(jnp.int32)
-        return self.norm(x), new_pools
+            counts = ([routed + skipped, peak, skipped]
+                      if self.cfg.router_skip_choice else [routed, peak])
+            out += (jnp.stack(counts).astype(jnp.int32),)
+        return out if slot_state is None else out + (new_state,)
 
     def forward(self, input_ids, labels=None):
         cfg = self.cfg
@@ -502,20 +813,21 @@ class MoEForCausalLM(nn.Layer):
         cos, sin = ((None, None) if self.rope_cos is None
                     else (self.rope_cos[:s], self.rope_sin[:s]))
         aux_total = jnp.zeros((), jnp.float32)
+        r = None                 # the MLP router's state, layer to layer
         if cfg.recompute == "full":
-            def run(layer, h):
-                return layer(h, cos, sin)
+            def run(layer, h, r_):
+                return layer(h, cos, sin, r_)
             ckpt = jax.checkpoint(run, static_argnums=(0,))
             for layer in self.layers:
-                x, aux = ckpt(layer, x)
+                x, aux, r = ckpt(layer, x, r)
                 aux_total = aux_total + aux
         else:
             for layer in self.layers:
-                x, aux = layer(x, cos, sin)
+                x, aux, r = layer(x, cos, sin, r)
                 aux_total = aux_total + aux
         hidden = self.norm(x)
         if labels is None:
-            return jnp.matmul(hidden, self.lm_head.astype(hidden.dtype))
+            return self.logits(hidden)
         from .llama import (causal_lm_loss, fused_causal_lm_loss,
                             fused_loss_enabled)
         logits = None
@@ -523,14 +835,14 @@ class MoEForCausalLM(nn.Layer):
             if fused_loss_enabled(cfg):
                 # fused blockwise head: no [b, s, vocab] logits (TP gets
                 # the per-shard fused path, same as Llama)
-                ce = fused_causal_lm_loss(hidden, self.lm_head, labels)
+                ce = fused_causal_lm_loss(hidden, self._head(), labels)
             else:
-                logits = jnp.matmul(hidden, self.lm_head.astype(hidden.dtype))
+                logits = self.logits(hidden)
                 # vocab-parallel CE when tp is active (no gathered logits)
                 ce = causal_lm_loss(logits, labels)
         loss = ce + cfg.aux_loss_weight * aux_total
         if logits is None:  # compat tuple; dead (DCE'd) when unused
-            logits = jnp.matmul(hidden, self.lm_head.astype(hidden.dtype))
+            logits = self.logits(hidden)
         return loss, logits
 
     def num_params(self) -> int:
@@ -549,6 +861,7 @@ class MoEForCausalLM(nn.Layer):
     def flops_per_token(self, seq_len: int) -> float:
         cfg = self.cfg
         n = self.num_activated_params()
-        n -= cfg.vocab_size * cfg.hidden_size  # embedding gather
+        if not cfg.tie_word_embeddings:      # tied: the table is the head
+            n -= cfg.vocab_size * cfg.hidden_size  # embedding gather
         attn = 12 * cfg.num_hidden_layers * cfg.hidden_size * seq_len
         return 6 * n + attn

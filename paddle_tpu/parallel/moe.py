@@ -407,6 +407,12 @@ def _aux_loss_ep(probs, e):
     return jnp.sum(me * ce) * e
 
 
+# the eps of the RMSNorm over the MLP router's state: the one family that
+# has such a router (ZAYA) normalises everything at 1e-5, and MoEConfig
+# refuses router="mlp" beside another rms_norm_eps
+ROUTER_NORM_EPS = 1e-5
+
+
 class MoELayer(Layer):
     """Top-k routed MoE block (reference: MoELayer, moe_layer.py:263).
 
@@ -427,6 +433,17 @@ class MoELayer(Layer):
     ``routed_scaling_factor`` multiplies them. The variants run on the
     dropless and the inference paths; the capacity and expert-parallel
     paths refuse them by name.
+
+    ``router="mlp"`` (ZAYA1's, arXiv:2511.17127) replaces the one matrix
+    by a small network with a state handed from layer to layer:
+    ``router_state(x, prev)`` is ``x W_d + b_d (+ g * prev)``
+    [.., router_hidden_size], the caller keeps it for the next layer and
+    hands it back to ``forward`` / ``forward_inference``, which score it
+    through RMSNorm and a three-layer GELU MLP, softmax over the experts
+    and, with ``skip_choice``, one more output whose choice sends a row
+    through NO expert (its output is 0 and ``load`` does not count it).
+    The weights are the softmax's own probabilities. Like the other
+    variants: dropless and inference paths only.
 
     ``forward_inference`` (what ``forward`` runs once the layer is in eval
     mode) computes no auxiliary loss and is dropless at any load.
@@ -449,8 +466,23 @@ class MoELayer(Layer):
                  dtype=None, gate: str = "gshard",
                  scoring: str = "softmax", select_bias: bool = False,
                  norm_topk_prob: Optional[bool] = None,
-                 routed_scaling_factor: float = 1.0):
+                 routed_scaling_factor: float = 1.0,
+                 router: str = "linear",
+                 router_hidden_size: Optional[int] = None,
+                 skip_choice: bool = False):
         super().__init__()
+        if router not in ("linear", "mlp"):
+            raise ValueError(f"router must be 'linear' or 'mlp', got "
+                             f"{router!r}")
+        if router == "mlp" and not router_hidden_size:
+            raise ValueError("router='mlp' needs router_hidden_size")
+        if router == "linear" and skip_choice:
+            raise ValueError("skip_choice is the MLP router's: pass "
+                             "router='mlp'")
+        if router == "mlp" and select_bias:
+            raise NotImplementedError(
+                "select_bias with router='mlp' (a balancing bias over the "
+                "MLP router's outputs) is not built")
         if top_k > num_experts:
             raise ValueError(f"top_k={top_k} > num_experts={num_experts}")
         if scoring not in ("softmax", "sigmoid"):
@@ -463,9 +495,16 @@ class MoELayer(Layer):
         self.renormalize = (self.top_k > 1 if norm_topk_prob is None
                             else bool(norm_topk_prob))
         self.routed_scaling_factor = float(routed_scaling_factor)
-        self.gate_weight = self.create_parameter(
-            [hidden_size, num_experts], dtype="float32",
-            initializer=I.Normal(0.0, 0.02))
+        self.router = router
+        self.skip_choice = bool(skip_choice)
+        if router == "linear":
+            self.gate_weight = self.create_parameter(
+                [hidden_size, num_experts], dtype="float32",
+                initializer=I.Normal(0.0, 0.02))
+        else:
+            self.add_parameter("gate_weight", None)
+            self._make_mlp_router(hidden_size, router_hidden_size,
+                                  num_experts + int(self.skip_choice))
         if select_bias:
             self.gate_bias = self.create_parameter(
                 [num_experts], dtype="float32", initializer=I.Constant(0.0))
@@ -473,13 +512,58 @@ class MoELayer(Layer):
             self.add_parameter("gate_bias", None)
         self._gshard_router = (scoring == "softmax" and not select_bias
                                and self.renormalize == (self.top_k > 1)
-                               and self.routed_scaling_factor == 1.0)
+                               and self.routed_scaling_factor == 1.0
+                               and router == "linear")
         if not self._gshard_router and capacity_factor is not None:
             raise ValueError(
                 "scoring / select_bias / norm_topk_prob / "
-                "routed_scaling_factor run on the dropless path only: pass "
-                "capacity_factor=None")
+                "routed_scaling_factor / router='mlp' run on the dropless "
+                "path only: pass capacity_factor=None")
         self.experts = MoEMLP(num_experts, hidden_size, ffn_size, dtype=dtype)
+
+    def _make_mlp_router(self, hidden_size: int, r: int, outputs: int):
+        """The MLP router's leaves, all float32: the down-projection, the
+        gate on the previous layer's state, RMSNorm, three layers."""
+        def make(name, shape, init):
+            setattr(self, name, self.create_parameter(
+                shape, dtype="float32", initializer=init))
+        normal, zeros = I.Normal(0.0, 0.02), I.Constant(0.0)
+        make("router_down", [hidden_size, r], normal)
+        make("router_down_bias", [r], zeros)
+        make("router_state_gate", [r], I.Constant(1.0))
+        make("router_norm", [r], I.Constant(1.0))
+        make("router_w1", [r, r], normal)
+        make("router_b1", [r], zeros)
+        make("router_w2", [r, r], normal)
+        make("router_b2", [r], zeros)
+        make("router_w3", [r, outputs], normal)
+
+    def router_state(self, x, prev=None):
+        """The MLP router's state of this layer, float32 [.., r]: ``x W_d +
+        b_d``, plus ``g * prev`` where a previous layer handed its own
+        down (after ITS addition: the state averages over depth)."""
+        r = jnp.matmul(x.astype(jnp.float32), self.router_down) \
+            + self.router_down_bias
+        return r if prev is None else r + self.router_state_gate * prev
+
+    def _route(self, flat, state=None):
+        """(scores [t, e(+1)], chosen scores [t, k], ids [t, k]) of the
+        rows ``flat`` [t, d]; ``state`` [t, r] is ``router_state``'s. An
+        id equal to ``num_experts`` is the skip choice."""
+        if self.router == "linear":
+            return self._choose(
+                jnp.matmul(flat.astype(jnp.float32), self.gate_weight))
+        if state is None:
+            raise ValueError("router='mlp' routes on router_state(x, prev): "
+                             "pass router_state=")
+        r = state.reshape(flat.shape[0], -1)
+        r = r * jax.lax.rsqrt(jnp.mean(r * r, -1, keepdims=True)
+                              + ROUTER_NORM_EPS) * self.router_norm
+        h = jax.nn.gelu(jnp.matmul(r, self.router_w1) + self.router_b1,
+                        approximate=False)
+        h = jax.nn.gelu(jnp.matmul(h, self.router_w2) + self.router_b2,
+                        approximate=False)
+        return self._choose(jnp.matmul(h, self.router_w3))
 
     def _choose(self, logits):
         """(scores [t, e], the chosen experts' scores [t, k], ids [t, k]):
@@ -513,7 +597,7 @@ class MoELayer(Layer):
         logits = jnp.matmul(flat.astype(jnp.float32), self.gate_weight)
         return routing_stats(logits, self.top_k)[2]
 
-    def forward(self, x):
+    def forward(self, x, router_state=None):
         b, s, d = x.shape
         t = b * s
         e = self.num_experts
@@ -525,24 +609,26 @@ class MoELayer(Layer):
         hm = current_mesh()
         ep = hm.axis_size("ep") if hm is not None else 1
         if not self.training and ep == 1 and self.capacity_factor is None:
-            out, _ = self.forward_inference(x)
+            out, _ = self.forward_inference(x, router_state)
             return out, jnp.zeros((), jnp.float32)
         if ep > 1 and t % ep == 0 and e % ep == 0 and (t // ep) > 0:
             if not self._gshard_router:
                 raise NotImplementedError(
                     "the expert-parallel paths route with the GShard "
-                    "router only (softmax, no selection bias, no scale)")
+                    "router only (softmax, one matrix, no selection bias, "
+                    "no scale)")
             if self.capacity_factor is None:
                 out, aux = self._forward_dropless_ep(flat, hm.mesh, ep)
             else:
                 out, aux = self._forward_capacity_ep(flat, hm.mesh, ep)
             return out.reshape(b, s, d), aux
 
-        logits = jnp.matmul(flat.astype(jnp.float32), self.gate_weight)
-
         if self.capacity_factor is None:
-            out, aux = self._forward_dropless(flat, logits)
+            out, aux = self._forward_dropless(flat,
+                                              self._route(flat, router_state))
             return out.reshape(b, s, d), aux
+
+        logits = jnp.matmul(flat.astype(jnp.float32), self.gate_weight)
 
         capacity = int(math.ceil(t * self.top_k / e * self.capacity_factor))
         slot, gates, aux = top_k_routing(logits, self.top_k, capacity)
@@ -655,17 +741,19 @@ class MoELayer(Layer):
                        check_vma=False)
         return fn(flat, gw, w_gu, w_dn)
 
-    def _forward_dropless(self, flat, logits):
+    def _forward_dropless(self, flat, routing):
         """Grouped-matmul experts over exact per-expert counts — the
         dropless path (reference analogue: global_scatter's exact
         count_by_gate split sizes). Both products are
         ``grouped_matmul``: XLA's ``lax.ragged_dot`` forward and
         backward since PR 28. The rows go to their experts and come back
         by gathers, forward and backward (``dispatch_rows``,
-        ``permute_rows``)."""
+        ``permute_rows``). ``routing`` is ``_route``'s. Rows whose choice
+        is the skip sort behind every expert's run, belong to no group and
+        count as 0; the MLP router trains with no auxiliary term."""
         t, d = flat.shape
         e, k = self.num_experts, self.top_k
-        probs, gates, ids = self._choose(logits)              # [t, k]
+        probs, gates, ids = routing                           # [t, k]
         flat_e = ids.T.reshape(-1)                            # [k*t]
         order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
         inv = inverse_permutation(order)
@@ -683,21 +771,24 @@ class MoELayer(Layer):
         # unsort to choice-major, weight, reduce over k
         y_cm = permute_rows(ys, inv, order).reshape(k, t, d)
         g_km = self._weights(gates.T, 0)                      # [k, t]
+        if self.skip_choice:
+            y_cm = jnp.where((ids.T < e)[..., None], y_cm, 0)
         out = jnp.sum(g_km[..., None].astype(ys.dtype) * y_cm, axis=0)
-        return out, _aux_loss(probs, e)
+        return out, (jnp.zeros((), jnp.float32) if self.router == "mlp"
+                     else _aux_loss(probs, e))
 
-    def forward_inference(self, x):
+    def forward_inference(self, x, router_state=None):
         """The routed block without a loss: x [b, s, d] -> (out [b, s, d],
-        load [e] int32: the rows each expert was sent). Dropless at any
-        load. At most ``DENSE_ROWS`` rows whose choices outnumber the
-        experts run every expert over every row as one batched matmul; the
-        rest are sorted to their experts and go through XLA's
-        ``ragged_dot`` (``xla_grouped_matmul``)."""
+        load [e] int32: the rows each expert was sent; rows x top-k less
+        its sum chose the skip). Dropless at any load. At most
+        ``DENSE_ROWS`` rows whose choices outnumber the experts run every
+        expert over every row as one batched matmul; the rest are sorted
+        to their experts and go through XLA's ``ragged_dot``
+        (``xla_grouped_matmul``)."""
         b, s, d = x.shape
         t, e, k = b * s, self.num_experts, self.top_k
         flat = x.reshape(t, d)
-        logits = jnp.matmul(flat.astype(jnp.float32), self.gate_weight)
-        _, gates, ids = self._choose(logits)
+        _, gates, ids = self._route(flat, router_state)
         gates = self._weights(gates, -1)                      # [t, k]
         load = jnp.bincount(ids.reshape(-1), length=e).astype(jnp.int32)
         w_gu = self.experts.w_gate_up.astype(flat.dtype)      # [e, d, 2f]
@@ -705,7 +796,7 @@ class MoELayer(Layer):
         if t <= self.DENSE_ROWS and t * k >= e:
             # weight [t, e]: an expert's share of a row, 0 if not chosen
             weight = jnp.zeros((t, e), jnp.float32).at[
-                jnp.arange(t)[:, None], ids].add(gates)
+                jnp.arange(t)[:, None], ids].add(gates, mode="drop")
             gu = jnp.einsum("etd,edf->etf",
                             jnp.broadcast_to(flat[None], (e, t, d)), w_gu,
                             preferred_element_type=jnp.float32)
@@ -721,5 +812,7 @@ class MoELayer(Layer):
         g, u = jnp.split(gu, 2, axis=-1)
         ys = xla_grouped_matmul(F.silu(g) * u, w_dn, load)    # f32
         y_cm = jnp.zeros_like(ys).at[order].set(ys).reshape(k, t, d)
+        if self.skip_choice:
+            y_cm = jnp.where((ids.T < e)[..., None], y_cm, 0)
         out = jnp.sum(gates.T[..., None] * y_cm, axis=0)
         return out.astype(x.dtype).reshape(b, s, d), load

@@ -202,6 +202,45 @@ def test_kernel_compiles_for_v5e(topo, case, monkeypatch):
     assert calls and all(any(k in c for k in KERNEL_NAMES) for c in calls), calls
 
 
+def test_a_cca_decode_layer_compiles_around_the_paged_kernel(topo, monkeypatch):
+    """One layer of ZAYA1-8B's decode tick (``zaya1-8b.reasoning``: 128
+    rows): CCA's front (the down-projections, the two convolutions from the
+    slot's state, the q-k mean, L2 norm, partial rotary) is XLA's, the
+    attention behind it the existing paged kernel at 2 KV heads of 128 with
+    4 query heads each over pools of 1,664 + 1 pages, and nothing else is a
+    Mosaic call. The slot's state comes back in the shapes it went in."""
+    from paddle_tpu.models.moe_lm import CompressedConvAttention, MoEConfig
+    from paddle_tpu.ops import registry
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
+    attn = CompressedConvAttention(MoEConfig(
+        hidden_size=2048, num_attention_heads=8, num_key_value_heads=2,
+        head_dim=128, attention="cca", partial_rotary_factor=0.5,
+        rope_theta=5e6, dtype="bfloat16"))
+
+    def step(p, x, pos, kv, tables, state):
+        with attn._bind(p):
+            return attn.decode_paged(x, None, None, pos, kv, tables, state)
+    dev = SingleDeviceSharding(topo.devices[0])
+    abstract = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev), t)
+    pool = jax.ShapeDtypeStruct((2, 1665, 128, 128), BF16)
+    state = jax.eval_shape(lambda: attn.alloc_slot_state(128))
+    args = abstract((attn.raw_parameters(),
+                     jax.ShapeDtypeStruct((128, 1, 2048), BF16),
+                     jax.ShapeDtypeStruct((128,), I32), (pool, pool),
+                     jax.ShapeDtypeStruct((128, 24), I32), state))
+    compiled = jax.jit(step, donate_argnums=(3, 5)).lower(*args).compile()
+    calls = _mosaic_calls(compiled.as_text())
+    assert calls and all("paged_attention_decode" in c for c in calls), calls
+    out, kv, new_state = jax.eval_shape(step, *args)
+    assert out.shape == (128, 1, 2048)
+    assert [a.shape for a in new_state] == [(128, 1280), (128, 1280),
+                                            (128, 128)]
+    # the pools are written in place: no copy of a pool in the program
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 @pytest.fixture(scope="module")
 def olmoe_routed_layer(topo):
     """OLMoE's routed layer as ``olmoe.pretrain-4k`` runs it (8 x 4096

@@ -109,6 +109,40 @@ def test_engine_spans_land_on_the_profilers_host_plane(tiny_llama, tmp_path):
     assert "compile::run" in spans or "compile::prefill_paged" in spans
 
 
+def test_a_cca_models_prefill_says_its_attention_and_its_tick_counts_skips(
+        tmp_path):
+    """``serving::prefill``'s ``attn`` attribute reads the model's attention
+    kind ("cca" here; "gqa", "mla" otherwise), and a model whose router has
+    a skip choice adds ``moe_skipped`` to ``stats()`` beside the gauge
+    ``slot_state_bytes`` (0 for a model that keeps no state beside its
+    pages)."""
+    from paddle_tpu.models.moe_lm import MoEConfig, MoEForCausalLM
+    model = MoEForCausalLM(MoEConfig(
+        vocab_size=512, hidden_size=128, moe_intermediate_size=64,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        num_experts=4, max_position_embeddings=256, rms_norm_eps=1e-5,
+        attention="cca", head_dim=16, partial_rotary_factor=0.5,
+        capacity_factor=None, first_k_dense_replace=0, num_shared_experts=0,
+        num_experts_per_tok=1, router="mlp", router_hidden_size=32,
+        router_skip_choice=True, residual_scaling=True,
+        tie_word_embeddings=True)).eval()
+    eng = _engine(model)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.submit(_prompts(1, 5, 512)[0])
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    pre = _host_spans(tmp_path)["serving::prefill"][0]
+    assert pre["attn"] == "cca" and pre["kind"] == "full"
+    stats = eng.stats()
+    assert {"moe_assignments", "moe_peak_load", "moe_skipped",
+            "kv_bytes_per_token", "slot_state_bytes"} <= set(stats)
+    c = 4 * 16 + 2 * 16         # Cq + Ck
+    assert stats["slot_state_bytes"] == 2 * 2 * (2 * c + 16) * 4
+    assert eng.attention_kind == "cca"
+
+
 def test_trainer_dispatch_is_a_step_span_with_its_step_number(tmp_path):
     tr, batch = _trainer()
     tr.train_step(batch)                   # compile outside the trace
