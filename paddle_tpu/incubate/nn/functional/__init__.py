@@ -239,8 +239,8 @@ def block_multihead_attention(qkv, key_cache, value_cache, seq_lens_decoder,
     value_cache = value_cache.at[:, phys_block, offset].set(
         jnp.swapaxes(v_new, 0, 1))
 
-    # TPU fast path: Pallas paged-decode kernel streams pages via a
-    # scalar-prefetched block table, never gathering [B, T] into HBM
+    # TPU fast path: the Pallas paged-decode kernel copies each row's live
+    # pages from the pools in HBM itself, never gathering [B, T] into HBM
     from ....ops.registry import backend_kind
     from ....ops.pallas.paged_attention import (paged_decode_attention,
                                                 paged_decode_supported)

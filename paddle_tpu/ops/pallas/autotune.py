@@ -176,17 +176,20 @@ def fused_vocab_ce_config(n: int, h: int, v: int,
 def paged_decode_crossover(default: int = 0) -> int:
     """Context length (tokens) above which the Pallas paged-decode kernel
     beats the dense XLA gather path for one decode step. Measured on v5e
-    at the serving shape (B=32, 32 query / 8 KV heads of 128, pages of
-    128; tools/tune_kernels.py --paged-decode: write-then-attend steps
-    chained in one program): the kernel is ahead at EVERY context, 0.22 /
-    0.27 / 0.34 / 0.43 ms a call at 256 / 512 / 1024 / 2048 tokens of a
-    2048-token table span against 1.04-1.30 ms dense, and 0.57-1.60 ms
-    against 4.46-5.13 ms over an 8192-token span — so the default is 0
-    and every tick of an engine takes one executable. (The 4096 it
-    replaces was read off single host-timed dispatches, which sit on one
-    ~3 ms floor.) A tuned value (op "paged_decode_crossover", config key
-    "ctx") in the TuneDB wins; the serving engine consults this per
-    dispatched decode block (inference/serving.py)."""
+    at the two serving cells' shapes (tools/tune_kernels.py
+    --paged-decode: write-then-attend steps chained in one program; PR 32,
+    since the kernel fetches its live pages itself): the kernel is ahead
+    at EVERY context. B=32, 32 query / 8 KV heads of 128, pages of 128:
+    0.13 / 0.14 / 0.23 / 0.41 ms a call at 256 / 512 / 1024 / 2048 tokens
+    of a 2048-token table span against 1.04-1.30 ms dense, and 0.25-1.49
+    ms against 4.46-5.13 ms over an 8192-token span; B=128, 8 / 2 heads,
+    24-page tables: 0.19 / 0.23 / 0.59 ms at 256 / 1024 / 3072 tokens
+    against 1.55-1.93 ms — so the default is 0 and every tick of an engine
+    takes one executable. (The 4096 it replaced was read off single
+    host-timed dispatches, which sit on one ~3 ms floor.) A tuned value
+    (op "paged_decode_crossover", config key "ctx") in the TuneDB wins;
+    the serving engine consults this per dispatched decode block
+    (inference/serving.py)."""
     key = TuneDB.key("paged_decode_crossover", _device_kind(), "any")
     hit = _DB.lookup(key)
     if hit:

@@ -91,17 +91,18 @@ def _tuned_flash_cases():
             id="flash_tuned[%s]" % key.split("|")[3])
 
 
-def _paged(page, dtype):
+def _paged(page, dtype, rows=8, h=32, h_kv=8, per_seq=None):
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
-    pages, per_seq = 8 * (2048 // page) + 1, 2048 // page
+    per_seq = per_seq or 2048 // page
+    pages = rows * per_seq + 1
     quant = dtype == I8
 
     def fn(q, kp, vp, tables, lens, *scales):
         kw = dict(k_scales=scales[0], v_scales=scales[1]) if quant else {}
         return paged_decode_attention(q, kp, vp, tables, lens, **kw)
-    pool = ((8, pages, page, 128), dtype)
-    args = [((8, 32, 128), BF16), pool, pool, ((8, per_seq), I32),
-            ((8,), I32)] + ([((pages,), F32)] * 2 if quant else [])
+    pool = ((h_kv, pages, page, 128), dtype)
+    args = [((rows, h, 128), BF16), pool, pool, ((rows, per_seq), I32),
+            ((rows,), I32)] + ([((pages,), F32)] * 2 if quant else [])
     return fn, args
 
 
@@ -171,6 +172,12 @@ ONE_CHIP = [
     pytest.param(lambda: _paged(128, BF16), id="paged_decode[bf16,page128]"),
     pytest.param(lambda: _paged(16, BF16), id="paged_decode[bf16,page16]"),
     pytest.param(lambda: _paged(128, I8), id="paged_decode[int8,page128]"),
+    # the two serving cells' calls: zaya1-8b.reasoning (128 rows, 8 query /
+    # 2 KV heads, 24-page tables) and mistral-7b.* (32 rows, 32 / 8, 16)
+    pytest.param(lambda: _paged(128, BF16, rows=128, h=8, h_kv=2, per_seq=24),
+                 id="paged_decode[bf16,128x8/2,24pages]"),
+    pytest.param(lambda: _paged(128, BF16, rows=32, per_seq=16),
+                 id="paged_decode[bf16,32x32/8,16pages]"),
     pytest.param(_rms_norm, id="rms_norm[D4096]"),
     pytest.param(_rope, id="rope[s2048,32/8]"),
     pytest.param(lambda: _fused_ce(16384, 4096, 128256),

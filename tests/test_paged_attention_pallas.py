@@ -10,6 +10,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src.pallas.mosaic.interpret import (
+    interpret_pallas_call as mosaic_interpret)
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas.paged_attention import (paged_decode_attention,
                                                    paged_decode_supported,
@@ -134,14 +137,16 @@ def _plain_f32(q, k_pages, v_pages, tables, lens, k_scales=None,
     return out
 
 
-def _ragged(group, pool, seed, page=16, H_kv=2, per_seq=4):
+def _ragged(group, pool, seed, page=16, H_kv=2, per_seq=4, lens=None):
     """B=5 rows over per_seq-page tables: a lone token, a length on a page
     boundary on either side, a full span, and -1 in every unused slot."""
-    D, num_pages = 32, 32
+    D = 32
     rs = np.random.RandomState(seed)
-    lens = np.array([0, page - 1, page, 2 * page + 5, per_seq * page - 1],
-                    np.int32)
+    if lens is None:
+        lens = [0, page - 1, page, 2 * page + 5, per_seq * page - 1]
+    lens = np.array(lens, np.int32)
     B = len(lens)
+    num_pages = max(32, B * per_seq)
     tables = rs.permutation(num_pages)[:B * per_seq].reshape(B, per_seq)
     used = -(-(lens + 1) // page)
     tables = np.where(np.arange(per_seq)[None] < used[:, None], tables, -1)
@@ -175,22 +180,59 @@ def test_grouped_fallback_matches_plain_float32(group, pool):
                                rtol=tol, atol=tol)
 
 
+def _zaya_rows(page, per_seq=24):
+    """Lengths on either side of every power-of-two count of pages (a
+    chunk of the kernel's walk is such a count, whatever it is), a lone
+    token and a full table."""
+    edges = [page << i for i in range(5)]
+    return [0, *(n - d for n in edges for d in (1, 0)), per_seq * page - 1]
+
+
+_INTERPRET = {
+    "interpret": True,
+    # unfetched or unwritten memory reads NaN, a copy lands only when it is
+    # waited for, and a buffer touched while a copy may write it is reported
+    "nan+races": pltpu.InterpretParams(uninitialized_memory="nan",
+                                       detect_races=True)}
+
+
+@pytest.mark.parametrize("mode", list(_INTERPRET))
 @pytest.mark.parametrize("pool,H_kv,per_seq", [
     ("float32", 2, 4), ("bfloat16", 2, 4), ("int8", 2, 4),
-    # three page groups a row (dead groups, a half-dead live group) and
-    # two blocks of eight KV heads
-    ("float32", 2, 6), ("float32", 16, 6), ("int8", 4, 6)])
-def test_kernel_interpret_matches_grouped_fallback(pool, H_kv, per_seq):
+    # a table wider than its rows' live pages, and two blocks of eight KV
+    # heads
+    ("float32", 2, 6), ("float32", 16, 6), ("int8", 4, 6),
+    # ZAYA's heads (8 query / 2 KV) over a 24-page table: several chunks a
+    # row, rows that end on either side of a chunk's edge
+    ("bfloat16", 2, 24)])
+def test_kernel_interpret_matches_grouped_fallback(pool, H_kv, per_seq, mode):
     # the int8 sublane multiple is 32
+    page = 32 if pool == "int8" else 16
     args, scales = _ragged(4 if H_kv < 16 else 1, pool, seed=11, H_kv=H_kv,
-                           page=32 if pool == "int8" else 16,
-                           per_seq=per_seq)
-    out = paged_decode_attention(*args, **scales, interpret=True)
+                           page=page, per_seq=per_seq,
+                           lens=_zaya_rows(page) if per_seq == 24 else None)
+    pltpu.reset_tpu_interpret_mode_state()
+    out = paged_decode_attention(*args, **scales,
+                                 interpret=_INTERPRET[mode])
     ref = paged_decode_xla(*args, **scales)
     tol = 2e-5 if pool == "float32" else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
+    if mode == "nan+races":
+        assert not mosaic_interpret.races.races_found
+
+
+def test_kernel_takes_no_operand_per_page():
+    """The pools enter the call once each and stay in HBM: a 24-page table
+    gives the ``pallas_call`` the operands a 4-page table gives it."""
+    def operands(per_seq):
+        args, _ = _ragged(4, "bfloat16", seed=3, per_seq=per_seq)
+        jaxpr = jax.make_jaxpr(paged_decode_attention)(*args)
+        call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return len(call.invars)
+    # tables, lengths, q, K pool, V pool
+    assert operands(4) == operands(24) == 5
 
 
 def _arrays(jaxpr):

@@ -12,8 +12,9 @@ On the chip:
     device) — into the file named by --out, or into the in-repo DB with
     --write-shipped (nothing is written otherwise);
   - microbenches pallas-vs-XLA for flash attention and paged decode
-    (--paged-decode: that comparison alone, per context length at the
-    serving cell's shape), printing one JSON line per case, so
+    (--paged-decode: that comparison alone, per context length at the two
+    serving cells' shapes, with jax's library kernel beside it), printing
+    one JSON line per case, so
     regressions are diffable (the in-repo analogue of
     ci_op_benchmark.sh).
 
@@ -147,17 +148,35 @@ def sweep_flash(shapes, candidates, interpret, record_db, quick=False):
     return results
 
 
-def bench_paged_decode(interpret, steps=128, per_seq=16,
-                       contexts=(256, 512, 1024, 2048, "ragged")):
-    """The Pallas paged-decode kernel against the XLA fallback at the
-    serving cell's shape (32 rows, 32 query / 8 KV heads of 128, tables of
+def _ragged_batch_decode(rs, B, span):
+    """``mistral-7b.batch-decode``'s rows: log-uniform over 5/64-5/8 of
+    the table's span (160-1280 of 2048 tokens)."""
+    import numpy as np
+    return np.exp(rs.uniform(np.log(span * 5 / 64), np.log(span * 5 / 8), B))
+
+
+def _ragged_reasoning(rs, B, span):
+    """``zaya1-8b.reasoning``'s rows: a prompt of 128-1024 plus a uniform
+    part of an output of 512-2048, both log-uniform."""
+    import numpy as np
+    lu = lambda lo, hi: np.exp(rs.uniform(np.log(lo), np.log(hi), B))
+    return np.minimum(lu(128, 1024) + rs.uniform(0, 1, B) * lu(512, 2048),
+                      span - 1)
+
+
+def bench_paged_decode(interpret, B=32, H=32, H_kv=8, per_seq=16,
+                       contexts=(256, 512, 1024, 2048, "ragged"),
+                       ragged=_ragged_batch_decode, steps=128):
+    """The Pallas paged-decode kernel against the XLA fallback and, for
+    comparison only (the program does not import it), jax's library
+    ``paged_attention`` over the same pools, at a serving cell's shape
+    (``B`` rows, ``H`` query / ``H_kv`` KV heads of 128, tables of
     ``per_seq`` pages of 128, bf16), per context length. ``steps`` calls
     are chained inside ONE program (each step's output is the next one's
     query and its new K/V row), so the reading is device time a call; a
     single host-timed dispatch sits on a ~3 ms floor and ranks nothing.
-    "ragged" is the batch-decode cell's mix: lengths log-uniform over
-    160-1280 of a 2048-token span. The reading that sets
-    ``autotune.paged_decode_crossover``."""
+    "ragged" is the cell's own mix of lengths (``ragged``). The reading
+    that sets ``autotune.paged_decode_crossover``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -167,11 +186,10 @@ def bench_paged_decode(interpret, steps=128, per_seq=16,
 
     kind = getattr(jax.devices()[0], "device_kind", "cpu")
     rs = np.random.RandomState(0)
+    D = page = 128
     if interpret:
         B, H, H_kv, D, page, per_seq = 2, 4, 2, 32, 16, 4
         contexts, steps = (24, "ragged"), 2
-    else:
-        B, H, H_kv, D, page = 32, 32, 8, 128, 128
     npages = B * per_seq
     span = page * per_seq
     dt = jnp.bfloat16
@@ -179,9 +197,20 @@ def bench_paged_decode(interpret, steps=128, per_seq=16,
     # head-major pools [H_kv, num_pages, page_size, D]
     kp = jnp.asarray(rs.normal(0, 1, (H_kv, npages, page, D)), dt)
     vp = jnp.asarray(rs.normal(0, 1, (H_kv, npages, page, D)), dt)
+
+    def library(q, kp, vp, tables, lens):
+        from jax.experimental.pallas.ops.tpu.paged_attention import (
+            paged_attention)
+        # it masks positions < length and wants every table slot in range
+        return paged_attention(
+            q, kp, vp, lens + 1, jnp.maximum(tables, 0),
+            pages_per_compute_block=max(n for n in (8, 4, 2, 1)
+                                        if per_seq % n == 0))
     impls = {"pallas": functools.partial(paged_decode_attention,
                                          interpret=interpret),
              "xla": paged_decode_xla}
+    if not interpret:                   # it has no interpret mode
+        impls["lib"] = library
 
     def chained(fn):
         # the decode tick's own order: write the step's K/V into its page
@@ -202,17 +231,19 @@ def bench_paged_decode(interpret, steps=128, per_seq=16,
     results = []
     for ctx in contexts:
         if ctx == "ragged":
-            lens = np.exp(rs.uniform(np.log(span * 5 / 64),
-                                     np.log(span * 5 / 8), B)).astype(np.int32)
+            lens = ragged(rs, B, span).astype(np.int32)
         else:
             lens = np.full((B,), ctx - 1, np.int32)
         used = lens // page + 1
         tables = rs.permutation(npages)[:B * per_seq].reshape(B, per_seq)
         tables = np.where(np.arange(per_seq)[None] < used[:, None], tables, -1)
         args = (q, kp, vp, jnp.asarray(tables, jnp.int32), jnp.asarray(lens))
+        live = int(lens.sum() + B)
         line = {"bench": "paged_decode", "device": kind, "ctx": ctx,
                 "shape": f"b{B}_h{H}x{H_kv}_d{D}_pages{per_seq}x{page}",
-                "live_tokens": int(lens.sum() + B), "steps": steps}
+                "live_tokens": live, "steps": steps,
+                # what the live K and V rows take at the chip's HBM rate
+                "bytes_us": round(live * H_kv * D * 2 * 2 / 819e3, 1)}
         for name, fn in fns.items():
             t = _time_fn(fn, *args, iters=1, warmup=1, reps=3)
             line[f"{name}_us"] = round(t / steps * 1e6, 1)
@@ -243,8 +274,14 @@ def main():
         require_tpu()
 
     if args.paged_decode:
+        # mistral-7b.*'s call, zaya1-8b.reasoning's, and a table four
+        # times as long as the first
         results = bench_paged_decode(interpret)
-        if not interpret:       # and a table four times as long
+        if not interpret:
+            results += bench_paged_decode(
+                interpret, B=128, H=8, H_kv=2, per_seq=24,
+                contexts=(256, 1024, 3072, "ragged"),
+                ragged=_ragged_reasoning)
             results += bench_paged_decode(interpret, per_seq=64,
                                           contexts=(1024, 2048, 8192))
         print(json.dumps({"tuned": False, "cases": len(results)}))
