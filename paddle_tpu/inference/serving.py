@@ -208,6 +208,11 @@ class _InflightBlock:
 HANDOFF_FMT = "pt-kv-pages-v2"
 HANDOFF_FMT_V1 = "pt-kv-pages-v1"
 
+# the most prefill programs (one a padded prompt width) an engine builds
+# over its ``max_len``: each is a compilation, a cache entry and seconds of
+# set-up. Every table of 24 pages or fewer keeps page-wide widths.
+MAX_PREFILL_PROGRAMS = 24
+
 
 def _entry_page_copy(entry, src, dst):
     """Copy physical page ``src`` → ``dst`` within one per-layer pool
@@ -326,6 +331,12 @@ class ContinuousBatchingEngine:
         self.page_size = page_size
         self.max_len = max_len
         self.pages_per_seq = -(-max_len // page_size)
+        # a prompt is padded to whole steps of so many tokens, one prefill
+        # program a width: a page while the table spans no more pages than
+        # MAX_PREFILL_PROGRAMS, else the fewest pages that keep the
+        # programs within it
+        self._bucket_step = page_size * -(-self.pages_per_seq
+                                          // MAX_PREFILL_PROGRAMS)
         # pool: page 0 is the reserved garbage page for inactive slots
         total = (num_pages if num_pages is not None
                  else max_batch * self.pages_per_seq) + 1
@@ -426,6 +437,8 @@ class ContinuousBatchingEngine:
         self.attn_crossover = int(attn_crossover)
         self.attn_path_ticks = {"dense": 0, "paged": 0}
         self._inflight: Deque[_InflightBlock] = deque()
+        # (rid, token) pairs a cancel() drained outside step()
+        self._held_emitted: List[tuple] = []
         # radix prefix-shared KV (ISSUE 7): tree nodes own refcounted
         # pages in THIS pool; one PrefixLock per occupied slot records
         # exactly which nodes its table maps. prefix_cache=False builds
@@ -600,7 +613,7 @@ class ContinuousBatchingEngine:
         next decode block. Returns [(rid, token), ...] whose results
         ARRIVED this tick — with ``async_depth > 1`` a token is emitted
         the tick its block drains, one block behind its dispatch."""
-        emitted: List[tuple] = []
+        emitted, self._held_emitted = self._held_emitted, []
         with RecordEvent("serving::admit", queued=len(self._queue),
                          free_pages=len(self._free)):
             self._admit()
@@ -743,16 +756,20 @@ class ContinuousBatchingEngine:
         slot = next((i for i, s in enumerate(self._slots) if s is req),
                     -1)
         if slot >= 0:
-            # tokens other slots commit in this drain are NOT lost: they
-            # land in their requests' .generated and the full stream
-            # ships with each finish — only this tick's incremental
-            # emission view is bypassed
-            self._drain_all()
+            # tokens other slots commit in this drain are held for the
+            # next step() to emit: a consumer that streams by emission
+            # (the fabric) must see every token of a stream that goes on
+            # exactly once; one that finished here ships whole with its
+            # finish
+            self._held_emitted.extend(
+                (r, t) for r, t in self._drain_all()
+                if r in self._requests and not self._requests[r].done)
             if not req.done and self._slots[slot] is req:
                 self._deactivate(slot)
                 self._free_slot(slot, cache=True)
         if req.done:
             return False
+        self._held_emitted = [e for e in self._held_emitted if e[0] != rid]
         if req.tspans is not None:
             for k in ("queue", "res"):
                 sp = req.tspans.pop(k, None)
@@ -1281,7 +1298,10 @@ class ContinuousBatchingEngine:
     # -- admission / prefill ------------------------------------------------
 
     def _bucket(self, L: int) -> int:
-        return -(-L // self.page_size) * self.page_size
+        """The width a prompt of ``L`` tokens is padded to: whole steps of
+        ``_bucket_step`` tokens, within the table's span."""
+        step = self._bucket_step
+        return min(-(-L // step) * step, self.pages_per_seq * self.page_size)
 
     def _prefill_fn(self, bucket: int):
         fn = self._prefill_cache.get(bucket)

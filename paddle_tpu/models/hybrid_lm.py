@@ -1,0 +1,560 @@
+"""Hybrid state-space / attention / expert causal LM (``nemotron_h``'s layout,
+as NVIDIA-Nemotron-3-Nano-30B-A3B publishes it).
+
+A decoder of blocks ``x + Mixer(RMSNorm(x))`` whose mixer is ONE of three
+kinds, by a pattern string with one character a block:
+
+- ``M``: a Mamba-2 layer (``Mamba2Mixer``, arXiv:2405.21060): one input
+  projection to a gate ``z``, a convolved stream ``xBC`` and a step ``dt`` a
+  head; a short causal depthwise convolution and SiLU over ``xBC``; the
+  recurrence ``S_t = exp(dt A) S_{t-1} + dt x_t (x) B_t``, ``y_t = S_t C_t +
+  D x_t`` over a float32 state [heads, head size, state size]; ``y silu(z)``
+  through a grouped RMSNorm; one output projection.
+- ``*``: causal GQA attention with NO position embedding (``NoPEAttention``:
+  the Mamba layers carry position), K and V in the paged pools.
+- ``E``: sigmoid-routed experts with a selection bias beside a shared expert
+  (``parallel.moe.MoELayer``), non-gated: ``relu(x W_up)^2 W_down``. The layer
+  may hold a SHARE of the experts (``experts_held``: one chip of an
+  expert-parallel deployment; the router keeps its full width).
+
+Served through ``inference.ContinuousBatchingEngine`` by the interface it has
+(``alloc_paged_caches`` / ``alloc_slot_state`` / ``prefill_paged`` /
+``decode_step_paged``): page pools for the attention layers ONLY, a per-slot
+state for the Mamba layers only: the convolution's last ``conv_kernel - 1``
+inputs (activation dtype) and the recurrence's state in float32, which a
+prefill writes at the prompt's true last position and every decode tick
+rewrites in place (``ops.pallas.ssm.ssm_state_update`` on a TPU). A prompt
+runs the same recurrence in chunks (``ssd_chunked``: matrix products inside a
+chunk, the state carried from chunk to chunk). Not trained: ``forward`` is
+the whole-sequence form for tests and evaluation; the chunked scan has no
+hand-written backward and no training cell runs it.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .. import nn
+from ..nn import initializer as I
+from ..parallel.moe import MoELayer, expert_ffn
+from .llama import (_kv_scatter_tokens, _kv_write_prompt, _normal,
+                    _paged_decode_attention)
+
+
+@dataclass
+class HybridConfig:
+    vocab_size: int = 32000
+    hidden_size: int = 2688
+    pattern: str = "MEM*E"                 # one character a block
+    num_hidden_layers: Optional[int] = None  # the first so many of them
+    # Mamba-2 (the inner width is heads x head size)
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    # attention
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    # experts
+    num_experts: int = 128                 # the router's width
+    first_expert_held: int = 0             # the share of them held here:
+    num_experts_held: Optional[int] = None  # None: all
+    num_experts_per_tok: int = 6
+    moe_intermediate_size: int = 1856
+    shared_expert_intermediate_size: int = 3712
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    rms_norm_eps: float = 1e-5
+    initializer_range: float = 0.02
+    dtype: str = "float32"
+
+    def __post_init__(self):
+        bad = set(self.pattern) - set("ME*")
+        if bad or not self.pattern:
+            raise ValueError(f"pattern {self.pattern!r}: one of 'M' (Mamba-2)"
+                             f", 'E' (experts), '*' (attention) a block")
+        if (self.num_hidden_layers is not None
+                and not 0 < self.num_hidden_layers <= len(self.pattern)):
+            raise ValueError(f"num_hidden_layers={self.num_hidden_layers}: "
+                             f"the pattern has {len(self.pattern)} blocks")
+        if self.mamba_num_heads % self.n_groups:
+            raise ValueError("n_groups must divide mamba_num_heads")
+
+    @property
+    def kinds(self) -> str:
+        """The blocks built: the pattern's first ``num_hidden_layers``
+        characters (a pipeline stage holds a prefix of the published
+        pattern), all of them by default."""
+        return self.pattern[:self.num_hidden_layers]
+
+    @property
+    def experts_held(self) -> Optional[Tuple[int, int]]:
+        """``MoELayer``'s (first, count), None where all are held."""
+        if self.num_experts_held is None:
+            return None
+        return self.first_expert_held, self.num_experts_held
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.ssm_state_size
+
+    @staticmethod
+    def tiny(**kw) -> "HybridConfig":
+        return HybridConfig(**{**dict(
+            vocab_size=256, hidden_size=64, pattern="MEM*E",
+            mamba_num_heads=8, mamba_head_dim=8, n_groups=2,
+            ssm_state_size=128, chunk_size=16, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, num_experts=8,
+            num_experts_per_tok=2, moe_intermediate_size=32,
+            shared_expert_intermediate_size=64), **kw})
+
+
+def _dtype(cfg):
+    return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+
+
+def ssd_chunked(x, dt, a, b_mat, c_mat, chunk: int):
+    """The Mamba-2 recurrence over whole sequences starting from a zero
+    state, in chunks (the SSD form): inside a chunk everything is matrix
+    products, the state is carried from chunk to chunk.
+
+    x [b, L, H, P]; dt [b, L, H] float32 (0 where a position must leave the
+    state as it is); a [H] float32; b_mat, c_mat [b, L, G, N]; L a multiple
+    of ``chunk``. Returns (y [b, L, H, P] float32 = S_t C_t, S_L [b, H, P,
+    N] float32). With la = dt a and cs its running sum inside a chunk, a
+    position l takes exp(cs[l] - cs[s]) (C_l . B_s) dt_s x_s from every s <=
+    l of its chunk and exp(cs[l]) C_l S from the state S entering the chunk.
+    """
+    b, L, H, P = x.shape
+    G, N = b_mat.shape[2:]
+    R, nc = H // G, L // chunk
+    f32 = jnp.float32
+    xdt = (x.astype(f32) * dt[..., None]).reshape(b, nc, chunk, G, R, P)
+    cs = jnp.cumsum((dt * a).reshape(b, nc, chunk, G, R), axis=2)
+    bc = b_mat.astype(f32).reshape(b, nc, chunk, G, N)
+    cc = c_mat.astype(f32).reshape(b, nc, chunk, G, N)
+    # inside a chunk: [b, c, g, r, l, s], 0 above the diagonal (masked
+    # BEFORE the exp: cs[l] - cs[s] is positive there)
+    seg = jnp.moveaxis(cs, 2, -1)                        # [b, c, g, r, l]
+    seg = seg[..., :, None] - seg[..., None, :]
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(tri, seg, -jnp.inf))
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc)
+    y = jnp.einsum("bcgrls,bcsgrp->bclgrp", cb[:, :, :, None] * decay, xdt)
+    # what a chunk adds to the state at its end, and the state entering it
+    to_end = jnp.exp(cs[:, :, -1:] - cs)                 # [b, c, s, g, r]
+    added = jnp.einsum("bcsgr,bcsgrp,bcsgn->bcgrpn", to_end, xdt, bc)
+    whole = jnp.exp(cs[:, :, -1])                        # [b, c, g, r]
+
+    def carry(s, step):
+        add, keep = step
+        return keep[..., None, None] * s + add, s
+
+    last, entering = jax.lax.scan(
+        carry, jnp.zeros((b, G, R, P, N), f32),
+        (jnp.moveaxis(added, 1, 0), jnp.moveaxis(whole, 1, 0)))
+    y = y + (jnp.einsum("bclgn,cbgrpn->bclgrp", cc, entering)
+             * jnp.exp(cs)[..., None])
+    return y.reshape(b, L, H, P), last.reshape(b, H, P, N)
+
+
+class Mamba2Mixer(nn.Layer):
+    """One Mamba-2 layer (the module docstring has its equations)."""
+
+    def __init__(self, cfg: HybridConfig):
+        super().__init__()
+        self.cfg = cfg
+        d, std = cfg.hidden_size, cfg.initializer_range
+        self.h, self.p = cfg.mamba_num_heads, cfg.mamba_head_dim
+        self.g, self.n = cfg.n_groups, cfg.ssm_state_size
+        inner, conv = cfg.d_inner, cfg.conv_dim
+
+        def vector(shape, value):
+            return self.create_parameter(shape, dtype="float32",
+                                         initializer=I.Constant(value))
+        # [z | xBC | dt]
+        self.in_proj = self.create_parameter(
+            [d, inner + conv + self.h], dtype=cfg.dtype,
+            initializer=_normal(std), sharding=("fsdp", None))
+        self.conv_weight = vector([cfg.conv_kernel, conv], 1.0)  # last: now
+        self.conv_bias = vector([conv], 0.0)
+        self.dt_bias = vector([self.h], 0.0)
+        self.A_log = vector([self.h], 0.0)
+        self.D = vector([self.h], 1.0)
+        self.norm_weight = vector([inner], 1.0)
+        self.out_proj = self.create_parameter(
+            [inner, d], dtype=cfg.dtype, initializer=_normal(std),
+            sharding=(None, "fsdp"))
+
+    def _project(self, u):
+        """u [.., d] -> (z [.., inner], xBC [.., conv_dim], dt [.., H])."""
+        inner, conv = self.cfg.d_inner, self.cfg.conv_dim
+        zxd = jnp.matmul(u, self.in_proj.astype(u.dtype))
+        return (zxd[..., :inner], zxd[..., inner:inner + conv],
+                zxd[..., inner + conv:])
+
+    def _conv(self, taps):
+        """The convolution's output from its ``conv_kernel`` inputs a
+        position, ``taps`` a list of [.., conv_dim] (oldest first), then
+        SiLU."""
+        out = self.conv_bias + sum(
+            t.astype(jnp.float32) * self.conv_weight[i]
+            for i, t in enumerate(taps))
+        return jax.nn.silu(out).astype(taps[0].dtype)
+
+    def _split(self, xbc):
+        """xBC [.., conv_dim] -> (x [.., H, P], B [.., G, N], C [.., G, N])."""
+        lead, inner, gn = xbc.shape[:-1], self.cfg.d_inner, self.g * self.n
+        return (xbc[..., :inner].reshape(*lead, self.h, self.p),
+                xbc[..., inner:inner + gn].reshape(*lead, self.g, self.n),
+                xbc[..., inner + gn:].reshape(*lead, self.g, self.n))
+
+    def _step(self, dt):
+        """(Delta [.., H] = softplus(dt + dt_bias), A [H]), float32."""
+        return (jax.nn.softplus(dt.astype(jnp.float32) + self.dt_bias),
+                -jnp.exp(self.A_log))
+
+    def _out(self, y, x, z):
+        """y [.., H, P] float32 (the state's reading) -> the layer's output:
+        + D x, times silu(z), RMSNorm over each group's channels, out_proj."""
+        cfg = self.cfg
+        lead = z.shape[:-1]
+        y = y + self.D[:, None] * x.astype(jnp.float32)
+        y = y.reshape(*lead, cfg.d_inner) * jax.nn.silu(
+            z.astype(jnp.float32))
+        grouped = y.reshape(*lead, self.g, cfg.d_inner // self.g)
+        grouped = grouped * jax.lax.rsqrt(
+            jnp.mean(grouped * grouped, -1, keepdims=True) + cfg.rms_norm_eps)
+        y = (grouped.reshape(*lead, cfg.d_inner)
+             * self.norm_weight).astype(z.dtype)
+        return jnp.matmul(y, self.out_proj.astype(z.dtype))
+
+    def _sequence(self, u, last_idx=None):
+        """Whole sequences u [b, s, d] from a zero state: (output [b, s, d],
+        the convolution's inputs after position ``last_idx`` [b, k - 1,
+        conv_dim], the state after it [b, H, P, N]). Positions past
+        ``last_idx`` (a bucket's padding; None: the last) take a step of 0,
+        so they leave the state as it is."""
+        cfg = self.cfg
+        b, s, _ = u.shape
+        k, chunk = cfg.conv_kernel, cfg.chunk_size
+        last_idx = s - 1 if last_idx is None else last_idx
+        z, xbc, dt = self._project(u)
+        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+        x, b_mat, c_mat = self._split(
+            self._conv([padded[:, i:i + s] for i in range(k)]))
+        delta, a = self._step(dt)
+        delta = jnp.where((jnp.arange(s) <= last_idx)[None, :, None],
+                          delta, 0.0)
+        pad = -s % chunk        # whole chunks; a step of 0 moves nothing
+
+        def whole(t):
+            return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        y, state = ssd_chunked(whole(x), whole(delta), a, whole(b_mat),
+                               whole(c_mat), chunk)
+        tail = jax.lax.dynamic_slice_in_dim(padded, last_idx + 1, k - 1,
+                                            axis=1)
+        return self._out(y[:, :s], x, z), tail, state
+
+    def forward(self, u):
+        return self._sequence(u)[0]
+
+    # -- serving path --------------------------------------------------------
+
+    def alloc_slot_state(self, slots: int):
+        """What each of ``slots`` sequences carries from token to token:
+        (the convolution's last k - 1 inputs [slots, k - 1, conv_dim] in the
+        activation dtype, the recurrence's state in float32, whatever the
+        activation dtype, in the layout its update sweeps:
+        ``ops.pallas.ssm.pack_state`` of [slots, H, P, N])."""
+        from ..ops.pallas.ssm import pack_state
+        cfg = self.cfg
+        return (jnp.zeros((slots, cfg.conv_kernel - 1, cfg.conv_dim),
+                          _dtype(cfg)),
+                pack_state(jnp.zeros((slots, self.h, self.p, self.n),
+                                     jnp.float32), self.g))
+
+    def prefill(self, u, state, slot, last_idx):
+        """The prompt of ONE sequence into slot ``slot``: the state written
+        is the state after the prompt's true last position ``last_idx``,
+        whatever the bucket the prompt was padded to."""
+        from ..ops.pallas.ssm import pack_state
+        out, tail, ssm = self._sequence(u, last_idx)
+        conv_state, ssm_state = state
+        return out, (conv_state.at[slot].set(tail[0].astype(conv_state.dtype)),
+                     ssm_state.at[slot].set(pack_state(ssm[0], self.g)))
+
+    def decode(self, u, state):
+        """One token of every row u [b, 1, d] through the rows' state (the
+        Pallas kernel on a TPU, in place; its ``jnp`` twin elsewhere)."""
+        from ..ops.pallas.ssm import (ssm_state_update,
+                                      ssm_state_update_supported,
+                                      ssm_state_update_xla)
+        from ..ops.registry import backend_kind
+        conv_state, ssm_state = state
+        z, xbc, dt = self._project(u[:, 0])
+        window = jnp.concatenate(
+            [conv_state, xbc[:, None].astype(conv_state.dtype)], axis=1)
+        x, b_mat, c_mat = self._split(
+            self._conv([window[:, i] for i in range(window.shape[1])]))
+        delta, a = self._step(dt)
+        update = (ssm_state_update if backend_kind() == "tpu"
+                  and ssm_state_update_supported(ssm_state, b_mat)
+                  else ssm_state_update_xla)
+        y, ssm_state = update(ssm_state, x, delta, a, b_mat, c_mat)
+        return self._out(y, x, z)[:, None], (window[:, 1:], ssm_state)
+
+
+class NoPEAttention(nn.Layer):
+    """Causal GQA attention with no position embedding at all (no rotary,
+    no table): ``nemotron_h``'s attention layers. The paged interface of
+    ``LlamaAttention`` (pools [H_kv, pages, page, d], the flash kernel for a
+    prompt, ``paged_attention_decode`` for a tick) without its rotation."""
+
+    def __init__(self, cfg: HybridConfig):
+        super().__init__()
+        self.cfg = cfg
+        d, hd, std = cfg.hidden_size, cfg.head_dim, cfg.initializer_range
+        self.n_q, self.n_kv, self.hd = (cfg.num_attention_heads,
+                                        cfg.num_key_value_heads, hd)
+        # [q | k | v], column-parallel over tp
+        self.qkv_proj = self.create_parameter(
+            [d, (self.n_q + 2 * self.n_kv) * hd], dtype=cfg.dtype,
+            initializer=_normal(std), sharding=("fsdp", "tp"))
+        self.o_proj = self.create_parameter(
+            [self.n_q * hd, d], dtype=cfg.dtype, initializer=_normal(std),
+            sharding=("tp", "fsdp"))
+
+    def _qkv(self, u):
+        b, s, _ = u.shape
+        qkv = jnp.matmul(u, self.qkv_proj.astype(u.dtype))
+        q, k, v = jnp.split(qkv, [self.n_q * self.hd,
+                                  (self.n_q + self.n_kv) * self.hd], axis=-1)
+        return (q.reshape(b, s, self.n_q, self.hd),
+                k.reshape(b, s, self.n_kv, self.hd),
+                v.reshape(b, s, self.n_kv, self.hd))
+
+    def _o(self, out, u):
+        b, s = u.shape[:2]
+        return jnp.matmul(out.reshape(b, s, self.n_q * self.hd).astype(
+            u.dtype), self.o_proj.astype(u.dtype))
+
+    def _sequence(self, u):
+        from ..ops.attention import flash_attention
+        q, k, v = self._qkv(u)
+        return self._o(flash_attention(q, k, v, causal=True), u), k, v
+
+    def forward(self, u):
+        return self._sequence(u)[0]
+
+    def alloc_pool(self, num_pages: int, page_size: int):
+        shape = (self.n_kv, num_pages, page_size, self.hd)
+        return (jnp.zeros(shape, _dtype(self.cfg)),
+                jnp.zeros(shape, _dtype(self.cfg)))
+
+    def prefill(self, u, kv, tables):
+        """Prompt pass: K and V pages written whole (rows past the prompt
+        lie beyond seq_len and are overwritten by decode steps before they
+        are unmasked)."""
+        out, k, v = self._sequence(u)
+        return out, _kv_write_prompt(kv, tables, k, v)
+
+    def decode(self, u, pos, kv, tables):
+        b = u.shape[0]
+        page = kv[0].shape[2]
+        q, k, v = self._qkv(u)
+        kv = _kv_scatter_tokens(kv, tables[jnp.arange(b), pos // page],
+                                pos % page, jnp.swapaxes(k[:, 0], 0, 1),
+                                jnp.swapaxes(v[:, 0], 0, 1))
+        return self._o(_paged_decode_attention(q[:, 0], kv, tables, pos),
+                       u), kv
+
+
+class Relu2MLP(nn.Layer):
+    """The shared expert: ``relu(x W_up)^2 W_down``, no gate, no bias."""
+
+    def __init__(self, cfg: HybridConfig):
+        super().__init__()
+        d, width, std = (cfg.hidden_size, cfg.shared_expert_intermediate_size,
+                         cfg.initializer_range)
+        self.up_proj = self.create_parameter(
+            [d, width], dtype=cfg.dtype, initializer=_normal(std),
+            sharding=("fsdp", "tp"))
+        self.down_proj = self.create_parameter(
+            [width, d], dtype=cfg.dtype, initializer=_normal(std),
+            sharding=("tp", "fsdp"))
+
+    def forward(self, x):
+        return expert_ffn(x, self.up_proj.astype(x.dtype),
+                          self.down_proj.astype(x.dtype), "relu2",
+                          jnp.matmul, jnp.matmul)
+
+
+class HybridBlock(nn.Layer):
+    """``x + Mixer(RMSNorm(x))``; ``kind`` is the block's pattern character."""
+
+    def __init__(self, cfg: HybridConfig, kind: str):
+        super().__init__()
+        self.kind = kind
+        self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
+                               dtype="float32")
+        if kind == "M":
+            self.mixer = Mamba2Mixer(cfg)
+        elif kind == "*":
+            self.mixer = NoPEAttention(cfg)
+        else:
+            self.mixer = MoELayer(
+                cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
+                top_k=cfg.num_experts_per_tok, capacity_factor=None,
+                dtype=cfg.dtype, scoring="sigmoid", select_bias=True,
+                norm_topk_prob=cfg.norm_topk_prob,
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                experts_held=cfg.experts_held, expert_act="relu2")
+            self.shared_expert = Relu2MLP(cfg)
+
+    def experts(self, u):
+        """The routed experts held here and the shared one, serving path:
+        (y, load [held] int32)."""
+        routed, load = self.mixer.forward_inference(u)
+        return routed + self.shared_expert(u), load
+
+    def forward(self, x):
+        """Whole sequences, no cache (the router's auxiliary loss is dropped:
+        not trained)."""
+        u = self.norm(x)
+        if self.kind != "E":
+            return x + self.mixer(u)
+        return x + self.mixer(u)[0] + self.shared_expert(u)
+
+
+class HybridForCausalLM(nn.Layer):
+    """The hybrid decoder with its embedding, final norm and (untied) head.
+    ``forward`` returns logits, or (loss, logits) given labels."""
+
+    attention_kind = "hybrid"       # what ``serving::prefill`` says of it
+
+    def __init__(self, cfg: HybridConfig):
+        super().__init__()
+        self.cfg = cfg
+        self.embed_tokens = self.create_parameter(
+            [cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
+            initializer=_normal(cfg.initializer_range),
+            sharding=("tp", "fsdp"))
+        self.layers = nn.LayerList([HybridBlock(cfg, kind)
+                                    for kind in cfg.kinds])
+        self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
+                               dtype="float32")
+        self.lm_head = self.create_parameter(
+            [cfg.hidden_size, cfg.vocab_size], dtype=cfg.dtype,
+            initializer=_normal(cfg.initializer_range),
+            sharding=("fsdp", "tp"))
+        # what a decode tick counts on the device (the engine adds them up
+        # into ``stats()``): rows x top-k routed, whoever holds the chosen
+        # expert; the most rows one HELD expert got; the choices that fell
+        # on a held expert; each summed over the expert layers
+        self.tick_counters = (("moe_assignments", "moe_peak_load",
+                               "moe_assignments_held")
+                              if "E" in cfg.kinds else ())
+
+    def logits(self, hidden):
+        return jnp.matmul(hidden, self.lm_head.astype(hidden.dtype))
+
+    def _kinds(self, kind: str):
+        return [layer for layer in self.layers if layer.kind == kind]
+
+    # -- serving path (inference.ContinuousBatchingEngine) -------------------
+
+    def alloc_paged_caches(self, batch: int, max_len: int,
+                           page_size: int = 128):
+        """(pools, tables): one pool entry for each ATTENTION layer, in
+        order (the other layers keep nothing a page), and the shared block
+        table."""
+        pages_per_seq = -(-max_len // page_size)
+        num_pages = batch * pages_per_seq
+        pools = [layer.mixer.alloc_pool(num_pages, page_size)
+                 for layer in self._kinds("*")]
+        return pools, jnp.arange(num_pages, dtype=jnp.int32).reshape(
+            batch, pages_per_seq)
+
+    def alloc_slot_state(self, slots: int):
+        """One entry for each MAMBA layer, in order, every leaf leading with
+        the slot; None for a pattern without one (the engine then keeps
+        nothing)."""
+        return ([layer.mixer.alloc_slot_state(slots)
+                 for layer in self._kinds("M")] or None)
+
+    def prefill_paged(self, input_ids, pools, tables, slot_state=None,
+                      slot=None, last_idx=None):
+        """The prompt of one sequence: (hidden, pools) and, given
+        ``slot_state``, the state with slot ``slot`` set from the prompt's
+        position ``last_idx``."""
+        x = jnp.take(self.embed_tokens, input_ids, axis=0)
+        pools, state = list(pools), list(slot_state or ())
+        n_attn = n_mamba = 0
+        for layer in self.layers:
+            u = layer.norm(x)
+            if layer.kind == "M":
+                y, state[n_mamba] = layer.mixer.prefill(
+                    u, state[n_mamba], slot, last_idx)
+                n_mamba += 1
+            elif layer.kind == "*":
+                y, pools[n_attn] = layer.mixer.prefill(u, pools[n_attn],
+                                                       tables)
+                n_attn += 1
+            else:
+                y, _ = layer.experts(u)
+            x = x + y
+        if slot_state is None:
+            return self.norm(x), pools
+        return self.norm(x), pools, state
+
+    def decode_step_paged(self, token_ids, pos, pools, tables,
+                          counters: bool = False, slot_state=None):
+        """token_ids [b] -> (hidden [b, 1, d], pools), then, with
+        ``counters``, the tick's ``tick_counters`` as int32 and, given
+        ``slot_state`` (its leaves [b, ..]: row i is sequence i's), the
+        rows' next state."""
+        x = jnp.take(self.embed_tokens, token_ids[:, None], axis=0)
+        pools, state = list(pools), list(slot_state or ())
+        n_attn = n_mamba = 0
+        routed = peak = held = 0
+        for layer in self.layers:
+            u = layer.norm(x)
+            if layer.kind == "M":
+                y, state[n_mamba] = layer.mixer.decode(u, state[n_mamba])
+                n_mamba += 1
+            elif layer.kind == "*":
+                y, pools[n_attn] = layer.mixer.decode(u, pos, pools[n_attn],
+                                                      tables)
+                n_attn += 1
+            else:
+                y, load = layer.experts(u)
+                routed += x.shape[0] * self.cfg.num_experts_per_tok
+                peak, held = peak + jnp.max(load), held + jnp.sum(load)
+            x = x + y
+        out = (self.norm(x), pools)
+        if counters:
+            out += (jnp.stack([jnp.int32(routed), peak, held]).astype(
+                jnp.int32),)
+        return out if slot_state is None else out + (state,)
+
+    def forward(self, input_ids, labels=None):
+        x = jnp.take(self.embed_tokens, input_ids, axis=0)
+        for layer in self.layers:
+            x = layer(x)
+        logits = self.logits(self.norm(x))
+        if labels is None:
+            return logits
+        from .llama import causal_lm_loss
+        return causal_lm_loss(logits, labels), logits

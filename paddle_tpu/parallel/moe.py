@@ -41,6 +41,7 @@ all-to-alls itself.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -223,31 +224,70 @@ def top_k_gating(gate_logits, k: int, capacity: int,
     return dispatch, combine, aux_loss
 
 
+EXPERT_ACTS = ("swiglu", "relu2")
+
+
+def expert_ffn(x, w_in, w_down, act: str, up, down):
+    """One expert feed-forward, whatever carries its two products:
+    ``down(hidden(up(x, w_in)), w_down)``. ``act`` "swiglu" reads the first
+    product as [gate | up] and gives ``silu(gate) * up``; "relu2" (non-gated:
+    ``w_in`` is the up-projection alone) gives ``relu(.)^2``. ``up`` and
+    ``down`` are the caller's products: a batched einsum over a dense
+    [e, slots, d] layout, a grouped matmul over sorted rows."""
+    pre = up(x, w_in)
+    if act == "swiglu":
+        g, u = jnp.split(pre, 2, axis=-1)
+        return down(F.silu(g) * u, w_down)
+    if act == "relu2":
+        return down(jnp.square(jax.nn.relu(pre)), w_down)
+    raise ValueError(f"expert_act must be one of {EXPERT_ACTS}, got {act!r}")
+
+
+def _dense_up(x, w):
+    return jnp.einsum("ecd,edf->ecf", x, w)
+
+
+def _dense_down(h, w):
+    return jnp.einsum("ecf,efd->ecd", h, w)
+
+
 class MoEMLP(Layer):
-    """Experts as batched weights [E, ...] — one einsum, not a python loop."""
+    """Experts as batched weights [E, ...] — one einsum, not a python loop.
+    ``act`` "swiglu": leaves ``w_gate_up`` [E, d, 2 f] and ``w_down``;
+    "relu2" (non-gated): ``w_up`` [E, d, f] and ``w_down``."""
 
     def __init__(self, num_experts: int, hidden_size: int, ffn_size: int,
-                 dtype=None):
+                 dtype=None, act: str = "swiglu"):
         super().__init__()
+        if act not in EXPERT_ACTS:
+            raise ValueError(f"expert_act must be one of {EXPERT_ACTS}, got "
+                             f"{act!r}")
+        self.act = act
         std = 0.02
         # the expert dim shards over ep first (real expert parallelism),
         # then the dp/fsdp data axes; _clean_spec drops "ep" on ep==1
         # meshes so pre-EP placements stay byte-identical
-        self.w_gate_up = self.create_parameter(
-            [num_experts, hidden_size, 2 * ffn_size], dtype=dtype,
-            initializer=I.Normal(0.0, std),
-            sharding=(("ep", "dp", "fsdp"), None, "tp"))
+        setattr(self, "w_gate_up" if act == "swiglu" else "w_up",
+                self.create_parameter(
+                    [num_experts, hidden_size,
+                     (2 if act == "swiglu" else 1) * ffn_size], dtype=dtype,
+                    initializer=I.Normal(0.0, std),
+                    sharding=(("ep", "dp", "fsdp"), None, "tp")))
         self.w_down = self.create_parameter(
             [num_experts, ffn_size, hidden_size], dtype=dtype,
             initializer=I.Normal(0.0, std),
             sharding=(("ep", "dp", "fsdp"), "tp", None))
 
+    @property
+    def w_in(self):
+        """The first product's weights: [gate | up] or the up-projection."""
+        return self.w_gate_up if self.act == "swiglu" else self.w_up
+
     def forward(self, x):
         # x: [e, c, d] -> [e, c, d]
-        gu = jnp.einsum("ecd,edf->ecf", x, self.w_gate_up.astype(x.dtype))
-        g, u = jnp.split(gu, 2, axis=-1)
-        h = F.silu(g) * u
-        return jnp.einsum("ecf,efd->ecd", h, self.w_down.astype(x.dtype))
+        return expert_ffn(x, self.w_in.astype(x.dtype),
+                          self.w_down.astype(x.dtype), self.act,
+                          _dense_up, _dense_down)
 
 
 def _constrain_experts(xe):
@@ -274,6 +314,64 @@ def xla_grouped_matmul(xs, w, group_sizes):
     accumulator dtype; callers cast back to the activation dtype."""
     return jax.lax.ragged_dot(xs, w, group_sizes,
                               preferred_element_type=jnp.float32)
+
+
+# rows a step of ``blocked_expert_rows`` takes: a [256, d] x [d, f] product
+# reads an expert's weights once for 256 rows (2 x 256 FLOPs a byte of bf16:
+# level with a v5e's 240), and a prompt's few hundred rows an expert are one
+# or two steps
+EXPERT_ROW_BLOCK = 256
+
+
+def ragged_dot_tiles_small(d: int, f: int) -> bool:
+    """Whether XLA:TPU's ``ragged_dot`` falls back to 128 x 128 x 128 tiles
+    for expert matrices [d, f] / [f, d]: it does when a width (of a tile or
+    more) is not a whole number of 256-lane tiles (2688 x 1856, chip run,
+    PR 33: a product over 13,824 sorted rows is ~35,000 tile steps of ~0.35
+    us, 12.4 ms where its FLOPs need 0.35 and its weights 0.39; at 2048 x
+    1536 and 2048 x 2048 it picks larger tiles and is the faster path)."""
+    return min(d, f) >= 256 and bool(d % 256 or f % 256)
+
+
+def blocked_expert_rows(xs, w_in, w_dn, act: str, load,
+                        block: int = EXPERT_ROW_BLOCK):
+    """The expert feed-forward over rows SORTED by expert (``load`` [e]: the
+    rows of each, in order; rows past their sum belong to none and come back
+    0), as a loop over blocks of ``block`` consecutive rows of ONE expert:
+    each step slices that expert's two matrices out of ``w_in`` / ``w_dn``
+    and runs plain products, so the MXU sees [block, d] x [d, f] whatever
+    the widths. A step's rows that run past its expert's (into the next
+    one's) are computed and not written. xs [m, d] -> [m, d_out] float32.
+    As many steps as the experts' rows need (a dynamic trip count: forward
+    only)."""
+    m, d = xs.shape
+    e = load.shape[0]
+    steps = -(-load // block)                                 # [e]
+    ends = jnp.cumsum(steps)
+    rows0 = jnp.cumsum(load) - load                           # first row of e
+    i = jnp.arange(-(-m // block) + e, dtype=jnp.int32)       # every step
+    owner = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), e - 1)
+    within = (i - (ends - steps)[owner]) * block              # rows before it
+    start = (rows0[owner] + within).astype(jnp.int32)
+    count = jnp.clip(load[owner] - within, 0, block).astype(jnp.int32)
+    xs = jnp.pad(xs, ((0, block), (0, 0)))      # a slice never runs off
+    dot = functools.partial(jnp.matmul, preferred_element_type=jnp.float32)
+
+    def step(j, out):
+        w1 = jax.lax.dynamic_index_in_dim(w_in, owner[j], keepdims=False)
+        w2 = jax.lax.dynamic_index_in_dim(w_dn, owner[j], keepdims=False)
+        x = jax.lax.dynamic_slice_in_dim(xs, start[j], block)
+        y = expert_ffn(x, w1, w2, act,
+                       lambda a, w: dot(a, w).astype(xs.dtype), dot)
+        old = jax.lax.dynamic_slice_in_dim(out, start[j], block)
+        keep = (jnp.arange(block) < count[j])[:, None]
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.where(keep, y, old), start[j], 0)
+
+    out = jax.lax.fori_loop(
+        0, ends[-1], step,
+        jnp.zeros((m + block, w_dn.shape[-1]), jnp.float32))
+    return out[:m]
 
 
 def _int_zero(a):
@@ -388,9 +486,7 @@ dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 def _expert_ffn(xe, w_gu, w_dn):
     """The per-expert SwiGLU on a dense [e_local, slots, d] layout —
     MoEMLP.forward's math on raw (shard_map-local) weight shards."""
-    gu = jnp.einsum("ecd,edf->ecf", xe, w_gu)
-    g, u = jnp.split(gu, 2, axis=-1)
-    return jnp.einsum("ecf,efd->ecd", F.silu(g) * u, w_dn)
+    return expert_ffn(xe, w_gu, w_dn, "swiglu", _dense_up, _dense_down)
 
 
 def _aux_loss_ep(probs, e):
@@ -445,21 +541,35 @@ class MoELayer(Layer):
     The weights are the softmax's own probabilities. Like the other
     variants: dropless and inference paths only.
 
+    ``experts_held=(first, count)`` builds the layer of ONE chip of an
+    expert-parallel deployment: the router stays ``num_experts`` wide and
+    the choice and the renormalisation run over all of them, the expert
+    leaves are ``[count, ...]`` (experts ``first .. first + count - 1``),
+    a row's choices that fall on an expert held elsewhere add nothing here
+    (their outputs are the other chips' to add), and ``load`` is over the
+    held experts. Nothing stands in for the absent chips or their traffic.
+    ``expert_act`` "swiglu" | "relu2" (``expert_ffn``: non-gated experts
+    ``relu(x W_up)^2 W_down``, leaves ``w_up`` / ``w_down``). Both on the
+    dropless and the inference paths only, like the router's variants.
+
     ``forward_inference`` (what ``forward`` runs once the layer is in eval
     mode) computes no auxiliary loss and is dropless at any load.
     """
 
-    # rows at or below which the inference path runs EVERY expert over
-    # every row and weights the outputs (0 where an expert was not chosen)
-    # instead of sorting rows to their experts, once the rows' choices
-    # outnumber the experts: a decode tick's few rows then hit nearly every
-    # expert anyway (64 rows x top-4 over 64 experts: 62.97 expected), the
-    # products are bound by the experts' weights streaming from HBM either
-    # way (e x 6 d f bytes against t x e x 6 d f FLOPs: level at t =
+    # rows at or below which the inference path runs EVERY expert held
+    # over every row and weights the outputs (0 where an expert was not
+    # chosen) instead of sorting rows to their experts, once the rows'
+    # choices that fall on the held experts are expected to outnumber them:
+    # a decode tick's few rows then hit nearly every held expert anyway (64
+    # rows x top-4 over 64 experts, all held: 62.97 expected), the products
+    # are bound by the held experts' weights streaming from HBM either way
+    # (held x 6 d f bytes against t x held x 6 d f FLOPs: level at t =
     # 197e12 / 819e9 = 240 rows on a v5e), and a plain batched matmul needs
     # no sort, no gather and no per-group tiles: 1.67 ms a layer against
-    # ragged_dot's 2.36 at GLM-4.7-Flash's sizes (chip run, PR 27)
-    DENSE_ROWS = 128
+    # ragged_dot's 2.36 at GLM-4.7-Flash's sizes (chip run, PR 27). Up to
+    # that level, whole: at 192 rows x top-6 over 64 held experts of 2688 x
+    # 1856 a tick read 25.75 ms this way and 122.6 sorted (chip run, PR 33)
+    DENSE_ROWS = 240
 
     def __init__(self, hidden_size: int, ffn_size: int, num_experts: int,
                  top_k: int = 2, capacity_factor: Optional[float] = 1.25,
@@ -469,7 +579,9 @@ class MoELayer(Layer):
                  routed_scaling_factor: float = 1.0,
                  router: str = "linear",
                  router_hidden_size: Optional[int] = None,
-                 skip_choice: bool = False):
+                 skip_choice: bool = False,
+                 experts_held: Optional[tuple] = None,
+                 expert_act: str = "swiglu"):
         super().__init__()
         if router not in ("linear", "mlp"):
             raise ValueError(f"router must be 'linear' or 'mlp', got "
@@ -510,16 +622,28 @@ class MoELayer(Layer):
                 [num_experts], dtype="float32", initializer=I.Constant(0.0))
         else:
             self.add_parameter("gate_bias", None)
+        first, count = experts_held or (0, num_experts)
+        if not (0 <= first and 0 < count and first + count <= num_experts):
+            raise ValueError(f"experts_held={experts_held!r} names experts "
+                             f"outside 0..{num_experts - 1}")
+        self.first_held, self.num_held = int(first), int(count)
+        # the router and the experts the capacity and expert-parallel paths
+        # were written for: GShard's softmax over every expert, all of them
+        # held, SwiGLU
         self._gshard_router = (scoring == "softmax" and not select_bias
                                and self.renormalize == (self.top_k > 1)
                                and self.routed_scaling_factor == 1.0
-                               and router == "linear")
+                               and router == "linear"
+                               and self.num_held == num_experts
+                               and expert_act == "swiglu")
         if not self._gshard_router and capacity_factor is not None:
             raise ValueError(
                 "scoring / select_bias / norm_topk_prob / "
-                "routed_scaling_factor / router='mlp' run on the dropless "
-                "path only: pass capacity_factor=None")
-        self.experts = MoEMLP(num_experts, hidden_size, ffn_size, dtype=dtype)
+                "routed_scaling_factor / router='mlp' / experts_held / "
+                "expert_act run on the dropless path only: pass "
+                "capacity_factor=None")
+        self.experts = MoEMLP(self.num_held, hidden_size, ffn_size,
+                              dtype=dtype, act=expert_act)
 
     def _make_mlp_router(self, hidden_size: int, r: int, outputs: int):
         """The MLP router's leaves, all float32: the down-projection, the
@@ -589,6 +713,17 @@ class MoELayer(Layer):
             gates = gates * self.routed_scaling_factor
         return gates
 
+    def _held(self, ids):
+        """The chosen experts as this layer's own indices
+        0 .. num_held - 1, and ``num_held`` for a choice held elsewhere (or
+        the skip choice): such a row sorts behind every held expert's run,
+        belongs to no group and is dropped by a bounded scatter."""
+        if self.num_held == self.num_experts:
+            return ids
+        local = ids - self.first_held
+        return jnp.where((local >= 0) & (local < self.num_held), local,
+                         self.num_held)
+
     def routing_histogram(self, x):
         """Measured per-expert token counts for ``x`` — the histogram
         the planner's entropy-priced all-to-all consumes
@@ -616,7 +751,8 @@ class MoELayer(Layer):
                 raise NotImplementedError(
                     "the expert-parallel paths route with the GShard "
                     "router only (softmax, one matrix, no selection bias, "
-                    "no scale)")
+                    "no scale) over SwiGLU experts all held here (no "
+                    "experts_held, no expert_act)")
             if self.capacity_factor is None:
                 out, aux = self._forward_dropless_ep(flat, hm.mesh, ep)
             else:
@@ -716,9 +852,8 @@ class MoELayer(Layer):
                                      tiled=True)          # [e_l, ep*cap, d]
             rows = buf.reshape(e_l * ep * cap, d)
             gsz = jnp.full((e_l,), ep * cap, jnp.int32)
-            gu = grouped_matmul(rows, wgu, gsz)
-            g, u = jnp.split(gu, 2, axis=-1)
-            ys = grouped_matmul(F.silu(g) * u, wdn, gsz)
+            gmm = lambda a, w: grouped_matmul(a, w, gsz)
+            ys = expert_ffn(rows, wgu, wdn, "swiglu", gmm, gmm)
             ybuf = jax.lax.all_to_all(ys.reshape(e_l, ep * cap, d), "ep",
                                       split_axis=1, concat_axis=0,
                                       tiled=True)             # [e, cap, d]
@@ -753,66 +888,77 @@ class MoELayer(Layer):
         count as 0; the MLP router trains with no auxiliary term."""
         t, d = flat.shape
         e, k = self.num_experts, self.top_k
+        held = self.num_held
         probs, gates, ids = routing                           # [t, k]
+        ids = self._held(ids)
         flat_e = ids.T.reshape(-1)                            # [k*t]
         order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
         inv = inverse_permutation(order)
-        group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
+        group_sizes = jnp.bincount(flat_e, length=held).astype(jnp.int32)
         xs = dispatch_rows(flat, order, inv)                  # [k*t, d]
 
-        w_gu = self.experts.w_gate_up.astype(flat.dtype)      # [e, d, 2f]
-        w_dn = self.experts.w_down.astype(flat.dtype)         # [e, f, d2]
+        w_in = self.experts.w_in.astype(flat.dtype)       # [held, d, (2)f]
+        w_dn = self.experts.w_down.astype(flat.dtype)     # [held, f, d2]
 
-        gu = grouped_matmul(xs, w_gu, group_sizes)
-        g, u = jnp.split(gu, 2, axis=-1)
-        h = F.silu(g) * u
-        ys = grouped_matmul(h, w_dn, group_sizes)
+        gmm = lambda a, w: grouped_matmul(a, w, group_sizes)
+        ys = expert_ffn(xs, w_in, w_dn, self.experts.act, gmm, gmm)
 
         # unsort to choice-major, weight, reduce over k
         y_cm = permute_rows(ys, inv, order).reshape(k, t, d)
         g_km = self._weights(gates.T, 0)                      # [k, t]
-        if self.skip_choice:
-            y_cm = jnp.where((ids.T < e)[..., None], y_cm, 0)
+        if self.skip_choice or held < e:
+            y_cm = jnp.where((ids.T < held)[..., None], y_cm, 0)
         out = jnp.sum(g_km[..., None].astype(ys.dtype) * y_cm, axis=0)
         return out, (jnp.zeros((), jnp.float32) if self.router == "mlp"
                      else _aux_loss(probs, e))
 
     def forward_inference(self, x, router_state=None):
         """The routed block without a loss: x [b, s, d] -> (out [b, s, d],
-        load [e] int32: the rows each expert was sent; rows x top-k less
-        its sum chose the skip). Dropless at any load. At most
-        ``DENSE_ROWS`` rows whose choices outnumber the experts run every
-        expert over every row as one batched matmul; the rest are sorted
-        to their experts and go through XLA's ``ragged_dot``
+        load [num_held] int32: the rows each expert held here was sent;
+        rows x top-k less its sum chose the skip or an expert held
+        elsewhere). Dropless at any load. At most ``DENSE_ROWS`` rows whose
+        choices are expected to outnumber the held experts they fall on run
+        every held expert over every row as one batched matmul; the rest
+        are sorted to their experts and go through XLA's ``ragged_dot``
         (``xla_grouped_matmul``)."""
         b, s, d = x.shape
         t, e, k = b * s, self.num_experts, self.top_k
+        held, act = self.num_held, self.experts.act
         flat = x.reshape(t, d)
         _, gates, ids = self._route(flat, router_state)
         gates = self._weights(gates, -1)                      # [t, k]
-        load = jnp.bincount(ids.reshape(-1), length=e).astype(jnp.int32)
-        w_gu = self.experts.w_gate_up.astype(flat.dtype)      # [e, d, 2f]
-        w_dn = self.experts.w_down.astype(flat.dtype)         # [e, f, d]
-        if t <= self.DENSE_ROWS and t * k >= e:
-            # weight [t, e]: an expert's share of a row, 0 if not chosen
-            weight = jnp.zeros((t, e), jnp.float32).at[
+        ids = self._held(ids)
+        load = jnp.bincount(ids.reshape(-1), length=held).astype(jnp.int32)
+        w_in = self.experts.w_in.astype(flat.dtype)       # [held, d, (2)f]
+        w_dn = self.experts.w_down.astype(flat.dtype)     # [held, f, d]
+        hits = t * k * held // e    # choices expected on the held experts
+        if t <= self.DENSE_ROWS and hits >= held:
+            # weight [t, held]: an expert's share of a row, 0 if not chosen
+            weight = jnp.zeros((t, held), jnp.float32).at[
                 jnp.arange(t)[:, None], ids].add(gates, mode="drop")
-            gu = jnp.einsum("etd,edf->etf",
-                            jnp.broadcast_to(flat[None], (e, t, d)), w_gu,
-                            preferred_element_type=jnp.float32)
-            g, u = jnp.split(gu, 2, axis=-1)
-            h = (F.silu(g) * u * weight.T[..., None]).astype(flat.dtype)
-            out = jnp.einsum("etf,efd->td", h, w_dn,
-                             preferred_element_type=jnp.float32)
+            out = expert_ffn(
+                jnp.broadcast_to(flat[None], (held, t, d)), w_in, w_dn, act,
+                lambda a, w: jnp.einsum(
+                    "etd,edf->etf", a, w,
+                    preferred_element_type=jnp.float32),
+                lambda h, w: jnp.einsum(
+                    "etf,efd->td",
+                    (h * weight.T[..., None]).astype(flat.dtype), w,
+                    preferred_element_type=jnp.float32))
             return out.astype(x.dtype).reshape(b, s, d), load
         flat_e = ids.T.reshape(-1)                            # [k*t]
         order = jnp.argsort(flat_e, stable=True)
         xs = flat[order % t]                                  # [k*t, d]
-        gu = xla_grouped_matmul(xs, w_gu, load).astype(flat.dtype)
-        g, u = jnp.split(gu, 2, axis=-1)
-        ys = xla_grouped_matmul(F.silu(g) * u, w_dn, load)    # f32
+        if ragged_dot_tiles_small(d, w_dn.shape[1]):
+            ys = blocked_expert_rows(xs, w_in, w_dn, act, load)
+        else:
+            ys = expert_ffn(
+                xs, w_in, w_dn, act,
+                lambda a, w: xla_grouped_matmul(a, w, load).astype(
+                    flat.dtype),
+                lambda h, w: xla_grouped_matmul(h, w, load))  # f32
         y_cm = jnp.zeros_like(ys).at[order].set(ys).reshape(k, t, d)
-        if self.skip_choice:
-            y_cm = jnp.where((ids.T < e)[..., None], y_cm, 0)
+        if self.skip_choice or held < e:
+            y_cm = jnp.where((ids.T < held)[..., None], y_cm, 0)
         out = jnp.sum(gates.T[..., None] * y_cm, axis=0)
         return out.astype(x.dtype).reshape(b, s, d), load

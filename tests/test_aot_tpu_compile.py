@@ -117,6 +117,18 @@ def _latent_decode():
                 ((64, 24), I32), ((64,), I32)]
 
 
+def _ssm_update(rows=192, h=64, p=64, n=128, g=8):
+    """Nemotron-3-Nano's decode tick, one Mamba-2 layer: every slot's
+    64 x [64, 128] float32 state as 32 tiles [128, 2 x 64], one slot's
+    2 MB a grid step."""
+    from paddle_tpu.ops.pallas.ssm import ssm_state_update
+    r = 128 // p
+    return ssm_state_update, [((rows, h // r, n, r * p), F32),
+                              ((rows, h, p), BF16),
+                              ((rows, h), F32), ((h,), F32),
+                              ((rows, g, n), BF16), ((rows, g, n), BF16)]
+
+
 def _rms_norm():
     from paddle_tpu.ops.pallas.fused_norm import rms_norm_pallas
     return (_grad_sum(lambda x, w: rms_norm_pallas(x, w, 1e-5), (0, 1)),
@@ -178,6 +190,11 @@ ONE_CHIP = [
                  id="paged_decode[bf16,128x8/2,24pages]"),
     pytest.param(lambda: _paged(128, BF16, rows=32, per_seq=16),
                  id="paged_decode[bf16,32x32/8,16pages]"),
+    # nemotron-3-nano.agent-turns: 192 rows, 32 query / 2 KV heads (16 a KV
+    # head: a group no other cell runs), 40-page tables
+    pytest.param(lambda: _paged(128, BF16, rows=192, h=32, h_kv=2, per_seq=40),
+                 id="paged_decode[bf16,192x32/2,40pages]"),
+    pytest.param(_ssm_update, id="ssm_state_update[192x64x64x128]"),
     pytest.param(_rms_norm, id="rms_norm[D4096]"),
     pytest.param(_rope, id="rope[s2048,32/8]"),
     pytest.param(lambda: _fused_ce(16384, 4096, 128256),
@@ -246,6 +263,40 @@ def test_a_cca_decode_layer_compiles_around_the_paged_kernel(topo, monkeypatch):
                                             (128, 128)]
     # the pools are written in place: no copy of a pool in the program
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_a_mamba_decode_layer_updates_its_state_in_place(topo, monkeypatch):
+    """One Mamba-2 layer of Nemotron-3-Nano's decode tick at 192 slots: the
+    state update is the one Mosaic call, the donated state (403 MB of
+    float32 and 7 MB of convolution inputs) comes back in the buffers it
+    went in by, and the program keeps no second copy of it (one more and
+    the cell's tick would not fit beside 10.6 GB of weights)."""
+    from paddle_tpu.models.hybrid_lm import HybridConfig, Mamba2Mixer
+    from paddle_tpu.ops import registry
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
+    mixer = Mamba2Mixer(HybridConfig(pattern="M", dtype="bfloat16"))
+
+    def step(p, u, state):
+        with mixer._bind(p):
+            return mixer.decode(u, state)
+    dev = SingleDeviceSharding(topo.devices[0])
+    abstract = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev), t)
+    state = jax.eval_shape(lambda: mixer.alloc_slot_state(192))
+    args = abstract((mixer.raw_parameters(),
+                     jax.ShapeDtypeStruct((192, 1, 2688), BF16), state))
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+    calls = _mosaic_calls(compiled.as_text())
+    assert calls and all("ssm_state_update" in c for c in calls), calls
+    out, new_state = jax.eval_shape(step, *args)
+    assert out.shape == (192, 1, 2688)
+    assert [(a.shape, a.dtype) for a in new_state] == [
+        ((192, 3, 6144), BF16), ((192, 32, 128, 128), F32)]
+    mem = compiled.memory_analysis()
+    state_bytes = 192 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
 
 
 @pytest.fixture(scope="module")

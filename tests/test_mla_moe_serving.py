@@ -216,14 +216,14 @@ def test_the_training_and_the_sorted_inference_paths_agree(router):
     ``ragged_dot`` over the same sorted rows, so the same weights give the
     same output."""
     layer = _router_layer(**router)
-    x = jax.random.normal(jax.random.key(6), (2, 80, 16))
+    x = jax.random.normal(jax.random.key(6), (2, 160, 16))
     assert x.shape[0] * x.shape[1] > MoELayer.DENSE_ROWS
     layer.train()
     trained, aux = layer(x)
     layer.eval()
     served, load = layer.forward_inference(x)
     assert np.abs(np.asarray(trained) - np.asarray(served)).max() < 1e-5
-    assert float(aux) > 0 and int(np.asarray(load).sum()) == 2 * 80 * 2
+    assert float(aux) > 0 and int(np.asarray(load).sum()) == 2 * 160 * 2
 
 
 def test_a_layer_with_no_new_argument_runs_the_parents_program():

@@ -222,3 +222,47 @@ def test_serving_spans_exported_to_chrome_trace(model, tmp_path):
         events = {e["name"] for e in json.load(f)["traceEvents"]}
     missing = set(profiler.SERVING_EVENTS) - events
     assert not missing, f"spans absent from chrome trace: {missing}"
+
+
+def test_cancel_with_a_block_in_flight_keeps_the_other_streams_emissions(
+        model):
+    """``cancel`` drains the blocks in flight outside ``step()``: the
+    tokens the OTHER slots commit in that drain are emitted by the next
+    ``step()``, so a consumer that streams by emission (the fabric's front
+    door) sees every token of a stream once and none of the cancelled one."""
+    rs = np.random.RandomState(5)
+    vocab = model.cfg.vocab_size
+    eng = ContinuousBatchingEngine(
+        model, max_batch=2, page_size=PAGE, max_len=64,
+        generation_config=GenerationConfig(max_new_tokens=12,
+                                           do_sample=False),
+        async_depth=2)
+    # a block stays in flight after every step, however fast the device
+    eng._block_ready = lambda blk: False
+    gone = eng.submit(_mk_prompt(rs, 6, vocab))
+    kept = eng.submit(_mk_prompt(rs, 7, vocab))
+    streamed = {gone: [], kept: []}
+
+    def step():
+        for rid, tok in eng.step():
+            streamed[rid].append(int(tok))
+
+    while len(streamed[kept]) < 3:
+        step()
+    assert eng._inflight            # a dispatched block not yet drained
+    before = len(streamed[gone])
+    assert eng.cancel(gone)
+    assert eng._held_emitted and all(r == kept for r, _ in eng._held_emitted)
+    while eng.has_work():
+        step()
+    assert len(streamed[gone]) == before
+    np.testing.assert_array_equal(streamed[kept],
+                                  eng.take_finished()[kept])
+    assert len(streamed[kept]) == 12
+    # a stream cancelled next has no token held from the first cancel's drain
+    a, b = (eng.submit(_mk_prompt(rs, n, vocab)) for n in (5, 6))
+    streamed.update({a: [], b: []})
+    while len(streamed[b]) < 2:
+        step()
+    assert eng._inflight and eng.cancel(a) and eng._held_emitted
+    assert eng.cancel(b) and not eng._held_emitted and eng.step() == []

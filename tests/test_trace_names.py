@@ -337,6 +337,14 @@ def _latent():
         jnp.zeros((2, 4, 160)),)
 
 
+def _ssm():
+    from paddle_tpu.ops.pallas.ssm import ssm_state_update
+    rest = (jnp.ones((2, 8, 8)), jnp.ones((2, 8)), -jnp.ones((8,)),
+            jnp.ones((2, 2, 128)), jnp.ones((2, 2, 128)))
+    return (lambda s: ssm_state_update(s, *rest, interpret=True)[0]), (
+        jnp.zeros((2, 2, 128, 32)),)
+
+
 @pytest.mark.parametrize("entry,expect", [
     (_flash, ["flash_attention_fwd", "flash_attention_bwd_dq",
               "flash_attention_bwd_dkv"]),
@@ -347,6 +355,7 @@ def _latent():
     (_int8, ["int8_matmul"]),
     (_paged, ["paged_attention_decode"]),
     (_latent, ["latent_attention_decode"]),
+    (_ssm, ["ssm_state_update"]),
 ], ids=lambda v: v.__name__.strip("_") if callable(v) else None)
 def test_a_pallas_entry_point_names_its_kernels(entry, expect):
     """Forward and gradient: every pallas_call in the traced program carries
@@ -410,13 +419,14 @@ def test_xla_own_share_reads_what_is_neither_a_kernel_nor_an_expert_product():
     # every kernel a training step can run is named in the pattern
     for kernel in pallas_ops.KERNEL_NAMES:
         served_only = kernel in ("paged_attention_decode", "int8_matmul",
-                                 "latent_attention_decode")
+                                 "latent_attention_decode",
+                                 "ssm_state_update")
         assert bool(rx.search(f"%jvp_{kernel}_.1 = " + tail)) == served_only
 
 
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
-    assert len(names) == len(set(names)) == 12
+    assert len(names) == len(set(names)) == 13
 
 
 # -- request timelines --------------------------------------------------------

@@ -24,6 +24,7 @@ backend with one tiny case in Pallas interpret mode (no timings recorded).
 Usage:
     python tools/tune_kernels.py [--quick] [--out PATH] [--write-shipped]
     python tools/tune_kernels.py --paged-decode
+    python tools/tune_kernels.py --ssm-update
     python tools/tune_kernels.py --interpret
 """
 
@@ -252,6 +253,54 @@ def bench_paged_decode(interpret, B=32, H=32, H_kv=8, per_seq=16,
     return results
 
 
+def bench_ssm_update(interpret, B=192, H=64, P=64, N=128, G=8, steps=64):
+    """The Mamba-2 state update (``ops/pallas/ssm.py``) against its ``jnp``
+    twin at a serving cell's shape (``nemotron-3-nano.agent-turns``: 192
+    slots of 64 heads' [64, 128] float32), ``steps`` calls chained inside ONE
+    program with the state carried and donated, as the tick carries it:
+    device time a call beside the time its bytes take (the state once each
+    way) at the chip's HBM rate."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas.ssm import (pack_state, ssm_state_update,
+                                           ssm_state_update_xla)
+    kind = getattr(jax.devices()[0], "device_kind", "cpu")
+    if interpret:
+        B, H, P, G, steps = 2, 8, 8, 2, 2
+    k = jax.random.split(jax.random.key(0), 5)
+    x = jax.random.normal(k[0], (B, H, P)).astype(jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (B, H)))
+    a = -jnp.exp(0.02 * jax.random.normal(k[2], (H,)))
+    b = jax.random.normal(k[3], (B, G, N)).astype(jnp.bfloat16)
+    c = jax.random.normal(k[4], (B, G, N)).astype(jnp.bfloat16)
+    impls = {"pallas": functools.partial(ssm_state_update,
+                                         interpret=interpret),
+             "xla": ssm_state_update_xla}
+
+    def chained(fn):
+        def run(state, x):
+            def body(carry, _):
+                state, x = carry
+                y, state = fn(state, x, dt, a, b, c)
+                return (state, (0.5 * x + 0.01 * y.astype(x.dtype))), None
+            return jax.lax.scan(body, (state, x), None, length=steps)[0]
+        return jax.jit(run, donate_argnums=(0,))
+
+    line = {"bench": "ssm_state_update", "device": kind, "steps": steps,
+            "shape": f"b{B}_h{H}_p{P}_n{N}_g{G}",
+            "bytes_us": round(2 * B * H * P * N * 4 / 819e3, 1)}
+    for name, fn in impls.items():
+        run = chained(fn)
+
+        def once(x):        # a fresh state a call: the last one was donated
+            return run(pack_state(jnp.zeros((B, H, P, N), jnp.float32), G),
+                       x)
+        t = _time_fn(once, x, iters=1, warmup=1, reps=3)
+        line[f"{name}_us"] = round(t / steps * 1e6, 1)
+    print(json.dumps(line), flush=True)
+    return [line]
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
@@ -261,6 +310,8 @@ def main():
                     help="write results into this JSON file instead")
     ap.add_argument("--paged-decode", action="store_true",
                     help="only the paged-decode kernel-vs-XLA comparison")
+    ap.add_argument("--ssm-update", action="store_true",
+                    help="only the Mamba-2 state update against its twin")
     ap.add_argument("--interpret", action="store_true",
                     help="validate the sweep machinery in Pallas interpret "
                          "mode (any backend, nothing recorded)")
@@ -272,6 +323,11 @@ def main():
         from paddle_tpu.ops.registry import require_tpu
         configure_compilation_cache()
         require_tpu()
+
+    if args.ssm_update:
+        results = bench_ssm_update(interpret)
+        print(json.dumps({"tuned": False, "cases": len(results)}))
+        return
 
     if args.paged_decode:
         # mistral-7b.*'s call, zaya1-8b.reasoning's, and a table four
