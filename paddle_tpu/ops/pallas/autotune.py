@@ -147,9 +147,12 @@ def fused_vocab_ce_config(n: int, h: int, v: int,
     """(block_n, block_v) for a fused vocab-CE call (ops/pallas/
     fused_vocab_ce.py): tuned if the DB has this (bucketed) shape on this
     device, else VMEM-fitting defaults. ``block_n`` comes back None when no
-    candidate divides N — the caller falls through to the XLA path. The dW
-    backward kernel's fp32 [H, block_v] accumulator is the VMEM pacer, so
-    the default block_v shrinks as H grows."""
+    candidate divides N — the caller falls through to the XLA path. What
+    paces VMEM is the larger of the dhidden kernel's fp32 [block_n, H]
+    blocks (its cross-slab sum in and out, double-buffered) and the dW
+    kernel's fp32 [H, block_v] accumulator, so the default block_v shrinks
+    as H grows; the dW kernel's own row block follows from the same formula
+    (``fused_vocab_ce.dw_block_n``)."""
     from ..registry import backend_kind
     key = TuneDB.key("fused_vocab_ce", _device_kind(), dtype,
                      h=h, v=v, sn=n)

@@ -11,7 +11,8 @@ module computes
     loss = CE(hidden @ W, labels)
 
 blockwise over the vocab dimension so the logits tensor NEVER materializes:
-peak loss-head memory drops from O(N*V) to O(N*block_v) with N = B*S.
+peak loss-head memory drops from O(N*V) to O(N*block_v) with N = B*S (the
+Pallas backward: a bounded slab of blocks, below).
 
 Design:
 
@@ -25,16 +26,28 @@ Design:
   denominator s — the flash-attention recurrence applied to the class dim)
   plus a masked target-logit accumulation, fp32 throughout.
 - Backward (custom_vjp): RECOMPUTES each block's logits from the saved
-  per-row lse — softmax p = exp(logits - lse) — and accumulates
-  ``dhidden += dlog @ W_j^T`` and ``dW_j = hidden^T @ dlog`` with
-  ``dlog = g_lse * p + g_tgt * onehot``. One extra blockwise matmul versus
-  the naive backward buys O(block) memory.
+  per-row lse — softmax p = exp(logits - lse) — forms the block's logits
+  cotangent ``dlog = g_lse * p + g_tgt * onehot`` ONCE and feeds both
+  ``dhidden += dlog @ W_j^T`` and ``dW_j = hidden^T @ dlog`` from it: three
+  products, four a step with the forward's, one more than a backward that
+  kept the logits.
 - Two interchangeable implementations behind one numerics contract:
   a Pallas TPU kernel set (forward; dhidden; dW — each streaming vocab
-  blocks through VMEM with fp32 scratch accumulators) and a pure-XLA
-  ``lax.scan`` over vocab blocks that keeps the same O(block) memory on
-  CPU/GPU and is the test oracle. ``ops/pallas/autotune.py`` picks block
-  sizes (TuneDB-consulted like flash_attention).
+  blocks through VMEM with fp32 accumulators) and a pure-XLA ``lax.scan``
+  over vocab blocks that keeps the same O(block) memory on CPU/GPU and is
+  the test oracle. ``ops/pallas/autotune.py`` picks block sizes
+  (TuneDB-consulted like flash_attention).
+- The Pallas backward hands ``dlog`` from the dhidden kernel to the dW
+  kernel through HBM, rounded to W's dtype as both products take it, ONE
+  SLAB of vocab blocks at a time (all rows of ``per_slab * block_v``
+  columns, at most SLAB_BYTES: all of it would be the logits tensor again).
+  One ``lax.scan`` runs the two kernels a slab. What the slabs cost: a
+  slab's dW columns are finished by one call, but dhidden sums over ALL
+  columns, so it crosses slabs as a float32 [N, H] array the dhidden kernel
+  reads and writes in place (``input_output_aliases``) once a slab; the
+  slab itself is written once and read once; a split that is not even
+  leaves dead grid steps in the last slab (no product, no fetch). Peak
+  memory is O(N * block_v * per_slab) + one float32 [N, H].
 
 Vocab not divisible by the block size: W is padded to the block multiple
 and padded columns are masked to NEG_INF inside the kernels (their softmax
@@ -76,6 +89,12 @@ VMEM_LIMIT = 48 * 2 ** 20
 # what kernel_vmem_bytes may reach: two thirds of the limit, the rest is
 # Mosaic's own (relayouts, spills) that the estimate does not itemize
 VMEM_BUDGET = 32 * 2 ** 20
+# what one slab of stored logit cotangents may take in HBM (the backward
+# keeps ONE alive): a few thousand vocab columns of all rows at a training
+# step's row count, the whole vocabulary at a small one. On a v5e at OLMoE's
+# shape 256, 384 and 512 MiB read the same kernel times (PERF.md section 6,
+# PR 35: the sum that crosses slabs hides in the pipeline), so the least
+SLAB_BYTES = 256 * 2 ** 20
 
 
 def _tpu_params(*semantics):
@@ -246,121 +265,155 @@ def _fwd_pallas(h, w, labels, block_n, block_v, interpret):
     return out[0][:, 0], out[1][:, 0]
 
 
-def _dlog_block(h, wb, lab_ref, lse_ref, glse_ref, gtgt_ref, vi, vocab,
-                block_v):
-    """Recompute one [bn, bv] softmax block from the saved lse and form the
-    logits cotangent dlog = g_lse * p + g_tgt * onehot (shared by the
-    dhidden and dW backward kernels)."""
-    logits = jax.lax.dot_general(
-        h, wb, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    cols = vi * block_v + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    logits = jnp.where(cols < vocab, logits, NEG_INF)
-    p = jnp.where(logits <= NEG_INF * 0.5, 0.0,
-                  jnp.exp(logits - lse_ref[:, :1]))
-    return glse_ref[:, :1] * p + jnp.where(cols == lab_ref[:, :1],
-                                           gtgt_ref[:, :1], 0.0)
+def _slab_block(slab_ref, vi, per_slab, n_blocks):
+    """The vocab block a slab's ``vi``-th step stands for, and whether it
+    exists: the last slab of a ragged split runs past the last block."""
+    vb = slab_ref[0] * per_slab + vi
+    return vb, vb < n_blocks
 
 
-def _bwd_dh_kernel(h_ref, w_ref, lab_ref, lse_ref, glse_ref, gtgt_ref,
-                   dh_ref, acc_scr, *, vocab, block_v):
-    """Grid (nN, nV): accumulate dhidden over vocab blocks."""
+def _bwd_dh_kernel(slab_ref, h_ref, w_ref, lab_ref, lse_ref, glse_ref,
+                   gtgt_ref, dh_in_ref, dh_ref, dlog_ref, *, vocab, block_v,
+                   per_slab, n_blocks):
+    """Grid (nN, per_slab) over ONE slab of vocab blocks: recompute a block's
+    softmax from the saved lse, form the logits cotangent
+    ``dlog = g_lse * p + g_tgt * onehot`` ONCE, write it out (rounded as the
+    products take it) for the dW kernel and add ``dlog @ W_j^T`` to the
+    float32 dhidden the slabs before this one left (aliased in and out)."""
     vi = pl.program_id(1)
-    nv = pl.num_programs(1)
+    vb, live = _slab_block(slab_ref, vi, per_slab, n_blocks)
 
     @pl.when(vi == 0)
-    def _init():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def _carry_in():
+        dh_ref[...] = dh_in_ref[...]
 
-    wb = w_ref[...]
-    dlog = _dlog_block(h_ref[...], wb, lab_ref, lse_ref, glse_ref, gtgt_ref,
-                       vi, vocab, block_v)
-    acc_scr[:] += jax.lax.dot_general(
-        dlog.astype(wb.dtype), wb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    @pl.when(live)
+    def _block():
+        wb = w_ref[...]
+        logits = jax.lax.dot_general(
+            h_ref[...], wb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        cols = vb * block_v + jax.lax.broadcasted_iota(jnp.int32,
+                                                       logits.shape, 1)
+        logits = jnp.where(cols < vocab, logits, NEG_INF)
+        p = jnp.where(logits <= NEG_INF * 0.5, 0.0,
+                      jnp.exp(logits - lse_ref[:, :1]))
+        dlog = (glse_ref[:, :1] * p
+                + jnp.where(cols == lab_ref[:, :1], gtgt_ref[:, :1], 0.0)
+                ).astype(wb.dtype)
+        dlog_ref[...] = dlog
+        dh_ref[...] += jax.lax.dot_general(
+            dlog, wb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(vi == nv - 1)
-    def _finalize():
-        dh_ref[...] = acc_scr[:].astype(dh_ref.dtype)
 
-
-def _bwd_dw_kernel(h_ref, w_ref, lab_ref, lse_ref, glse_ref, gtgt_ref,
-                   dw_ref, acc_scr, *, vocab, block_v):
-    """Grid (nV, nN): accumulate dW at vocab-block resolution over rows."""
-    vi = pl.program_id(0)
+def _bwd_dw_kernel(slab_ref, h_ref, dlog_ref, dw_in_ref, dw_ref, acc_scr, *,
+                   per_slab, n_blocks):
+    """Grid (per_slab, nN): one product a step, ``h^T @ dlog`` over the rows
+    of a stored slab, into a [hd, block_v] float32 accumulator; the slab's
+    columns of dW are written into the array the slabs before it filled
+    (``dw_in_ref``: the same buffer, aliased, never read)."""
+    del dw_in_ref
     ni = pl.program_id(1)
-    nn = pl.num_programs(1)
+    _, live = _slab_block(slab_ref, pl.program_id(0), per_slab, n_blocks)
 
-    @pl.when(ni == 0)
-    def _init():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    @pl.when(live)                 # a dead step leaves the last block be
+    def _block():
+        @pl.when(ni == 0)
+        def _init():
+            acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    h = h_ref[...]
-    dlog = _dlog_block(h, w_ref[...], lab_ref, lse_ref, glse_ref, gtgt_ref,
-                       vi, vocab, block_v)
-    acc_scr[:] += jax.lax.dot_general(
-        h, dlog.astype(h.dtype), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        acc_scr[:] += jax.lax.dot_general(
+            h_ref[...], dlog_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(ni == nn - 1)
-    def _finalize():
-        dw_ref[...] = acc_scr[:].astype(dw_ref.dtype)
+        @pl.when(ni == pl.num_programs(1) - 1)
+        def _finalize():
+            dw_ref[...] = acc_scr[:].astype(dw_ref.dtype)
 
 
 def _bwd_pallas(h, w, labels, lse, g_lse, g_tgt, block_n, block_v, interpret):
+    """One ``lax.scan`` over slabs of vocab blocks: the dhidden kernel leaves
+    a slab of ``dlog`` [n, per_slab * block_v] behind, the dW kernel reads it.
+    Both results are carried through the scan and written in place: dW a
+    slab's columns a call, dhidden as a float32 sum over the slabs."""
     n, hd = h.shape
     v = w.shape[1]
     wp = _pad_vocab(w, block_v)
-    vp = wp.shape[1]
-    nb = vp // block_v
-    nn = n // block_n
+    itemsize = w.dtype.itemsize
+    n_blocks = wp.shape[1] // block_v
+    per_slab = slab_blocks(n, block_v, itemsize, n_blocks)
+    dw_n = dw_block_n(n, block_n, block_v, hd, itemsize)
     lab2 = _lift_rows(labels, jnp.int32)
     lse2 = _lift_rows(lse, jnp.float32)
     glse2 = _lift_rows(g_lse, jnp.float32)
     gtgt2 = _lift_rows(g_tgt, jnp.float32)
+    statics = dict(per_slab=per_slab, n_blocks=n_blocks)
 
-    row_specs = [
-        _block_spec((block_n, hd), lambda ni, vi: (ni, 0)),
-        _block_spec((hd, block_v), lambda ni, vi: (0, vi)),
-        _block_spec((block_n, LANES), lambda ni, vi: (ni, 0)),
-        _block_spec((block_n, LANES), lambda ni, vi: (ni, 0)),
-        _block_spec((block_n, LANES), lambda ni, vi: (ni, 0)),
-        _block_spec((block_n, LANES), lambda ni, vi: (ni, 0)),
-    ]
-    dh = pl.pallas_call(
-        functools.partial(_bwd_dh_kernel, vocab=v, block_v=block_v),
-        grid=(nn, nb),
-        in_specs=row_specs,
-        out_specs=[_block_spec((block_n, hd), lambda ni, vi: (ni, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n, hd), h.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_n, hd), jnp.float32)],
+    # the last slab of a ragged split runs past the last vocab block: a dead
+    # step stays on a block already in VMEM (no fetch, no write-back)
+    def vocab_block(slab, vi):
+        return jnp.minimum(_slab_block(slab, vi, **statics)[0], n_blocks - 1)
+
+    def live_row(slab, vi, ni):
+        return jnp.where(_slab_block(slab, vi, **statics)[1], ni, 0)
+
+    row = lambda ni, vi, slab: (ni, 0)
+    rows_hd = _block_spec((block_n, hd), row)
+    rows_lanes = _block_spec((block_n, LANES), row)
+    dh_call = pl.pallas_call(
+        functools.partial(_bwd_dh_kernel, vocab=v, block_v=block_v,
+                          **statics),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // block_n, per_slab),
+            in_specs=[rows_hd,
+                      _block_spec((hd, block_v), lambda ni, vi, slab:
+                                  (0, vocab_block(slab, vi))),
+                      rows_lanes, rows_lanes, rows_lanes, rows_lanes,
+                      rows_hd],
+            out_specs=[rows_hd,
+                       _block_spec((block_n, block_v),
+                                   lambda ni, vi, slab: (ni, vi))]),
+        out_shape=[jax.ShapeDtypeStruct((n, hd), jnp.float32),
+                   jax.ShapeDtypeStruct((n, per_slab * block_v), w.dtype)],
+        input_output_aliases={7: 0},
         compiler_params=_tpu_params("parallel", "arbitrary"),
         interpret=interpret,
         name="fused_vocab_ce_bwd_dh",
-    )(h, wp, lab2, lse2, glse2, gtgt2)[0]
-
+    )
     # dW: grid transposed (vocab blocks parallel, rows sequential) so the
     # [hd, block_v] fp32 accumulator lives in VMEM across the row sweep
-    col_specs = [
-        _block_spec((block_n, hd), lambda vi, ni: (ni, 0)),
-        _block_spec((hd, block_v), lambda vi, ni: (0, vi)),
-        _block_spec((block_n, LANES), lambda vi, ni: (ni, 0)),
-        _block_spec((block_n, LANES), lambda vi, ni: (ni, 0)),
-        _block_spec((block_n, LANES), lambda vi, ni: (ni, 0)),
-        _block_spec((block_n, LANES), lambda vi, ni: (ni, 0)),
-    ]
-    dwp = pl.pallas_call(
-        functools.partial(_bwd_dw_kernel, vocab=v, block_v=block_v),
-        grid=(nb, nn),
-        in_specs=col_specs,
-        out_specs=[_block_spec((hd, block_v), lambda vi, ni: (0, vi))],
-        out_shape=[jax.ShapeDtypeStruct((hd, vp), w.dtype)],
-        scratch_shapes=[pltpu.VMEM((hd, block_v), jnp.float32)],
+    dw_call = pl.pallas_call(
+        functools.partial(_bwd_dw_kernel, **statics),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(per_slab, n // dw_n),
+            in_specs=[_block_spec((dw_n, hd), lambda vi, ni, slab:
+                                  (live_row(slab, vi, ni), 0)),
+                      _block_spec((dw_n, block_v), lambda vi, ni, slab:
+                                  (live_row(slab, vi, ni), vi)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=_block_spec((hd, block_v), lambda vi, ni, slab:
+                                  (0, vocab_block(slab, vi))),
+            scratch_shapes=[pltpu.VMEM((hd, block_v), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(wp.shape, w.dtype),
+        input_output_aliases={3: 0},
         compiler_params=_tpu_params("parallel", "arbitrary"),
         interpret=interpret,
         name="fused_vocab_ce_bwd_dw",
-    )(h, wp, lab2, lse2, glse2, gtgt2)[0]
-    return dh, dwp[:, :v]
+    )
+
+    def slab_step(carry, slab):
+        dh, dw = carry
+        dh, dlog = dh_call(slab, h, wp, lab2, lse2, glse2, gtgt2, dh)
+        return (dh, dw_call(slab, h, dlog, dw)), None
+
+    (dh, dw), _ = jax.lax.scan(
+        slab_step,
+        (jnp.zeros((n, hd), jnp.float32), jnp.zeros(wp.shape, w.dtype)),
+        jnp.arange(-(-n_blocks // per_slab), dtype=jnp.int32)[:, None])
+    return dh.astype(h.dtype), dw[:, :v]
 
 
 # ---------------------------------------------------------------------------
@@ -411,22 +464,58 @@ lse_and_target.defvjp(_lse_fwd_rule, _lse_bwd_rule)
 # support gates + public entry
 # ---------------------------------------------------------------------------
 
-def kernel_vmem_bytes(block_n, block_v, hd, itemsize) -> int:
-    """Upper bound on the scoped VMEM Mosaic allocates for one
-    (block_n, block_v) config, over the two backward kernels (the forward
-    holds strictly less). Counts what the compiler holds, not just what
-    the kernel names: every blocked operand and output TWICE (the pipeline
-    double-buffers them), the fp32 scratch accumulator plus the matmul
-    result it is added to, the [block_n, block_v] fp32 temporaries of the
-    softmax recompute, and the per-row scalar tiles padded to 128 lanes.
-    The ONE formula shared by the support gate and the default block
-    chooser, so the chooser never picks what the gate rejects."""
+def _dh_vmem_bytes(block_n, block_v, hd, itemsize) -> int:
+    """The dhidden kernel: h and W blocks, the [block_n, block_v] fp32
+    temporaries of the softmax recompute, the dlog block it writes out, the
+    per-row scalar tiles padded to 128 lanes, and the fp32 dhidden block in
+    and out plus the product added to it."""
     io = 2 * (block_n * hd + hd * block_v) * itemsize   # h + W blocks
     temps = 4 * block_n * block_v * 4         # logits, p, dlog, column iota
     rows = 6 * 2 * block_n * 128 * 4          # labels/lse/g_lse/g_tgt tiles
-    dh = 2 * block_n * hd * itemsize + 2 * block_n * hd * 4
-    dw = 2 * hd * block_v * itemsize + 2 * hd * block_v * 4
-    return io + temps + rows + max(dh, dw)
+    dlog = 2 * block_n * block_v * itemsize
+    dh = 5 * block_n * hd * 4
+    return io + temps + rows + dlog + dh
+
+
+def _dw_vmem_bytes(block_n, block_v, hd, itemsize) -> int:
+    """The dW kernel: h and dlog blocks (h once more, transposed for the
+    product), the fp32 [hd, block_v] accumulator plus the product added to
+    it, and the dW block it writes."""
+    io = (3 * block_n * hd + 2 * block_n * block_v) * itemsize
+    return io + 2 * hd * block_v * 4 + 2 * hd * block_v * itemsize
+
+
+def kernel_vmem_bytes(block_n, block_v, hd, itemsize) -> int:
+    """Upper bound on the scoped VMEM Mosaic allocates for one
+    (block_n, block_v) config, over the two backward kernels (the forward
+    holds strictly less than the dhidden kernel). Counts what the compiler
+    holds, not just what the kernel names: every blocked operand and output
+    TWICE (the pipeline double-buffers them) and a matmul's result beside
+    the accumulator it is added to. The ONE formula shared by the support
+    gate, the default block chooser and the dW kernel's row block, so the
+    chooser never picks what the gate rejects."""
+    return max(_dh_vmem_bytes(block_n, block_v, hd, itemsize),
+               _dw_vmem_bytes(block_n, block_v, hd, itemsize))
+
+
+def dw_block_n(n, block_n, block_v, hd, itemsize) -> int:
+    """Row block of the dW kernel: it holds no softmax temporaries, so it
+    sweeps the rows in the largest multiple of ``block_n`` (up to 4x) that
+    divides N and fits the same budget — fewer, longer products a vocab
+    block."""
+    return next((m * block_n for m in (4, 2)
+                 if n % (m * block_n) == 0
+                 and _dw_vmem_bytes(m * block_n, block_v, hd, itemsize)
+                 <= VMEM_BUDGET), block_n)
+
+
+def slab_blocks(n, block_v, itemsize, n_blocks) -> int:
+    """Vocab blocks a slab of the backward: as many as keep the stored dlog
+    slab [N, blocks * block_v] within SLAB_BYTES, evened out over the slabs
+    that takes (the gate refuses a shape of which not one block fits)."""
+    fit = SLAB_BYTES // (n * block_v * itemsize)
+    n_slabs = -(-n_blocks // fit)
+    return -(-n_blocks // n_slabs)
 
 
 def default_blocks(n, hd, dtype_str) -> Tuple[Optional[int], int]:
@@ -450,19 +539,20 @@ def fused_ce_supported(n, hd, v, dtype, block_n, block_v,
                        interpret=False) -> bool:
     """Static gate encoding the Mosaic lowering rules for this block
     layout: row blocks are [block_n, H] (H is the full lane dim), vocab
-    blocks [H, block_v]; the dW kernel's fp32 [H, block_v] accumulator is
-    the VMEM pacer. ``interpret`` relaxes alignment so CPU tests can run
-    tiny blocks."""
+    blocks [H, block_v]; the dhidden kernel's fp32 [block_n, H] blocks or
+    the dW kernel's fp32 [H, block_v] accumulator pace VMEM, whichever is
+    larger. One vocab block of ALL rows must fit the backward's dlog slab.
+    ``interpret`` relaxes alignment so CPU tests can run tiny blocks."""
     from ..registry import pallas_disabled
     if not _HAS_PLTPU or pallas_disabled():
         return False
     if block_n is None or block_v is None:
         return False
-    if n % block_n:
+    itemsize = jnp.dtype(dtype).itemsize
+    if n % block_n or n * block_v * itemsize > SLAB_BYTES:
         return False
     if interpret:
         return True
-    itemsize = jnp.dtype(dtype).itemsize
     return (block_n % 8 == 0 and block_v % 128 == 0 and hd % 128 == 0
             and kernel_vmem_bytes(block_n, block_v, hd, itemsize)
             <= VMEM_BUDGET)
@@ -496,7 +586,8 @@ def fused_linear_cross_entropy(hidden, w, labels, ignore_index: int = -100,
 
     Numerically interchangeable with
     ``F.cross_entropy((hidden @ w).astype(f32), labels)`` to fp32
-    tolerance; peak memory is O(N * block_v) instead of O(N * V)."""
+    tolerance; peak memory is O(N * block_v) (the Pallas backward: one
+    SLAB_BYTES slab of blocks) instead of O(N * V)."""
     lead = hidden.shape[:-1]
     hd = hidden.shape[-1]
     v = w.shape[-1]

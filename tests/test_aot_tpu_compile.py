@@ -201,6 +201,9 @@ ONE_CHIP = [
                  id="fused_ce[16384x4096x128256]"),
     pytest.param(lambda: _fused_ce(16384, 1536, 32000),
                  id="fused_ce[16384x1536x32000]"),
+    # olmoe.pretrain-4k's loss head: 8 rows of 4096, the whole vocabulary
+    pytest.param(lambda: _fused_ce(32768, 2048, 50304),
+                 id="fused_ce[32768x2048x50304]"),
     pytest.param(_int8_matmul, id="int8_matmul[8x4096x14336]"),
 ]
 
@@ -224,6 +227,41 @@ def test_kernel_compiles_for_v5e(topo, case, monkeypatch):
     # is the kernel's event text in the device trace (PERF.md section 3)
     calls = _mosaic_calls(text)
     assert calls and all(any(k in c for k in KERNEL_NAMES) for c in calls), calls
+
+
+def test_the_loss_head_backward_keeps_one_slab_of_logit_cotangents(
+        topo, monkeypatch):
+    """OLMoE's loss head as ``olmoe.pretrain-4k`` runs it (32768 rows, 2048
+    wide, 50304 classes, bf16), forward and backward: the backward stores the
+    logit cotangents it forms once, ONE slab of vocab blocks at a time. All
+    of them would be 3.3 GB: no buffer of rows x vocabulary (padded or not)
+    exists in any dtype, nothing in the program is larger than a slab's
+    budget, and the blocks the chooser gives pass the gate for both backward
+    kernels (the dW kernel's row block under its own estimate)."""
+    from paddle_tpu.analysis import (BanRule, banned_buffers,
+                                     materialization_report, parse_hlo)
+    from paddle_tpu.ops.pallas import fused_vocab_ce as fce
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    n, h, v = 32768, 2048, 50304
+    fn, shapes = _fused_ce(n, h, v)      # asserts fused_ce_supported
+    bn, bv = autotune.fused_vocab_ce_config(n, h, v, "bfloat16")
+    dw_n = fce.dw_block_n(n, bn, bv, h, 2)
+    assert n % dw_n == 0
+    assert fce._dw_vmem_bytes(dw_n, bv, h, 2) <= fce.VMEM_BUDGET
+    n_blocks = -(-v // bv)
+    per_slab = fce.slab_blocks(n, bv, 2, n_blocks)
+    assert 1 <= per_slab < n_blocks      # several slabs at this size
+    assert n * per_slab * bv * 2 <= fce.SLAB_BYTES
+    dev = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=dev) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    mod = parse_hlo(compiled.as_text())
+    hits = banned_buffers(mod, [BanRule(v, n), BanRule(n_blocks * bv, n)])
+    assert hits == [], "\n".join(hit.describe() for hit in hits)
+    report = materialization_report(mod)
+    assert report["largest_intermediate_bytes"] <= fce.SLAB_BYTES, report
+    # the slab, dhidden's float32 sum, W padded and dW, and little besides
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * fce.SLAB_BYTES
 
 
 def test_a_cca_decode_layer_compiles_around_the_paged_kernel(topo, monkeypatch):

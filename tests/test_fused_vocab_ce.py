@@ -103,6 +103,94 @@ def test_pallas_interpret_matches_xla():
                                    rtol=2e-5, atol=2e-5)
 
 
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (scan / cond / pjit bodies, a pallas_call's kernel)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub)
+
+
+def test_pallas_backward_multiplies_three_times():
+    """What ISSUE 35 is about: a block of logit cotangents is formed ONCE.
+    The two backward kernels hold three products between them (logits and
+    ``dlog @ W^T`` in dhidden's, ``h^T @ dlog`` in dW's), and the dW kernel
+    is handed neither W nor the saved lse: it cannot recompute a softmax."""
+    n, hd, v, bn, bv = 24, 16, 300, 8, 128
+    h, w, lab = _mk(n, hd, v, jnp.float32)
+
+    def loss(h, w):
+        lse, tgt = lse_and_target(h, w, lab, bn, bv, "pallas", True)
+        return jnp.sum(lse - tgt)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, w)
+    calls = {}
+    for eqn in _walk_eqns(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            calls[eqn.params["name"]] = eqn
+    assert sorted(calls) == ["fused_vocab_ce_bwd_dh", "fused_vocab_ce_bwd_dw",
+                             "fused_vocab_ce_fwd"]
+    dots = {name: sum(e.primitive.name == "dot_general"
+                      for e in _walk_eqns(eqn.params["jaxpr"]))
+            for name, eqn in calls.items()}
+    assert dots == {"fused_vocab_ce_fwd": 1, "fused_vocab_ce_bwd_dh": 2,
+                    "fused_vocab_ce_bwd_dw": 1}
+    # both calls stand in one scan body: the dW call takes the slab's index,
+    # h, the stored dlog and the dW buffer it fills in place (aliased to its
+    # result, never read) -- not the dhidden call's W, not its lse
+    dh, dw = calls["fused_vocab_ce_bwd_dh"], calls["fused_vocab_ce_bwd_dw"]
+    vp = -(-v // bv) * bv               # one slab: the whole padded vocab
+    w_var, lse_var = dh.invars[2], dh.invars[4]
+    assert w_var.aval.shape == (hd, vp) and lse_var.aval.shape == (n, 8)
+    assert not {w_var, lse_var} & set(dw.invars)
+    assert [tuple(x.aval.shape) for x in dw.invars] == [
+        (1,), (n, hd), (n, vp), (hd, vp)]
+    assert dw.invars[2] is dh.outvars[1]
+    assert dw.params["input_output_aliases"] == ((3, 0),)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("v,block_v,slab_blocks", [
+    (256, 64, None),     # one slab holds every block
+    (256, 64, 2),        # two slabs of two blocks
+    (300, 128, 1),       # padded vocabulary, a slab a block
+    (600, 128, 2),       # padded AND ragged: five blocks in slabs of two
+])
+def test_pallas_backward_matches_xla_over_slabs(dtype, tol, v, block_v,
+                                                slab_blocks, monkeypatch):
+    """``lse_and_target``'s own VJP, Pallas (interpret) against XLA, with a
+    DIFFERENT cotangent on every row of both outputs and rows whose label
+    lies outside the vocabulary: dhidden is summed across the slabs in
+    float32, dW is assembled from them, and neither depends on how many
+    blocks a slab holds."""
+    from paddle_tpu.ops.pallas import fused_vocab_ce
+    n, hd, bn = 48, 32, 8
+    if slab_blocks is not None:
+        monkeypatch.setattr(fused_vocab_ce, "SLAB_BYTES",
+                            slab_blocks * n * block_v
+                            * jnp.dtype(dtype).itemsize)
+    h, w, lab = _mk(n, hd, v, dtype, seed=3, ignore_rows=5)
+    safe = jnp.where(lab == -100, -1, lab)
+    rs = np.random.RandomState(4)
+    g = (jnp.asarray(rs.uniform(0.5, 1.5, n), jnp.float32),
+         jnp.asarray(rs.uniform(-1.5, -0.5, n), jnp.float32))
+
+    def grads(impl, interpret):
+        out, vjp = jax.vjp(lambda h, w: lse_and_target(
+            h, w, safe, bn, block_v, impl, interpret), h, w)
+        return out, vjp(g)
+    (ox, gx), (op, gp) = grads("xla", False), grads("pallas", True)
+    for a, b in zip(ox + gx, op + gp):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol)
+
+
 @pytest.mark.parametrize("hd", [128, 1024, 1536, 2048, 4096, 8192])
 @pytest.mark.parametrize("n", [16384, 4096])
 def test_default_blocks_pass_the_support_gate(hd, n):
@@ -111,11 +199,18 @@ def test_default_blocks_pass_the_support_gate(hd, n):
     call to the XLA fallback at production hidden sizes (the failure the
     first review caught) — pin that the defaults are gate-accepted across
     the Llama size range."""
-    from paddle_tpu.ops.pallas.fused_vocab_ce import (default_blocks,
-                                                      fused_ce_supported)
+    from paddle_tpu.ops.pallas.fused_vocab_ce import (
+        VMEM_BUDGET, _dw_vmem_bytes, default_blocks, dw_block_n,
+        fused_ce_supported, slab_blocks)
     bn, bv = default_blocks(n, hd, "bfloat16")
     assert bn is not None and n % bn == 0 and bv % 128 == 0
     assert fused_ce_supported(n, hd, 128256, jnp.bfloat16, bn, bv)
+    # the dW kernel sweeps rows in a block of its own, under the same budget
+    dw_n = dw_block_n(n, bn, bv, hd, 2)
+    assert dw_n % bn == 0 and n % dw_n == 0
+    assert _dw_vmem_bytes(dw_n, bv, hd, 2) <= VMEM_BUDGET
+    # and a slab of the backward holds at least one vocab block of all rows
+    assert slab_blocks(n, bv, 2, -(-128256 // bv)) >= 1
 
 
 # -- model-level: fused is the default loss path ----------------------------
@@ -316,18 +411,10 @@ def test_hlo_guard_jaxpr_return_logits_false():
     jaxpr = jax.make_jaxpr(jax.value_and_grad(step))(params)
 
     bad = []
-
-    def walk(jx):
-        for eqn in jx.eqns:
-            for v in eqn.outvars:
-                shape = getattr(getattr(v, "aval", None), "shape", ())
-                if (len(shape) >= 2 and shape[-1] == cfg.vocab_size
-                        and int(np.prod(shape[:-1])) == B * S):
-                    bad.append(shape)
-            for val in eqn.params.values():
-                if hasattr(val, "jaxpr"):        # ClosedJaxpr (scan/cond)
-                    walk(val.jaxpr)
-                elif hasattr(val, "eqns"):       # raw Jaxpr
-                    walk(val)
-    walk(jaxpr.jaxpr)
+    for eqn in _walk_eqns(jaxpr.jaxpr):      # scan/cond sub-jaxprs too
+        for v in eqn.outvars:
+            shape = getattr(getattr(v, "aval", None), "shape", ())
+            if (len(shape) >= 2 and shape[-1] == cfg.vocab_size
+                    and int(np.prod(shape[:-1])) == B * S):
+                bad.append(shape)
     assert not bad, f"B*S*V avals traced: {bad}"
