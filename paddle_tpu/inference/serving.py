@@ -195,6 +195,145 @@ class _InflightBlock:
     # stride the host projected at dispatch) — drains subtract it back
     # out of the projection when the device committed fewer
     steps: Optional[Dict[int, int]] = None
+    # the stream's books (_StreamBooks.dispatched): programs the admission
+    # path enqueued in front of this block, the instant the first program
+    # of its interval went to a QUIET device (None: a block dispatched
+    # before was unfinished), and whether one was undrained at its dispatch
+    admit_calls: int = 0
+    quiet_t: Optional[float] = None
+    behind: bool = False
+
+
+class _StreamBooks:
+    """The books of the engine's ONE device stream, kept by the host from
+    what it knows: the order of what it enqueued, whether a block was
+    still unfinished when it looked, and the instant a drain handed a
+    block's tokens over (its STAMP). An INTERVAL holds the device time of
+    exactly what was enqueued between two dispatches: the block's K ticks
+    and the programs the admission path put in front of them
+    (prefill_paged, prefill_chunk, tail_logits, cow_page, activate_slot).
+
+    An interval is attributed only where the device was demonstrably busy
+    from its start to its end and both instants are known. It ENDS at its
+    stamp where the host had to WAIT for the block in the drain (a block
+    that was ready before the host looked has a late stamp; a host that
+    is stalled INSIDE its wait comes back late too, and that the books
+    cannot see: the stall is in the run it closes). It STARTS
+    (b) at the enqueue of its first program where every block dispatched
+    before was finished by then, so the device was quiet and started at
+    that instant (what lies before it is the device's idle time: in
+    ``stream_s`` and in no program's sum); or (a) at the previous stamp,
+    where the block was dispatched behind a predecessor that was still
+    unfinished at that first enqueue, so the device went from one straight
+    to the other, and that predecessor's stamp was waited for. Where the
+    predecessor's stamp was LATE but its own start is known, the two
+    intervals are one RUN with one start, the K's and the admission
+    programs of both, closed by the next stamp that is waited for: a late
+    stamp cannot split a run, it does not break it.
+
+    A closed run with no admission work is clean ticks (``stream_tick_s``
+    over ``stream_ticks``); one with admission work, less its ticks at the
+    RECENT clean tick's time (the tick drifts with the live context, so
+    the baseline is local), is that work's device time
+    (``stream_admit_s``). What the books could NOT attribute is
+    ``stream_unattributed_s``: the time up to the stamp of an interval
+    whose start they cannot know, and a closed admission run that no clean
+    tick came before. The other sums read low by up to its share of
+    ``stream_s``. All lifetime sums, monotone; a run left open when the
+    engine stops is in none of them."""
+
+    RECENT = 8          # clean ticks the baseline is the mean of
+
+    def __init__(self, clock=time.perf_counter):
+        self._clock = clock
+        self.stream_s = 0.0
+        self.stream_tick_s = 0.0
+        self.stream_ticks = 0
+        self.stream_admit_s = 0.0
+        self.stream_unattributed_s = 0.0
+        self._recent: Deque[float] = deque(maxlen=self.RECENT)
+        self._booked: Optional[float] = None  # stream_s is summed up to here
+        self._exact = False         # ... and that instant is a waited stamp
+        # a run whose last stamp was late: (start, K, admission programs)
+        self._open: Optional[Tuple[float, int, int]] = None
+        self._pending = 0        # admission programs since the last dispatch
+        self._quiet_t: Optional[float] = None
+
+    def _enqueue(self, busy: bool) -> None:
+        if not self._pending and not busy:         # an interval's first
+            self._quiet_t = self._clock()
+
+    def admitted(self, busy: bool) -> None:
+        """The admission path is about to enqueue one program (``busy``: a
+        block dispatched before is still unfinished)."""
+        self._enqueue(busy)
+        self._pending += 1
+
+    def dispatched(self, busy: bool, behind: bool) -> dict:
+        """A decode block is about to be enqueued (``behind``: a block
+        dispatched before is not drained yet): the fields its
+        ``_InflightBlock`` keeps for ``drained``."""
+        self._enqueue(busy)
+        out = dict(admit_calls=self._pending, quiet_t=self._quiet_t,
+                   behind=behind)
+        self._pending, self._quiet_t = 0, None
+        return out
+
+    def recent_tick_s(self) -> Optional[float]:
+        """The mean of the last few clean ticks (None before the first)."""
+        return sum(self._recent) / len(self._recent) if self._recent else None
+
+    def drained(self, K: int, admit_calls: int, quiet_t: Optional[float],
+                behind: bool, waited: bool) -> Tuple[float, dict]:
+        """A block's tokens just reached the host (``waited``: the host
+        found it unfinished and blocked for it). Returns the stamp and
+        what the ``serving::drain`` span says of it: ``chained`` 1 with
+        the closed run's ``interval_us``, ``ticks`` and ``admit_calls``;
+        2 where the stamp was late and the run stays open; 0 where the
+        books gave up the time since the last instant they had booked."""
+        now = self._clock()
+        run, self._open = self._open, None
+        start = None
+        if quiet_t is not None:                                      # (b)
+            start = quiet_t
+        elif behind and run is not None:                # (a), the run goes on
+            (start, k, n), run = run, None
+            K, admit_calls = K + k, admit_calls + n
+        elif behind and self._exact:                                 # (a)
+            start = self._booked
+        said = dict(ticks=K, admit_calls=admit_calls)
+        if self._booked is None:
+            self._booked = start
+        booked = self._booked
+        if booked is None:              # the first stamp: nothing to span
+            self._booked, self._exact = now, waited
+            return now, dict(said, interval_us=0, chained=0)
+        if start is None or start < booked:
+            self.stream_s += now - booked
+            self.stream_unattributed_s += now - booked
+            self._booked, self._exact = now, waited
+            return now, dict(said, chained=0,
+                             interval_us=round((now - booked) * 1e6))
+        if run is not None:     # a run no stamp closed ended before ``start``
+            self.stream_s += start - booked
+            self.stream_unattributed_s += start - booked
+            self._booked = booked = start
+        length = now - start
+        if not waited:
+            self._open, self._exact = (start, K, admit_calls), False
+            return now, dict(said, interval_us=round(length * 1e6), chained=2)
+        self.stream_s += now - booked
+        if not admit_calls:
+            self.stream_tick_s += length
+            self.stream_ticks += K
+            self._recent.append(length / K)
+        elif self._recent:
+            self.stream_admit_s += max(
+                length - K * self.recent_tick_s(), 0.0)
+        else:
+            self.stream_unattributed_s += length
+        self._booked, self._exact = now, True
+        return now, dict(said, interval_us=round(length * 1e6), chained=1)
 
 
 # self-describing KV-page handoff payload format (serialize_pages /
@@ -491,10 +630,22 @@ class ContinuousBatchingEngine:
         # preemption or a long peer prefill inflicted on them)
         self._itl_gaps = deque(maxlen=100_000)
         # cost observatory (ISSUE 9): attached when a decode executable is
-        # built with the metrics plane on; drain timestamps give the
-        # measured seconds-per-block its breakdown gauges divide
+        # built with the metrics plane on; the books' recent clean tick is
+        # the measured seconds its breakdown gauges divide
         self._cost_watch = None
-        self._drain_stamps = deque(maxlen=256)
+        # always-on lifetime counters, all returned by stats(). The
+        # admission path's work (_prefill_event): the prefill programs'
+        # ids' widths summed, and what of those was padding
+        self.prefill_width_tokens = 0
+        self.prefill_pad_tokens = 0
+        # a request's stage waits, summed when its first token reaches
+        # the host (_reconcile_host) from the stamps request_timelines()
+        # hands out
+        self.first_tokens = 0
+        self.prefill_dispatch_s = 0.0
+        self.first_token_wait_s = 0.0
+        # which of the engine's programs had the device (_StreamBooks)
+        self._books = _StreamBooks()
         # metrics-plane lifetime counters (plain attrs: zero cost until
         # publish_metrics mirrors them into the registry as deltas)
         self._tokens_emitted = 0
@@ -644,11 +795,6 @@ class ContinuousBatchingEngine:
         {rid: np.ndarray of generated tokens} for the requests finished by
         this call and RELEASES them (a long-lived engine must not retain
         every request it ever served)."""
-        # run() is a burst boundary: drop drain stamps from earlier runs
-        # so the cost observatory's seconds-per-block never averages in
-        # inter-run idle gaps (the median filter alone loses once idle
-        # gaps outnumber genuine ones under short bursty runs)
-        self._drain_stamps.clear()
         while self.has_work():
             self.step()
         # leftover speculative blocks are fully masked on device (every
@@ -667,7 +813,11 @@ class ContinuousBatchingEngine:
             _sentry.maybe_tick()
         return out
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, float]:
+        """Gauges (``free_pages``, ``active``, ``queued``, ``inflight``,
+        ``prefix_shared_pages``, the two ``*_bytes``) and monotone lifetime
+        counters: attribute reads only, so a caller may ask every step."""
+        books = self._books
         out = {"free_pages": len(self._free),
                "active": sum(s is not None for s in self._slots),
                "queued": len(self._queue),
@@ -677,6 +827,16 @@ class ContinuousBatchingEngine:
                "attn_paged_ticks": self.attn_path_ticks["paged"],
                "kv_bytes_per_token": self.kv_bytes_per_token,
                "slot_state_bytes": self.slot_state_bytes,
+               "prefill_width_tokens": self.prefill_width_tokens,
+               "prefill_pad_tokens": self.prefill_pad_tokens,
+               "first_tokens": self.first_tokens,
+               "prefill_dispatch_s": self.prefill_dispatch_s,
+               "first_token_wait_s": self.first_token_wait_s,
+               "stream_s": books.stream_s,
+               "stream_tick_s": books.stream_tick_s,
+               "stream_ticks": books.stream_ticks,
+               "stream_admit_s": books.stream_admit_s,
+               "stream_unattributed_s": books.stream_unattributed_s,
                **self.tick_counts}
         if self.spec_k:
             out["spec_tokens_proposed"] = self.spec_tokens_proposed
@@ -1041,21 +1201,17 @@ class ContinuousBatchingEngine:
         return compiled
 
     def _publish_cost_metrics(self) -> None:
-        """Breakdown/MFU gauges for the serving tick: measured seconds
-        per decode block from drain-to-drain gaps (median-filtered so
-        idle gaps between runs don't pollute the estimate), attributed
-        against the analyzed tick executable."""
+        """Breakdown/MFU gauges for the serving tick: the stream books'
+        recent clean tick (the device's seconds a tick with no admission
+        work in front of it and no idle gap in it), attributed against
+        the analyzed block executable of ``decode_block`` (or
+        ``spec_k + 1``) ticks."""
         watch = self._cost_watch
-        if watch is None or not watch.attached:
+        tick = self._books.recent_tick_s()
+        if watch is None or not watch.attached or tick is None:
             return
-        stamps = list(self._drain_stamps)
-        gaps = [b - a for a, b in zip(stamps, stamps[1:])]
-        if not gaps:
-            return
-        gaps.sort()
-        med = gaps[len(gaps) // 2]
-        kept = [g for g in gaps if g <= 10 * med] or [med]
-        watch.publish(sum(kept) / len(kept))
+        watch.publish(tick, steps_per_exec=(self.spec_k + 1 if self.spec_k
+                                            else self.decode_block))
 
     def publish_metrics(self) -> Dict[str, float]:
         """Mirror the engine's telemetry into the process metrics registry
@@ -1387,6 +1543,7 @@ class ContinuousBatchingEngine:
             def cow_page(pools, src, dst):
                 return [_entry_page_copy(e, src, dst) for e in pools]
             self._cow_fn = jax.jit(cow_page, donate_argnums=(0,))
+        self._books.admitted(self._stream_busy())
         with self._building("cow_page"):
             self.pools = self._cow_fn(self.pools, jnp.int32(src),
                                       jnp.int32(dst))
@@ -1524,7 +1681,7 @@ class ContinuousBatchingEngine:
                 assert src is not None, "matched tail page vanished"
                 self.prefix_cow_copies += 1
                 psp = self._prefill_span(req, "cow")
-                with self._prefill_event(req, slot, 1, "cow"), \
+                with self._prefill_event(req, slot, 1, "cow", 1), \
                         self._building("tail_logits"):
                     logits, self.pools = self._tail_logits_fn()(
                         self._params,
@@ -1553,7 +1710,7 @@ class ContinuousBatchingEngine:
             req.prefilled = L
             psp = self._prefill_span(req, "suffix" if off else "full")
             with self._prefill_event(req, slot, bucket - off,
-                                     "suffix" if off else "full"):
+                                     "suffix" if off else "full", L - off):
                 if off:
                     # suffix-only prefill from the page-aligned offset:
                     # the existing chunked-prefill extend attends over
@@ -1601,13 +1758,18 @@ class ContinuousBatchingEngine:
                               tags={"kind": kind})
 
     def _prefill_event(self, req: _Request, slot: int, bucket: int,
-                       kind: str):
+                       kind: str, tokens: int):
         """The ``serving::prefill`` span of one prefill program for
         ``req`` (``bucket``: the width of ids it takes; ``kind``: full,
         suffix, cow or chunk; ``attn``: the model's attention kind),
-        stamping the start of the request's first."""
+        stamping the start of the request's first. ``tokens``: what of
+        the width the call really forwards. The one place every prefill
+        program is announced, so the one place its work is counted."""
         if not req.prefill_start_t:
             req.prefill_start_t = time.perf_counter()
+        self.prefill_width_tokens += bucket
+        self.prefill_pad_tokens += bucket - tokens
+        self._books.admitted(self._stream_busy())
         return RecordEvent("serving::prefill", rid=req.rid, slot=slot,
                            bucket=bucket, kind=kind,
                            attn=self.attention_kind)
@@ -1657,7 +1819,7 @@ class ContinuousBatchingEngine:
         ids[0, :len(chunk)] = chunk
         last_idx = req.prefill_target - 1
         psp = self._prefill_span(req, "chunk")
-        with self._prefill_event(req, slot, C, "chunk"), \
+        with self._prefill_event(req, slot, C, "chunk", len(chunk)), \
                 self._building("prefill_chunk", width=C):
             logits, self.pools = self._chunk_fn(C)(
                 self._params, jnp.asarray(ids), jnp.int32(off), self.pools,
@@ -2058,6 +2220,8 @@ class ContinuousBatchingEngine:
             self._tables_dirty = False
         seq = self._block_seq
         self._block_seq += 1
+        booked = self._books.dispatched(self._stream_busy(),
+                                        bool(self._inflight))
         fn = self._decode_fns.get(fkey)
         with RecordEvent("serving::dispatch", block=seq, K=K,
                          active=len(parts)), \
@@ -2094,7 +2258,7 @@ class ContinuousBatchingEngine:
             self._proj_pos[s] += steps
         self._inflight.append(_InflightBlock(
             toks, kept, self._state[1], self._state[2], parts, K, seq=seq,
-            steps=stride))
+            steps=stride, **booked))
         return True
 
     def _block_ready(self, blk: _InflightBlock) -> bool:
@@ -2102,6 +2266,12 @@ class ContinuousBatchingEngine:
             return bool(blk.toks.is_ready()) and bool(blk.active.is_ready())
         except Exception:
             return False
+
+    def _stream_busy(self) -> bool:
+        """Whether a block dispatched before is still unfinished: a
+        program enqueued now starts straight behind it."""
+        return bool(self._inflight) and not self._block_ready(
+            self._inflight[-1])
 
     def _drain_all(self) -> List[tuple]:
         emitted: List[tuple] = []
@@ -2114,7 +2284,10 @@ class ContinuousBatchingEngine:
         the device already moved past: append kept tokens, retire slots
         whose done flag came back, record arrival-time latency metrics."""
         blk = self._inflight.popleft()
-        with RecordEvent("serving::drain", block=blk.seq):
+        # a block already finished has a late stamp: the stream's books
+        # cannot end its interval here
+        waited = not self._block_ready(blk)
+        with RecordEvent("serving::drain", block=blk.seq) as span:
             toks = np.asarray(blk.toks)            # [K, B]
             kept = np.asarray(blk.kept)            # [K, B] prefix mask
             if self._tick_counters and not self.spec_k:
@@ -2124,22 +2297,22 @@ class ContinuousBatchingEngine:
                 toks = toks[:blk.K]
             pos_after = np.asarray(blk.pos)
             active_after = np.asarray(blk.active)
+            # TTFT/ITL stamp at token-ARRIVAL time: under pipelining a
+            # block's tokens only exist on host once its drain completes,
+            # so percentiles stay honest about what a client would
+            # observe; where the host waited it is also the instant the
+            # block finished on the device, which the books go by
+            now, said = self._books.drained(
+                blk.K, blk.admit_calls, blk.quiet_t, blk.behind, waited)
+            span.tag(**said)
         with RecordEvent("serving::reconcile", block=blk.seq):
             return self._reconcile_host(blk, toks, kept, pos_after,
-                                        active_after)
+                                        active_after, now)
 
     def _reconcile_host(self, blk: _InflightBlock, toks, kept, pos_after,
-                        active_after) -> List[tuple]:
-        """The host bookkeeping of one drained block."""
+                        active_after, now: float) -> List[tuple]:
+        """The host bookkeeping of one block drained at ``now``."""
         emitted: List[tuple] = []
-        # TTFT/ITL stamp at token-ARRIVAL time: under pipelining a
-        # block's tokens only exist on host once its drain completes, so
-        # percentiles stay honest about what a client would observe
-        now = time.perf_counter()
-        if _REG.enabled:
-            # cost observatory: drain-to-drain gaps are the measured
-            # seconds-per-block its breakdown divides
-            self._drain_stamps.append(now)
         for slot, req in blk.participants:
             if self._slots[slot] is not req or req.done:
                 continue      # retired by an earlier block's reconcile
@@ -2152,6 +2325,11 @@ class ContinuousBatchingEngine:
                 nk += 1
                 if req.first_tok_t == 0.0:
                     req.first_tok_t = now
+                    self.first_tokens += 1
+                    self.prefill_dispatch_s += (req.prefill_dispatched_t
+                                                - req.prefill_start_t)
+                    self.first_token_wait_s += (now
+                                                - req.prefill_dispatched_t)
                 emitted.append((req.rid, t))
             if nk:
                 self._tokens_emitted += nk

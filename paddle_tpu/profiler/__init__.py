@@ -188,6 +188,15 @@ class RecordEvent:
         if _collector.enabled or _flight_sink is not None:
             self._start_ns = time.perf_counter_ns()
 
+    def tag(self, **attrs):
+        """Add attributes known only once the span runs (a drain's
+        interval, read after the wait it spans). Nothing to add to when
+        nothing records."""
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
+        if self._start_ns is not None:
+            self.attrs.update(attrs)
+
     def end(self):
         if self._annotation is not None:
             self._annotation.__exit__(None, None, None)

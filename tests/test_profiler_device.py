@@ -171,6 +171,20 @@ def test_benchmark_timer():
     assert r["ips"] > 0
 
 
+def test_record_event_tag_adds_attrs_to_a_running_span_only():
+    idle = RecordEvent("outside", a=1)
+    with idle:
+        idle.tag(b=2)                 # nothing records: nothing to add to
+    assert idle.attrs == {"a": 1}
+    p = Profiler()
+    p.start()
+    with RecordEvent("inside", a=1) as ev:
+        ev.tag(b=2)
+    p.stop()
+    (got,) = [e for e in p.result.events if e.name == "inside"]
+    assert got.attrs == {"a": 1, "b": 2}
+
+
 # ---------------------------------------------------------------------------
 # device API
 # ---------------------------------------------------------------------------

@@ -488,6 +488,337 @@ def test_latency_stats_is_computed_from_the_one_record_store(tiny_llama):
     assert eng.latency_stats() == {} and eng.request_timelines() == []
 
 
+# -- the engine's own books (ISSUE 36) ------------------------------------------
+
+NEW_KEYS = ("prefill_width_tokens", "prefill_pad_tokens", "first_tokens",
+            "prefill_dispatch_s", "first_token_wait_s", "stream_s",
+            "stream_tick_s", "stream_ticks", "stream_admit_s",
+            "stream_unattributed_s")
+
+
+class _Script:
+    """A device stream played to ``_StreamBooks`` on a scripted clock, in
+    milliseconds: ``dispatch`` returns what the engine keeps on the block,
+    ``drain`` hands it back at the instant the block's tokens arrive."""
+
+    def __init__(self):
+        from paddle_tpu.inference import serving
+        self.t = 0.0
+        self.books = serving._StreamBooks(clock=lambda: self.t / 1e3)
+        self.inflight = []
+
+    def prefill(self, t, busy=None):
+        """``busy``: a block dispatched before is still unfinished (as a
+        rule one that is not drained yet is)."""
+        self.t = t
+        self.books.admitted(bool(self.inflight) if busy is None else busy)
+
+    def dispatch(self, t, K=1, busy=None):
+        self.t = t
+        self.inflight.append(dict(K=K, **self.books.dispatched(
+            bool(self.inflight) if busy is None else busy,
+            bool(self.inflight))))
+
+    def drain(self, t, waited=True):
+        self.t = t
+        _, said = self.books.drained(**self.inflight.pop(0), waited=waited)
+        self.said = said
+        return said["interval_us"] / 1e3, said["chained"]
+
+    def read(self):
+        b = self.books
+        return {k: round(getattr(b, k) * 1e3, 6) for k in (
+            "stream_s", "stream_tick_s", "stream_admit_s",
+            "stream_unattributed_s")} | {"ticks": b.stream_ticks}
+
+
+def _four_intervals():
+    """Intervals of 10, 10, 30 (one prefill in front of its tick) and 10
+    ms, each block dispatched behind its predecessor and waited for."""
+    s = _Script()
+    s.dispatch(0)                   # a quiet engine: the stream starts here
+    s.dispatch(1)
+    assert s.drain(10) == (10.0, True)
+    s.prefill(11)
+    s.dispatch(12)
+    assert s.drain(20) == (10.0, True)
+    s.dispatch(21)
+    assert s.drain(50) == (30.0, True)
+    assert s.drain(60) == (10.0, True)
+    return s
+
+
+def test_the_stream_books_split_an_interval_into_its_ticks_and_its_prefill():
+    got = _four_intervals().read()
+    assert got == {"stream_s": 60.0, "stream_tick_s": 30.0, "ticks": 3,
+                   "stream_admit_s": 20.0, "stream_unattributed_s": 0.0}
+    # what the sums leave of stream_s is attributed too: the prefill
+    # interval's own tick, at the recent clean tick's time
+    assert got["stream_s"] - got["stream_tick_s"] - got["stream_admit_s"] \
+        == got["stream_tick_s"] / got["ticks"]
+
+
+def test_the_stream_books_book_a_quiet_devices_gap_and_start_at_the_enqueue():
+    s = _four_intervals()
+    s.dispatch(100)                 # nothing in flight: 40 ms after a stamp
+    assert s.drain(112) == (12.0, True)
+    got = s.read()
+    # the 40 ms the device was quiet are in stream_s and in no program's
+    # sum, and are not "unattributed" either: the books know it idled
+    assert got["stream_s"] == 112.0 and got["stream_tick_s"] == 42.0
+    assert got["ticks"] == 4 and got["stream_unattributed_s"] == 0.0
+    # a prefill that wakes a quiet engine starts the interval itself, and
+    # its tick is taken at the mean of the last clean ticks (10, 10, 10, 12)
+    s.prefill(130)
+    s.dispatch(133)
+    assert s.drain(190) == (60.0, True)
+    got = s.read()
+    assert got["stream_s"] == 190.0 and got["stream_unattributed_s"] == 0.0
+    assert got["stream_admit_s"] == 20.0 + 60.0 - 10.5
+
+
+def _flowing():
+    """A stream in full flow: three clean ticks of 10 ms, each block
+    dispatched behind its predecessor, one block (sent at 21) in flight."""
+    s = _Script()
+    s.dispatch(0)
+    for t in (0, 10, 20):
+        s.dispatch(t + 1)
+        assert s.drain(t + 10) == (10.0, 1)
+    return s
+
+
+def test_a_late_stamp_cannot_split_a_run_and_does_not_break_it():
+    s = _flowing()
+    before = s.read()
+    # the block in flight was ready before the host looked: its stamp (45)
+    # is late, but the next went out behind it while it was unfinished, so
+    # the device ran both back to back from 30 to 52: one run, two ticks
+    s.dispatch(31)
+    assert s.drain(45, waited=False) == (15.0, 2)       # left open
+    assert s.read() == before                           # nothing booked yet
+    s.dispatch(46)
+    assert s.drain(52) == (22.0, 1) and s.said["ticks"] == 2
+    got = s.read()
+    assert got["stream_s"] == 52.0 and got["stream_tick_s"] == 52.0
+    assert got["ticks"] == 5
+    # two late stamps in a row, a prefill in front of the third block: one
+    # run of 3 ticks and 1 program, its ticks at the recent clean tick
+    # (10, 10, 10, 11)
+    s.dispatch(53)
+    assert s.drain(65, waited=False) == (13.0, 2)
+    s.prefill(66)
+    s.dispatch(67)
+    assert s.drain(110, waited=False) == (58.0, 2)
+    s.dispatch(111)
+    assert s.drain(120) == (68.0, 1)
+    assert (s.said["ticks"], s.said["admit_calls"]) == (3, 1)
+    assert s.read() == {"stream_s": 120.0, "stream_tick_s": 52.0, "ticks": 5,
+                        "stream_admit_s": 68.0 - 3 * 10.25,
+                        "stream_unattributed_s": 0.0}
+
+
+def test_what_the_books_cannot_start_or_end_is_left_unattributed():
+    s = _flowing()
+    s.dispatch(31)
+    assert s.drain(40) == (10.0, 1)
+    # the block sent at 31 had FINISHED when the next was enqueued at 55
+    # (the host was that late): the device idled from some instant the
+    # books cannot know to 55, where it started the next. 40 to 55 is
+    # given up; the block enqueued at 55 is a clean tick of 11
+    s.dispatch(55, busy=False)
+    assert s.drain(56, waited=False) == (16.0, 2)
+    s.dispatch(57)
+    assert s.drain(66) == (11.0, 1)
+    got = s.read()
+    assert got["stream_s"] == 66.0 and got["stream_unattributed_s"] == 15.0
+    assert got["stream_tick_s"] == 51.0 and got["ticks"] == 5
+    assert s.drain(76) == (10.0, 1)
+    # a prefill enqueued behind a block that is drained before the
+    # prefill's own block goes out: that stamp lies inside the interval,
+    # and the device may have idled behind the prefill
+    s.dispatch(77)
+    s.prefill(78)
+    assert s.drain(87) == (10.0, 1)
+    s.dispatch(95)
+    s.dispatch(96)
+    assert s.drain(125) == (38.0, 0)
+    got = s.read()
+    assert got["stream_admit_s"] == 0.0
+    assert got["stream_unattributed_s"] == 15.0 + 38.0
+    # a stamp the host waited for is exact whatever came before it: the
+    # block behind it starts there
+    assert s.drain(135) == (10.0, 1)
+    assert s.read()["stream_s"] == 135.0
+
+
+def test_the_stream_books_divide_a_block_by_its_own_k():
+    s = _Script()
+    s.prefill(0)
+    s.dispatch(2, K=4)
+    s.dispatch(3, K=4)
+    assert s.drain(70) == (70.0, True)
+    # chained, but no clean tick has been seen yet to take its ticks at
+    assert s.read() == {"stream_s": 70.0, "stream_tick_s": 0.0, "ticks": 0,
+                        "stream_admit_s": 0.0,
+                        "stream_unattributed_s": 70.0}
+    s.prefill(71)
+    s.dispatch(72, K=2)
+    assert s.drain(110) == (40.0, True)
+    assert s.books.recent_tick_s() == pytest.approx(0.010)
+    assert s.drain(180) == (70.0, True)
+    got = s.read()
+    assert got["ticks"] == 4 and got["stream_tick_s"] == 40.0
+    assert got["stream_admit_s"] == 50.0
+    assert got["stream_s"] == 180.0 and got["stream_unattributed_s"] == 70.0
+
+
+def _preempting(model):
+    return _engine(model, num_pages=3, max_len=8 * PAGE,
+                   generation_config=GenerationConfig(
+                       max_new_tokens=PAGE + 4, do_sample=False))
+
+
+def _chunking(model):
+    return _engine(model, chunked_prefill=True, prefill_chunk=PAGE)
+
+
+@pytest.mark.parametrize("make, lengths", [(_preempting, (PAGE - 2,) * 2),
+                                           (_chunking, (3 * PAGE - 1, 5))],
+                         ids=["preemption", "chunked_prefill"])
+def test_every_new_key_of_stats_is_monotone(tiny_llama, make, lengths):
+    eng = make(tiny_llama)
+    for i, n in enumerate(lengths):
+        eng.submit(_prompts(1, n, tiny_llama.cfg.vocab_size, i)[0])
+    prev = eng.stats()
+    assert set(NEW_KEYS) <= set(prev) and not any(prev[k] for k in NEW_KEYS)
+    while eng.has_work():
+        eng.step()
+        now = eng.stats()
+        for k in NEW_KEYS:
+            assert isinstance(now[k], (int, float)) and now[k] >= prev[k], k
+        prev = now
+    eng.run()
+    last = eng.stats()
+    assert all(last[k] >= prev[k] for k in NEW_KEYS)
+    assert last["first_tokens"] == len(lengths)
+    assert last["prefill_width_tokens"] > last["prefill_pad_tokens"] >= 0
+    assert last["stream_s"] >= (last["stream_tick_s"] + last["stream_admit_s"]
+                                + last["stream_unattributed_s"]) > 0
+
+
+def test_the_prefill_counters_count_what_each_call_forwards(tiny_llama):
+    vocab = tiny_llama.cfg.vocab_size
+    eng = _engine(tiny_llama)
+    lengths = (5, PAGE, PAGE + 3, 2 * PAGE + 1)
+    for i, n in enumerate(lengths):
+        eng.submit(_prompts(1, n, vocab, i)[0])
+    eng.run()
+    st = eng.stats()
+    assert st["prefill_width_tokens"] == sum(-(-n // PAGE) * PAGE
+                                             for n in lengths)
+    assert st["prefill_width_tokens"] - st["prefill_pad_tokens"] \
+        == sum(lengths)
+    # a chunked prompt: one call a chunk, the last one padded; a preempted
+    # request's replay is prefilled (and counted) again, with what it had
+    # generated
+    for make, lengths in ((_chunking, (3 * PAGE - 1,)),
+                          (_preempting, (PAGE - 2,) * 2)):
+        eng = make(tiny_llama)
+        with profiler.Profiler() as prof:
+            for i, n in enumerate(lengths):
+                eng.submit(_prompts(1, n, vocab, 4 + i)[0])
+            eng.run()
+        calls = [e.attrs for e in prof.result.events
+                 if e.name == "serving::prefill"]
+        st = eng.stats()
+        assert st["prefill_width_tokens"] == sum(c["bucket"] for c in calls)
+        forwarded = st["prefill_width_tokens"] - st["prefill_pad_tokens"]
+        if eng.chunked_prefill:
+            assert [c["bucket"] for c in calls] == [PAGE] * 3
+            assert forwarded == sum(lengths) and st["prefill_pad_tokens"] == 1
+        else:
+            assert eng.preemptions >= 1
+            assert len(calls) == len(lengths) + eng.preemptions
+            assert forwarded > sum(lengths)
+
+
+def test_the_first_tokens_waits_are_the_timelines_sums(tiny_llama):
+    eng = _preempting(tiny_llama)
+    for p in _prompts(2, PAGE - 2, tiny_llama.cfg.vocab_size, 4):
+        eng.submit(p)
+    eng.run()
+    for p in _prompts(3, 5, tiny_llama.cfg.vocab_size, 7):
+        eng.submit(p)
+    eng.run()
+    assert eng.preemptions >= 1
+    recs, st = eng.request_timelines(), eng.stats()
+    assert st["first_tokens"] == len(recs) == 5      # a replay counts once
+    for key, a, b in (("prefill_dispatch_s", "prefill_start_t",
+                       "prefill_dispatched_t"),
+                      ("first_token_wait_s", "prefill_dispatched_t",
+                       "first_tok_t")):
+        assert st[key] == pytest.approx(sum(r[b] - r[a] for r in recs),
+                                        rel=1e-9, abs=1e-12), key
+    assert st["prefill_dispatch_s"] + st["first_token_wait_s"] <= sum(
+        r["first_tok_t"] - r["submit_t"] for r in recs) + 1e-9
+
+
+def test_the_drain_span_carries_the_books_stats(tiny_llama, tmp_path):
+    eng = _engine(tiny_llama)
+    eng.submit(_prompts(1, 3, tiny_llama.cfg.vocab_size)[0])
+    eng.run()                               # builds outside the trace
+    before = eng.stats()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for p in _prompts(3, PAGE + 3, tiny_llama.cfg.vocab_size, 2):
+            eng.submit(p)
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    spans, st = _host_spans(tmp_path), eng.stats()
+    assert [s["bucket"] for s in spans["serving::prefill"]] == [2 * PAGE] * 3
+    drains = spans["serving::drain"]
+    assert all({"block", "admit_calls", "ticks", "interval_us", "chained"}
+               <= set(s) for s in drains)
+    assert all(s["chained"] in (0, 1, 2) and s["interval_us"] >= 0
+               for s in drains)
+    # a drain that leaves its run open (2) hands its programs and ticks on
+    # to the one that closes it (1) or gives it up (0)
+    closed = [s for s in drains if s["chained"] != 2]
+    assert sum(s["admit_calls"] for s in closed) <= 3 \
+        <= sum(s["admit_calls"] for s in drains)
+    # every run of the books lies in the trace: the lengths of those that
+    # closed are what the books attributed, plus what they could not
+    closed_s = sum(s["interval_us"] for s in drains if s["chained"] == 1) / 1e6
+    booked = sum(st[k] - before[k] for k in ("stream_tick_s",
+                                             "stream_admit_s"))
+    assert booked <= closed_s + 1e-5 * len(drains)
+    assert st["stream_ticks"] - before["stream_ticks"] <= sum(
+        s["ticks"] for s in closed)
+
+
+def test_the_drain_stamps_are_gone_and_the_books_feed_the_cost_gauges(
+        tiny_llama):
+    eng = _engine(tiny_llama)
+    assert not hasattr(eng, "_drain_stamps")
+    assert eng._books.recent_tick_s() is None
+    eng._publish_cost_metrics()             # nothing attached: nothing read
+
+    class Watch:
+        attached, got = True, []
+
+        def publish(self, seconds, steps_per_exec):
+            self.got.append((seconds, steps_per_exec))
+
+    eng._cost_watch = Watch()
+    eng._publish_cost_metrics()             # no clean tick yet: nothing said
+    assert Watch.got == []
+    eng._books._recent.extend([0.010, 0.012])
+    eng._publish_cost_metrics()
+    assert Watch.got == [(pytest.approx(0.011), eng.decode_block)]
+
+
 # -- build log ----------------------------------------------------------------
 
 def test_build_log_has_one_row_per_program_built(tiny_llama):
