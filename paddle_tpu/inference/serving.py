@@ -1148,12 +1148,21 @@ class ContinuousBatchingEngine:
         """Context for a CALL of ``program``: the first one per shape
         variant is its build (trace + lower + compile or cache read), so it
         runs as a ``compile::<program>`` span and leaves a ``build_log``
-        row; every later one is a set lookup."""
+        row; every later one is a set lookup. A prefill program of a routed
+        model also says how its experts run at that many rows
+        (``core.expert_path``: ``expert_path``, and ``expert_step_rows``
+        where the path is the loop over an expert's rows)."""
         key = (program, *shape.values())
         if key in self._built:
             return _NULL
         self._built.add(key)
-        return compile_cache.building(program, self.build_log, **shape)
+        rows = shape.get("bucket", shape.get("width"))   # a prefill's
+        how = getattr(self.core, "expert_path", None)
+        path, step = (rows and how and how(rows)) or (None, None)
+        said = {"expert_path": path, "expert_step_rows": step}
+        return compile_cache.building(
+            program, self.build_log, **shape,
+            **{k: v for k, v in said.items() if v})
 
     # -- metrics plane -------------------------------------------------------
 

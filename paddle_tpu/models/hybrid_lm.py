@@ -475,6 +475,14 @@ class HybridForCausalLM(nn.Layer):
 
     # -- serving path (inference.ContinuousBatchingEngine) -------------------
 
+    def expert_path(self, rows: int):
+        """(path, rows of a step) by which the expert layers run a program
+        of ``rows`` rows (``MoELayer.inference_path``), None without an
+        expert layer: what the engine writes into the program's
+        ``build_log`` row."""
+        routed = self._kinds("E")
+        return routed[0].mixer.inference_path(rows) if routed else None
+
     def alloc_paged_caches(self, batch: int, max_len: int,
                            page_size: int = 128):
         """(pools, tables): one pool entry for each ATTENTION layer, in

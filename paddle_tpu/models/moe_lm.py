@@ -727,6 +727,17 @@ class MoEForCausalLM(nn.Layer):
 
     # -- paged-KV serving path (inference.ContinuousBatchingEngine) ---------
 
+    def expert_path(self, rows: int):
+        """(path, rows of a step) by which the routed layers run a program
+        of ``rows`` rows (``MoELayer.inference_path``: every routed layer
+        has the same shapes); None for a model whose experts the inference
+        path does not run (none routed, or capacity routing). The engine
+        writes it into the program's ``build_log`` row."""
+        routed = [layer.moe for layer in self.layers if layer.moe is not None]
+        if not routed or self.cfg.capacity_factor is not None:
+            return None
+        return routed[0].inference_path(rows)
+
     def alloc_paged_caches(self, batch: int, max_len: int,
                            page_size: int = 128):
         """One pool entry a layer, laid out by the layer's attention

@@ -311,50 +311,74 @@ def xla_grouped_matmul(xs, w, group_sizes):
     """Ragged grouped matmul: rows of ``xs`` [m, k] are split into runs
     by ``group_sizes`` [g] and run ``i`` multiplies its own ``w[i]``
     [k, n], through XLA's ``lax.ragged_dot``. Returns f32, the
-    accumulator dtype; callers cast back to the activation dtype."""
+    accumulator dtype; callers cast back to the activation dtype. What
+    ``blocked_expert_rows`` is tested and timed against (no path of the
+    program calls it: training goes through ``grouped_matmul``)."""
     return jax.lax.ragged_dot(xs, w, group_sizes,
                               preferred_element_type=jnp.float32)
 
 
-# rows a step of ``blocked_expert_rows`` takes: a [256, d] x [d, f] product
-# reads an expert's weights once for 256 rows (2 x 256 FLOPs a byte of bf16:
-# level with a v5e's 240), and a prompt's few hundred rows an expert are one
-# or two steps
-EXPERT_ROW_BLOCK = 256
+# FLOPs the MXU does in the time HBM delivers one byte, on a v5e (197e12 /
+# 819e9; ``MoELayer.DENSE_ROWS`` is this number read as rows)
+RIDGE_FLOPS_PER_BYTE = 240
+# what a step of ``blocked_expert_rows`` is a whole number of: every size the
+# rule below can choose in bf16 (64, 128, 192, 256) was read on the chip
+STEP_QUANTUM = 64
 
 
-def ragged_dot_tiles_small(d: int, f: int) -> bool:
-    """Whether XLA:TPU's ``ragged_dot`` falls back to 128 x 128 x 128 tiles
-    for expert matrices [d, f] / [f, d]: it does when a width (of a tile or
-    more) is not a whole number of 256-lane tiles (2688 x 1856, chip run,
-    PR 33: a product over 13,824 sorted rows is ~35,000 tile steps of ~0.35
-    us, 12.4 ms where its FLOPs need 0.35 and its weights 0.39; at 2048 x
-    1536 and 2048 x 2048 it picks larger tiles and is the faster path)."""
-    return min(d, f) >= 256 and bool(d % 256 or f % 256)
+def expert_step_rows(t: int, k: int, e: int, dtype) -> int:
+    """Rows a step of ``blocked_expert_rows`` takes in a call that routes
+    ``t`` rows top-``k`` over a router ``e`` wide: TWICE the rows an expert
+    is expected to get, ``t k / e``, rounded up to ``STEP_QUANTUM``, and
+    never over the rows at which a step turns MXU-bound. The experts'
+    widths do not enter: a [step, d] x [d, f] product does 2 step / itemsize
+    FLOPs a byte of the expert's weights whatever d and f are, so it crosses
+    the ridge at 120 x itemsize rows (256 in bf16), and over that a larger
+    step only computes more rows that are not the expert's.
+
+    Twice, because a step costs nearly the same whatever it holds and a
+    router does not deal evenly (``tools/expert_path_probe.py`` on a v5e, PR
+    37: at 2688 x 1856 a step of 32 / 64 / 128 / 192 / 256 rows takes 36.4
+    / 37.6 / 41.7 / 45.5 / 50.6 us, two products that stream 10 MB of
+    weights each at four fifths of HBM's rate, and the rows on top; at 2048
+    x 1536 gated 34.1 / 35.1 / 37.7 / 40.6 / 44.2; at 2048 x 2048 gated
+    42.3 / 43.5 / 47.0 / - / 51.8). A prompt's rows an expert spread wide
+    on seeded weights: of Nemotron's 64 held experts at 1,536 tokens the
+    median gets 47-71 rows, one in ten 119-177, the busiest 174-344 (even:
+    72); GLM's and ZAYA's wider still. A step of the even share then needs
+    104 steps where one of 256 rows needs 66, and the loop is longest
+    there; of steps of 64 / 128 / 192 / 256 rows the layer is fastest at
+    this rule's in seven of nine readings (three lengths of each cell's
+    prompts, loads skewed as theirs: PERF.md section 6, PR 37)."""
+    whole = lambda rows: max(-(-rows // STEP_QUANTUM), 1) * STEP_QUANTUM
+    ridge = RIDGE_FLOPS_PER_BYTE * jnp.dtype(dtype).itemsize // 2
+    return min(whole(-(-2 * t * k // e)), whole(ridge))
 
 
-def blocked_expert_rows(xs, w_in, w_dn, act: str, load,
-                        block: int = EXPERT_ROW_BLOCK):
+def blocked_expert_rows(xs, w_in, w_dn, act: str, load, block: int):
     """The expert feed-forward over rows SORTED by expert (``load`` [e]: the
     rows of each, in order; rows past their sum belong to none and come back
-    0), as a loop over blocks of ``block`` consecutive rows of ONE expert:
+    0), as a loop over steps of ``block`` consecutive rows of ONE expert:
     each step slices that expert's two matrices out of ``w_in`` / ``w_dn``
     and runs plain products, so the MXU sees [block, d] x [d, f] whatever
-    the widths. A step's rows that run past its expert's (into the next
-    one's) are computed and not written. xs [m, d] -> [m, d_out] float32.
-    As many steps as the experts' rows need (a dynamic trip count: forward
-    only)."""
+    the widths. An expert with more rows than a step takes several and
+    reads its weights in each. A step's rows that are not its expert's (the
+    next one's behind them; at the array's end, where the step is moved up
+    to end with the rows, the ones before) are computed and not written.
+    xs [m, d] -> [m, d_out] float32. As many steps as the experts' rows
+    need (a dynamic trip count: forward only)."""
     m, d = xs.shape
     e = load.shape[0]
+    block = min(block, m)
     steps = -(-load // block)                                 # [e]
     ends = jnp.cumsum(steps)
     rows0 = jnp.cumsum(load) - load                           # first row of e
     i = jnp.arange(-(-m // block) + e, dtype=jnp.int32)       # every step
     owner = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), e - 1)
     within = (i - (ends - steps)[owner]) * block              # rows before it
-    start = (rows0[owner] + within).astype(jnp.int32)
-    count = jnp.clip(load[owner] - within, 0, block).astype(jnp.int32)
-    xs = jnp.pad(xs, ((0, block), (0, 0)))      # a slice never runs off
+    first = (rows0[owner] + within).astype(jnp.int32)         # its own rows:
+    last = first + jnp.clip(load[owner] - within, 0, block).astype(jnp.int32)
+    start = jnp.minimum(first, m - block)       # a slice never runs off
     dot = functools.partial(jnp.matmul, preferred_element_type=jnp.float32)
 
     def step(j, out):
@@ -364,14 +388,13 @@ def blocked_expert_rows(xs, w_in, w_dn, act: str, load,
         y = expert_ffn(x, w1, w2, act,
                        lambda a, w: dot(a, w).astype(xs.dtype), dot)
         old = jax.lax.dynamic_slice_in_dim(out, start[j], block)
-        keep = (jnp.arange(block) < count[j])[:, None]
+        row = start[j] + jnp.arange(block)
+        keep = ((row >= first[j]) & (row < last[j]))[:, None]
         return jax.lax.dynamic_update_slice_in_dim(
             out, jnp.where(keep, y, old), start[j], 0)
 
-    out = jax.lax.fori_loop(
-        0, ends[-1], step,
-        jnp.zeros((m + block, w_dn.shape[-1]), jnp.float32))
-    return out[:m]
+    return jax.lax.fori_loop(
+        0, ends[-1], step, jnp.zeros((m, w_dn.shape[-1]), jnp.float32))
 
 
 def _int_zero(a):
@@ -569,7 +592,7 @@ class MoELayer(Layer):
     # ragged_dot's 2.36 at GLM-4.7-Flash's sizes (chip run, PR 27). Up to
     # that level, whole: at 192 rows x top-6 over 64 held experts of 2688 x
     # 1856 a tick read 25.75 ms this way and 122.6 sorted (chip run, PR 33)
-    DENSE_ROWS = 240
+    DENSE_ROWS = RIDGE_FLOPS_PER_BYTE
 
     def __init__(self, hidden_size: int, ffn_size: int, num_experts: int,
                  top_k: int = 2, capacity_factor: Optional[float] = 1.25,
@@ -912,6 +935,19 @@ class MoELayer(Layer):
         return out, (jnp.zeros((), jnp.float32) if self.router == "mlp"
                      else _aux_loss(probs, e))
 
+    def inference_path(self, t: int, dtype=None):
+        """How ``forward_inference`` runs the experts over a call of ``t``
+        rows of ``dtype`` (the experts' own if not given), from shapes
+        alone: ("dense", None): every held expert over every row; or
+        ("loop", rows of a step): the rows sorted to their experts and
+        ``blocked_expert_rows`` over them (``expert_step_rows``)."""
+        e, k, held = self.num_experts, self.top_k, self.num_held
+        hits = t * k * held // e    # choices expected on the held experts
+        if t <= self.DENSE_ROWS and hits >= held:
+            return "dense", None
+        return "loop", expert_step_rows(
+            t, k, e, dtype or self.experts.w_down.dtype)
+
     def forward_inference(self, x, router_state=None):
         """The routed block without a loss: x [b, s, d] -> (out [b, s, d],
         load [num_held] int32: the rows each expert held here was sent;
@@ -919,8 +955,11 @@ class MoELayer(Layer):
         elsewhere). Dropless at any load. At most ``DENSE_ROWS`` rows whose
         choices are expected to outnumber the held experts they fall on run
         every held expert over every row as one batched matmul; the rest
-        are sorted to their experts and go through XLA's ``ragged_dot``
-        (``xla_grouped_matmul``)."""
+        are sorted to their experts, run the loop over an expert's rows
+        (``blocked_expert_rows`` at ``expert_step_rows``: faster than XLA's
+        ``ragged_dot`` at every width and length a served model's prompts
+        have, ``tools/expert_path_probe.py``) and come back by gathers
+        (``inference_path`` says which, from shapes alone)."""
         b, s, d = x.shape
         t, e, k = b * s, self.num_experts, self.top_k
         held, act = self.num_held, self.experts.act
@@ -931,8 +970,8 @@ class MoELayer(Layer):
         load = jnp.bincount(ids.reshape(-1), length=held).astype(jnp.int32)
         w_in = self.experts.w_in.astype(flat.dtype)       # [held, d, (2)f]
         w_dn = self.experts.w_down.astype(flat.dtype)     # [held, f, d]
-        hits = t * k * held // e    # choices expected on the held experts
-        if t <= self.DENSE_ROWS and hits >= held:
+        path, step = self.inference_path(t, flat.dtype)
+        if path == "dense":
             # weight [t, held]: an expert's share of a row, 0 if not chosen
             weight = jnp.zeros((t, held), jnp.float32).at[
                 jnp.arange(t)[:, None], ids].add(gates, mode="drop")
@@ -947,18 +986,21 @@ class MoELayer(Layer):
                     preferred_element_type=jnp.float32))
             return out.astype(x.dtype).reshape(b, s, d), load
         flat_e = ids.T.reshape(-1)                            # [k*t]
-        order = jnp.argsort(flat_e, stable=True)
+        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
         xs = flat[order % t]                                  # [k*t, d]
-        if ragged_dot_tiles_small(d, w_dn.shape[1]):
-            ys = blocked_expert_rows(xs, w_in, w_dn, act, load)
-        else:
-            ys = expert_ffn(
-                xs, w_in, w_dn, act,
-                lambda a, w: xla_grouped_matmul(a, w, load).astype(
-                    flat.dtype),
-                lambda h, w: xla_grouped_matmul(h, w, load))  # f32
-        y_cm = jnp.zeros_like(ys).at[order].set(ys).reshape(k, t, d)
-        if self.skip_choice or held < e:
-            y_cm = jnp.where((ids.T < held)[..., None], y_cm, 0)
-        out = jnp.sum(gates.T[..., None] * y_cm, axis=0)
+        ys = blocked_expert_rows(xs, w_in, w_dn, act, load, step)  # f32
+        # each row's k outputs come back by GATHERS (``order`` is a
+        # permutation: sorted row inv[j, i] is row i's j-th choice; one that
+        # fell on no held expert sorted behind every expert's run, where
+        # ``ys`` is 0), weighted and added a choice at a time in float32. A
+        # scan, so that the k gathers run one after another: left to itself
+        # XLA runs them all first and keeps k x t float32 rows beside ``ys``
+        # (the peak of the widest prefill program)
+        inv = inverse_permutation(order).reshape(k, t)
+
+        def add(acc, choice):
+            rows, gate = choice
+            return acc + gate[:, None] * ys[rows], None
+        out, _ = jax.lax.scan(add, jnp.zeros((t, d), jnp.float32),
+                              (inv, gates.T))
         return out.astype(x.dtype).reshape(b, s, d), load

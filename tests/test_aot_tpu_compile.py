@@ -337,6 +337,46 @@ def test_a_mamba_decode_layer_updates_its_state_in_place(topo, monkeypatch):
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
 
 
+@pytest.mark.parametrize("tokens,step,temp_mib", [(1536, 192, 160),
+                                                 (4096, 256, 400)])
+def test_a_prompts_routed_layer_keeps_one_copy_of_its_sorted_rows(
+        topo, tokens, step, temp_mib):
+    """One expert layer of Nemotron-3-Nano's prefill (64 of 128 experts of
+    2688 x 1856 held, top-6) at a mid and at the widest prompt: the rows
+    run the loop over an expert's rows at the step the shapes give, no
+    ``ragged-dot``, no padded copy and no scatter of the float32 rows; the
+    k gathers back run one after another (a second loop), so the program
+    keeps the sorted rows once in bf16 and once in float32 and a choice's
+    rows beside them: at 4,096 tokens 381 MiB where the scatter form kept
+    510 (PR 37), in the program that sets the cell's peak (0.24 GiB of the
+    chip are left there)."""
+    from paddle_tpu.base import LazyGuard
+    from paddle_tpu.parallel.moe import MoELayer
+    with LazyGuard():
+        layer = MoELayer(2688, 1856, 128, top_k=6, capacity_factor=None,
+                         dtype="bfloat16", scoring="sigmoid",
+                         select_bias=True, norm_topk_prob=True,
+                         routed_scaling_factor=2.5, experts_held=(0, 64),
+                         expert_act="relu2").eval()
+    assert layer.inference_path(tokens) == ("loop", step)
+
+    def fn(p, x):
+        with layer._bind(p):
+            return layer.forward_inference(x)
+    dev = SingleDeviceSharding(topo.devices[0])
+    leaves = {n: jax.ShapeDtypeStruct(p.value.shape, p.value.dtype,
+                                      sharding=dev)
+              for n, p in layer.named_parameters()}
+    x = jax.ShapeDtypeStruct((1, tokens, 2688), BF16, sharding=dev)
+    compiled = jax.jit(fn).lower(leaves, x).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 2 and "ragged-dot" not in text
+    rows = tokens * 6
+    assert f"f32[{rows},2688]" in text and f"[{rows + step},2688]" not in text
+    assert not re.search(rf"f32\[{rows},2688\]\S* scatter\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mib * 2 ** 20
+
+
 @pytest.fixture(scope="module")
 def olmoe_routed_layer(topo):
     """OLMoE's routed layer as ``olmoe.pretrain-4k`` runs it (8 x 4096

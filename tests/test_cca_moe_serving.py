@@ -259,10 +259,43 @@ def test_the_skip_choice_runs_no_expert_and_the_weight_is_the_probability():
         assert np.allclose(np.asarray(got[0, t]), want, atol=1e-6), t
 
 
+def test_a_prompts_sorted_rows_equal_the_router_written_out():
+    """ZAYA-like: top-1 behind the MLP router, the skip choice in it; 300
+    rows, more than ``DENSE_ROWS``, so they are sorted to their experts,
+    the skipped ones behind every expert's run, run the loop over an
+    expert's rows and come back by a gather. Row by row against the chosen
+    expert's SwiGLU times the softmax's probability, 0 for a skipped row."""
+    layer = _mlp_layer().eval()
+    x = jax.random.normal(jax.random.key(1), (1, 300, 16))
+    r = layer.router_state(x)
+    assert layer.inference_path(300) == ("loop", 128)
+    got, load = layer.forward_inference(x, r)
+    text = str(jax.make_jaxpr(layer.forward_inference)(x, r))
+    assert "while" in text and "ragged_dot" not in text
+    n = r[0] * jax.lax.rsqrt(jnp.mean(r[0] ** 2, -1, keepdims=True) + 1e-5)
+    h = jax.nn.gelu(n @ layer.router_w1 + layer.router_b1, approximate=False)
+    h = jax.nn.gelu(h @ layer.router_w2 + layer.router_b2, approximate=False)
+    p = np.asarray(jax.nn.softmax(h @ layer.router_w3, -1))
+    choice = p.argmax(-1)
+    assert 10 < (choice == 6).sum() < 290
+    assert np.array_equal(np.asarray(load),
+                          np.bincount(choice, minlength=7)[:6])
+    g, u = jnp.split(jnp.einsum("td,edf->etf", x[0],
+                                layer.experts.w_gate_up), 2, -1)
+    every = np.asarray(jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u,
+                                  layer.experts.w_down))
+    want = np.stack([np.zeros(16, np.float32) if c == 6
+                     else p[t, c] * every[c, t]
+                     for t, c in enumerate(choice)])
+    assert np.abs(want).max() > 1e-4
+    assert np.abs(np.asarray(got[0]) - want).max() < 1e-6
+
+
 @pytest.mark.parametrize("skip", [True, False])
 def test_both_inference_paths_and_the_training_path_agree(monkeypatch, skip):
     """Every expert over every row (at most DENSE_ROWS rows), rows sorted
-    to their experts (``ragged_dot``) and the differentiated dropless path
+    to their experts (the loop over an expert's rows) and the differentiated
+    dropless path (``ragged_dot``)
     compute one result under the MLP router, skipped rows and all."""
     layer = _mlp_layer(skip)
     x = jax.random.normal(jax.random.key(4), (2, 24, 16))

@@ -38,8 +38,10 @@ from paddle_tpu.models.hybrid_lm import (HybridConfig,  # noqa: E402
 from paddle_tpu.ops.pallas.ssm import (pack_state,  # noqa: E402
                                        ssm_state_update,
                                        ssm_state_update_xla, unpack_state)
+from paddle_tpu.base import LazyGuard  # noqa: E402
 from paddle_tpu.parallel.moe import (MoELayer, blocked_expert_rows,  # noqa: E402
-                                     expert_ffn, ragged_dot_tiles_small,
+                                     expert_ffn, expert_step_rows,
+                                     inverse_permutation,
                                      xla_grouped_matmul)
 
 TOL = 3e-5
@@ -351,37 +353,102 @@ def test_relu2_experts_have_no_gate_and_swiglu_ones_are_as_they_were():
                    @ gated.experts.w_down[0]), atol=1e-6)
 
 
+@pytest.mark.parametrize("block", [16, 32, 64])
 @pytest.mark.parametrize("act", ["relu2", "swiglu"])
-def test_blocked_expert_rows_equal_the_ragged_products(act):
-    """Rows sorted by expert, through a loop over blocks of 16 rows of one
-    expert against ``ragged_dot``: an expert with no row, one with less
-    than a block, one with exactly two blocks, one that ends inside a
-    block, and rows past every expert's, which come back 0."""
-    load = jnp.array([0, 5, 32, 37, 0, 16, 1], jnp.int32)
+def test_blocked_expert_rows_equal_the_ragged_products(act, block):
+    """Rows sorted by expert, through a loop over steps of ``block`` rows of
+    one expert against ``ragged_dot``: an expert with no row, one with less
+    than a step, one with exactly two steps of 16, one that ends inside a
+    step, one that needs three steps of 64 and more of the others, and rows
+    past every expert's, which come back 0. ``m`` is no whole number of
+    steps and the rows are NOT padded: the last expert's last step is moved
+    up to end with the array and writes only its own rows."""
+    load = jnp.array([0, 5, 32, 37, 0, 150, 1], jnp.int32)
     m, d, f = int(load.sum()) + 9, 24, 40
+    assert m % block and int(load.max()) > 2 * block
     k = jax.random.split(jax.random.key(4), 3)
     xs = jax.random.normal(k[0], (m, d))
     w_in = jax.random.normal(k[1], (7, d, f * (2 if act == "swiglu" else 1)))
     w_dn = jax.random.normal(k[2], (7, f, d))
-    got = jax.jit(lambda *a: blocked_expert_rows(*a, act, load, block=16))(
-        xs, w_in, w_dn)
+    run = jax.jit(lambda *a: blocked_expert_rows(*a, act, load, block))
+    got = run(xs, w_in, w_dn)
+    assert "pad" not in str(jax.make_jaxpr(run)(xs, w_in, w_dn))
     gmm = lambda a, w: xla_grouped_matmul(a, w, load)
     want = expert_ffn(xs, w_in, w_dn, act, gmm, gmm)
+    assert got.shape == want.shape and got.dtype == jnp.float32
     assert np.abs(np.asarray(want)).max() > 1.0
     assert np.allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                        atol=1e-4)
     assert not np.asarray(got[int(load.sum()):]).any()
+    # every row an expert's: the last step ends exactly with the array
+    full = jnp.array([0, 5, 32, 37, 0, 150, 10], jnp.int32)
+    got = jax.jit(lambda *a: blocked_expert_rows(*a, act, full, block))(
+        xs, w_in, w_dn)
+    gmm = lambda a, w: xla_grouped_matmul(a, w, full)
+    assert np.allclose(np.asarray(got), np.asarray(
+        expert_ffn(xs, w_in, w_dn, act, gmm, gmm)), rtol=1e-5, atol=1e-4)
 
 
-def test_widths_that_ragged_dot_tiles_badly_take_the_blocked_path():
-    """2688 x 1856 (Nemotron's experts) is not whole 256-lane tiles and
-    takes the loop over blocks; OLMoE's, GLM's and ZAYA's widths and every
-    tiny test size keep ``ragged_dot``. A layer of such widths, half its
-    experts held, agrees with the reference on the sorted path."""
-    assert ragged_dot_tiles_small(2688, 1856)
-    for d, f in ((2048, 1024), (2048, 1536), (2048, 2048), (32, 48),
-                 (64, 48)):
-        assert not ragged_dot_tiles_small(d, f)
+def test_the_rows_come_back_by_a_gather_equal_to_the_scatter():
+    """``ys[inverse_permutation(order)]`` is ``zeros.at[order].set(ys)`` for
+    the stable argsort of choices of which some belong to no held expert
+    (they sort behind every expert's run): the same numbers in the same
+    places, as a gather."""
+    ids = jax.random.randint(jax.random.key(0), (333,), 0, 7)   # 6: none
+    order = jnp.argsort(ids, stable=True).astype(jnp.int32)
+    assert int((ids == 6).sum()) > 20
+    ys = jax.random.normal(jax.random.key(1), (333, 5))
+    inv = inverse_permutation(order)
+    assert np.array_equal(np.asarray(inv[order]), np.arange(333))
+    assert np.array_equal(np.asarray(ys[inv]),
+                          np.asarray(jnp.zeros_like(ys).at[order].set(ys)))
+    text = str(jax.make_jaxpr(lambda y, o: y[inverse_permutation(o)])(
+        ys, order))
+    import re
+    assert re.findall(r"(\w+\[[\d,]*\]) = scatter", text) == ["i32[333]"]
+
+
+@pytest.mark.parametrize("shape,step", [
+    # (t, k, router width, dtype): the three routed cells' prompts
+    ((768, 6, 128, "bfloat16"), 128),       # Nemotron: 36 rows an expert
+    ((1536, 6, 128, "bfloat16"), 192),      # 72
+    ((3072, 6, 128, "bfloat16"), 256),      # 144: the ridge
+    ((640, 4, 64, "bfloat16"), 128),        # GLM: 40
+    ((1152, 4, 64, "bfloat16"), 192),       # 72
+    ((1920, 4, 64, "bfloat16"), 256),       # 120
+    ((256, 1, 16, "bfloat16"), 64),         # ZAYA: 16
+    ((512, 1, 16, "bfloat16"), 64),         # 32
+    ((1024, 1, 16, "bfloat16"), 128),       # 64
+    # the edges: fewer expected rows than the least step, more than the
+    # largest, and float32, whose ridge lies at twice the rows
+    ((241, 1, 64, "bfloat16"), 64),
+    ((4096, 8, 8, "bfloat16"), 256),
+    ((4096, 8, 8, "float32"), 512),
+    ((300, 3, 8, "float32"), 256),
+])
+def test_a_step_holds_twice_the_rows_an_expert_is_expected_to_get(shape,
+                                                                  step):
+    t, k, e, dtype = shape
+    got = expert_step_rows(t, k, e, dtype)
+    assert got == step and got % 64 == 0
+    assert got >= min(2 * t * k / e, 120 * jnp.dtype(dtype).itemsize)
+
+
+def test_a_prompts_rows_take_the_loop_at_every_width():
+    """More rows than ``DENSE_ROWS`` are sorted and run the loop over an
+    expert's rows whatever the experts' widths (Nemotron's 2688 x 1856, which
+    ``ragged_dot`` tiles 128^3, and OLMoE's, GLM's and ZAYA's, where the
+    loop read 1.5 to 1.9 times faster): no ``ragged_dot`` is left on the
+    inference path, and a call's step follows its rows. A layer of widths
+    that are not whole 256-lane tiles, half its experts held, agrees with
+    the reference on the sorted path."""
+    for d, f in ((2688, 1856), (2048, 1024), (2048, 1536), (2048, 2048)):
+        with LazyGuard():
+            wide = MoELayer(d, f, 64, top_k=4, capacity_factor=None,
+                            dtype="bfloat16")
+        assert wide.inference_path(64) == ("dense", None)
+        assert wide.inference_path(640) == ("loop", 128)
+        assert wide.inference_path(1920, jnp.float32) == ("loop", 256)
     pt.seed(5)
     layer = MoELayer(384, 320, 8, top_k=3, capacity_factor=None,
                      dtype="float32", scoring="sigmoid", select_bias=True,
@@ -393,10 +460,42 @@ def test_widths_that_ragged_dot_tiles_badly_take_the_blocked_path():
          experts__w_down=4.0 * layer.experts.w_down)
     x = jax.random.normal(jax.random.key(2), (1, 300, 384))
     got, load = layer.forward_inference(x)
-    assert "while" in str(jax.make_jaxpr(layer.forward_inference)(x))
+    text = str(jax.make_jaxpr(layer.forward_inference)(x))
+    assert "while" in text and "ragged_dot" not in text
+    assert layer.inference_path(300) == ("loop", 256)
     want = _ref_experts(layer, x, 2, 4)
     assert np.abs(want).max() > 0.3 and int(load.sum()) > 300
     assert np.abs(np.asarray(got) - want).max() < 1e-4
+
+
+def test_the_probe_times_both_forms_and_leaves_the_layer_as_it_was(capsys):
+    """``tools/expert_path_probe.py`` at a tiny size on the CPU: one line a
+    length with the loads, the steps they need and the rule's choice (what
+    the engine writes into ``build_log``), a variant for ``ragged_dot`` and
+    one a step; no device plane in a CPU trace, so every time is null; the
+    functions it steers the layer by are back in place after it."""
+    import importlib.util
+    from paddle_tpu.parallel import moe
+    spec = importlib.util.spec_from_file_location(
+        "expert_path_probe", os.path.join(ROOT, "tools",
+                                          "expert_path_probe.py"))
+    probe = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(probe)
+    before = (moe.expert_step_rows, moe.blocked_expert_rows)
+    probe.main("--t 300,500 --k 3 --e 8 --held 4 --d 64 --f 48 --act relu2 "
+               "--dtype float32 --steps 16,64 --skew 0.2 --iters 1".split())
+    assert (moe.expert_step_rows, moe.blocked_expert_rows) == before
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
+    assert [l["t"] for l in lines] == [300, 500]
+    for line in lines:
+        assert line["expert_path"] == "loop"
+        assert line["expert_step_rows"] == expert_step_rows(
+            line["t"], 3, 8, "float32")
+        assert list(line["ms"]) == ["rule", "ragged", "loop_16", "loop_64"]
+        assert line["device"] == "cpu" and not any(line["ms"].values())
+        load = line["load"]
+        assert 0 < load["sum"] < 3 * line["t"]      # half the experts held
+        assert load["steps"]["16"] > load["steps"]["64"] >= 4
 
 
 @pytest.mark.parametrize("kw,word", [
@@ -425,11 +524,10 @@ def test_capacity_and_expert_parallel_paths_refuse_both_by_name(kw, word):
 # equation. (jax 0.9.0's printing: a jax upgrade re-pins them from the
 # commit before it.)
 PARENT_JAXPRS = {
-    "gshard.infer4": "ceb4f45443e080c5", "gshard.infer320": "3a8fb23ca48c1280",
+    "gshard.infer4": "ceb4f45443e080c5",
     "gshard.train": "8b10471cbf1c1896", "gshard.grad": "aa6b84a2d69af151",
-    "glm.infer4": "4c1df47df0307047", "glm.infer320": "84954e4197067162",
+    "glm.infer4": "4c1df47df0307047",
     "glm.train": "129fe7319009d631", "glm.grad": "53a89d1ffdd80f3e",
-    "zaya.infer4": "ecc687bcccd8f601", "zaya.infer320": "f83a19fae139d987",
     "zaya.train": "ce7271d7d7478edc", "zaya.grad": "010f90f8331694ce",
     "capacity.train": "362adbfaf6c2cb73",
 }
@@ -443,6 +541,19 @@ CHANGED_JAXPRS = {
     "gshard.infer192": ("cb623e2962d91b43", "4add2fc62f354902"),
     "glm.infer192": ("8adb0262f0108aa2", "21591b9956195c71"),
     "zaya.infer192": ("6995382409854b85", "5c84fd9f810d6450"),
+}
+# The sorted inference path is PR 37's: the loop over an expert's rows at
+# every width (the default layer's widths took ``ragged_dot`` before) and the
+# rows back by gathers (name: (PR 37's, PR 33's = the parent's)). The dense
+# path (4 and 192 rows: what a decode tick runs) and the training programs
+# above are untouched.
+SORTED_JAXPRS = {
+    "gshard.infer320": ("af1260f576b70290", "3a8fb23ca48c1280"),
+    "glm.infer320": ("54c1856b5997cf0e", "84954e4197067162"),
+    "zaya.infer320": ("af1bcec1762b81ec", "f83a19fae139d987"),
+    # 4 rows x top-1 are expected on 4 of the 8 experts: fewer than are
+    # held, so such a call was sorted before and is now
+    "zaya.infer4": ("ee712a2ba9af1256", "ecc687bcccd8f601"),
 }
 ROUTERS = {
     "gshard": {},
@@ -472,10 +583,16 @@ def test_the_default_layers_jaxpr_is_the_parents(name):
     state = ((lambda x: layer.router_state(x))
              if kw.get("router") == "mlp" else (lambda x: None))
     layer.eval()
-    for rows in (4, 320):         # the dense and the sorted inference paths
-        assert _sha(lambda x: layer.forward_inference(x, state(x)),
-                    jnp.ones((1, rows, 32))) == \
-            PARENT_JAXPRS[f"{name}.infer{rows}"]
+    for rows in (4, 320):
+        text = str(jax.make_jaxpr(lambda x: layer.forward_inference(
+            x, state(x)))(jnp.ones((1, rows, 32))))
+        sha = hashlib.sha256(text.encode()).hexdigest()[:16]
+        if layer.inference_path(rows)[0] == "dense":
+            assert sha == PARENT_JAXPRS[f"{name}.infer{rows}"]
+            continue
+        ours, parents = SORTED_JAXPRS[f"{name}.infer{rows}"]
+        assert sha == ours != parents
+        assert "ragged_dot" not in text and "while" in text
     ours, parents = CHANGED_JAXPRS[f"{name}.infer192"]
     text = str(jax.make_jaxpr(
         lambda x: layer.forward_inference(x, state(x)))(jnp.ones((1, 192, 32))))
@@ -575,6 +692,46 @@ def test_engine_serves_the_references_tokens_with_slots_reused(hybrid,
     assert _gaps(reference, prompts, outs).max() < TOL
     stats = eng.stats()
     assert stats["active"] == 0 and stats["free_pages"] == ENGINE["num_pages"]
+
+
+@pytest.mark.parametrize("kind", ["hybrid", "routed-gqa", "llama"])
+def test_a_prefill_programs_build_log_row_says_how_its_experts_run(hybrid,
+                                                                   kind):
+    """``build_log``'s row of a prefill program (and the
+    ``compile::prefill_paged`` span with it) carries ``expert_path`` and,
+    where the rows are sorted, ``expert_step_rows``: what the model's
+    ``expert_path(rows)`` says, static per program. A bucket of at most
+    ``DENSE_ROWS`` rows runs every held expert over every row; a model
+    without routed layers leaves both keys out."""
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.models.moe_lm import MoEConfig, MoEForCausalLM
+    model = {
+        "hybrid": lambda: hybrid[1],
+        "routed-gqa": lambda: MoEForCausalLM(MoEConfig.tiny(
+            capacity_factor=None, dtype="float32")).eval(),
+        "llama": lambda: LlamaForCausalLM(
+            LlamaConfig.tiny(dtype="float32")).eval(),
+    }[kind]()
+    eng = _engine(model, max_len=288, num_pages=40)
+    for n in (20, 250):
+        eng.submit(_ids(n, n) % 200, max_new_tokens=2)
+    eng.run()
+    rows = {r["bucket"]: r for r in eng.build_log
+            if r["name"] == "prefill_paged"}
+    assert sorted(rows) == [32, 256]
+    if kind == "llama":
+        assert not hasattr(model, "expert_path")
+        assert all("expert_path" not in r and "expert_step_rows" not in r
+                   for r in eng.build_log)
+        return
+    assert rows[32]["expert_path"] == "dense"
+    assert "expert_step_rows" not in rows[32]
+    layer = next(l for l in model.sublayers() if isinstance(l, MoELayer))
+    step = expert_step_rows(256, layer.top_k, layer.num_experts, "float32")
+    assert rows[256]["expert_path"] == "loop"
+    assert rows[256]["expert_step_rows"] == step == model.expert_path(256)[1]
+    assert all("expert_path" not in r for r in eng.build_log
+               if not r["name"].startswith("prefill"))
 
 
 def test_a_request_in_a_reused_slot_equals_itself_in_a_fresh_engine(hybrid,

@@ -24,6 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import paddle_tpu as pt  # noqa: E402
 from benchmarks import families, program, weights  # noqa: E402
 from paddle_tpu.inference import ContinuousBatchingEngine  # noqa: E402
 from paddle_tpu.inference.generation import GenerationConfig  # noqa: E402
@@ -205,16 +206,51 @@ def test_the_batched_and_the_sorted_inference_paths_agree(monkeypatch):
     assert int(np.asarray(load_a).sum()) == 2 * 9 * 2
 
 
+def test_a_prompts_sorted_rows_equal_every_expert_written_out():
+    """GLM-like: 16 SwiGLU experts, all held, top-4 of sigmoid scores + a
+    selection bias, renormalised, x 1.8; 300 rows, more than ``DENSE_ROWS``,
+    so the rows are sorted, run the loop over an expert's rows (a step of
+    192 where the busiest expert has more: it takes two) and come back by
+    gathers. Against every expert over every row, written out."""
+    pt.seed(3)
+    layer = MoELayer(16, 24, 16, top_k=4, capacity_factor=None,
+                     dtype="float32", scoring="sigmoid", select_bias=True,
+                     norm_topk_prob=True, routed_scaling_factor=1.8).eval()
+    _set(layer, "gate_weight", jax.random.normal(jax.random.key(1), (16, 16)))
+    _set(layer, "gate_bias", 0.8 * jax.random.normal(jax.random.key(2), (16,)))
+    _set(layer, "experts.w_gate_up", 6.0 * layer.experts.w_gate_up)
+    _set(layer, "experts.w_down", 6.0 * layer.experts.w_down)
+    x = jax.random.normal(jax.random.key(4), (1, 300, 16))
+    assert layer.inference_path(300) == ("loop", 192)
+    got, load = layer.forward_inference(x)
+    assert "while" in str(jax.make_jaxpr(layer.forward_inference)(x))
+    assert int(load.sum()) == 1200 and int(load.max()) > 192
+
+    with jax.default_matmul_precision("highest"):
+        scores = jax.nn.sigmoid(x[0] @ layer.gate_weight)
+        _, ids = jax.lax.top_k(scores + layer.gate_bias, 4)
+        w = jnp.take_along_axis(scores, ids, -1)
+        w = 1.8 * w / w.sum(-1, keepdims=True)
+        weight = jnp.zeros((300, 16)).at[jnp.arange(300)[:, None], ids].add(w)
+        g, u = jnp.split(jnp.einsum("td,edf->etf", x[0],
+                                    layer.experts.w_gate_up), 2, -1)
+        every = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u,
+                           layer.experts.w_down)
+        want = jnp.einsum("te,etd->td", weight, every)
+    assert np.abs(np.asarray(want)).max() > 0.05
+    assert np.abs(np.asarray(got[0]) - np.asarray(want)).max() < 1e-5
+
+
 @pytest.mark.parametrize("router", [
     dict(),
     dict(scoring="sigmoid", select_bias=True, norm_topk_prob=True,
          routed_scaling_factor=1.8)], ids=["gshard", "sigmoid_bias_scale"])
 def test_the_training_and_the_sorted_inference_paths_agree(router):
     """More rows than ``DENSE_ROWS``: a layer in train mode
-    (``_forward_dropless``: ``grouped_matmul``) and the same layer in eval
-    mode (``forward_inference``: ``xla_grouped_matmul``) run the one
-    ``ragged_dot`` over the same sorted rows, so the same weights give the
-    same output."""
+    (``_forward_dropless``: ``grouped_matmul``, XLA's ``ragged_dot``) and
+    the same layer in eval mode (``forward_inference``: the loop over an
+    expert's rows) multiply the same sorted rows by the same weights, so
+    they give the same output."""
     layer = _router_layer(**router)
     x = jax.random.normal(jax.random.key(6), (2, 160, 16))
     assert x.shape[0] * x.shape[1] > MoELayer.DENSE_ROWS

@@ -133,8 +133,17 @@ def test_a_cca_models_prefill_says_its_attention_and_its_tick_counts_skips(
         eng.run()
     finally:
         jax.profiler.stop_trace()
-    pre = _host_spans(tmp_path)["serving::prefill"][0]
+    spans = _host_spans(tmp_path)
+    pre = spans["serving::prefill"][0]
     assert pre["attn"] == "cca" and pre["kind"] == "full"
+    # the prefill program's build says by name how its experts run: 8 rows
+    # x top-1 over 4 experts are expected on all of them, so every expert over
+    # every row (``expert_step_rows`` rides only with the sorted rows' loop)
+    (built,) = spans["compile::prefill_paged"]
+    assert built["expert_path"] == "dense" and built["bucket"] == 8
+    assert "expert_step_rows" not in built
+    (row,) = [r for r in eng.build_log if r["name"] == "prefill_paged"]
+    assert row["expert_path"] == "dense" and "expert_step_rows" not in row
     stats = eng.stats()
     assert {"moe_assignments", "moe_peak_load", "moe_skipped",
             "kv_bytes_per_token", "slot_state_bytes"} <= set(stats)
