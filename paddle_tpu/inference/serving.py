@@ -1151,7 +1151,10 @@ class ContinuousBatchingEngine:
         row; every later one is a set lookup. A prefill program of a routed
         model also says how its experts run at that many rows
         (``core.expert_path``: ``expert_path``, and ``expert_step_rows``
-        where the path is the loop over an expert's rows)."""
+        where the path is the loop over an expert's rows); a prefill
+        program and the tick of a model with state-space layers say which
+        form their recurrence took (``core.state_path``: ``state_path``,
+        "kernel" or "xla")."""
         key = (program, *shape.values())
         if key in self._built:
             return _NULL
@@ -1160,6 +1163,9 @@ class ContinuousBatchingEngine:
         how = getattr(self.core, "expert_path", None)
         path, step = (rows and how and how(rows)) or (None, None)
         said = {"expert_path": path, "expert_step_rows": step}
+        state = getattr(self.core, "state_path", None)
+        if state and program in ("prefill_paged", "run"):
+            said["state_path"] = state(rows, self.max_batch)
         return compile_cache.building(
             program, self.build_log, **shape,
             **{k: v for k, v in said.items() if v})

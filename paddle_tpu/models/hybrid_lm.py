@@ -1,7 +1,8 @@
 """Hybrid state-space / attention / expert causal LM (``nemotron_h``'s layout,
-as NVIDIA-Nemotron-3-Nano-30B-A3B publishes it).
+as NVIDIA-Nemotron-3-Nano-30B-A3B publishes it, and ``jamba``'s, as
+AI21-Jamba2-3B does).
 
-A decoder of blocks ``x + Mixer(RMSNorm(x))`` whose mixer is ONE of three
+A decoder of blocks ``x + Mixer(RMSNorm(x))`` whose mixer is ONE of five
 kinds, by a pattern string with one character a block:
 
 - ``M``: a Mamba-2 layer (``Mamba2Mixer``, arXiv:2405.21060): one input
@@ -16,6 +17,17 @@ kinds, by a pattern string with one character a block:
   (``parallel.moe.MoELayer``), non-gated: ``relu(x W_up)^2 W_down``. The layer
   may hold a SHARE of the experts (``experts_held``: one chip of an
   expert-parallel deployment; the router keeps its full width).
+- ``m``: a Mamba-1 layer (``Mamba1Mixer``, arXiv:2312.00752, with Jamba's
+  inner norms): an input projection to ``x`` and a gate ``z``; the same
+  convolution and SiLU over ``x``; ``[dt | B | C] = x W_x``, each through an
+  RMSNorm of its own, the step ``Delta = softplus(dt W_dt + b_dt)`` a CHANNEL
+  through a low-rank bottleneck; the recurrence ``h_t = exp(Delta A) h_{t-1}
+  + Delta x_t (x) B_t``, ``y_t = h_t C_t + D x_t`` over a float32 state
+  [state size, channels] in which EVERY element has a decay of its own (``A``
+  [state size, channels]); ``y silu(z)``, no norm; one output projection.
+- ``-``: a dense gated MLP (``GatedMLP``): ``(silu(x W_gate) * (x W_up))
+  W_down``. A Jamba layer is TWO blocks, its mixer's and this one (``m-`` or
+  ``*-``), each behind its own RMSNorm, as the published layer is.
 
 Served through ``inference.ContinuousBatchingEngine`` by the interface it has
 (``alloc_paged_caches`` / ``alloc_slot_state`` / ``prefill_paged`` /
@@ -23,11 +35,14 @@ Served through ``inference.ContinuousBatchingEngine`` by the interface it has
 state for the Mamba layers only: the convolution's last ``conv_kernel - 1``
 inputs (activation dtype) and the recurrence's state in float32, which a
 prefill writes at the prompt's true last position and every decode tick
-rewrites in place (``ops.pallas.ssm.ssm_state_update`` on a TPU). A prompt
-runs the same recurrence in chunks (``ssd_chunked``: matrix products inside a
-chunk, the state carried from chunk to chunk). Not trained: ``forward`` is
-the whole-sequence form for tests and evaluation; the chunked scan has no
-hand-written backward and no training cell runs it.
+rewrites in place (``ops.pallas.ssm.ssm_state_update``, or for a Mamba-1
+layer ``ops.pallas.selective_ssm.selective_state_update``, on a TPU). A
+prompt runs the Mamba-2 recurrence in chunks (``ssd_chunked``: matrix
+products inside a chunk, the state carried from chunk to chunk) and the
+Mamba-1 recurrence, which has no such form, with time inside a kernel
+(``selective_scan``). Not trained: ``forward`` is the whole-sequence form for
+tests and evaluation; neither scan has a hand-written backward and no
+training cell runs one.
 """
 
 from __future__ import annotations
@@ -58,6 +73,10 @@ class HybridConfig:
     ssm_state_size: int = 128
     conv_kernel: int = 4
     chunk_size: int = 128
+    # Mamba-1 (the inner width is mamba_expand x hidden_size)
+    mamba_d_state: int = 16
+    mamba_dt_rank: int = 160
+    mamba_expand: int = 2
     # attention
     num_attention_heads: int = 32
     num_key_value_heads: int = 2
@@ -71,15 +90,18 @@ class HybridConfig:
     shared_expert_intermediate_size: int = 3712
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 2.5
+    intermediate_size: int = 8192          # the dense MLP's width
+    tie_word_embeddings: bool = False
     rms_norm_eps: float = 1e-5
     initializer_range: float = 0.02
     dtype: str = "float32"
 
     def __post_init__(self):
-        bad = set(self.pattern) - set("ME*")
+        bad = set(self.pattern) - set("ME*m-")
         if bad or not self.pattern:
             raise ValueError(f"pattern {self.pattern!r}: one of 'M' (Mamba-2)"
-                             f", 'E' (experts), '*' (attention) a block")
+                             f", 'm' (Mamba-1), '*' (attention), 'E' "
+                             f"(experts), '-' (dense MLP) a block")
         if (self.num_hidden_layers is not None
                 and not 0 < self.num_hidden_layers <= len(self.pattern)):
             raise ValueError(f"num_hidden_layers={self.num_hidden_layers}: "
@@ -109,6 +131,10 @@ class HybridConfig:
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
+    @property
+    def mamba1_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
     @staticmethod
     def tiny(**kw) -> "HybridConfig":
         return HybridConfig(**{**dict(
@@ -117,7 +143,8 @@ class HybridConfig:
             ssm_state_size=128, chunk_size=16, num_attention_heads=4,
             num_key_value_heads=2, head_dim=16, num_experts=8,
             num_experts_per_tok=2, moe_intermediate_size=32,
-            shared_expert_intermediate_size=64), **kw})
+            shared_expert_intermediate_size=64, mamba_dt_rank=8,
+            intermediate_size=96), **kw})
 
 
 def _dtype(cfg):
@@ -169,6 +196,20 @@ def ssd_chunked(x, dt, a, b_mat, c_mat, chunk: int):
     return y.reshape(b, L, H, P), last.reshape(b, H, P, N)
 
 
+def _conv_silu(taps, weight, bias):
+    """A depthwise causal convolution's output from its inputs a position,
+    ``taps`` a list of [.., channels] (oldest first; ``weight`` [taps,
+    channels], the last row the token's own), then SiLU."""
+    out = bias + sum(t.astype(jnp.float32) * weight[i]
+                     for i, t in enumerate(taps))
+    return jax.nn.silu(out).astype(taps[0].dtype)
+
+
+def _on_tpu() -> bool:
+    from ..ops.registry import backend_kind
+    return backend_kind() == "tpu"
+
+
 class Mamba2Mixer(nn.Layer):
     """One Mamba-2 layer (the module docstring has its equations)."""
 
@@ -205,13 +246,7 @@ class Mamba2Mixer(nn.Layer):
                 zxd[..., inner + conv:])
 
     def _conv(self, taps):
-        """The convolution's output from its ``conv_kernel`` inputs a
-        position, ``taps`` a list of [.., conv_dim] (oldest first), then
-        SiLU."""
-        out = self.conv_bias + sum(
-            t.astype(jnp.float32) * self.conv_weight[i]
-            for i, t in enumerate(taps))
-        return jax.nn.silu(out).astype(taps[0].dtype)
+        return _conv_silu(taps, self.conv_weight, self.conv_bias)
 
     def _split(self, xbc):
         """xBC [.., conv_dim] -> (x [.., H, P], B [.., G, N], C [.., G, N])."""
@@ -295,13 +330,21 @@ class Mamba2Mixer(nn.Layer):
         return out, (conv_state.at[slot].set(tail[0].astype(conv_state.dtype)),
                      ssm_state.at[slot].set(pack_state(ssm[0], self.g)))
 
+    def state_path(self, rows, slots: int) -> str:
+        """The form the recurrence takes ("kernel" or "xla") in a prompt of
+        ``rows`` positions (the chunked scan: matrix products, XLA's) or,
+        ``rows`` None, in a tick of ``slots`` slots."""
+        from ..ops.pallas.ssm import ssm_state_update_supported
+        if rows is not None or not _on_tpu():
+            return "xla"
+        state = jax.eval_shape(lambda: self.alloc_slot_state(slots))[1]
+        b_mat = jax.ShapeDtypeStruct((slots, self.g, self.n), jnp.float32)
+        return "kernel" if ssm_state_update_supported(state, b_mat) else "xla"
+
     def decode(self, u, state):
         """One token of every row u [b, 1, d] through the rows' state (the
         Pallas kernel on a TPU, in place; its ``jnp`` twin elsewhere)."""
-        from ..ops.pallas.ssm import (ssm_state_update,
-                                      ssm_state_update_supported,
-                                      ssm_state_update_xla)
-        from ..ops.registry import backend_kind
+        from ..ops.pallas.ssm import ssm_state_update, ssm_state_update_xla
         conv_state, ssm_state = state
         z, xbc, dt = self._project(u[:, 0])
         window = jnp.concatenate(
@@ -309,10 +352,158 @@ class Mamba2Mixer(nn.Layer):
         x, b_mat, c_mat = self._split(
             self._conv([window[:, i] for i in range(window.shape[1])]))
         delta, a = self._step(dt)
-        update = (ssm_state_update if backend_kind() == "tpu"
-                  and ssm_state_update_supported(ssm_state, b_mat)
+        update = (ssm_state_update
+                  if self.state_path(None, u.shape[0]) == "kernel"
                   else ssm_state_update_xla)
         y, ssm_state = update(ssm_state, x, delta, a, b_mat, c_mat)
+        return self._out(y, x, z)[:, None], (window[:, 1:], ssm_state)
+
+
+class Mamba1Mixer(nn.Layer):
+    """One Mamba-1 layer with Jamba's inner norms (the module docstring has
+    its equations). ``A_log`` is kept [state size, channels], the layout of
+    the state it decays (``ops.pallas.selective_ssm``), where the published
+    tensor is its transpose."""
+
+    def __init__(self, cfg: HybridConfig):
+        super().__init__()
+        self.cfg = cfg
+        d, std = cfg.hidden_size, cfg.initializer_range
+        inner, n, rank = cfg.mamba1_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+
+        def vector(shape, value):
+            return self.create_parameter(shape, dtype="float32",
+                                         initializer=I.Constant(value))
+
+        def matrix(shape, sharding):
+            return self.create_parameter(shape, dtype=cfg.dtype,
+                                         initializer=_normal(std),
+                                         sharding=sharding)
+        self.in_proj = matrix([d, 2 * inner], ("fsdp", None))     # [x | z]
+        self.conv_weight = vector([cfg.conv_kernel, inner], 1.0)  # last: now
+        self.conv_bias = vector([inner], 0.0)
+        self.x_proj = matrix([inner, rank + 2 * n], (None, None))  # [dt|B|C]
+        self.dt_norm = vector([rank], 1.0)
+        self.b_norm = vector([n], 1.0)
+        self.c_norm = vector([n], 1.0)
+        self.dt_proj = matrix([rank, inner], (None, None))
+        self.dt_bias = vector([inner], 0.0)
+        self.A_log = vector([n, inner], 0.0)
+        self.D = vector([inner], 1.0)
+        self.out_proj = matrix([inner, d], (None, "fsdp"))
+
+    def _project(self, u):
+        """u [.., d] -> (x [.., inner], the gate z [.., inner])."""
+        xz = jnp.matmul(u, self.in_proj.astype(u.dtype))
+        return jnp.split(xz, 2, axis=-1)
+
+    def _selection(self, x):
+        """x [.., inner] (convolved) -> (Delta [.., inner], B [.., N], C
+        [.., N]), float32: what the token selects of the recurrence."""
+        cfg, f32 = self.cfg, jnp.float32
+        n, rank = cfg.mamba_d_state, cfg.mamba_dt_rank
+
+        def norm(t, w):
+            return t * jax.lax.rsqrt(
+                jnp.mean(t * t, -1, keepdims=True) + cfg.rms_norm_eps) * w
+        dbc = jnp.matmul(x, self.x_proj.astype(x.dtype),
+                         preferred_element_type=f32)
+        dt = norm(dbc[..., :rank], self.dt_norm).astype(x.dtype)
+        delta = jax.nn.softplus(jnp.matmul(
+            dt, self.dt_proj.astype(x.dtype), preferred_element_type=f32)
+            + self.dt_bias)
+        return (delta, norm(dbc[..., rank:rank + n], self.b_norm),
+                norm(dbc[..., rank + n:], self.c_norm))
+
+    def _out(self, y, x, z):
+        """y [.., inner] float32 (the state's reading) -> the layer's
+        output: + D x, times silu(z), out_proj."""
+        y = (y + self.D * x.astype(jnp.float32)) * jax.nn.silu(
+            z.astype(jnp.float32))
+        return jnp.matmul(y.astype(z.dtype), self.out_proj.astype(z.dtype))
+
+    def state_path(self, rows, slots: int) -> str:
+        """The form the recurrence takes ("kernel" or "xla") in a prompt of
+        ``rows`` positions or, ``rows`` None, in a tick of ``slots``."""
+        from ..ops.pallas.selective_ssm import (
+            selective_scan_supported, selective_state_update_supported)
+        cfg = self.cfg
+        if not _on_tpu():
+            return "xla"
+        if rows is None:
+            ok = selective_state_update_supported(jax.ShapeDtypeStruct(
+                (slots, cfg.mamba_d_state, cfg.mamba1_inner), jnp.float32))
+        else:
+            ok = selective_scan_supported(jax.ShapeDtypeStruct(
+                (1, rows, cfg.mamba1_inner), _dtype(cfg)), cfg.mamba_d_state)
+        return "kernel" if ok else "xla"
+
+    def _sequence(self, u, last_idx=None):
+        """Whole sequences u [b, s, d] from a zero state: (output [b, s, d],
+        the convolution's inputs after position ``last_idx`` [b, k - 1,
+        inner], the state after it [b, N, inner]). Positions past
+        ``last_idx`` (a bucket's padding; None: the last) take a step of 0,
+        so they leave the state as it is."""
+        from ..ops.pallas.selective_ssm import (selective_scan,
+                                                selective_scan_xla)
+        b, s, _ = u.shape
+        k = self.cfg.conv_kernel
+        last_idx = s - 1 if last_idx is None else last_idx
+        x, z = self._project(u)
+        padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+        x = _conv_silu([padded[:, i:i + s] for i in range(k)],
+                       self.conv_weight, self.conv_bias)
+        delta, b_mat, c_mat = self._selection(x)
+        delta = jnp.where((jnp.arange(s) <= last_idx)[None, :, None],
+                          delta, 0.0)
+        scan = (selective_scan if self.state_path(s, b) == "kernel"
+                else selective_scan_xla)
+        y, state = scan(x, delta, -jnp.exp(self.A_log), b_mat, c_mat)
+        tail = jax.lax.dynamic_slice_in_dim(padded, last_idx + 1, k - 1,
+                                            axis=1)
+        return self._out(y, x, z), tail, state
+
+    def forward(self, u):
+        return self._sequence(u)[0]
+
+    # -- serving path --------------------------------------------------------
+
+    def alloc_slot_state(self, slots: int):
+        """(the convolution's last k - 1 inputs [slots, k - 1, inner] in the
+        activation dtype, the recurrence's state [slots, N, inner] in
+        float32 whatever the activation dtype)."""
+        cfg = self.cfg
+        return (jnp.zeros((slots, cfg.conv_kernel - 1, cfg.mamba1_inner),
+                          _dtype(cfg)),
+                jnp.zeros((slots, cfg.mamba_d_state, cfg.mamba1_inner),
+                          jnp.float32))
+
+    def prefill(self, u, state, slot, last_idx):
+        """The prompt of ONE sequence into slot ``slot``: the state written
+        is the state after the prompt's true last position ``last_idx``,
+        whatever the bucket the prompt was padded to."""
+        out, tail, ssm = self._sequence(u, last_idx)
+        conv_state, ssm_state = state
+        return out, (conv_state.at[slot].set(tail[0].astype(conv_state.dtype)),
+                     ssm_state.at[slot].set(ssm[0]))
+
+    def decode(self, u, state):
+        """One token of every row u [b, 1, d] through the rows' state (the
+        Pallas kernel on a TPU, in place; its ``jnp`` twin elsewhere)."""
+        from ..ops.pallas.selective_ssm import (selective_state_update,
+                                                selective_state_update_xla)
+        conv_state, ssm_state = state
+        x, z = self._project(u[:, 0])
+        window = jnp.concatenate(
+            [conv_state, x[:, None].astype(conv_state.dtype)], axis=1)
+        x = _conv_silu([window[:, i] for i in range(window.shape[1])],
+                       self.conv_weight, self.conv_bias)
+        delta, b_mat, c_mat = self._selection(x)
+        update = (selective_state_update
+                  if self.state_path(None, u.shape[0]) == "kernel"
+                  else selective_state_update_xla)
+        y, ssm_state = update(ssm_state, x, delta, -jnp.exp(self.A_log),
+                              b_mat, c_mat)
         return self._out(y, x, z)[:, None], (window[:, 1:], ssm_state)
 
 
@@ -401,6 +592,30 @@ class Relu2MLP(nn.Layer):
                           jnp.matmul, jnp.matmul)
 
 
+class GatedMLP(nn.Layer):
+    """A dense MLP: ``(silu(x W_gate) * (x W_up)) W_down``, no bias; gate and
+    up in one leaf [gate | up]."""
+
+    def __init__(self, cfg: HybridConfig):
+        super().__init__()
+        d, width, std = (cfg.hidden_size, cfg.intermediate_size,
+                         cfg.initializer_range)
+        self.gate_up_proj = self.create_parameter(
+            [d, 2 * width], dtype=cfg.dtype, initializer=_normal(std),
+            sharding=("fsdp", "tp"))
+        self.down_proj = self.create_parameter(
+            [width, d], dtype=cfg.dtype, initializer=_normal(std),
+            sharding=("tp", "fsdp"))
+
+    def forward(self, x):
+        return expert_ffn(x, self.gate_up_proj.astype(x.dtype),
+                          self.down_proj.astype(x.dtype), "swiglu",
+                          jnp.matmul, jnp.matmul)
+
+
+STATEFUL = "Mm"     # the kinds whose mixer keeps a per-slot state
+
+
 class HybridBlock(nn.Layer):
     """``x + Mixer(RMSNorm(x))``; ``kind`` is the block's pattern character."""
 
@@ -409,10 +624,9 @@ class HybridBlock(nn.Layer):
         self.kind = kind
         self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                dtype="float32")
-        if kind == "M":
-            self.mixer = Mamba2Mixer(cfg)
-        elif kind == "*":
-            self.mixer = NoPEAttention(cfg)
+        if kind in "Mm-*":
+            self.mixer = {"M": Mamba2Mixer, "m": Mamba1Mixer, "-": GatedMLP,
+                          "*": NoPEAttention}[kind](cfg)
         else:
             self.mixer = MoELayer(
                 cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
@@ -439,8 +653,9 @@ class HybridBlock(nn.Layer):
 
 
 class HybridForCausalLM(nn.Layer):
-    """The hybrid decoder with its embedding, final norm and (untied) head.
-    ``forward`` returns logits, or (loss, logits) given labels."""
+    """The hybrid decoder with its embedding, final norm and head (the
+    embedding's transpose under ``tie_word_embeddings``, else a leaf of its
+    own). ``forward`` returns logits, or (loss, logits) given labels."""
 
     attention_kind = "hybrid"       # what ``serving::prefill`` says of it
 
@@ -455,10 +670,11 @@ class HybridForCausalLM(nn.Layer):
                                     for kind in cfg.kinds])
         self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                dtype="float32")
-        self.lm_head = self.create_parameter(
-            [cfg.hidden_size, cfg.vocab_size], dtype=cfg.dtype,
-            initializer=_normal(cfg.initializer_range),
-            sharding=("fsdp", "tp"))
+        if not cfg.tie_word_embeddings:
+            self.lm_head = self.create_parameter(
+                [cfg.hidden_size, cfg.vocab_size], dtype=cfg.dtype,
+                initializer=_normal(cfg.initializer_range),
+                sharding=("fsdp", "tp"))
         # what a decode tick counts on the device (the engine adds them up
         # into ``stats()``): rows x top-k routed, whoever holds the chosen
         # expert; the most rows one HELD expert got; the choices that fell
@@ -468,10 +684,13 @@ class HybridForCausalLM(nn.Layer):
                               if "E" in cfg.kinds else ())
 
     def logits(self, hidden):
+        if self.cfg.tie_word_embeddings:
+            return jnp.matmul(hidden,
+                              self.embed_tokens.astype(hidden.dtype).T)
         return jnp.matmul(hidden, self.lm_head.astype(hidden.dtype))
 
-    def _kinds(self, kind: str):
-        return [layer for layer in self.layers if layer.kind == kind]
+    def _kinds(self, kinds: str):
+        return [layer for layer in self.layers if layer.kind in kinds]
 
     # -- serving path (inference.ContinuousBatchingEngine) -------------------
 
@@ -482,6 +701,14 @@ class HybridForCausalLM(nn.Layer):
         ``build_log`` row."""
         routed = self._kinds("E")
         return routed[0].mixer.inference_path(rows) if routed else None
+
+    def state_path(self, rows, slots: int):
+        """The form ("kernel" or "xla") the state-space layers' recurrence
+        takes in a prefill program of ``rows`` positions or, ``rows`` None,
+        in a tick of ``slots`` slots (``Mamba1Mixer.state_path``), None
+        without such a layer: ``build_log``'s ``state_path``."""
+        stateful = self._kinds(STATEFUL)
+        return stateful[0].mixer.state_path(rows, slots) if stateful else None
 
     def alloc_paged_caches(self, batch: int, max_len: int,
                            page_size: int = 128):
@@ -496,11 +723,11 @@ class HybridForCausalLM(nn.Layer):
             batch, pages_per_seq)
 
     def alloc_slot_state(self, slots: int):
-        """One entry for each MAMBA layer, in order, every leaf leading with
-        the slot; None for a pattern without one (the engine then keeps
-        nothing)."""
+        """One entry for each MAMBA layer (either kind), in order, every
+        leaf leading with the slot; None for a pattern without one (the
+        engine then keeps nothing)."""
         return ([layer.mixer.alloc_slot_state(slots)
-                 for layer in self._kinds("M")] or None)
+                 for layer in self._kinds(STATEFUL)] or None)
 
     def prefill_paged(self, input_ids, pools, tables, slot_state=None,
                       slot=None, last_idx=None):
@@ -512,7 +739,7 @@ class HybridForCausalLM(nn.Layer):
         n_attn = n_mamba = 0
         for layer in self.layers:
             u = layer.norm(x)
-            if layer.kind == "M":
+            if layer.kind in STATEFUL:
                 y, state[n_mamba] = layer.mixer.prefill(
                     u, state[n_mamba], slot, last_idx)
                 n_mamba += 1
@@ -520,6 +747,8 @@ class HybridForCausalLM(nn.Layer):
                 y, pools[n_attn] = layer.mixer.prefill(u, pools[n_attn],
                                                        tables)
                 n_attn += 1
+            elif layer.kind == "-":
+                y = layer.mixer(u)
             else:
                 y, _ = layer.experts(u)
             x = x + y
@@ -539,13 +768,15 @@ class HybridForCausalLM(nn.Layer):
         routed = peak = held = 0
         for layer in self.layers:
             u = layer.norm(x)
-            if layer.kind == "M":
+            if layer.kind in STATEFUL:
                 y, state[n_mamba] = layer.mixer.decode(u, state[n_mamba])
                 n_mamba += 1
             elif layer.kind == "*":
                 y, pools[n_attn] = layer.mixer.decode(u, pos, pools[n_attn],
                                                       tables)
                 n_attn += 1
+            elif layer.kind == "-":
+                y = layer.mixer(u)
             else:
                 y, load = layer.experts(u)
                 routed += x.shape[0] * self.cfg.num_experts_per_tok

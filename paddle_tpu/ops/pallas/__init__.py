@@ -6,6 +6,7 @@ from . import fused_norm  # noqa: F401
 from . import fused_vocab_ce  # noqa: F401
 from . import latent_attention  # noqa: F401
 from . import paged_attention  # noqa: F401
+from . import selective_ssm  # noqa: F401
 from . import ssm  # noqa: F401
 
 # every ``pl.pallas_call`` here passes one of these as ``name=``: jax puts
@@ -20,4 +21,5 @@ KERNEL_NAMES = (
     "fused_vocab_ce_bwd_dw", "paged_attention_decode", "fused_rmsnorm_fwd",
     "fused_rmsnorm_bwd", "fused_rope", "int8_matmul",
     "latent_attention_decode", "ssm_state_update",
+    "selective_state_update", "selective_scan",
 )

@@ -129,6 +129,24 @@ def _ssm_update(rows=192, h=64, p=64, n=128, g=8):
                               ((rows, g, n), BF16), ((rows, g, n), BF16)]
 
 
+def _selective_update(rows=256, d=5120, n=16):
+    """AI21-Jamba2-3B's decode tick, one Mamba-1 layer: every slot's
+    [16, 5120] float32 state, 8 slots (2.6 MB) a grid step."""
+    from paddle_tpu.ops.pallas.selective_ssm import selective_state_update
+    return selective_state_update, [((rows, n, d), F32), ((rows, d), BF16),
+                                    ((rows, d), F32), ((n, d), F32),
+                                    ((rows, n), F32), ((rows, n), F32)]
+
+
+def _selective_scan(length=1024, d=5120, n=16):
+    """AI21-Jamba2-3B's widest prefill program, one Mamba-1 layer: 1,024
+    positions in blocks of 64, the state [16, 5120] resident in VMEM."""
+    from paddle_tpu.ops.pallas.selective_ssm import selective_scan
+    return selective_scan, [((1, length, d), BF16), ((1, length, d), F32),
+                            ((n, d), F32), ((1, length, n), F32),
+                            ((1, length, n), F32)]
+
+
 def _rms_norm():
     from paddle_tpu.ops.pallas.fused_norm import rms_norm_pallas
     return (_grad_sum(lambda x, w: rms_norm_pallas(x, w, 1e-5), (0, 1)),
@@ -195,6 +213,12 @@ ONE_CHIP = [
     pytest.param(lambda: _paged(128, BF16, rows=192, h=32, h_kv=2, per_seq=40),
                  id="paged_decode[bf16,192x32/2,40pages]"),
     pytest.param(_ssm_update, id="ssm_state_update[192x64x64x128]"),
+    # jamba2-3b.batch-reasoning: 256 rows, 20 query heads on ONE KV head (a
+    # group that is no power of two), 24-page tables; its two kernels
+    pytest.param(lambda: _paged(128, BF16, rows=256, h=20, h_kv=1, per_seq=24),
+                 id="paged_decode[bf16,256x20/1,24pages]"),
+    pytest.param(_selective_update, id="selective_state_update[256x16x5120]"),
+    pytest.param(_selective_scan, id="selective_scan[1024x16x5120]"),
     pytest.param(_rms_norm, id="rms_norm[D4096]"),
     pytest.param(_rope, id="rope[s2048,32/8]"),
     pytest.param(lambda: _fused_ce(16384, 4096, 128256),
@@ -333,6 +357,42 @@ def test_a_mamba_decode_layer_updates_its_state_in_place(topo, monkeypatch):
         ((192, 3, 6144), BF16), ((192, 32, 128, 128), F32)]
     mem = compiled.memory_analysis()
     state_bytes = 192 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_a_mamba1_decode_layer_updates_its_state_in_place(topo, monkeypatch):
+    """One Mamba-1 layer of AI21-Jamba2-3B's decode tick at 256 slots: the
+    state update is the one Mosaic call, the donated state (84 MB of
+    float32 and 8 MB of convolution inputs) comes back in the buffers it
+    went in by, and the program keeps no second copy of it."""
+    from paddle_tpu.models.hybrid_lm import HybridConfig, Mamba1Mixer
+    from paddle_tpu.ops import registry
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
+    mixer = Mamba1Mixer(HybridConfig(pattern="m", hidden_size=2560,
+                                     dtype="bfloat16"))
+    assert mixer.state_path(None, 256) == mixer.state_path(1024, 1) == "kernel"
+
+    def step(p, u, state):
+        with mixer._bind(p):
+            return mixer.decode(u, state)
+    dev = SingleDeviceSharding(topo.devices[0])
+    abstract = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev), t)
+    state = jax.eval_shape(lambda: mixer.alloc_slot_state(256))
+    args = abstract((mixer.raw_parameters(),
+                     jax.ShapeDtypeStruct((256, 1, 2560), BF16), state))
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+    calls = _mosaic_calls(compiled.as_text())
+    assert calls and all("selective_state_update" in c for c in calls), calls
+    out, new_state = jax.eval_shape(step, *args)
+    assert out.shape == (256, 1, 2560)
+    assert [(a.shape, a.dtype) for a in new_state] == [
+        ((256, 3, 5120), BF16), ((256, 16, 5120), F32)]
+    mem = compiled.memory_analysis()
+    state_bytes = 256 * (16 * 5120 * 4 + 3 * 5120 * 2)
+    assert state_bytes == 256 * 358_400
     assert mem.alias_size_in_bytes >= state_bytes
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
 

@@ -354,6 +354,22 @@ def _ssm():
         jnp.zeros((2, 2, 128, 32)),)
 
 
+def _selective_update():
+    from paddle_tpu.ops.pallas.selective_ssm import selective_state_update
+    rest = (jnp.ones((2, 128)), jnp.ones((2, 128)), -jnp.ones((16, 128)),
+            jnp.ones((2, 16)), jnp.ones((2, 16)))
+    return (lambda s: selective_state_update(s, *rest, interpret=True)[0]), (
+        jnp.zeros((2, 16, 128)),)
+
+
+def _selective_scan():
+    from paddle_tpu.ops.pallas.selective_ssm import selective_scan
+    rest = (jnp.ones((1, 16, 128)), -jnp.ones((16, 128)),
+            jnp.ones((1, 16, 16)), jnp.ones((1, 16, 16)))
+    return (lambda x: selective_scan(x, *rest, interpret=True)[0]), (
+        jnp.zeros((1, 16, 128)),)
+
+
 @pytest.mark.parametrize("entry,expect", [
     (_flash, ["flash_attention_fwd", "flash_attention_bwd_dq",
               "flash_attention_bwd_dkv"]),
@@ -365,6 +381,8 @@ def _ssm():
     (_paged, ["paged_attention_decode"]),
     (_latent, ["latent_attention_decode"]),
     (_ssm, ["ssm_state_update"]),
+    (_selective_update, ["selective_state_update"]),
+    (_selective_scan, ["selective_scan"]),
 ], ids=lambda v: v.__name__.strip("_") if callable(v) else None)
 def test_a_pallas_entry_point_names_its_kernels(entry, expect):
     """Forward and gradient: every pallas_call in the traced program carries
@@ -429,13 +447,14 @@ def test_xla_own_share_reads_what_is_neither_a_kernel_nor_an_expert_product():
     for kernel in pallas_ops.KERNEL_NAMES:
         served_only = kernel in ("paged_attention_decode", "int8_matmul",
                                  "latent_attention_decode",
-                                 "ssm_state_update")
+                                 "ssm_state_update",
+                                 "selective_state_update", "selective_scan")
         assert bool(rx.search(f"%jvp_{kernel}_.1 = " + tail)) == served_only
 
 
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
-    assert len(names) == len(set(names)) == 13
+    assert len(names) == len(set(names)) == 15
 
 
 # -- request timelines --------------------------------------------------------

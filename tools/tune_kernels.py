@@ -25,6 +25,8 @@ Usage:
     python tools/tune_kernels.py [--quick] [--out PATH] [--write-shipped]
     python tools/tune_kernels.py --paged-decode
     python tools/tune_kernels.py --ssm-update
+    python tools/tune_kernels.py --selective-update
+    python tools/tune_kernels.py --selective-scan
     python tools/tune_kernels.py --interpret
 """
 
@@ -253,52 +255,134 @@ def bench_paged_decode(interpret, B=32, H=32, H_kv=8, per_seq=16,
     return results
 
 
-def bench_ssm_update(interpret, B=192, H=64, P=64, N=128, G=8, steps=64):
-    """The Mamba-2 state update (``ops/pallas/ssm.py``) against its ``jnp``
-    twin at a serving cell's shape (``nemotron-3-nano.agent-turns``: 192
-    slots of 64 heads' [64, 128] float32), ``steps`` calls chained inside ONE
-    program with the state carried and donated, as the tick carries it:
-    device time a call beside the time its bytes take (the state once each
-    way) at the chip's HBM rate."""
+def _bench_update(bench, shape, impls, fresh_state, x, rest, steps,
+                  state_bytes):
+    """One state-update kernel against its ``jnp`` twin: ``steps`` calls
+    chained inside ONE program with the state carried and donated, as the
+    tick carries it (a call's reading feeds the next one's input): device
+    time a call beside the time its bytes take (the state once each way) at
+    the chip's HBM rate."""
     import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.ssm import (pack_state, ssm_state_update,
-                                           ssm_state_update_xla)
     kind = getattr(jax.devices()[0], "device_kind", "cpu")
-    if interpret:
-        B, H, P, G, steps = 2, 8, 8, 2, 2
-    k = jax.random.split(jax.random.key(0), 5)
-    x = jax.random.normal(k[0], (B, H, P)).astype(jnp.bfloat16)
-    dt = jax.nn.softplus(jax.random.normal(k[1], (B, H)))
-    a = -jnp.exp(0.02 * jax.random.normal(k[2], (H,)))
-    b = jax.random.normal(k[3], (B, G, N)).astype(jnp.bfloat16)
-    c = jax.random.normal(k[4], (B, G, N)).astype(jnp.bfloat16)
-    impls = {"pallas": functools.partial(ssm_state_update,
-                                         interpret=interpret),
-             "xla": ssm_state_update_xla}
 
     def chained(fn):
         def run(state, x):
             def body(carry, _):
                 state, x = carry
-                y, state = fn(state, x, dt, a, b, c)
+                y, state = fn(state, x, *rest)
                 return (state, (0.5 * x + 0.01 * y.astype(x.dtype))), None
             return jax.lax.scan(body, (state, x), None, length=steps)[0]
         return jax.jit(run, donate_argnums=(0,))
 
-    line = {"bench": "ssm_state_update", "device": kind, "steps": steps,
-            "shape": f"b{B}_h{H}_p{P}_n{N}_g{G}",
-            "bytes_us": round(2 * B * H * P * N * 4 / 819e3, 1)}
+    line = {"bench": bench, "device": kind, "steps": steps, "shape": shape,
+            "bytes_us": round(2 * state_bytes / 819e3, 1)}
     for name, fn in impls.items():
         run = chained(fn)
 
         def once(x):        # a fresh state a call: the last one was donated
-            return run(pack_state(jnp.zeros((B, H, P, N), jnp.float32), G),
-                       x)
+            return run(fresh_state(), x)
         t = _time_fn(once, x, iters=1, warmup=1, reps=3)
         line[f"{name}_us"] = round(t / steps * 1e6, 1)
     print(json.dumps(line), flush=True)
     return [line]
+
+
+def bench_ssm_update(interpret, B=192, H=64, P=64, N=128, G=8, steps=64):
+    """The Mamba-2 state update (``ops/pallas/ssm.py``) at a serving cell's
+    shape (``nemotron-3-nano.agent-turns``: 192 slots of 64 heads' [64, 128]
+    float32)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas.ssm import (pack_state, ssm_state_update,
+                                           ssm_state_update_xla)
+    if interpret:
+        B, H, P, G, steps = 2, 8, 8, 2, 2
+    k = jax.random.split(jax.random.key(0), 5)
+    x = jax.random.normal(k[0], (B, H, P)).astype(jnp.bfloat16)
+    rest = (jax.nn.softplus(jax.random.normal(k[1], (B, H))),
+            -jnp.exp(0.02 * jax.random.normal(k[2], (H,))),
+            jax.random.normal(k[3], (B, G, N)).astype(jnp.bfloat16),
+            jax.random.normal(k[4], (B, G, N)).astype(jnp.bfloat16))
+    return _bench_update(
+        "ssm_state_update", f"b{B}_h{H}_p{P}_n{N}_g{G}",
+        {"pallas": functools.partial(ssm_state_update, interpret=interpret),
+         "xla": ssm_state_update_xla},
+        lambda: pack_state(jnp.zeros((B, H, P, N), jnp.float32), G), x, rest,
+        steps, B * H * P * N * 4)
+
+
+def _selective_inputs(B, L, D, N):
+    """Seeded inputs of the Mamba-1 recurrence: x [B, L, D] bf16, the steps
+    [B, L, D] after their softplus, A [N, D] ~ -1, B and C [B, L, N]."""
+    import jax
+    import jax.numpy as jnp
+    k = jax.random.split(jax.random.key(0), 5)
+    return (jax.random.normal(k[0], (B, L, D)).astype(jnp.bfloat16),
+            jax.nn.softplus(jax.random.normal(k[1], (B, L, D))),
+            -jnp.exp(0.02 * jax.random.normal(k[2], (N, D))),
+            jax.random.normal(k[3], (B, L, N)),
+            jax.random.normal(k[4], (B, L, N)))
+
+
+def bench_selective_update(interpret, B=256, D=5120, N=16, steps=52):
+    """The Mamba-1 state update (``ops/pallas/selective_ssm.py``) at a
+    serving cell's shape (``jamba2-3b.batch-reasoning``: 256 slots of
+    [16, 5120] float32)."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas.selective_ssm import (
+        selective_state_update, selective_state_update_xla)
+    if interpret:
+        B, D, steps = 2, 128, 2
+    x, *rest = (t[:, 0] if t.ndim == 3 else t
+                for t in _selective_inputs(B, 1, D, N))
+    return _bench_update(
+        "selective_state_update", f"b{B}_d{D}_n{N}",
+        {"pallas": functools.partial(selective_state_update,
+                                     interpret=interpret),
+         "xla": selective_state_update_xla},
+        lambda: jnp.zeros((B, N, D), jnp.float32), x, rest, steps,
+        B * D * N * 4)
+
+
+def bench_selective_scan(interpret, lengths=(128, 1024), D=5120, N=16,
+                         layers=26):
+    """The Mamba-1 prompt scan (``selective_scan``) against the form XLA
+    has for it (``selective_scan_xla``: L sequential steps of the update in
+    a ``lax.scan``), one prompt at the cell's widths, ``layers`` calls
+    chained inside ONE program as a prefill chains its layers (a call's
+    reading feeds the next one's input): device time a call beside the
+    time its bytes take (x in bf16, the steps and y in float32, once each)
+    at the chip's HBM rate."""
+    import jax
+    from paddle_tpu.ops.pallas.selective_ssm import (selective_scan,
+                                                     selective_scan_xla)
+    kind = getattr(jax.devices()[0], "device_kind", "cpu")
+    if interpret:
+        lengths, D, layers = (16,), 128, 2
+    impls = {"pallas": functools.partial(selective_scan,
+                                         interpret=interpret),
+             "xla": selective_scan_xla}
+
+    def chained(fn):
+        def run(x, dt, a_t, b, c):
+            def body(x, _):
+                y, last = fn(x, dt, a_t, b, c)
+                return (0.5 * x + 0.01 * y.astype(x.dtype)), last[0, 0, 0]
+            return jax.lax.scan(body, x, None, length=layers)
+        return jax.jit(run)
+
+    results = []
+    for L in lengths:
+        args = _selective_inputs(1, L, D, N)
+        line = {"bench": "selective_scan", "device": kind, "layers": layers,
+                "shape": f"l{L}_d{D}_n{N}",
+                "bytes_us": round(L * D * 10 / 819e3, 1)}
+        for name, fn in impls.items():
+            t = _time_fn(chained(fn), *args, iters=1, warmup=1, reps=3)
+            line[f"{name}_us"] = round(t / layers * 1e6, 1)
+        print(json.dumps(line), flush=True)
+        results.append(line)
+    return results
 
 
 def main():
@@ -312,6 +396,10 @@ def main():
                     help="only the paged-decode kernel-vs-XLA comparison")
     ap.add_argument("--ssm-update", action="store_true",
                     help="only the Mamba-2 state update against its twin")
+    ap.add_argument("--selective-update", action="store_true",
+                    help="only the Mamba-1 state update against its twin")
+    ap.add_argument("--selective-scan", action="store_true",
+                    help="only the Mamba-1 prompt scan against XLA's form")
     ap.add_argument("--interpret", action="store_true",
                     help="validate the sweep machinery in Pallas interpret "
                          "mode (any backend, nothing recorded)")
@@ -324,8 +412,12 @@ def main():
         configure_compilation_cache()
         require_tpu()
 
-    if args.ssm_update:
-        results = bench_ssm_update(interpret)
+    alone = [bench for flag, bench in (
+        (args.ssm_update, bench_ssm_update),
+        (args.selective_update, bench_selective_update),
+        (args.selective_scan, bench_selective_scan)) if flag]
+    if alone:
+        results = [line for bench in alone for line in bench(interpret)]
         print(json.dumps({"tuned": False, "cases": len(results)}))
         return
 
