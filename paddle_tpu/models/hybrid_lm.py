@@ -36,7 +36,8 @@ state for the Mamba layers only: the convolution's last ``conv_kernel - 1``
 inputs (activation dtype) and the recurrence's state in float32, which a
 prefill writes at the prompt's true last position and every decode tick
 rewrites in place (``ops.pallas.ssm.ssm_state_update``, or for a Mamba-1
-layer ``ops.pallas.selective_ssm.selective_state_update``, on a TPU). A
+layer ``ops.pallas.selective_ssm``'s ``conv_window_step`` and
+``selective_state_update``, window and state each in one pass, on a TPU). A
 prompt runs the Mamba-2 recurrence in chunks (``ssd_chunked``: matrix
 products inside a chunk, the state carried from chunk to chunk) and the
 Mamba-1 recurrence, which has no such form, with time inside a kernel
@@ -422,21 +423,44 @@ class Mamba1Mixer(nn.Layer):
             z.astype(jnp.float32))
         return jnp.matmul(y.astype(z.dtype), self.out_proj.astype(z.dtype))
 
+    def _tick_selection(self, x):
+        """``_selection`` for a tick's kernel: (the step [.., inner] BEFORE
+        its bias and softplus, which the kernel applies, B [.., N], C
+        [.., N]), float32."""
+        cfg, f32 = self.cfg, jnp.float32
+        n, rank = cfg.mamba_d_state, cfg.mamba_dt_rank
+
+        def norm(t, w):
+            return t * jax.lax.rsqrt(
+                jnp.mean(t * t, -1, keepdims=True) + cfg.rms_norm_eps) * w
+        dbc = jnp.matmul(x, self.x_proj.astype(x.dtype),
+                         preferred_element_type=f32)
+        dt = norm(dbc[..., :rank], self.dt_norm).astype(x.dtype)
+        return (jnp.matmul(dt, self.dt_proj.astype(x.dtype),
+                           preferred_element_type=f32),
+                norm(dbc[..., rank:rank + n], self.b_norm),
+                norm(dbc[..., rank + n:], self.c_norm))
+
     def state_path(self, rows, slots: int) -> str:
-        """The form the recurrence takes ("kernel" or "xla") in a prompt of
-        ``rows`` positions or, ``rows`` None, in a tick of ``slots``."""
+        """The form the recurrence takes in a prompt of ``rows`` positions
+        ("kernel" or "xla") or, ``rows`` None, in a tick of ``slots``:
+        "fused" where both of the tick's kernels run (the window's step and
+        the state update), "kernel" where the update's alone does, "xla" off
+        the TPU. Decided from shapes alone."""
         from ..ops.pallas.selective_ssm import (
-            selective_scan_supported, selective_state_update_supported)
+            conv_window_step_supported, selective_scan_supported,
+            selective_state_update_supported)
         cfg = self.cfg
         if not _on_tpu():
             return "xla"
-        if rows is None:
-            ok = selective_state_update_supported(jax.ShapeDtypeStruct(
-                (slots, cfg.mamba_d_state, cfg.mamba1_inner), jnp.float32))
-        else:
+        if rows is not None:
             ok = selective_scan_supported(jax.ShapeDtypeStruct(
                 (1, rows, cfg.mamba1_inner), _dtype(cfg)), cfg.mamba_d_state)
-        return "kernel" if ok else "xla"
+            return "kernel" if ok else "xla"
+        window, state = jax.eval_shape(lambda: self.alloc_slot_state(slots))
+        if not selective_state_update_supported(state):
+            return "xla"
+        return "fused" if conv_window_step_supported(window) else "kernel"
 
     def _sequence(self, u, last_idx=None):
         """Whole sequences u [b, s, d] from a zero state: (output [b, s, d],
@@ -488,23 +512,27 @@ class Mamba1Mixer(nn.Layer):
                      ssm_state.at[slot].set(ssm[0]))
 
     def decode(self, u, state):
-        """One token of every row u [b, 1, d] through the rows' state (the
-        Pallas kernel on a TPU, in place; its ``jnp`` twin elsewhere)."""
-        from ..ops.pallas.selective_ssm import (selective_state_update,
-                                                selective_state_update_xla)
+        """One token of every row u [b, 1, d] through the rows' state: on a
+        TPU two Pallas kernels, each one pass over its rows in place (the
+        window's step; the state update from the raw step to the gated
+        row), with ``x_proj``, ``dt_proj`` and the inner norms between them
+        XLA's; their ``jnp`` twins elsewhere (``state_path``)."""
+        from ..ops.pallas import selective_ssm as k
         conv_state, ssm_state = state
-        x, z = self._project(u[:, 0])
-        window = jnp.concatenate(
-            [conv_state, x[:, None].astype(conv_state.dtype)], axis=1)
-        x = _conv_silu([window[:, i] for i in range(window.shape[1])],
-                       self.conv_weight, self.conv_bias)
-        delta, b_mat, c_mat = self._selection(x)
-        update = (selective_state_update
-                  if self.state_path(None, u.shape[0]) == "kernel"
-                  else selective_state_update_xla)
-        y, ssm_state = update(ssm_state, x, delta, -jnp.exp(self.A_log),
-                              b_mat, c_mat)
-        return self._out(y, x, z)[:, None], (window[:, 1:], ssm_state)
+        path = self.state_path(None, u.shape[0])
+        xz = jnp.matmul(u[:, 0], self.in_proj.astype(u.dtype))     # [x | z]
+        x, conv_state = (
+            k.conv_window_step if path == "fused"
+            else k.conv_window_step_xla)(
+                conv_state, xz, self.conv_weight, self.conv_bias)
+        step, b_mat, c_mat = self._tick_selection(x)
+        y, ssm_state = (
+            k.selective_state_update_xla if path == "xla"
+            else k.selective_state_update)(
+                ssm_state, x, step, self.dt_bias, -jnp.exp(self.A_log),
+                b_mat, c_mat, self.D, xz)
+        out = jnp.matmul(y.astype(u.dtype), self.out_proj.astype(u.dtype))
+        return out[:, None], (conv_state, ssm_state)
 
 
 class NoPEAttention(nn.Layer):
@@ -703,10 +731,11 @@ class HybridForCausalLM(nn.Layer):
         return routed[0].mixer.inference_path(rows) if routed else None
 
     def state_path(self, rows, slots: int):
-        """The form ("kernel" or "xla") the state-space layers' recurrence
-        takes in a prefill program of ``rows`` positions or, ``rows`` None,
-        in a tick of ``slots`` slots (``Mamba1Mixer.state_path``), None
-        without such a layer: ``build_log``'s ``state_path``."""
+        """The form the state-space layers' recurrence takes in a prefill
+        program of ``rows`` positions ("kernel" or "xla") or, ``rows`` None,
+        in a tick of ``slots`` slots (also "fused": ``Mamba1Mixer.
+        state_path``), None without such a layer: ``build_log``'s
+        ``state_path``."""
         stateful = self._kinds(STATEFUL)
         return stateful[0].mixer.state_path(rows, slots) if stateful else None
 

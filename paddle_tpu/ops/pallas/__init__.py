@@ -21,5 +21,5 @@ KERNEL_NAMES = (
     "fused_vocab_ce_bwd_dw", "paged_attention_decode", "fused_rmsnorm_fwd",
     "fused_rmsnorm_bwd", "fused_rope", "int8_matmul",
     "latent_attention_decode", "ssm_state_update",
-    "selective_state_update", "selective_scan",
+    "selective_state_update", "selective_scan", "conv_window_step",
 )

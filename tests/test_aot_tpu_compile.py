@@ -131,11 +131,24 @@ def _ssm_update(rows=192, h=64, p=64, n=128, g=8):
 
 def _selective_update(rows=256, d=5120, n=16):
     """AI21-Jamba2-3B's decode tick, one Mamba-1 layer: every slot's
-    [16, 5120] float32 state, 8 slots (2.6 MB) a grid step."""
+    [16, 5120] float32 state, 8 slots (2.6 MB) a grid step, from the raw
+    step to the gated bf16 row (the gate: the second half of [x | z])."""
     from paddle_tpu.ops.pallas.selective_ssm import selective_state_update
     return selective_state_update, [((rows, n, d), F32), ((rows, d), BF16),
-                                    ((rows, d), F32), ((n, d), F32),
-                                    ((rows, n), F32), ((rows, n), F32)]
+                                    ((rows, d), F32), ((d,), F32),
+                                    ((n, d), F32), ((rows, n), F32),
+                                    ((rows, n), F32), ((d,), F32),
+                                    ((rows, 2 * d), BF16)]
+
+
+def _window_step(rows=256, d=5120, k=4):
+    """The same layer's convolution window [256, 3, 5120] bf16 stepping in
+    place, 32 slots a grid step, the token's input the first half of
+    [x | z]."""
+    from paddle_tpu.ops.pallas.selective_ssm import conv_window_step
+    return conv_window_step, [((rows, k - 1, d), BF16),
+                              ((rows, 2 * d), BF16), ((k, d), F32),
+                              ((d,), F32)]
 
 
 def _selective_scan(length=1024, d=5120, n=16):
@@ -214,10 +227,11 @@ ONE_CHIP = [
                  id="paged_decode[bf16,192x32/2,40pages]"),
     pytest.param(_ssm_update, id="ssm_state_update[192x64x64x128]"),
     # jamba2-3b.batch-reasoning: 256 rows, 20 query heads on ONE KV head (a
-    # group that is no power of two), 24-page tables; its two kernels
+    # group that is no power of two), 24-page tables; its three kernels
     pytest.param(lambda: _paged(128, BF16, rows=256, h=20, h_kv=1, per_seq=24),
                  id="paged_decode[bf16,256x20/1,24pages]"),
     pytest.param(_selective_update, id="selective_state_update[256x16x5120]"),
+    pytest.param(_window_step, id="conv_window_step[256x3x5120]"),
     pytest.param(_selective_scan, id="selective_scan[1024x16x5120]"),
     pytest.param(_rms_norm, id="rms_norm[D4096]"),
     pytest.param(_rope, id="rope[s2048,32/8]"),
@@ -363,16 +377,19 @@ def test_a_mamba_decode_layer_updates_its_state_in_place(topo, monkeypatch):
 
 def test_a_mamba1_decode_layer_updates_its_state_in_place(topo, monkeypatch):
     """One Mamba-1 layer of AI21-Jamba2-3B's decode tick at 256 slots: the
-    state update is the one Mosaic call, the donated state (84 MB of
-    float32 and 8 MB of convolution inputs) comes back in the buffers it
-    went in by, and the program keeps no second copy of it."""
+    window's step and the state update are the two Mosaic calls, the donated
+    state (84 MB of float32 and 8 MB of convolution inputs) comes back in
+    the buffers it went in by, and the program keeps no second copy of it:
+    the window reaches its kernel as a bitcast of the leaf (a tap a plane,
+    which is how the chip lays ``[256, 3, 5120]`` out), never as a copy."""
     from paddle_tpu.models.hybrid_lm import HybridConfig, Mamba1Mixer
     from paddle_tpu.ops import registry
     monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
     monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
     mixer = Mamba1Mixer(HybridConfig(pattern="m", hidden_size=2560,
                                      dtype="bfloat16"))
-    assert mixer.state_path(None, 256) == mixer.state_path(1024, 1) == "kernel"
+    assert mixer.state_path(None, 256) == "fused"
+    assert mixer.state_path(1024, 1) == "kernel"
 
     def step(p, u, state):
         with mixer._bind(p):
@@ -384,8 +401,12 @@ def test_a_mamba1_decode_layer_updates_its_state_in_place(topo, monkeypatch):
     args = abstract((mixer.raw_parameters(),
                      jax.ShapeDtypeStruct((256, 1, 2560), BF16), state))
     compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
-    calls = _mosaic_calls(compiled.as_text())
-    assert calls and all("selective_state_update" in c for c in calls), calls
+    text = compiled.as_text()
+    calls = _mosaic_calls(text)
+    assert sorted(c.split(".")[0] for c in calls) == [
+        "conv_window_step", "selective_state_update"], calls
+    assert not re.search(r"bf16\[(256,3|3,256),5120\]\S* (copy|transpose)\(",
+                         text)
     out, new_state = jax.eval_shape(step, *args)
     assert out.shape == (256, 1, 2560)
     assert [(a.shape, a.dtype) for a in new_state] == [
@@ -395,6 +416,50 @@ def test_a_mamba1_decode_layer_updates_its_state_in_place(topo, monkeypatch):
     assert state_bytes == 256 * 358_400
     assert mem.alias_size_in_bytes >= state_bytes
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_the_jamba_tick_holds_one_copy_of_its_slot_state(topo, monkeypatch):
+    """``jamba2-3b.batch-reasoning``'s whole decode tick (28 layers, 256
+    slots, the cell's engine) compiled for one described chip: 26 calls of
+    each of the tick's two kernels, the 2,385,510,400 B of slot state
+    aliased in place (ONE copy), no copy of a window anywhere, and the
+    program no larger than the 8.82 GiB it took before the window stepped
+    in place (8.73 since)."""
+    from benchmarks import program, run as bench
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.inference.generation import GenerationConfig
+    from paddle_tpu.ops import registry
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = bench.resolve("jamba2-3b.batch-reasoning",
+                           os.path.join(root, "BENCHMARK.json"))[1]
+    model, _ = program.build_model(config)
+    eng = ContinuousBatchingEngine(
+        model.eval(), generation_config=GenerationConfig(do_sample=False),
+        **config["engine"])
+    assert model.state_path(None, eng.max_batch) == "fused"
+    eng._init_state(jax.ShapeDtypeStruct((config["vocab_size"],), BF16))
+    eng._tables_dev = jnp.asarray(eng.tables)
+    dev = SingleDeviceSharding(topo.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
+                                       if not hasattr(a, "dtype") else a.dtype,
+                                       sharding=dev),
+        eng._decode_args(False))
+    compiled = eng._build_decode(1, False, "paged").lower(*args).compile()
+    text = compiled.as_text()
+    names = [c.split(".")[0] for c in _mosaic_calls(text)]
+    assert names.count("conv_window_step") == 26
+    assert names.count("selective_state_update") == 26
+    assert not re.search(r"bf16\[(256,3|3,256),5120\]\S* (copy|transpose)\(",
+                         text)
+    mem = compiled.memory_analysis()
+    assert eng.stats()["slot_state_bytes"] == 2_385_510_400
+    assert mem.alias_size_in_bytes >= 2_385_510_400
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total <= 8.83 * 2 ** 30, total / 2 ** 30
 
 
 @pytest.mark.parametrize("tokens,step,temp_mib", [(1536, 192, 160),

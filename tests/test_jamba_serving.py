@@ -35,10 +35,12 @@ from paddle_tpu.inference import ContinuousBatchingEngine  # noqa: E402
 from paddle_tpu.inference.generation import GenerationConfig  # noqa: E402
 from paddle_tpu.models.hybrid_lm import (HybridConfig,  # noqa: E402
                                          HybridForCausalLM, Mamba1Mixer)
+from paddle_tpu.ops.pallas import selective_ssm  # noqa: E402
 from paddle_tpu.ops.pallas.selective_ssm import (  # noqa: E402
-    selective_scan, selective_scan_supported, selective_scan_xla,
-    selective_state_update, selective_state_update_supported,
-    selective_state_update_xla)
+    conv_window_step, conv_window_step_supported, conv_window_step_xla,
+    selective_recurrence_xla, selective_scan, selective_scan_supported,
+    selective_scan_xla, selective_state_update,
+    selective_state_update_supported, selective_state_update_xla)
 
 TOL = 3e-5
 SEED = 11
@@ -182,13 +184,36 @@ SCAN_SHAPES = [(1, 16, 16, 128), (2, 48, 16, 256), (1, 128, 16, 640),
 
 
 def _update_args(shape, dtype=jnp.float32):
+    """(state, x, the RAW step, dt_bias, A, B, C, D, [x | z]): what the
+    fused update takes of a layer."""
     B, N, D = shape
-    k = jax.random.split(jax.random.key(B + D), 6)
+    k = jax.random.split(jax.random.key(B + D), 9)
     return (jax.random.normal(k[0], (B, N, D)),
             jax.random.normal(k[1], (B, D)).astype(dtype),
-            jax.nn.softplus(jax.random.normal(k[2], (B, D))),
+            jax.random.normal(k[2], (B, D)),
+            0.3 * jax.random.normal(k[6], (D,)),
             -jnp.exp(0.3 * jax.random.normal(k[3], (N, D))),
-            jax.random.normal(k[4], (B, N)), jax.random.normal(k[5], (B, N)))
+            jax.random.normal(k[4], (B, N)), jax.random.normal(k[5], (B, N)),
+            1.0 + 0.3 * jax.random.normal(k[7], (D,)),
+            jax.random.normal(k[8], (B, 2 * D)).astype(dtype))
+
+
+def _window_args(shape, dtype=jnp.float32):
+    """(window [B, 3, D], [x | z], the four taps, the bias)."""
+    B, _, D = shape
+    k = jax.random.split(jax.random.key(B + D + 1), 4)
+    return (jax.random.normal(k[0], (B, 3, D)).astype(dtype),
+            jax.random.normal(k[1], (B, 2 * D)).astype(dtype),
+            1.0 + 0.3 * jax.random.normal(k[2], (4, D)),
+            0.3 * jax.random.normal(k[3], (D,)))
+
+
+def _close(got, want, dtype):
+    """Equal to float32 rounding in another order; in bfloat16 to one unit
+    in the last place of the cast at the end."""
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    tol = 2e-5 if dtype == jnp.float32 else 2.0 ** -7
+    return bool((np.abs(got - want) <= tol * (1.0 + np.abs(want))).all())
 
 
 def _scan_args(shape, dtype=jnp.float32):
@@ -205,12 +230,41 @@ def _scan_args(shape, dtype=jnp.float32):
 @pytest.mark.parametrize("shape", UPDATE_SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_the_update_kernel_equals_its_twin_in_interpret_mode(shape, dtype):
+    """The fused form: softplus, ``Delta x``, the recurrence, the skip and
+    the gate inside, the row out in the activation dtype; and the twin is
+    the plain recurrence with the same arithmetic around it."""
     args = _update_args(shape, dtype)
+    state, x, step, bias, a, b, c, skip, xz = args
     y0, h0 = selective_state_update_xla(*args)
     y1, h1 = selective_state_update(*args, interpret=True)
-    assert np.abs(np.asarray(y0)).max() > 1.0
-    assert np.abs(np.asarray(y0 - y1)).max() < 2e-5
+    assert y0.dtype == y1.dtype == dtype
+    assert np.abs(np.asarray(y0, np.float32)).max() > 1.0
+    assert _close(y1, y0, dtype)
     assert np.abs(np.asarray(h0 - h1)).max() < 2e-6
+    y, h = selective_recurrence_xla(state, x, jax.nn.softplus(step + bias),
+                                    a, b, c)
+    want = (y + skip * x.astype(jnp.float32)) * jax.nn.silu(
+        xz[:, shape[2]:].astype(jnp.float32))
+    assert _close(y0, want.astype(dtype), dtype)
+    assert np.abs(np.asarray(h0 - h)).max() == 0.0
+
+
+@pytest.mark.parametrize("shape", UPDATE_SHAPES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_window_kernel_equals_its_twin_in_interpret_mode(shape, dtype):
+    """``silu(conv)`` of the three kept inputs and the token's, and the
+    window one token on: rows 1 and 2 moved up, the token's input last,
+    bit for bit."""
+    args = _window_args(shape, dtype)
+    y0, w0 = conv_window_step_xla(*args)
+    y1, w1 = conv_window_step(*args, interpret=True)
+    assert y0.dtype == y1.dtype == w1.dtype == dtype
+    assert w1.shape == args[0].shape
+    assert np.abs(np.asarray(y0, np.float32)).max() > 1.0
+    assert _close(y1, y0, dtype)
+    want = jnp.concatenate([args[0][:, 1:], args[1][:, None, :shape[2]]], 1)
+    for w in (w0, w1):
+        assert np.abs(np.asarray(w - want, np.float32)).max() == 0.0
 
 
 @pytest.mark.parametrize("shape", SCAN_SHAPES)
@@ -233,17 +287,23 @@ def test_the_scan_is_the_update_token_by_token():
     y, last = selective_scan_xla(*args)
     h = jnp.zeros((2, 16, 128))
     for t in range(24):
-        y_t, h = selective_state_update_xla(h, args[0][:, t], args[1][:, t],
-                                            args[2], args[3][:, t],
-                                            args[4][:, t])
+        y_t, h = selective_recurrence_xla(h, args[0][:, t], args[1][:, t],
+                                          args[2], args[3][:, t],
+                                          args[4][:, t])
         assert np.abs(np.asarray(y_t - y[:, t])).max() < 1e-5
     assert np.abs(np.asarray(h - last)).max() < 1e-5
 
 
-def test_the_update_kernel_updates_its_state_operand_in_place():
-    """``input_output_aliases``: the state operand of the Pallas call is its
-    second result."""
-    args = _update_args((8, 16, 128))
+@pytest.mark.parametrize("kernel,make,operand,seen", [
+    (selective_state_update, _update_args, 8, (8, 16, 128)),
+    (conv_window_step, _window_args, 3, (3, 8, 128))],
+    ids=["selective_state_update", "conv_window_step"])
+def test_the_update_kernel_updates_its_state_operand_in_place(
+        kernel, make, operand, seen):
+    """``input_output_aliases``: the state operand of either Pallas call of
+    a tick (the last one) is its second result; the window goes in as the
+    kernel sees it, a tap a plane."""
+    args = make((8, 16, 128))
 
     def calls(jaxpr, out):
         for eqn in jaxpr.eqns:
@@ -253,11 +313,12 @@ def test_the_update_kernel_updates_its_state_operand_in_place():
                 calls(sub, out)
         return out
     (call,) = calls(jax.make_jaxpr(
-        lambda *a: selective_state_update(*a, interpret=True))(*args).jaxpr, [])
-    assert call.params["name"] == "selective_state_update"
-    assert tuple(call.params["input_output_aliases"]) == ((5, 1),)
-    assert call.invars[5].aval.shape == call.outvars[1].aval.shape == \
-        args[0].shape
+        lambda *a: kernel(*a, interpret=True))(*args).jaxpr, [])
+    assert call.params["name"] == kernel.__name__
+    assert tuple(call.params["input_output_aliases"]) == ((operand, 1),)
+    assert len(call.invars) == operand + 1
+    assert call.invars[operand].aval.shape == \
+        call.outvars[1].aval.shape == seen
 
 
 def test_the_gates_say_what_mosaic_takes(monkeypatch):
@@ -270,6 +331,13 @@ def test_the_gates_say_what_mosaic_takes(monkeypatch):
     assert not selective_state_update_supported(S((12, 16, 128), f32))
     assert not selective_state_update_supported(S((8, 16, 96), f32))
     assert not selective_state_update_supported(S((8, 16, 128), bf16))
+    # the window: whole sublane tiles of slots in ITS dtype (16 of bfloat16)
+    assert conv_window_step_supported(S((256, 3, 5120), bf16))
+    assert conv_window_step_supported(S((8, 3, 128), f32))
+    assert conv_window_step_supported(S((48, 3, 128), bf16))
+    assert not conv_window_step_supported(S((8, 3, 128), bf16))
+    assert not conv_window_step_supported(S((2, 3, 128), f32))
+    assert not conv_window_step_supported(S((16, 3, 96), bf16))
     for bucket in range(128, 1025, 128):
         assert selective_scan_supported(S((1, bucket, 5120), bf16), 16)
     assert selective_scan_supported(S((1, 24, 128), bf16), 16)  # pads time
@@ -277,6 +345,7 @@ def test_the_gates_say_what_mosaic_takes(monkeypatch):
     monkeypatch.setenv("PT_DISABLE_PALLAS", "1")        # the kill-switch
     assert not selective_state_update_supported(S((256, 16, 5120), f32))
     assert not selective_scan_supported(S((1, 1024, 5120), bf16), 16)
+    assert not conv_window_step_supported(S((256, 3, 5120), bf16))
 
 
 # -- (b) the model: two blocks a layer, MQA 20/1, a tied head ------------------
@@ -333,20 +402,39 @@ def test_mqa_20_on_1_through_the_paged_helpers_equals_the_reference():
         pos = pos + jnp.array([0, 1], jnp.int32)
 
 
+def _kernels_on_the_cpu(monkeypatch):
+    """The Mamba layers take the forms they take on a TPU, with every one
+    of their kernels in interpret mode (the attention layers keep asking
+    the backend themselves)."""
+    import functools
+    from paddle_tpu.models import hybrid_lm
+    monkeypatch.setattr(hybrid_lm, "_on_tpu", lambda: True)
+    for name in ("conv_window_step", "selective_state_update",
+                 "selective_scan"):
+        monkeypatch.setattr(selective_ssm, name, functools.partial(
+            getattr(selective_ssm, name), interpret=True))
+
+
+@pytest.mark.parametrize("path,slots", [("xla", 2), ("fused", 8)])
 def test_prefill_then_ticks_through_the_slot_state_equal_the_full_forward(
-        hybrid):
+        hybrid, path, slots, monkeypatch):
     """A prompt of 21 tokens padded to TWO buckets leaves the same state and
     the same next-token logits; then 15 decode ticks through the pages and
-    the slot state read the reference's logits at every position."""
+    the slot state read the reference's logits at every position: through
+    the twins, and through the tick's two kernels (and the prompt's) at 8
+    slots, a whole float32 tile of them."""
     _, model, reference = hybrid
+    if path == "fused":
+        _kernels_on_the_cpu(monkeypatch)
+    assert model.state_path(None, slots) == path
     ids = _ids(36, 1)
     want = reference(ids)
-    pools, tables = model.alloc_paged_caches(2, 64, 16)
+    pools, tables = model.alloc_paged_caches(slots, 64, 16)
     seen = []
     for bucket in (32, 48):
         padded = jnp.zeros((1, bucket), jnp.int32).at[0, :21].set(ids[:21])
         h, filled, state = model.prefill_paged(
-            padded, pools, tables[1:2], model.alloc_slot_state(2), 1,
+            padded, pools, tables[1:2], model.alloc_slot_state(slots), 1,
             jnp.int32(20))
         logits = np.asarray(model.logits(h[0, 20]))
         assert np.abs(logits - want[20]).max() < TOL
@@ -354,13 +442,13 @@ def test_prefill_then_ticks_through_the_slot_state_equal_the_full_forward(
     assert np.abs(seen[0][0] - seen[1][0]).max() < 1e-6
     for a, b in zip(jax.tree.leaves(seen[0][1]), jax.tree.leaves(seen[1][1])):
         assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-6
-    pos = jnp.array([0, 21], jnp.int32)
+    pos = jnp.zeros((slots,), jnp.int32).at[1].set(21)
     for t in range(21, 36):
         h, filled, state = model.decode_step_paged(
-            jnp.array([0, ids[t]], jnp.int32), pos, filled, tables,
-            slot_state=state)
+            jnp.zeros((slots,), jnp.int32).at[1].set(ids[t]), pos, filled,
+            tables, slot_state=state)
         assert np.abs(np.asarray(model.logits(h[1, 0])) - want[t]).max() < TOL
-        pos = pos + jnp.array([0, 1], jnp.int32)
+        pos = pos.at[1].add(1)
 
 
 # -- (c) through the engine ----------------------------------------------------
@@ -409,8 +497,10 @@ def test_the_build_log_says_which_form_the_recurrence_took(hybrid, served,
     """``build_log``'s rows of the tick and of every prefill program carry
     ``state_path``: the twin off the TPU; the kernels where the backend is a
     TPU and the shapes are Mosaic's (the cell's are; a tiny model of 128
-    channels too, a bucket of whole sublane tiles of time). A model without
-    a state-space layer says nothing."""
+    channels too, a bucket of whole sublane tiles of time). A tick says
+    "fused" where the window's kernel runs too (whole sublane tiles of slots
+    in the window's dtype: the cell's 256), "kernel" where the update's runs
+    alone. A model without a state-space layer says nothing."""
     eng = served[0]
     rows = [r for r in eng.build_log if r["name"] in ("prefill_paged", "run")]
     assert {r["name"] for r in rows} == {"prefill_paged", "run"}
@@ -421,10 +511,12 @@ def test_the_build_log_says_which_form_the_recurrence_took(hybrid, served,
     model = hybrid[1]
     monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
     assert model.state_path(None, 2) == model.state_path(32, 2) == "kernel"
+    assert model.state_path(None, 8) == model.state_path(None, 64) == "fused"
     assert model.state_path(None, 12) == "xla"       # no whole steps of slots
     big = HybridForCausalLM(HybridConfig(pattern="m-", hidden_size=2560,
-                                         vocab_size=8))
-    assert big.state_path(None, 256) == "kernel"
+                                         vocab_size=8, dtype="bfloat16"))
+    assert big.state_path(None, 256) == "fused"
+    assert big.state_path(None, 8) == "kernel"       # half a bfloat16 tile
     assert all(big.state_path(b, 256) == "kernel"
                for b in range(128, 1025, 128))
     plain = HybridForCausalLM(HybridConfig.tiny(pattern="*-"))
@@ -481,16 +573,20 @@ def test_the_nemotron_cells_programs_are_the_parents():
                np.int32(0)) == NEMOTRON_JAXPRS["prefill_paged_32"]
 
 
-@pytest.mark.parametrize("flag,bench", [
-    ("--selective-update", "selective_state_update"),
-    ("--selective-scan", "selective_scan")])
-def test_the_tuning_tool_times_each_kernel_against_its_twin(flag, bench,
+@pytest.mark.parametrize("flag,benches", [
+    ("--selective-update", ["selective_state_update", "conv_window_step",
+                            "mamba1_decode_layer"]),
+    ("--selective-scan", ["selective_scan"])])
+def test_the_tuning_tool_times_each_kernel_against_its_twin(flag, benches,
                                                             capsys,
                                                             monkeypatch):
     """``tools/tune_kernels.py --selective-update`` / ``--selective-scan``
     at a tiny size in interpret mode: one line a shape, the kernel's time
     and its twin's beside the time the bytes would take (no timing of a
-    CPU run is kept: the line's device says so)."""
+    CPU run is kept: the line's device says so). ``--selective-update``
+    judges each half of a layer's tick: alone, and inside whole layers in
+    the three forms ``state_path`` names, with the Pallas calls each form
+    MADE beside its time."""
     import importlib.util
     spec = importlib.util.spec_from_file_location(
         "tune_kernels", os.path.join(ROOT, "tools", "tune_kernels.py"))
@@ -498,8 +594,23 @@ def test_the_tuning_tool_times_each_kernel_against_its_twin(flag, bench,
     spec.loader.exec_module(tool)
     monkeypatch.setattr(sys, "argv", ["tune_kernels.py", "--interpret", flag])
     tool.main()
+    assert selective_ssm.conv_window_step is conv_window_step   # put back
     lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
              if l.startswith("{")]
-    assert lines[-1] == {"tuned": False, "cases": 1}
-    assert lines[0]["bench"] == bench and lines[0]["device"] == "cpu"
-    assert lines[0]["pallas_us"] > 0 and lines[0]["xla_us"] > 0
+    assert lines[-1] == {"tuned": False, "cases": len(benches)}
+    assert [l["bench"] for l in lines[:-1]] == benches
+    assert all(l["device"] == "cpu" for l in lines[:-1])
+    for line in lines[:-1]:
+        if line["bench"] == "mamba1_decode_layer":
+            assert line["fused_calls"] == ["conv_window_step",
+                                           "selective_state_update"]
+            assert line["kernel_calls"] == ["selective_state_update"]
+            assert line["xla_calls"] == []
+            assert min(line[f"{p}_us"] for p in ("fused", "kernel", "xla")) > 0
+            assert {line["window_winner"], line["update_winner"]} <= {
+                "pallas", "xla"}
+        else:
+            assert line["pallas_us"] > 0 and line["xla_us"] > 0
+            if "winner" in line:
+                assert line["pallas_calls"] == [line["bench"]]
+                assert line["xla_calls"] == [] and line["bytes"] > 0

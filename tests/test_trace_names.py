@@ -356,10 +356,18 @@ def _ssm():
 
 def _selective_update():
     from paddle_tpu.ops.pallas.selective_ssm import selective_state_update
-    rest = (jnp.ones((2, 128)), jnp.ones((2, 128)), -jnp.ones((16, 128)),
-            jnp.ones((2, 16)), jnp.ones((2, 16)))
+    rest = (jnp.ones((2, 128)), jnp.ones((2, 128)), jnp.zeros((128,)),
+            -jnp.ones((16, 128)), jnp.ones((2, 16)), jnp.ones((2, 16)),
+            jnp.ones((128,)), jnp.ones((2, 256)))
     return (lambda s: selective_state_update(s, *rest, interpret=True)[0]), (
         jnp.zeros((2, 16, 128)),)
+
+
+def _window_step():
+    from paddle_tpu.ops.pallas.selective_ssm import conv_window_step
+    rest = (jnp.ones((2, 256)), jnp.ones((4, 128)), jnp.zeros((128,)))
+    return (lambda w: conv_window_step(w, *rest, interpret=True)[0]), (
+        jnp.zeros((2, 3, 128)),)
 
 
 def _selective_scan():
@@ -382,6 +390,7 @@ def _selective_scan():
     (_latent, ["latent_attention_decode"]),
     (_ssm, ["ssm_state_update"]),
     (_selective_update, ["selective_state_update"]),
+    (_window_step, ["conv_window_step"]),
     (_selective_scan, ["selective_scan"]),
 ], ids=lambda v: v.__name__.strip("_") if callable(v) else None)
 def test_a_pallas_entry_point_names_its_kernels(entry, expect):
@@ -448,13 +457,39 @@ def test_xla_own_share_reads_what_is_neither_a_kernel_nor_an_expert_product():
         served_only = kernel in ("paged_attention_decode", "int8_matmul",
                                  "latent_attention_decode",
                                  "ssm_state_update",
-                                 "selective_state_update", "selective_scan")
+                                 "selective_state_update", "selective_scan",
+                                 "conv_window_step")
         assert bool(rx.search(f"%jvp_{kernel}_.1 = " + tail)) == served_only
+
+
+@pytest.mark.parametrize("metric,kernel", [
+    ("selective_update_share.jamba", "selective_state_update"),
+    ("selective_update_roofline.jamba", "selective_state_update"),
+    ("selective_scan_share.jamba", "selective_scan")])
+def test_the_jamba_metrics_read_their_kernel_and_not_the_windows(metric,
+                                                                 kernel):
+    """The tick's two kernels stand side by side in the trace, 26 of each:
+    the accepted metrics of the state update and of the prompt's scan find
+    their kernel by a SUBSTRING of the instruction's name, so the window's
+    kernel carries a name that holds neither, in every form the compiler
+    gives a named Pallas call."""
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "layer_metrics",
+                           metric + ".json")) as f:
+        rx = re.compile(json.load(f)["pattern"])
+    tail = (" = (bf16[256,5120]{1,0:T(8,128)(2,1)S(1)}, bf16[3,256,5120]"
+            "{2,1,0:T(8,128)(2,1)}) custom-call(f32[4,5120]{1,0} %copy-done.2"
+            "), custom_call_target=\"tpu_custom_call\"")
+    for text in ("%{}.56", "%jvp_{}_.1", "{}.7"):
+        assert rx.search(text.format(kernel) + tail)
+        assert not rx.search(text.format("conv_window_step") + tail)
 
 
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
-    assert len(names) == len(set(names)) == 15
+    assert len(names) == len(set(names)) == 16
 
 
 # -- request timelines --------------------------------------------------------

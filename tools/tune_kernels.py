@@ -16,7 +16,10 @@ On the chip:
     serving cells' shapes, with jax's library kernel beside it), printing
     one JSON line per case, so
     regressions are diffable (the in-repo analogue of
-    ci_op_benchmark.sh).
+    ci_op_benchmark.sh);
+  - --selective-update: a Mamba-1 layer's tick, each of its two kernels
+    against XLA's fusion of its twin, alone and inside 26 whole layers
+    chained as a tick chains them (the evidence a kernel stays by).
 
 Without a TPU it fails. --interpret validates the sweep machinery on any
 backend with one tiny case in Pallas interpret mode (no timings recorded).
@@ -255,34 +258,54 @@ def bench_paged_decode(interpret, B=32, H=32, H_kv=8, per_seq=16,
     return results
 
 
+def _pallas_calls(fn, *args):
+    """Names of the Pallas calls a traced ``fn`` makes, in order: what was
+    MADE, printed beside what was timed."""
+    import jax
+
+    def walk(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.append(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, out)
+        return out
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr, [])
+
+
 def _bench_update(bench, shape, impls, fresh_state, x, rest, steps,
-                  state_bytes):
-    """One state-update kernel against its ``jnp`` twin: ``steps`` calls
-    chained inside ONE program with the state carried and donated, as the
-    tick carries it (a call's reading feeds the next one's input): device
-    time a call beside the time its bytes take (the state once each way) at
-    the chip's HBM rate."""
+                  moved_bytes, feed=None):
+    """One in-place kernel against its ``jnp`` twin: ``steps`` calls chained
+    inside ONE program with the state carried and donated, as the tick
+    carries it (a call's reading feeds the next one's input through
+    ``feed``): device time a call beside the time ``moved_bytes`` take at
+    the chip's HBM rate, the winner, and the Pallas calls each side made."""
     import jax
     kind = getattr(jax.devices()[0], "device_kind", "cpu")
+    feed = feed or (lambda x, y: 0.5 * x + 0.01 * y.astype(x.dtype))
 
     def chained(fn):
         def run(state, x):
             def body(carry, _):
                 state, x = carry
                 y, state = fn(state, x, *rest)
-                return (state, (0.5 * x + 0.01 * y.astype(x.dtype))), None
+                return (state, feed(x, y)), None
             return jax.lax.scan(body, (state, x), None, length=steps)[0]
         return jax.jit(run, donate_argnums=(0,))
 
     line = {"bench": bench, "device": kind, "steps": steps, "shape": shape,
-            "bytes_us": round(2 * state_bytes / 819e3, 1)}
+            "bytes": moved_bytes, "bytes_us": round(moved_bytes / 819e3, 1)}
     for name, fn in impls.items():
-        run = chained(fn)
+        run, kept = chained(fn), [fresh_state()]
 
-        def once(x):        # a fresh state a call: the last one was donated
-            return run(fresh_state(), x)
+        def once(x):        # the state goes round: the last one was donated
+            kept[0], out = run(kept[0], x)
+            return out
         t = _time_fn(once, x, iters=1, warmup=1, reps=3)
         line[f"{name}_us"] = round(t / steps * 1e6, 1)
+        line[f"{name}_calls"] = sorted(set(_pallas_calls(
+            lambda s, x, fn=fn: fn(s, x, *rest), fresh_state(), x)))
+    line["winner"] = min(impls, key=lambda name: line[f"{name}_us"])
     print(json.dumps(line), flush=True)
     return [line]
 
@@ -308,7 +331,7 @@ def bench_ssm_update(interpret, B=192, H=64, P=64, N=128, G=8, steps=64):
         {"pallas": functools.partial(ssm_state_update, interpret=interpret),
          "xla": ssm_state_update_xla},
         lambda: pack_state(jnp.zeros((B, H, P, N), jnp.float32), G), x, rest,
-        steps, B * H * P * N * 4)
+        steps, 2 * B * H * P * N * 4)
 
 
 def _selective_inputs(B, L, D, N):
@@ -324,24 +347,99 @@ def _selective_inputs(B, L, D, N):
             jax.random.normal(k[4], (B, L, N)))
 
 
-def bench_selective_update(interpret, B=256, D=5120, N=16, steps=52):
-    """The Mamba-1 state update (``ops/pallas/selective_ssm.py``) at a
-    serving cell's shape (``jamba2-3b.batch-reasoning``: 256 slots of
-    [16, 5120] float32)."""
+def bench_selective_update(interpret, B=256, d=2560, N=16, layers=26):
+    """A Mamba-1 layer's tick (``ops/pallas/selective_ssm.py``) at a serving
+    cell's shape (``jamba2-3b.batch-reasoning``: 256 slots of [16, 5120]
+    float32 and a window [3, 5120] bf16), each half against XLA's fusion of
+    its ``jnp`` twin: (1) the state update, from the raw step to the gated
+    row, and (2) the window's step, each chained ``layers`` times alone with
+    its state fed back; (3) ``layers`` whole layers (``Mamba1Mixer.decode``:
+    in_proj to out_proj, each with weights and state of its own) chained in
+    one program as a tick chains them, in the three forms ``state_path``
+    names: the window's kernel is judged by "fused" against "kernel", the
+    update's by "kernel" against "xla"."""
+    from paddle_tpu.ops.pallas import selective_ssm as k
+    if not interpret:
+        return _bench_mamba1_tick(k, B, d, N, layers, "bfloat16")
+    kernels = {name: getattr(k, name)
+               for name in ("conv_window_step", "selective_state_update")}
+    try:        # the layers look their kernels up in the module
+        for name, fn in kernels.items():
+            setattr(k, name, functools.partial(fn, interpret=True))
+        return _bench_mamba1_tick(k, 8, 64, N, 2, "float32")
+    finally:
+        for name, fn in kernels.items():
+            setattr(k, name, fn)
+
+
+def _bench_mamba1_tick(k, B, d, N, layers, act):
+    import jax
     import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.selective_ssm import (
-        selective_state_update, selective_state_update_xla)
-    if interpret:
-        B, D, steps = 2, 128, 2
-    x, *rest = (t[:, 0] if t.ndim == 3 else t
-                for t in _selective_inputs(B, 1, D, N))
-    return _bench_update(
+    from paddle_tpu.models import hybrid_lm
+    D, act = 2 * d, jnp.dtype(act)
+    key = jax.random.split(jax.random.key(0), 8)
+    x, step, a_t, b_mat, c_mat = (
+        t[:, 0] if t.ndim == 3 else t for t in _selective_inputs(B, 1, D, N))
+    xz = jax.random.normal(key[0], (B, 2 * D)).astype(act)
+    bias, skip = (0.02 * jax.random.normal(key[i], (D,)) for i in (1, 2))
+    taps = 1.0 + 0.02 * jax.random.normal(key[3], (4, D))
+    item = act.itemsize
+    results = _bench_update(
         "selective_state_update", f"b{B}_d{D}_n{N}",
-        {"pallas": functools.partial(selective_state_update,
-                                     interpret=interpret),
-         "xla": selective_state_update_xla},
-        lambda: jnp.zeros((B, N, D), jnp.float32), x, rest, steps,
-        B * D * N * 4)
+        {"pallas": k.selective_state_update,
+         "xla": k.selective_state_update_xla},
+        lambda: jnp.zeros((B, N, D), jnp.float32), x.astype(act),
+        (step, bias, a_t, b_mat, c_mat, 1.0 + skip, xz), layers,
+        B * D * (2 * N * 4 + 3 * item + 4))
+    results += _bench_update(
+        "conv_window_step", f"b{B}_k4_d{D}",
+        {"pallas": k.conv_window_step, "xla": k.conv_window_step_xla},
+        lambda: jnp.zeros((B, 3, D), act), xz, (taps, bias), layers,
+        B * D * 8 * item,
+        feed=lambda xz, y: 0.5 * xz + 0.01 * jnp.concatenate([y, y], 1))
+
+    # (3) whole layers, the form forced where ``state_path`` would choose
+    cfg = hybrid_lm.HybridConfig(pattern="m", hidden_size=d, vocab_size=8,
+                                 mamba_d_state=N, dtype=act.name)
+    mixer = hybrid_lm.Mamba1Mixer(cfg)
+    seeded = [{n: (p + 0.02 * jax.random.normal(
+        jax.random.fold_in(key[4], 97 * i + j), p.shape, jnp.float32
+    ).astype(p.dtype)) for j, (n, p) in enumerate(
+        sorted(mixer.raw_parameters().items()))} for i in range(layers)]
+
+    def tick(path, params, u, states):
+        # the form is the trace's, not an argument: one function a form
+        mixer.state_path = lambda rows, slots: path
+        out = []
+        for p, state in zip(params, states):
+            with mixer._bind(p):
+                y, state = mixer.decode(u, state)
+            u, out = 0.5 * u + 0.5 * y, out + [state]
+        return u, out
+    u = jax.random.normal(key[5], (B, 1, d)).astype(act)
+    line = {"bench": "mamba1_decode_layer", "layers": layers,
+            "device": getattr(jax.devices()[0], "device_kind", "cpu"),
+            "shape": f"b{B}_h{d}_d{D}_n{N}",
+            "weight_bytes": sum(p.size * p.dtype.itemsize
+                                for p in seeded[0].values())}
+    for path in ("fused", "kernel", "xla"):
+        run = jax.jit(functools.partial(tick, path), donate_argnums=(2,))
+        kept = [[mixer.alloc_slot_state(B) for _ in range(layers)]]
+
+        def once(u):        # the states go round: the last were donated
+            out, kept[0] = run(seeded, u, kept[0])
+            return out
+        line[f"{path}_calls"] = sorted(set(_pallas_calls(
+            functools.partial(tick, path), seeded, u,
+            [mixer.alloc_slot_state(B)] * layers)))
+        line[f"{path}_us"] = round(_time_fn(
+            once, u, iters=1, warmup=1, reps=3) / layers * 1e6, 1)
+    line["window_winner"] = ("pallas" if line["fused_us"] < line["kernel_us"]
+                             else "xla")
+    line["update_winner"] = ("pallas" if line["kernel_us"] < line["xla_us"]
+                             else "xla")
+    print(json.dumps(line), flush=True)
+    return results + [line]
 
 
 def bench_selective_scan(interpret, lengths=(128, 1024), D=5120, N=16,
