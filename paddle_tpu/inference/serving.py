@@ -482,11 +482,19 @@ class ContinuousBatchingEngine:
         pools, _ = self.core.alloc_paged_caches(
             1, total * page_size, page_size)
         self.pools = pools
+        # a model with NO paged layer (every mixer keeps a per-slot state:
+        # ``pools`` is empty) is served without a page: no page is counted,
+        # claimed or freed (the allocator below holds none and the tables
+        # stay at 0), a request is admitted by a free slot alone, and
+        # ``page_size`` is only the step of the prefill programs' widths
+        self.paged_layers = len(pools)
+        if not pools:
+            total = 1
         # int8 KV pages (ISSUE 17): a quantized pool's per-layer entry is
         # the 4-tuple (kp, vp, kscale, vscale); everything below that
         # moves pages (COW, handoff, adoption) is layout-generic, and the
         # decode/prefill write paths quantize inside the model
-        self.kv_quant = len(pools[0]) == 4
+        self.kv_quant = bool(pools) and len(pools[0]) == 4
         self.kv_quant_ticks = 0             # decode ticks on an int8 pool
         # "gqa": K and V pages per KV head; "mla": one latent row a token.
         # Nothing below reads a pool entry's arrays as K and V except the
@@ -727,8 +735,8 @@ class ContinuousBatchingEngine:
         # against the pool here — otherwise a router failover re-submit
         # passes validation and _admit raises mid-step, which would
         # crash the whole fabric instead of failing one request
-        if -(-(len(ids) + len(replay)) // self.page_size) \
-                > self._total_pages:
+        if self.paged_layers and -(-(len(ids) + len(replay))
+                                   // self.page_size) > self._total_pages:
             raise ValueError(f"prompt needs more pages than the pool "
                              f"holds ({self._total_pages}); raise "
                              f"num_pages")
@@ -815,10 +823,13 @@ class ContinuousBatchingEngine:
 
     def stats(self) -> Dict[str, float]:
         """Gauges (``free_pages``, ``active``, ``queued``, ``inflight``,
-        ``prefix_shared_pages``, the two ``*_bytes``) and monotone lifetime
-        counters: attribute reads only, so a caller may ask every step."""
+        ``prefix_shared_pages``, the two ``*_bytes``, ``paged_layers``: 0
+        says the model keeps no page, so ``free_pages`` 0 is no dry pool)
+        and monotone lifetime counters: attribute reads only, so a caller
+        may ask every step."""
         books = self._books
         out = {"free_pages": len(self._free),
+               "paged_layers": self.paged_layers,
                "active": sum(s is not None for s in self._slots),
                "queued": len(self._queue),
                "preemptions": self.preemptions,
@@ -1166,6 +1177,8 @@ class ContinuousBatchingEngine:
         state = getattr(self.core, "state_path", None)
         if state and program in ("prefill_paged", "run"):
             said["state_path"] = state(rows, self.max_batch)
+            if not self.paged_layers:
+                said["pages"] = "none"
         return compile_cache.building(
             program, self.build_log, **shape,
             **{k: v for k, v in said.items() if v})
@@ -1181,7 +1194,8 @@ class ContinuousBatchingEngine:
         self._g_active.set(sum(s is not None for s in self._slots), **lb)
         self._g_free.set(len(self._free), **lb)
         self._g_occupancy.set(
-            1.0 - len(self._free) / max(self._total_pages, 1), **lb)
+            1.0 - len(self._free) / self._total_pages
+            if self._total_pages else 0.0, **lb)
         if self._prefix is not None:
             self._g_prefix_pages.set(self._prefix.num_pages, **lb)
 
@@ -1618,7 +1632,8 @@ class ContinuousBatchingEngine:
             else:
                 qi, req = 0, self._queue[0]
             L = len(req.prompt) + len(req.generated)
-            need = -(-self._bucket(L) // self.page_size)
+            need = (-(-self._bucket(L) // self.page_size)
+                    if self.paged_layers else 0)
             toks = self._req_tokens(req)
             # prefix sharing: map every FULLY matched page; a full-prompt
             # match keeps the boundary page shared too and COWs it (the
@@ -2089,8 +2104,8 @@ class ContinuousBatchingEngine:
         them would evict victims for pages never legitimately written.
         With speculative blocks outstanding a dry pool raises _PoolDry
         instead: draining may retire slots and free pages without an
-        eviction."""
-        for slot in range(self.max_batch):
+        eviction. A model without a paged layer claims nothing."""
+        for slot in range(self.max_batch if self.paged_layers else 0):
             req = self._slots[slot]
             if not self._decode_ready(req):
                 continue              # mid-prefill slots claim at admission

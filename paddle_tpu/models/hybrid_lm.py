@@ -1,8 +1,8 @@
 """Hybrid state-space / attention / expert causal LM (``nemotron_h``'s layout,
-as NVIDIA-Nemotron-3-Nano-30B-A3B publishes it, and ``jamba``'s, as
-AI21-Jamba2-3B does).
+as NVIDIA-Nemotron-3-Nano-30B-A3B publishes it, ``jamba``'s, as
+AI21-Jamba2-3B does, and ``brumby``'s, as Brumby-14B-Base does).
 
-A decoder of blocks ``x + Mixer(RMSNorm(x))`` whose mixer is ONE of five
+A decoder of blocks ``x + Mixer(RMSNorm(x))`` whose mixer is ONE of six
 kinds, by a pattern string with one character a block:
 
 - ``M``: a Mamba-2 layer (``Mamba2Mixer``, arXiv:2405.21060): one input
@@ -28,6 +28,16 @@ kinds, by a pattern string with one character a block:
 - ``-``: a dense gated MLP (``GatedMLP``): ``(silu(x W_gate) * (x W_up))
   W_down``. A Jamba layer is TWO blocks, its mixer's and this one (``m-`` or
   ``*-``), each behind its own RMSNorm, as the published layer is.
+- ``p``: a power-retention layer of degree 2 (``PowerRetentionMixer``,
+  arXiv:2507.04239; Brumby's mixer, a Qwen3 attention layer with its softmax
+  replaced): q, k and v as GQA attention's, a per-head RMSNorm and THEN a
+  rotary embedding on q and k (the one mixer here that reads positions), a
+  gate ``g = sigmoid(u W_g)`` a KV head a token; position t weighs position
+  j <= t by ``prod_{j < i <= t} g_i (q_t . k_j)^2 / d`` and the output is
+  the weighted mean of v (the weights are not negative). As a recurrence:
+  a float32 state ``S = g S + phi(k) v^T`` [D, d] and ``z = g z + phi(k)``
+  a KV head, ``phi`` the D = d (d + 1) / 2 products of pairs, ``y = phi(q)^T
+  S / (phi(q) . z + eps)``. A Brumby layer is ``p-``.
 
 Served through ``inference.ContinuousBatchingEngine`` by the interface it has
 (``alloc_paged_caches`` / ``alloc_slot_state`` / ``prefill_paged`` /
@@ -37,11 +47,14 @@ inputs (activation dtype) and the recurrence's state in float32, which a
 prefill writes at the prompt's true last position and every decode tick
 rewrites in place (``ops.pallas.ssm.ssm_state_update``, or for a Mamba-1
 layer ``ops.pallas.selective_ssm``'s ``conv_window_step`` and
-``selective_state_update``, window and state each in one pass, on a TPU). A
+``selective_state_update``, window and state each in one pass, on a TPU; for
+a power-retention layer ``ops.pallas.power_retention``'s
+``power_state_update``). A
 prompt runs the Mamba-2 recurrence in chunks (``ssd_chunked``: matrix
-products inside a chunk, the state carried from chunk to chunk) and the
+products inside a chunk, the state carried from chunk to chunk), power
+retention in chunks too (``power_retention_chunked``) and the
 Mamba-1 recurrence, which has no such form, with time inside a kernel
-(``selective_scan``). Not trained: ``forward`` is the whole-sequence form for
+(``selective_scan``). A pattern without ``*`` keeps no page at all. Not trained: ``forward`` is the whole-sequence form for
 tests and evaluation; neither scan has a hand-written backward and no
 training cell runs one.
 """
@@ -73,7 +86,7 @@ class HybridConfig:
     n_groups: int = 8
     ssm_state_size: int = 128
     conv_kernel: int = 4
-    chunk_size: int = 128
+    chunk_size: int = 128                  # of a prompt's chunked scan
     # Mamba-1 (the inner width is mamba_expand x hidden_size)
     mamba_d_state: int = 16
     mamba_dt_rank: int = 160
@@ -82,6 +95,7 @@ class HybridConfig:
     num_attention_heads: int = 32
     num_key_value_heads: int = 2
     head_dim: int = 128
+    rope_theta: float = 10000.0            # power retention's q and k alone
     # experts
     num_experts: int = 128                 # the router's width
     first_expert_held: int = 0             # the share of them held here:
@@ -98,11 +112,12 @@ class HybridConfig:
     dtype: str = "float32"
 
     def __post_init__(self):
-        bad = set(self.pattern) - set("ME*m-")
+        bad = set(self.pattern) - set("ME*m-p")
         if bad or not self.pattern:
             raise ValueError(f"pattern {self.pattern!r}: one of 'M' (Mamba-2)"
                              f", 'm' (Mamba-1), '*' (attention), 'E' "
-                             f"(experts), '-' (dense MLP) a block")
+                             f"(experts), '-' (dense MLP), 'p' (power "
+                             f"retention) a block")
         if (self.num_hidden_layers is not None
                 and not 0 < self.num_hidden_layers <= len(self.pattern)):
             raise ValueError(f"num_hidden_layers={self.num_hidden_layers}: "
@@ -535,11 +550,9 @@ class Mamba1Mixer(nn.Layer):
         return out[:, None], (conv_state, ssm_state)
 
 
-class NoPEAttention(nn.Layer):
-    """Causal GQA attention with no position embedding at all (no rotary,
-    no table): ``nemotron_h``'s attention layers. The paged interface of
-    ``LlamaAttention`` (pools [H_kv, pages, page, d], the flash kernel for a
-    prompt, ``paged_attention_decode`` for a tick) without its rotation."""
+class _HeadsProjection(nn.Layer):
+    """What the two attention-shaped mixers share: one input projection to
+    [q | k | v] heads and one output projection back, no bias."""
 
     def __init__(self, cfg: HybridConfig):
         super().__init__()
@@ -568,6 +581,122 @@ class NoPEAttention(nn.Layer):
         b, s = u.shape[:2]
         return jnp.matmul(out.reshape(b, s, self.n_q * self.hd).astype(
             u.dtype), self.o_proj.astype(u.dtype))
+
+
+class PowerRetentionMixer(_HeadsProjection):
+    """One power-retention layer of degree 2 (the module docstring has its
+    equations; ``ops.pallas.power_retention`` the state's layout). The gate's
+    projection is float32, as a router's is."""
+
+    def __init__(self, cfg: HybridConfig):
+        super().__init__(cfg)
+
+        def vector(shape, value):
+            return self.create_parameter(shape, dtype="float32",
+                                         initializer=I.Constant(value))
+        self.g_proj = self.create_parameter(
+            [cfg.hidden_size, self.n_kv], dtype="float32",
+            initializer=_normal(cfg.initializer_range))
+        self.q_norm = vector([self.hd], 1.0)
+        self.k_norm = vector([self.hd], 1.0)
+
+    def _qkvg(self, u, positions):
+        """u [b, s, d] at ``positions`` [s] or [b, s] -> (q [b, s, Hq, hd]
+        and k [b, s, Hkv, hd], each head normalised and THEN rotated, in u's
+        dtype; v [b, s, Hkv, hd]; log g [b, s, Hkv] float32)."""
+        from ..ops.rope import rope_at, rotate_half
+        f32 = jnp.float32
+        q, k, v = self._qkv(u)
+        cos, sin = rope_at(positions, self.hd, self.cfg.rope_theta)
+        cos, sin = cos[..., None, :], sin[..., None, :]
+
+        def head(t, w):
+            t = t.astype(f32)
+            t = t * jax.lax.rsqrt(jnp.mean(t * t, -1, keepdims=True)
+                                  + self.cfg.rms_norm_eps) * w
+            return (t * cos + rotate_half(t) * sin).astype(u.dtype)
+        log_g = jax.nn.log_sigmoid(jnp.matmul(u.astype(f32), self.g_proj))
+        return head(q, self.q_norm), head(k, self.k_norm), v, log_g
+
+    def state_path(self, rows, slots: int) -> str:
+        """The form the recurrence takes ("kernel" or "xla") in a prompt of
+        ``rows`` positions or, ``rows`` None, in a tick of ``slots`` slots.
+        Decided from shapes alone."""
+        from ..ops.pallas import power_retention as k
+        cfg = self.cfg
+        if not _on_tpu():
+            return "xla"
+        if rows is not None:
+            ok = k.power_retention_chunked_supported(
+                jax.ShapeDtypeStruct((1, rows, self.n_q, self.hd), _dtype(cfg)),
+                jax.ShapeDtypeStruct((1, rows, self.n_kv, self.hd),
+                                     _dtype(cfg)), cfg.chunk_size)
+        else:
+            ok = k.power_state_update_supported(
+                jax.eval_shape(lambda: self.alloc_slot_state(slots))[0],
+                jax.ShapeDtypeStruct((slots, self.n_q, self.hd), _dtype(cfg)))
+        return "kernel" if ok else "xla"
+
+    def _sequence(self, u, last_idx=None):
+        """Whole sequences u [b, s, d] from a zero state, positions counted
+        from 0: (output [b, s, d], the state after position ``last_idx``
+        ([b, Hkv, T, hd, hd], [b, Hkv, rows, hd])). Positions past ``last_idx``
+        (a bucket's padding; None: the last) take a gate of 1 and a key of
+        0, so they leave the state as it is."""
+        from ..ops.pallas import power_retention as kern
+        b, s, _ = u.shape
+        q, k, v, log_g = self._qkvg(u, jnp.arange(s))
+        if last_idx is not None:
+            live = (jnp.arange(s) <= last_idx)[None, :, None]
+            k = jnp.where(live[..., None], k, 0)
+            log_g = jnp.where(live, log_g, 0.0)
+        scan = (kern.power_retention_chunked
+                if self.state_path(s, b) == "kernel"
+                else kern.power_retention_chunked_xla)
+        y, state, z = scan(q, k, v, log_g, self.cfg.chunk_size)
+        return self._o(y, u), state, z
+
+    def forward(self, u):
+        return self._sequence(u)[0]
+
+    # -- serving path --------------------------------------------------------
+
+    def alloc_slot_state(self, slots: int):
+        """(the state [slots, Hkv, T, hd, hd], T = hd / 2 + 1 tiles, and the
+        normaliser's [slots, Hkv, T in whole 8s, hd]), float32 whatever the
+        activation dtype."""
+        from ..ops.pallas.power_retention import tiles, z_rows
+        return (jnp.zeros((slots, self.n_kv, tiles(self.hd), self.hd,
+                           self.hd), jnp.float32),
+                jnp.zeros((slots, self.n_kv, z_rows(self.hd), self.hd),
+                          jnp.float32))
+
+    def prefill(self, u, state, slot, last_idx):
+        """The prompt of ONE sequence into slot ``slot``: the state written
+        is the state after the prompt's true last position ``last_idx``,
+        whatever the bucket the prompt was padded to."""
+        out, new, z = self._sequence(u, last_idx)
+        return out, (state[0].at[slot].set(new[0]),
+                     state[1].at[slot].set(z[0]))
+
+    def decode(self, u, state, pos):
+        """One token of every row u [b, 1, d] at position ``pos`` [b]
+        through the rows' state (the Pallas kernel on a TPU, in place; its
+        ``jnp`` twin elsewhere)."""
+        from ..ops.pallas import power_retention as kern
+        q, k, v, log_g = self._qkvg(u, pos[:, None])
+        update = (kern.power_state_update
+                  if self.state_path(None, u.shape[0]) == "kernel"
+                  else kern.power_state_update_xla)
+        y, new, z = update(*state, q[:, 0], k[:, 0], v[:, 0], log_g[:, 0])
+        return self._o(y[:, None], u), (new, z)
+
+
+class NoPEAttention(_HeadsProjection):
+    """Causal GQA attention with no position embedding at all (no rotary,
+    no table): ``nemotron_h``'s attention layers. The paged interface of
+    ``LlamaAttention`` (pools [H_kv, pages, page, d], the flash kernel for a
+    prompt, ``paged_attention_decode`` for a tick) without its rotation."""
 
     def _sequence(self, u):
         from ..ops.attention import flash_attention
@@ -641,7 +770,7 @@ class GatedMLP(nn.Layer):
                           jnp.matmul, jnp.matmul)
 
 
-STATEFUL = "Mm"     # the kinds whose mixer keeps a per-slot state
+STATEFUL = "Mmp"    # the kinds whose mixer keeps a per-slot state
 
 
 class HybridBlock(nn.Layer):
@@ -652,9 +781,10 @@ class HybridBlock(nn.Layer):
         self.kind = kind
         self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                dtype="float32")
-        if kind in "Mm-*":
+        if kind in "Mm-*p":
             self.mixer = {"M": Mamba2Mixer, "m": Mamba1Mixer, "-": GatedMLP,
-                          "*": NoPEAttention}[kind](cfg)
+                          "*": NoPEAttention,
+                          "p": PowerRetentionMixer}[kind](cfg)
         else:
             self.mixer = MoELayer(
                 cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
@@ -742,8 +872,9 @@ class HybridForCausalLM(nn.Layer):
     def alloc_paged_caches(self, batch: int, max_len: int,
                            page_size: int = 128):
         """(pools, tables): one pool entry for each ATTENTION layer, in
-        order (the other layers keep nothing a page), and the shared block
-        table."""
+        order (the other layers keep nothing a page; a pattern without
+        ``*`` has NO pool, and the engine then holds no page), and the
+        shared block table."""
         pages_per_seq = -(-max_len // page_size)
         num_pages = batch * pages_per_seq
         pools = [layer.mixer.alloc_pool(num_pages, page_size)
@@ -752,9 +883,9 @@ class HybridForCausalLM(nn.Layer):
             batch, pages_per_seq)
 
     def alloc_slot_state(self, slots: int):
-        """One entry for each MAMBA layer (either kind), in order, every
-        leaf leading with the slot; None for a pattern without one (the
-        engine then keeps nothing)."""
+        """One entry for each layer that carries a state (Mamba of either
+        kind, power retention), in order, every leaf leading with the slot;
+        None for a pattern without one (the engine then keeps nothing)."""
         return ([layer.mixer.alloc_slot_state(slots)
                  for layer in self._kinds(STATEFUL)] or None)
 
@@ -798,7 +929,10 @@ class HybridForCausalLM(nn.Layer):
         for layer in self.layers:
             u = layer.norm(x)
             if layer.kind in STATEFUL:
-                y, state[n_mamba] = layer.mixer.decode(u, state[n_mamba])
+                # power retention rotates q and k: the one mixer that
+                # reads the rows' positions
+                y, state[n_mamba] = layer.mixer.decode(
+                    u, state[n_mamba], *((pos,) if layer.kind == "p" else ()))
                 n_mamba += 1
             elif layer.kind == "*":
                 y, pools[n_attn] = layer.mixer.decode(u, pos, pools[n_attn],

@@ -185,7 +185,7 @@ def _rotate(x, cos, sin):
     """Rotate-half RoPE in float32; x [b, s, h, dim], cos/sin [b|1, s, dim]."""
     xf = x.astype(jnp.float32)
     return (xf * cos[:, :, None, :]
-            + rope_ops._rotate_half(xf) * sin[:, :, None, :]).astype(x.dtype)
+            + rope_ops.rotate_half(xf) * sin[:, :, None, :]).astype(x.dtype)
 
 
 class LatentAttention(nn.Layer):

@@ -6,6 +6,7 @@ from . import fused_norm  # noqa: F401
 from . import fused_vocab_ce  # noqa: F401
 from . import latent_attention  # noqa: F401
 from . import paged_attention  # noqa: F401
+from . import power_retention  # noqa: F401
 from . import selective_ssm  # noqa: F401
 from . import ssm  # noqa: F401
 
@@ -22,4 +23,5 @@ KERNEL_NAMES = (
     "fused_rmsnorm_bwd", "fused_rope", "int8_matmul",
     "latent_attention_decode", "ssm_state_update",
     "selective_state_update", "selective_scan", "conv_window_step",
+    "power_state_update", "power_retention_chunked",
 )

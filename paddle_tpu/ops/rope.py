@@ -27,7 +27,18 @@ def rope_freqs(head_dim: int, max_seq: int, base: float = 10000.0,
     return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
 
 
-def _rotate_half(x):
+def rope_at(positions, head_dim: int, base: float = 10000.0):
+    """(cos, sin) [.., head_dim] float32 AT ``positions`` [..] (any whole
+    numbers): what a decode tick whose rows stand at different positions
+    needs, with no table sized by a longest sequence."""
+    inv_freq = 1.0 / (base ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                               / head_dim))
+    freqs = positions.astype(jnp.float32)[..., None] * inv_freq
+    emb = jnp.concatenate([freqs, freqs], axis=-1)
+    return jnp.cos(emb), jnp.sin(emb)
+
+
+def rotate_half(x):
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([-x2, x1], axis=-1)
 
@@ -52,8 +63,8 @@ def apply_rotary_pos_emb(q, k, cos, sin, position_ids=None):
         sin = sin[:s][None, :, None, :]
     cos = cos.astype(q.dtype)
     sin = sin.astype(q.dtype)
-    q_out = q * cos + _rotate_half(q) * sin
-    k_out = k * cos + _rotate_half(k) * sin
+    q_out = q * cos + rotate_half(q) * sin
+    k_out = k * cos + rotate_half(k) * sin
     return q_out, k_out
 
 
@@ -115,9 +126,9 @@ def _rope_bwd(block_s, res, g):
     f32 = jnp.float32
     dcos = (jnp.sum(gq.astype(f32) * q.astype(f32), axis=(0, 2))
             + jnp.sum(gk.astype(f32) * k.astype(f32), axis=(0, 2)))
-    dsin = (jnp.sum(gq.astype(f32) * _rotate_half(q).astype(f32),
+    dsin = (jnp.sum(gq.astype(f32) * rotate_half(q).astype(f32),
                     axis=(0, 2))
-            + jnp.sum(gk.astype(f32) * _rotate_half(k).astype(f32),
+            + jnp.sum(gk.astype(f32) * rotate_half(k).astype(f32),
                       axis=(0, 2)))
     return dq, dk, dcos.astype(cos.dtype), dsin.astype(sin.dtype)
 

@@ -160,6 +160,28 @@ def _selective_scan(length=1024, d=5120, n=16):
                             ((1, length, n), F32)]
 
 
+def _power_update(slots=32, hq=40, hkv=8, d=128):
+    """Brumby-14B-Base's decode tick, one layer: 32 slots of 8 KV heads'
+    [65, 128, 128] float32 (4.26 MB a grid step, in and out, double-buffered:
+    the 64 MiB of VMEM asked for is what lets it compile) and five query
+    heads a KV head."""
+    from paddle_tpu.ops.pallas.power_retention import power_state_update
+    return power_state_update, [
+        ((slots, hkv, 65, d, d), F32), ((slots, hkv, 72, d), F32),
+        ((slots, hq, d), BF16), ((slots, hkv, d), BF16),
+        ((slots, hkv, d), BF16), ((slots, hkv), F32)]
+
+
+def _power_chunked(length=4096, hq=40, hkv=8, d=128):
+    """Brumby's widest prefill program, one layer: 4,096 positions in chunks
+    of 128, a KV head's state [65, 128, 128] resident in VMEM as the
+    kernel's output block."""
+    from paddle_tpu.ops.pallas.power_retention import power_retention_chunked
+    return power_retention_chunked, [
+        ((1, length, hq, d), BF16), ((1, length, hkv, d), BF16),
+        ((1, length, hkv, d), BF16), ((1, length, hkv), F32)]
+
+
 def _rms_norm():
     from paddle_tpu.ops.pallas.fused_norm import rms_norm_pallas
     return (_grad_sum(lambda x, w: rms_norm_pallas(x, w, 1e-5), (0, 1)),
@@ -233,6 +255,12 @@ ONE_CHIP = [
     pytest.param(_selective_update, id="selective_state_update[256x16x5120]"),
     pytest.param(_window_step, id="conv_window_step[256x3x5120]"),
     pytest.param(_selective_scan, id="selective_scan[1024x16x5120]"),
+    # brumby-14b.context-answers: 32 rows, 40 query heads on 8 KV heads, a
+    # state of 65 tiles of [128, 128] a KV head; its two kernels
+    pytest.param(_power_update, id="power_state_update[32x8x65x128x128]"),
+    pytest.param(_power_chunked, id="power_retention_chunked[4096x40/8x128]"),
+    pytest.param(lambda: _power_chunked(768),
+                 id="power_retention_chunked[768x40/8x128]"),
     pytest.param(_rms_norm, id="rms_norm[D4096]"),
     pytest.param(_rope, id="rope[s2048,32/8]"),
     pytest.param(lambda: _fused_ce(16384, 4096, 128256),
@@ -460,6 +488,61 @@ def test_the_jamba_tick_holds_one_copy_of_its_slot_state(topo, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total <= 8.83 * 2 ** 30, total / 2 ** 30
+
+
+def test_the_brumby_tick_holds_one_copy_of_its_slot_state_and_no_page(
+        topo, monkeypatch):
+    """``brumby-14b.context-answers``'s whole decode tick (5 layers, 32
+    slots, the cell's engine, NO pool) and its widest prefill program
+    compiled for one described chip: five calls of the tick's kernel (of
+    the prompt's in the prefill), the 5,499,781,120 B of slot state aliased
+    in place (ONE copy: a second would be 5.5 GB and would not fit), no
+    copy or transpose of a state leaf anywhere, and both programs under
+    12 GiB of the chip's 15.75."""
+    from benchmarks import program, run as bench
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.inference.generation import GenerationConfig
+    from paddle_tpu.ops import registry
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = bench.resolve("brumby-14b.context-answers",
+                           os.path.join(root, "BENCHMARK.json"))[1]
+    model, _ = program.build_model(config)
+    eng = ContinuousBatchingEngine(
+        model.eval(), generation_config=GenerationConfig(do_sample=False),
+        **config["engine"])
+    assert eng.pools == [] and eng.stats()["paged_layers"] == 0
+    assert model.state_path(None, eng.max_batch) == "kernel"
+    assert model.state_path(4096, 1) == "kernel"
+    assert eng.stats()["slot_state_bytes"] == 5_499_781_120
+    eng._init_state(jax.ShapeDtypeStruct((config["vocab_size"],), BF16))
+    eng._tables_dev = jnp.asarray(eng.tables)
+    dev = SingleDeviceSharding(topo.devices[0])
+    abstract = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
+                                       if not hasattr(a, "dtype") else a.dtype,
+                                       sharding=dev), t)
+    programs = {
+        "power_state_update": eng._build_decode(1, False, "paged").lower(
+            *abstract(eng._decode_args(False))),
+        "power_retention_chunked": eng._prefill_fn(4096).lower(*abstract((
+            eng._params, jnp.zeros((1, 4096), I32), eng.pools,
+            jnp.asarray(eng.tables[:1]), jnp.int32(0), eng.slot_state,
+            np.int32(0))))}
+    for kernel, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        names = [c.split(".")[0] for c in _mosaic_calls(text)]
+        assert names.count(kernel) == 5, (kernel, names)
+        assert set(names) <= {kernel, "fused_rmsnorm_fwd"}, names
+        assert not re.search(
+            r"f32\[32,8,(65,128,128|72,128)\]\S* (copy|transpose)\(", text)
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 5_499_781_120
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+        assert total <= 12 * 2 ** 30, (kernel, total / 2 ** 30)
 
 
 @pytest.mark.parametrize("tokens,step,temp_mib", [(1536, 192, 160),

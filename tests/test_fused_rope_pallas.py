@@ -109,7 +109,7 @@ class TestFusedRopeKernel:
         gq, gk = jnp.ones_like(out[0]), jnp.ones_like(out[1])
         want_dcos, want_dsin = vjp_fn((gq, gk))
 
-        rot = rope_ops._rotate_half
+        rot = rope_ops.rotate_half
         got_dcos = (jnp.sum(gq * q, axis=(0, 2))
                     + jnp.sum(gk * k, axis=(0, 2)))
         got_dsin = (jnp.sum(gq * rot(q), axis=(0, 2))

@@ -378,6 +378,22 @@ def _selective_scan():
         jnp.zeros((1, 16, 128)),)
 
 
+def _power_update():
+    from paddle_tpu.ops.pallas.power_retention import power_state_update
+    rest = (jnp.zeros((1, 1, 72, 128)), jnp.ones((1, 5, 128)),
+            jnp.ones((1, 1, 128)), jnp.ones((1, 1, 128)), jnp.zeros((1, 1)))
+    return (lambda s: power_state_update(s, *rest, interpret=True)[0]), (
+        jnp.zeros((1, 1, 65, 128, 128)),)
+
+
+def _power_chunked():
+    from paddle_tpu.ops.pallas.power_retention import power_retention_chunked
+    rest = (jnp.ones((1, 128, 1, 128)), jnp.ones((1, 128, 1, 128)),
+            jnp.zeros((1, 128, 1)))
+    return (lambda q: power_retention_chunked(q, *rest, interpret=True)[0]), (
+        jnp.ones((1, 128, 5, 128)),)
+
+
 @pytest.mark.parametrize("entry,expect", [
     (_flash, ["flash_attention_fwd", "flash_attention_bwd_dq",
               "flash_attention_bwd_dkv"]),
@@ -392,6 +408,8 @@ def _selective_scan():
     (_selective_update, ["selective_state_update"]),
     (_window_step, ["conv_window_step"]),
     (_selective_scan, ["selective_scan"]),
+    (_power_update, ["power_state_update"]),
+    (_power_chunked, ["power_retention_chunked"]),
 ], ids=lambda v: v.__name__.strip("_") if callable(v) else None)
 def test_a_pallas_entry_point_names_its_kernels(entry, expect):
     """Forward and gradient: every pallas_call in the traced program carries
@@ -458,7 +476,8 @@ def test_xla_own_share_reads_what_is_neither_a_kernel_nor_an_expert_product():
                                  "latent_attention_decode",
                                  "ssm_state_update",
                                  "selective_state_update", "selective_scan",
-                                 "conv_window_step")
+                                 "conv_window_step", "power_state_update",
+                                 "power_retention_chunked")
         assert bool(rx.search(f"%jvp_{kernel}_.1 = " + tail)) == served_only
 
 
@@ -489,7 +508,7 @@ def test_the_jamba_metrics_read_their_kernel_and_not_the_windows(metric,
 
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
-    assert len(names) == len(set(names)) == 16
+    assert len(names) == len(set(names)) == 18
 
 
 # -- request timelines --------------------------------------------------------

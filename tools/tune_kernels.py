@@ -30,6 +30,8 @@ Usage:
     python tools/tune_kernels.py --ssm-update
     python tools/tune_kernels.py --selective-update
     python tools/tune_kernels.py --selective-scan
+    python tools/tune_kernels.py --power-update
+    python tools/tune_kernels.py --power-prefill
     python tools/tune_kernels.py --interpret
 """
 
@@ -483,6 +485,106 @@ def bench_selective_scan(interpret, lengths=(128, 1024), D=5120, N=16,
     return results
 
 
+def _power_inputs(B, L, hq, hkv, d):
+    """Seeded inputs of power retention: q [B, L, Hq, d] and k [B, L, Hkv,
+    d] at unit RMS, v, all bf16, and log g [B, L, Hkv] ~ log sigmoid(N(0,
+    1.4)) as the cell's seeded gate gives it."""
+    import jax
+    import jax.numpy as jnp
+    k = jax.random.split(jax.random.key(0), 4)
+    bf16 = jnp.bfloat16
+    return (jax.random.normal(k[0], (B, L, hq, d)).astype(bf16),
+            jax.random.normal(k[1], (B, L, hkv, d)).astype(bf16),
+            jax.random.normal(k[2], (B, L, hkv, d)).astype(bf16),
+            jax.nn.log_sigmoid(1.4 * jax.random.normal(k[3], (B, L, hkv))))
+
+
+def bench_power_update(interpret, B=32, hq=40, hkv=8, d=128, layers=5):
+    """The power-retention state update (``ops/pallas/power_retention.py``)
+    at a serving cell's shape (``brumby-14b.context-answers``: 32 slots of 8
+    KV heads' [65, 128, 128] float32 and their normaliser), ``layers`` calls
+    chained with the state fed back as a tick chains its layers, against
+    XLA's fusion of its ``jnp`` twin; and the widest difference of the two
+    readings after one call on a state both started from."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import power_retention as k
+    if interpret:
+        B, layers = 2, 2
+    q, key, v, log_g = (t[:, 0] for t in _power_inputs(B, 1, hq, hkv, d))
+
+    def fresh():
+        return (jnp.zeros((B, hkv, k.tiles(d), d, d), jnp.float32),
+                jnp.zeros((B, hkv, k.z_rows(d), d), jnp.float32))
+
+    def form(fn):
+        def update(state, q, key, v, log_g):
+            y, new, z = fn(*state, q, key, v, log_g)
+            return y, (new, z)
+        return update
+    impls = {"pallas": form(functools.partial(k.power_state_update,
+                                              interpret=interpret)),
+             "xla": form(k.power_state_update_xla)}
+    lines = _bench_update(
+        "power_state_update", f"b{B}_hq{hq}_hkv{hkv}_d{d}", impls, fresh, q,
+        (key, v, log_g), layers, 2 * sum(a.size * 4 for a in fresh()))
+    warm, twin = fresh(), jax.jit(impls["xla"])
+    for i in range(8):      # eight tokens in the state: a sum worth dividing by
+        warm = twin(warm, q, jnp.roll(key, i, 0), jnp.roll(v, i, 0), log_g)[1]
+    ys = [jax.jit(fn)(warm, q, key, v, log_g)[0] for fn in impls.values()]
+    lines[0]["reading_max_diff"] = float(jnp.max(jnp.abs(ys[0] - ys[1])))
+    print(json.dumps({"reading_max_diff": lines[0]["reading_max_diff"]}),
+          flush=True)
+    return lines
+
+
+def bench_power_prefill(interpret, lengths=(512, 4096), hq=40, hkv=8, d=128,
+                        layers=5):
+    """The prompt's chunked power retention (``power_retention_chunked``)
+    against its ``jnp`` twin (a ``lax.scan`` over chunks whose ``phi``
+    lives in HBM a chunk at a time), one prompt at the cell's widths,
+    ``layers`` calls chained inside ONE program as a prefill chains its
+    layers: device time a call beside the time its required operations take
+    at the chip's peak (2 (R + 1) D (d + 1) a position a KV head), and the
+    widest difference of the two readings."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import power_retention as k
+    kind = getattr(jax.devices()[0], "device_kind", "cpu")
+    if interpret:
+        lengths, layers = (160,), 2
+    impls = {"pallas": functools.partial(k.power_retention_chunked,
+                                         interpret=interpret),
+             "xla": k.power_retention_chunked_xla}
+
+    def chained(fn):
+        def run(q, key, v, log_g):
+            def body(q, _):
+                y, state, _ = fn(q, key, v, log_g)
+                return (0.5 * q + 0.01 * y.astype(q.dtype)), state[0, 0, 0, 0]
+            return jax.lax.scan(body, q, None, length=layers)
+        return jax.jit(run)
+
+    results = []
+    rows = d * (d + 1) // 2
+    for L in lengths:
+        args = _power_inputs(1, L, hq, hkv, d)
+        flops = L * hkv * 2 * (hq // hkv + 1) * rows * (d + 1)
+        line = {"bench": "power_retention_chunked", "device": kind,
+                "layers": layers, "shape": f"l{L}_hq{hq}_hkv{hkv}_d{d}",
+                "flops_us": round(flops / 197e6, 1)}
+        ys = {}
+        for name, fn in impls.items():
+            t = _time_fn(chained(fn), *args, iters=1, warmup=1, reps=3)
+            line[f"{name}_us"] = round(t / layers * 1e6, 1)
+            ys[name] = jax.jit(fn)(*args)[0]
+        line["reading_max_diff"] = float(jnp.max(jnp.abs(
+            ys["pallas"] - ys["xla"])))
+        print(json.dumps(line), flush=True)
+        results.append(line)
+    return results
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
@@ -498,6 +600,12 @@ def main():
                     help="only the Mamba-1 state update against its twin")
     ap.add_argument("--selective-scan", action="store_true",
                     help="only the Mamba-1 prompt scan against XLA's form")
+    ap.add_argument("--power-update", action="store_true",
+                    help="only the power-retention state update against its "
+                         "twin")
+    ap.add_argument("--power-prefill", action="store_true",
+                    help="only the prompt's chunked power retention against "
+                         "its twin")
     ap.add_argument("--interpret", action="store_true",
                     help="validate the sweep machinery in Pallas interpret "
                          "mode (any backend, nothing recorded)")
@@ -513,7 +621,9 @@ def main():
     alone = [bench for flag, bench in (
         (args.ssm_update, bench_ssm_update),
         (args.selective_update, bench_selective_update),
-        (args.selective_scan, bench_selective_scan)) if flag]
+        (args.selective_scan, bench_selective_scan),
+        (args.power_update, bench_power_update),
+        (args.power_prefill, bench_power_prefill)) if flag]
     if alone:
         results = [line for bench in alone for line in bench(interpret)]
         print(json.dumps({"tuned": False, "cases": len(results)}))
