@@ -35,6 +35,7 @@ contents, devices' wall clock) must not.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import hashlib
 import json
 import os
@@ -199,6 +200,20 @@ def _store(fp: str, fn) -> None:
             _EXECUTABLES.popitem(last=False)
 
 
+# what the code being traced says about the build under way (``note``)
+_NOTES: contextvars.ContextVar = contextvars.ContextVar("build_notes",
+                                                        default=None)
+
+
+def note(key: str, value) -> None:
+    """Called by code a program's build traces (a kernel that chose how to
+    run from its shapes): ``value`` joins the list under ``key`` in that
+    build's ``build_log`` row, once. Outside a build it is dropped."""
+    notes = _NOTES.get()
+    if notes is not None and value not in notes.setdefault(key, []):
+        notes[key].append(value)
+
+
 @contextlib.contextmanager
 def building(name: str, log: Optional[list] = None, **attrs):
     """One program's build — trace + lower + compile, or the read from
@@ -209,20 +224,26 @@ def building(name: str, log: Optional[list] = None, **attrs):
     persistent cache, ``miss`` when the backend compiled one, ``uncached``
     when no persistent cache is in use. The engine's and the trainer's
     ``build_log`` are such lists: which program compiled, when, for how
-    long."""
+    long, and what the traced code said of itself (:func:`note`: the flash
+    kernels' ``flash_plan``, a list of the distinct plans of the build)."""
     _listen()
     with _LOCK:
         hits, misses = _STATS["persistent_hits"], _STATS["persistent_misses"]
     t0 = time.perf_counter()
-    with RecordEvent("compile::" + name, **attrs):
-        yield
+    token = _NOTES.set({})
+    try:
+        with RecordEvent("compile::" + name, **attrs):
+            yield
+    finally:
+        notes = _NOTES.get()
+        _NOTES.reset(token)
     seconds = time.perf_counter() - t0
     if log is not None:
         with _LOCK:
             cache = ("miss" if _STATS["persistent_misses"] > misses else
                      "hit" if _STATS["persistent_hits"] > hits else
                      "uncached")
-        log.append(dict(attrs, name=name, t_s=t0, seconds=seconds,
+        log.append(dict(attrs, **notes, name=name, t_s=t0, seconds=seconds,
                         cache=cache))
 
 

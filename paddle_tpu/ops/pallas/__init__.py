@@ -17,7 +17,7 @@ from . import ssm  # noqa: F401
 # kernel's event on the device trace's ``XLA Ops`` line. The benchmark's
 # ``*_share`` metrics find kernels by these names (PERF.md section 3).
 KERNEL_NAMES = (
-    "flash_attention_fwd", "flash_attention_bwd_dq",
+    "flash_attention_fwd", "flash_attention_bwd", "flash_attention_bwd_dq",
     "flash_attention_bwd_dkv", "fused_vocab_ce_fwd", "fused_vocab_ce_bwd_dh",
     "fused_vocab_ce_bwd_dw", "paged_attention_decode", "fused_rmsnorm_fwd",
     "fused_rmsnorm_bwd", "fused_rope", "int8_matmul",

@@ -105,15 +105,27 @@ class TuneDB:
 _DB = TuneDB()
 
 
-def _default_blocks(sq: int, sk: int) -> Tuple[int, int]:
-    """Heuristic when the DB has no entry: the v5-chip sweep (round 3)
-    showed larger blocks amortize the per-step grid overhead — bq=512/
-    bk=1024 ran ~2.8x faster than 128/128 at s=2048 — so pick the largest
-    candidate that divides the sequence (divisibility is required for the
-    pallas path to be selected at all)."""
-    bq = next((c for c in (512, 256, 128) if sq % c == 0), 128)
-    bk = next((c for c in (1024, 512, 256, 128) if sk % c == 0), 128)
-    return bq, bk
+def _default_blocks(sq: int, sk: int, d: int) -> Tuple[int, int]:
+    """The rule when the DB has no entry, one for every length and head
+    size: ONE block for the whole length up to 2,048, so a head is one grid
+    step whose K and V sit in VMEM once; a longer length is cut evenly, in
+    whole 128s (4,096 runs 2 x 2,048; 2,688 runs 2 x 1,408 and pads 128
+    rows: a block need not divide the length, the kernel pads to whole
+    blocks). The kernel runs a block in row parts
+    (``flash_attention.FWD_PART_ROWS`` / ``BWD_PART_ROWS``), so VMEM holds a
+    part's scores and not the block's, and a part on the causal line
+    multiplies only the keys up to its last row. On a v5e (PR 42,
+    ``tools/tune_kernels.py --flash``) wide blocks beat every grid of
+    smaller ones at every length the cells send: a grid step costs ~0.35
+    us whatever it holds and re-reads K and V. (One block of 4,096 read 5%
+    faster still and compiles three times as long: Mosaic unrolls a
+    block's vector code.) ``d`` does not move the width: forward and
+    backward compile for a v5e at every head size the gate admits (32 to
+    256), because the parts, not the blocks, set what VMEM holds."""
+    def cut(s):
+        n = -(-s // 2048)
+        return min(s, -(-s // (n * 128)) * 128)
+    return cut(sq), cut(sk)
 
 
 def flash_attention_config(sq: int, sk: int, d: int,
@@ -129,9 +141,9 @@ def flash_attention_config(sq: int, sk: int, d: int,
     key = TuneDB.key("flash_attention", _device_kind(default="tpu"), dtype,
                      sq=sq, sk=sk, d=d, causal=int(causal))
     hit = _DB.lookup(key)
-    if hit and sq % int(hit["block_q"]) == 0 and sk % int(hit["block_k"]) == 0:
+    if hit:
         return int(hit["block_q"]), int(hit["block_k"])
-    return _default_blocks(sq, sk)
+    return _default_blocks(sq, sk, d)
 
 
 def _device_kind(default: str = "cpu") -> str:

@@ -62,16 +62,22 @@ def _grad_sum(fn, argnums):
 # each case: () -> (fn, [(shape, dtype), ...])
 
 def _flash(s, bq, bk, causal=True, d=128, h=32, h_kv=8, seg=False,
-           grad=True):
+           grad=True, **kw):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_pallas
 
     def fn(q, k, v, *ids):
         return flash_attention_pallas(
             q, k, v, causal=causal, block_q=bq, block_k=bk,
-            segment_ids=ids[0] if ids else None)
+            segment_ids=ids[0] if ids else None, **kw)
     args = [((1, s, h, d), BF16), ((1, s, h_kv, d), BF16),
             ((1, s, h_kv, d), BF16)] + ([((1, s), I32)] if seg else [])
     return (_grad_sum(fn, (0, 1, 2)) if grad else fn), args
+
+
+def _flash_rule(s, d=128, **kw):
+    """A call at the blocks the rule gives its length (what a cell whose
+    shape the tune DB does not hold runs on the chip)."""
+    return _flash(s, *autotune._default_blocks(s, s, d), d=d, **kw)
 
 
 def _tuned_flash_cases():
@@ -224,15 +230,37 @@ ONE_CHIP = [
     pytest.param(lambda: _flash(8192, 1024, 1024, seg=True),
                  id="flash_segment_ids[s8192,1024/1024]"),
     *_tuned_flash_cases(),
+    # the rule's blocks (PR 42: one rule for every length, blocks that need
+    # not divide it). olmoe.pretrain-4k's call, forward and the one-pass
+    # backward (a KV head's whole dk and dv in VMEM), and the two-pass form
+    # it falls back to; the serving cells' buckets at lengths no power of
+    # two, with Mistral's, Nemotron's and Jamba's head groups; packed
+    # documents at a ragged length
+    pytest.param(lambda: _flash_rule(4096, h=16, h_kv=16),
+                 id="flash_rule[s4096,16/16,one_pass]"),
+    pytest.param(lambda: _flash_rule(4096, h=16, h_kv=16, vmem_budget=0),
+                 id="flash_rule[s4096,16/16,two_pass]"),
+    pytest.param(lambda: _flash_rule(8192, vmem_budget=0),
+                 id="flash_rule[s8192,32/8,two_pass]"),
+    pytest.param(lambda: _flash_rule(1920, grad=False),
+                 id="flash_rule_fwd[s1920,32/8]"),
+    pytest.param(lambda: _flash_rule(896, grad=False),
+                 id="flash_rule_fwd[s896,32/8]"),
+    pytest.param(lambda: _flash_rule(2688, h_kv=2, grad=False),
+                 id="flash_rule_fwd[s2688,32/2]"),
+    pytest.param(lambda: _flash_rule(3840, h=20, h_kv=1, grad=False),
+                 id="flash_rule_fwd[s3840,20/1]"),
+    pytest.param(lambda: _flash_rule(1920, seg=True),
+                 id="flash_rule_segment_ids[s1920,32/8]"),
     # latent attention's expanded prefill: 20 heads of 256 (192 nope + 64
-    # rope; values 256 too), forward only, at the blocks the chooser gives
-    # the serving buckets on a v5e (512/1024, 256/256, 128/128)
-    pytest.param(lambda: _flash(2048, 512, 1024, d=256, h=20, h_kv=20,
-                                grad=False), id="flash_fwd[s2048,512/1024,d256]"),
-    pytest.param(lambda: _flash(1792, 256, 256, d=256, h=20, h_kv=20,
-                                grad=False), id="flash_fwd[s1792,256/256,d256]"),
-    pytest.param(lambda: _flash(1920, 128, 128, d=256, h=20, h_kv=20,
-                                grad=False), id="flash_fwd[s1920,128/128,d256]"),
+    # rope; values 256 too), forward only, at the blocks the rule gives
+    # the serving buckets on a v5e (one block of the whole length each)
+    pytest.param(lambda: _flash_rule(2048, d=256, h=20, h_kv=20, grad=False),
+                 id="flash_fwd[s2048,d256]"),
+    pytest.param(lambda: _flash_rule(1792, d=256, h=20, h_kv=20, grad=False),
+                 id="flash_fwd[s1792,d256]"),
+    pytest.param(lambda: _flash_rule(1920, d=256, h=20, h_kv=20, grad=False),
+                 id="flash_fwd[s1920,d256]"),
     pytest.param(_latent_decode, id="latent_decode[bf16,64x20x576,page128]"),
     pytest.param(lambda: _paged(128, BF16), id="paged_decode[bf16,page128]"),
     pytest.param(lambda: _paged(16, BF16), id="paged_decode[bf16,page16]"),
@@ -293,6 +321,74 @@ def test_kernel_compiles_for_v5e(topo, case, monkeypatch):
     # is the kernel's event text in the device trace (PERF.md section 3)
     calls = _mosaic_calls(text)
     assert calls and all(any(k in c for k in KERNEL_NAMES) for c in calls), calls
+
+
+def test_the_olmoe_step_runs_flash_attention_in_two_bf16_calls(
+        topo, monkeypatch):
+    """``olmoe.pretrain-4k``'s whole ``jit_one_step`` (the cell's trainer,
+    built abstractly) compiled for one described chip: attention is two
+    Mosaic calls, the forward and ONE backward, whose first operand and
+    first result are 4-D bf16 (what ``flash_attn_roofline``'s pattern finds
+    them by); no float32 ``[b, h, sq, d]`` buffer stands in for dq anywhere;
+    the program is no larger than before the backward became one pass
+    (14.493 GiB, at the compiler's rematerialization limit: PERF.md section
+    7) and rematerializes the one fusion it did; the build's row says how
+    the kernel runs."""
+    from benchmarks import program, run as bench, traffic
+    from paddle_tpu import optimizer as opt_mod
+    from paddle_tpu.core import compile_cache
+    from paddle_tpu.ops import registry
+    from paddle_tpu.trainer import Trainer
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    _, config, mix, _, _ = bench.resolve(
+        "olmoe.pretrain-4k", os.path.join(root, "BENCHMARK.json"))
+    model, _ = program.build_model(config)
+    o = dict(config["optimizer"])
+    opt = getattr(opt_mod, o.pop("class"))(parameters=model, **o)
+    make_state = opt.init_state       # the leaves are shapes: so is the state
+    monkeypatch.setattr(opt, "init_state",
+                        lambda params: jax.eval_shape(make_state, params))
+    tr = Trainer(model, opt)
+    tr._ensure_built()
+    rows = config["trainer"]["rows_per_chip"]
+    batch = traffic.training_rows(mix, 1, 0, rows, config["vocab_size"])
+    dev = SingleDeviceSharding(topo.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
+                                       if not hasattr(a, "dtype") else a.dtype,
+                                       sharding=dev),
+        (tr.params, tr.opt_state, dict(batch), tr._lr_scalar(),
+         tr._key_data()))
+    log = []
+    with compile_cache.building("one_step", log):
+        lowered = tr._step_jit.lower(*args)
+    assert log[0]["flash_plan"] == [dict(
+        block_q=2048, block_k=2048, nq=2, nk=2, interior=1, edge=2, dead=1,
+        fwd_parts=4, bwd_parts=8, backward="one_pass", group=1)]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    flash = re.findall(r"^\s*%(\S*flash_attention\S*) = (\(?\w+\[[\d,]*\])"
+                       r"[^\n]*? custom-call\(([^)]*)\)", text, re.M)
+    assert sorted(name.split(".")[0] for name, _, _ in flash) == [
+        "jvp_flash_attention_fwd_", "transpose_jvp_flash_attention_bwd__"]
+    seq = int(mix["seq_len"])
+    heads = f"bf16[{rows},16,{seq},128]"
+    for name, result, operands in flash:
+        assert result.lstrip("(") == heads, (name, result)
+        first = operands.split(",")[0].strip().lstrip("%")
+        assert re.search(r"^\s*%%%s = %s" % (re.escape(first),
+                                            re.escape(heads)), text, re.M), (
+            name, first)
+    # dq leaves its kernel in bf16: no float32 [b, h, sq, d] anywhere, in a
+    # fusion or out of one
+    assert f"f32[{rows},16,{seq},128]" not in text
+    assert len(set(re.findall(r"%(\S*\.remat\S*) = ", text))) == 1
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total <= 14.50 * 2 ** 30, total / 2 ** 30
 
 
 def test_the_loss_head_backward_keeps_one_slab_of_logit_cotangents(

@@ -94,12 +94,14 @@ def test_dispatch_uses_db_on_tpu(monkeypatch, tmp_path):
     assert (bq, bk) == (512, 256)
     # unknown shape falls back to defaults
     monkeypatch.setattr(jax, "devices", lambda *a: [FakeDev()])
-    # unknown shape: shape-aware heuristic defaults (largest dividing
-    # candidate — the round-3 hardware sweep favors big blocks)
+    # unknown shape: the rule's blocks (the widest, clipped to the length;
+    # they need not divide it, and a length of several is cut evenly)
     bq, bk = flash_attention_config(1024, 1024, 64, "bfloat16", False)
-    assert (bq, bk) == (512, 1024)
+    assert (bq, bk) == (1024, 1024)
     bq, bk = flash_attention_config(384, 384, 64, "bfloat16", False)
-    assert (bq, bk) == (128, 128)
+    assert (bq, bk) == (384, 384)
+    assert flash_attention_config(2688, 2688, 64, "bfloat16", True) == (1408,
+                                                                       1408)
 
 
 def test_dispatch_defaults_on_cpu():
@@ -124,32 +126,56 @@ def test_flash_attention_auto_blocks_still_correct():
 
 def test_shipped_db_nonempty_and_consulted(monkeypatch):
     """Round-3 invariant: the in-repo tune DB carries real-hardware
-    winners (the round-2 DB shipped empty) and dispatch returns them for
-    the bench shape on the recorded device kind."""
+    readings (the round-2 DB shipped empty). Since PR 42 it holds no
+    flash-attention entry: the sweep on the chip read the rule's own blocks
+    fastest at every shape, and a table beside a rule that says the same is
+    a second place to be wrong. Dispatch on the recorded device kind gives
+    the rule's, and an entry a later sweep records still wins
+    (``test_dispatch_uses_db_on_tpu``)."""
     import json as _json
-    import os
     from paddle_tpu.ops.pallas import autotune
     from paddle_tpu.ops import registry
 
     shipped = _json.load(open(autotune._SHIPPED))
     assert shipped, "shipped tune_db.json is empty"
-    key = TuneDB.key("flash_attention", "TPU v5 lite", "bfloat16",
-                     sq=2048, sk=2048, d=128, causal=1)
-    assert key in shipped, f"bench-shape key missing: {key}"
+    assert not [k for k in shipped if k.startswith("flash_attention|")]
 
-    fresh = TuneDB()
-    monkeypatch.setattr(autotune, "_DB", fresh)
+    monkeypatch.setattr(autotune, "_DB", TuneDB())
     monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
+    monkeypatch.setattr(autotune, "_device_kind",
+                        lambda default="cpu": "TPU v5 lite")
+    for s, blocks in ((2048, (2048, 2048)), (4096, (2048, 2048)),
+                      (1920, (1920, 1920)), (2688, (1408, 1408))):
+        assert flash_attention_config(s, s, 128, "bfloat16", True) == blocks
+        assert autotune._default_blocks(s, s, 128) == blocks
 
-    class FakeDev:
-        device_kind = "TPU v5 lite"
 
-    import jax
-    real = jax.devices
-    monkeypatch.setattr(jax, "devices", lambda *a: [FakeDev()])
-    try:
-        bq, bk = flash_attention_config(2048, 2048, 128, "bfloat16", True)
-    finally:
-        monkeypatch.setattr(jax, "devices", real)
-    rec = shipped[key]
-    assert (bq, bk) == (rec["block_q"], rec["block_k"])
+def test_the_flash_sweep_prints_what_it_was_asked_to_make(capsys,
+                                                         monkeypatch):
+    """``tools/tune_kernels.py --flash`` in interpret mode: every timed
+    candidate's line carries the plan the kernel ran (blocks as clipped,
+    the classes' counts, the edge blocks' parts, the backward's form)
+    beside its time; the rule's own choice runs; and a candidate that does
+    not divide the length (320 in blocks of 128) is timed, not skipped."""
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "tools"))
+    import tune_kernels
+    monkeypatch.setattr(sys, "argv",
+                        ["tune_kernels.py", "--flash", "--interpret"])
+    tune_kernels.main()
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+             if l.startswith("{")]
+    sweep = [l for l in lines
+             if l.get("bench") in ("flash_attention_fwd",
+                                   "flash_attention_fwdbwd")]
+    assert {l["bench"] for l in sweep} == {"flash_attention_fwd",
+                                           "flash_attention_fwdbwd"}
+    plan = {"block_q", "block_k", "nq", "nk", "interior", "edge", "dead",
+            "fwd_parts", "bwd_parts", "backward", "group"}
+    assert all(plan <= set(l) and l["pallas_us"] > 0 for l in sweep)
+    assert sum(l["rule"] for l in sweep) == 2
+    ragged = [l for l in sweep if l["nq"] * l["block_q"] > 320]
+    assert ragged and all(l["block_q"] == 128 for l in ragged)
+    assert lines[-1] == {"tuned": False, "cases": len(sweep)}

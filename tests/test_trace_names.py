@@ -300,6 +300,16 @@ def _flash():
     return jax.grad(f, argnums=(0, 1, 2)), (q, q, q)
 
 
+def _flash_two_pass():
+    """A KV head whose dk and dv the backward may not keep in VMEM."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_pallas
+    q = jnp.zeros((1, 256, 4, 128), jnp.bfloat16)
+    f = lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=True, interpret=True,
+        vmem_budget=0).astype(jnp.float32).sum()
+    return jax.grad(f, argnums=(0, 1, 2)), (q, q, q)
+
+
 def _rmsnorm():
     from paddle_tpu.ops.pallas.fused_norm import rms_norm_pallas
     f = lambda x, w: rms_norm_pallas(x, w, interpret=True).sum()
@@ -395,8 +405,9 @@ def _power_chunked():
 
 
 @pytest.mark.parametrize("entry,expect", [
-    (_flash, ["flash_attention_fwd", "flash_attention_bwd_dq",
-              "flash_attention_bwd_dkv"]),
+    (_flash, ["flash_attention_fwd", "flash_attention_bwd"]),
+    (_flash_two_pass, ["flash_attention_fwd", "flash_attention_bwd_dq",
+                       "flash_attention_bwd_dkv"]),
     (_rmsnorm, ["fused_rmsnorm_fwd", "fused_rmsnorm_bwd"]),
     (_rope, ["fused_rope"]),
     (_vocab_ce, ["fused_vocab_ce_fwd", "fused_vocab_ce_bwd_dh",
@@ -508,7 +519,7 @@ def test_the_jamba_metrics_read_their_kernel_and_not_the_windows(metric,
 
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
-    assert len(names) == len(set(names)) == 18
+    assert len(names) == len(set(names)) == 19
 
 
 # -- request timelines --------------------------------------------------------
@@ -922,6 +933,55 @@ def test_build_log_has_one_row_per_program_built(tiny_llama):
     eng.run()
     assert [r["name"] for r in eng.build_log[len(rows):]] == ["prefill_paged"]
     assert eng.build_log[-1]["bucket"] == 2 * PAGE
+
+
+def test_the_programs_that_run_flash_attention_say_its_plan(monkeypatch):
+    """A prefill program's row and the trainer's step row carry
+    ``flash_plan``: the distinct plans the flash kernel built its grids
+    from while the program was traced (blocks, the classes' counts, the
+    backward's form), written by the kernel itself through
+    ``compile_cache.note``. On the CPU the op runs XLA's composition and
+    says nothing; here the kernel stands in, interpreted."""
+    import functools
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.ops import registry
+    from paddle_tpu.ops.pallas.flash_attention import (flash_attention_pallas,
+                                                       flash_plan)
+    model = LlamaForCausalLM(LlamaConfig.tiny())      # fresh: traced here
+    cfg = model.cfg
+    eng = _engine(model)
+    eng.submit(_prompts(1, 5, cfg.vocab_size)[0])
+    eng.run()
+    assert not any("flash_plan" in r for r in eng.build_log)
+
+    monkeypatch.setitem(registry._KERNELS, ("flash_attention", "cpu"),
+                        functools.partial(flash_attention_pallas,
+                                          interpret=True))
+    model = LlamaForCausalLM(LlamaConfig.tiny())
+    eng = _engine(model)
+    eng.submit(_prompts(1, PAGE + 3, cfg.vocab_size)[0])
+    eng.run()
+    heads = cfg.num_attention_heads // cfg.num_key_value_heads
+    d = cfg.hidden_size // cfg.num_attention_heads
+    for row in eng.build_log:
+        if row["name"] == "prefill_paged":
+            s = row["bucket"]
+            assert row["flash_plan"] == [flash_plan(
+                s, s, d, True, heads, dtype="float32")._asdict()]
+        else:
+            assert "flash_plan" not in row, row
+
+    from paddle_tpu.core import compile_cache
+    compile_cache.clear()
+    tr = Trainer(model, SGD(learning_rate=0.05, parameters=model),
+                 donate=False)
+    ids = np.ones((2, 32), np.int32)
+    tr.train_step({"input_ids": ids, "labels": ids})
+    (row,) = tr.build_log
+    assert row["name"] == "one_step"
+    assert row["flash_plan"] == [flash_plan(32, 32, d, True, heads,
+                                            dtype="float32")._asdict()]
+    assert row["flash_plan"][0]["backward"] == "one_pass"
 
 
 def test_the_trainers_build_log_names_the_step_program():

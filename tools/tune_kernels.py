@@ -26,6 +26,7 @@ backend with one tiny case in Pallas interpret mode (no timings recorded).
 
 Usage:
     python tools/tune_kernels.py [--quick] [--out PATH] [--write-shipped]
+    python tools/tune_kernels.py --flash [--out PATH]
     python tools/tune_kernels.py --paged-decode
     python tools/tune_kernels.py --ssm-update
     python tools/tune_kernels.py --selective-update
@@ -78,74 +79,108 @@ def _mk_qkv(b, s, h, h_kv, d, dtype, seed=0):
     return q, k, v
 
 
-def sweep_flash(shapes, candidates, interpret, record_db, quick=False):
+# the cells' calls (BENCHMARK.json): olmoe.pretrain-4k's training step, and
+# the serving cells' prefill buckets at lengths no power of two, with the
+# Mistral / OLMoE (32/8), Nemotron (32/2) and Jamba (20/1) head groups
+FLASH_CELL_SHAPES = (
+    [(8, 4096, 16, 16, 128, "bfloat16", True, ("fwd", "fwdbwd"))]
+    + [(1, s, 32, 8, 128, "bfloat16", True, ("fwd",))
+       for s in (384, 896, 1664, 1920, 2688, 3840)]
+    + [(1, s, h, h_kv, 128, "bfloat16", True, ("fwd",))
+       for h, h_kv in ((32, 2), (20, 1)) for s in (1920, 3840)])
+FLASH_CANDIDATES = [(512, 512), (512, 1024), (1024, 1024), (1408, 1408),
+                    (1920, 1920), (2048, 1024), (2048, 2048), (4096, 4096)]
+CHAIN = 4        # calls chained in one program: a layer's call feeds the next
+
+
+def sweep_flash(shapes, candidates, interpret, record_db, xla=True):
+    """Time every candidate block pair of every shape, ``CHAIN`` calls
+    chained in one program (a call's output is the next one's queries, so
+    the device runs them back to back and the host's dispatch is paid
+    once), and print one line a candidate: what the kernel was ASKED to
+    make (``flash_plan``: blocks as clipped, the classes' counts, the
+    backward's form) beside what it timed. Blocks need not divide the
+    length; candidates that clip to the same plan run once, and the rule's
+    own choice (``autotune._default_blocks``) always runs."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.attention import _sdpa_xla
-    from paddle_tpu.ops.pallas.autotune import TuneDB, get_db
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention_pallas
+    from paddle_tpu.ops.pallas.autotune import (TuneDB, _default_blocks,
+                                                get_db)
+    from paddle_tpu.ops.pallas.flash_attention import (flash_attention_pallas,
+                                                       flash_plan)
 
     kind = getattr(jax.devices()[0], "device_kind", "cpu")
     db = get_db()
     results = []
-    for (b, s, h, h_kv, d, dtype, causal) in shapes:
+    timing = (dict(iters=2, warmup=1, reps=1) if interpret
+              else dict(iters=5, warmup=2, reps=3))
+
+    def program(attn, mode):
+        def chain(q, k, v):
+            for _ in range(CHAIN):
+                q = attn(q, k, v)
+            return q
+        if mode == "fwd":
+            return jax.jit(chain)
+        return jax.jit(jax.grad(
+            lambda q, k, v: chain(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))
+
+    for (b, s, h, h_kv, d, dtype, causal, modes) in shapes:
         q, k, v = _mk_qkv(b, s, h, h_kv, d, dtype)
-
-        def grad_of(attn):
-            def loss(q, k, v):
-                return attn(q, k, v).astype(jnp.float32).sum()
-            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-
+        rule = _default_blocks(s, s, d)
         best = {}
-        for mode in ("fwd", "fwdbwd"):
+        for mode in modes:
             timings = {}
-            for (bq, bk) in candidates:
-                if s % bq or s % bk:
+            for (bq, bk) in [rule] + [c for c in candidates if c != rule]:
+                plan = flash_plan(s, s, d, causal, h // h_kv, block_q=bq,
+                                  block_k=bk, dtype=str(q.dtype))
+                if plan in timings:
                     continue
-                attn = functools.partial(flash_attention_pallas,
-                                         causal=causal, block_q=bq,
-                                         block_k=bk, interpret=interpret)
+                attn = functools.partial(
+                    flash_attention_pallas, causal=causal, block_q=bq,
+                    block_k=bk, interpret=interpret)
+                line = {"bench": f"flash_attention_{mode}",
+                        "shape": f"b{b}_s{s}_h{h}x{h_kv}_d{d}",
+                        "dtype": str(q.dtype), "causal": causal,
+                        "device": kind, "rule": (bq, bk) == rule,
+                        **plan._asdict()}
                 try:
-                    fn = (jax.jit(attn) if mode == "fwd"
-                          else grad_of(attn))
-                    dt = _time_fn(fn, q, k, v,
-                                  iters=2 if interpret else 10,
-                                  warmup=1 if interpret else 2,
-                                  reps=1 if interpret else 3)
-                    timings[(bq, bk)] = dt
+                    dt = _time_fn(program(attn, mode), q, k, v,
+                                  **timing) / CHAIN
+                    timings[plan] = dt
+                    line["pallas_us"] = round(dt * 1e6, 1)
                 except Exception as e:  # config invalid on this hw
-                    print(f"  skip bq={bq} bk={bk}: "
-                          f"{type(e).__name__}: {str(e)[:120]}",
-                          file=sys.stderr)
+                    line["skipped"] = f"{type(e).__name__}: {str(e)[:120]}"
+                results.append(line)
+                print(json.dumps(line), flush=True)
             if not timings:
                 continue
-            (bq, bk), dt = min(timings.items(), key=lambda kv: kv[1])
-            best[mode] = {"block_q": bq, "block_k": bk, "us": dt * 1e6}
-
+            plan, dt = min(timings.items(), key=lambda kv: kv[1])
+            best[mode] = {"block_q": plan.block_q, "block_k": plan.block_k,
+                          "us": dt * 1e6}
+            if not xla:
+                continue
             # XLA baseline for the microbench comparison; the dense [s, s]
             # score tensor OOMs at long seq (8GB at s=8K) — that is the
             # point of the flash kernel, so report pallas-only there
             try:
-                xattn = functools.partial(_sdpa_xla, causal=causal)
-                xfn = jax.jit(xattn) if mode == "fwd" else grad_of(xattn)
-                xdt = _time_fn(xfn, q, k, v,
-                               iters=2 if interpret else 10,
-                               warmup=1 if interpret else 2,
-                               reps=1 if interpret else 3)
+                xdt = _time_fn(
+                    program(functools.partial(_sdpa_xla, causal=causal),
+                            mode), q, k, v, **timing) / CHAIN
             except Exception as e:
                 print(f"  xla baseline failed (s={s}): "
                       f"{type(e).__name__}: {str(e)[:100]}", file=sys.stderr)
-                xdt = None
-            line = {"bench": f"flash_attention_{mode}",
+                continue
+            line = {"bench": f"flash_attention_{mode}_vs_xla",
                     "shape": f"b{b}_s{s}_h{h}x{h_kv}_d{d}",
-                    "dtype": str(q.dtype),
-                    "causal": causal, "device": kind,
                     "pallas_us": round(dt * 1e6, 1),
-                    "xla_us": round(xdt * 1e6, 1) if xdt else None,
-                    "speedup": round(xdt / dt, 3) if xdt else None,
-                    "best_block": [bq, bk]}
+                    "xla_us": round(xdt * 1e6, 1),
+                    "speedup": round(xdt / dt, 3),
+                    "best_block": [plan.block_q, plan.block_k]}
             results.append(line)
-            print(json.dumps(line))
+            print(json.dumps(line), flush=True)
         if record_db and "fwdbwd" in best:
             # fwd+bwd is the training-path config — that's what dispatch uses
             key = TuneDB.key("flash_attention", kind, str(q.dtype),
@@ -592,6 +627,10 @@ def main():
                     help="write results into the in-repo tune_db.json")
     ap.add_argument("--out", default=None,
                     help="write results into this JSON file instead")
+    ap.add_argument("--flash", action="store_true",
+                    help="only the flash-attention block sweep, at the "
+                         "benchmark cells' shapes (blocks that do not "
+                         "divide the length among the candidates)")
     ap.add_argument("--paged-decode", action="store_true",
                     help="only the paged-decode kernel-vs-XLA comparison")
     ap.add_argument("--ssm-update", action="store_true",
@@ -643,24 +682,28 @@ def main():
         print(json.dumps({"tuned": False, "cases": len(results)}))
         return
 
-    import jax.numpy as jnp
+    both = ("fwd", "fwdbwd")
     if interpret or args.quick:
-        shapes = [(1, 256, 2, 2, 64, jnp.float32, True)]
+        # 320 is no multiple of either candidate: a padded, masked end
+        shapes = [(1, 320, 2, 2, 64, "float32", True, both)]
         candidates = [(128, 128), (128, 256)]
+    elif args.flash:
+        shapes, candidates = FLASH_CELL_SHAPES, FLASH_CANDIDATES
     else:
         shapes = [
-            (8, 2048, 12, 4, 128, jnp.bfloat16, True),    # chip_smoke train shape
-            (4, 4096, 12, 4, 128, jnp.bfloat16, True),
-            (1, 8192, 32, 8, 128, jnp.bfloat16, True),    # Llama-3-8B @ 8K
-            (8, 2048, 16, 16, 64, jnp.bfloat16, True),
-            (4, 2048, 12, 4, 128, jnp.bfloat16, False),
+            (8, 2048, 12, 4, 128, "bfloat16", True, both),  # chip_smoke train
+            (4, 4096, 12, 4, 128, "bfloat16", True, both),
+            (1, 8192, 32, 8, 128, "bfloat16", True, both),  # Llama-3-8B @ 8K
+            (8, 2048, 16, 16, 64, "bfloat16", True, both),
+            (4, 2048, 12, 4, 128, "bfloat16", False, both),
         ]
-        candidates = [(bq, bk) for bq in (128, 256, 512, 1024)
-                      for bk in (128, 256, 512, 1024)]
+        candidates = [(bq, bk) for bq in (256, 512, 1024)
+                      for bk in (256, 512, 1024)] + [(2048, 1024)]
 
     results = sweep_flash(shapes, candidates, interpret,
-                          record_db=not interpret, quick=args.quick)
-    results += bench_paged_decode(interpret)
+                          record_db=not interpret, xla=not args.flash)
+    if not args.flash:
+        results += bench_paged_decode(interpret)
 
     from paddle_tpu.ops.pallas.autotune import get_db
     if not interpret:
