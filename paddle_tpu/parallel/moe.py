@@ -26,6 +26,17 @@ implementation of that product, forward and backward, on every platform
 still at this scale but DROPS overflow tokens — the measured trade is
 recorded in ops/pallas/tune_db.json (moe_grouped_mm).
 
+Around those two products a differentiated step moves its routed rows by
+GATHERS alone (PR 30) and does its index work by SORTS alone (PR 43:
+``sorted_assignments``): rows go out through ``dispatch_rows`` (a gather
+from [t, d]) and come back through ``combine_rows`` (a gather and a sum
+over k), which are each other's transposes. The router's weight is applied
+to the SORTED rows before the down product (``weighted_hidden``: the
+product is linear in its rows, so ``down(hidden * w) == w * down(hidden)``),
+in float32 and rounded once; nothing of size [k, t, d] is kept for the
+backward or built in it, and d weight of a row is the row sum of d hidden x
+activation, remade from the saved pre-activation.
+
 Expert parallelism (ISSUE 20): expert weights shard their expert dim over
 the ("ep","dp","fsdp") submesh — "ep" is a REAL mesh axis carved out of
 the data ranks (HybridMesh.build's ep degree; _clean_spec drops it on
@@ -462,48 +473,200 @@ def inverse_permutation(order):
 # compiles on TPU to passes over a u32 copy of the rows with a mask and an
 # index sort, and the transpose of ``flat[order % t]`` to a scatter-add
 # with another sort (140 ms of OLMoE's 532 ms step, chip run, PR 28).
-# Stated as what they are, a permutation's transpose is the gather by its
-# inverse and the dispatch's transpose a gather and a sum over k.
+# Stated as what they are, a permutation's transpose is the permutation by
+# its inverse, and going out (``dispatch_rows``: a gather from [t, d]) and coming
+# back (``combine_rows``: a gather and a sum over k) are each other's
+# transposes. Since PR 43 nothing else happens on the way back: the router's
+# weight rides the SORTED rows into the down product (``weighted_hidden``),
+# so no [k, t, d] array is kept for the backward and none is built in it.
 
 @jax.custom_vjp
-def permute_rows(x, idx, idx_inv):
-    """``x[idx]`` for a permutation ``idx`` whose inverse is ``idx_inv``."""
-    return x[idx]
+def permute_scalars(x, idx, idx_inv):
+    """``x[idx]`` of a VECTOR ``x`` for a permutation ``idx`` whose inverse
+    is ``idx_inv``, by a SORT: position i of the pairs (idx_inv, x) sorted
+    by key holds the j with idx_inv[j] == i, that is x[idx[i]]; the
+    transpose is the same with the two permutations exchanged. A gather of
+    scalars runs one index at a time on a TPU, as a scatter does: 2.26 and
+    1.87 ms for the 262,144 router weights of OLMoE's step and their
+    cotangents, where the two sorts take 0.32 and 0.30 (chip run, PR 43)."""
+    return jax.lax.sort((idx_inv, x), num_keys=1)[1]
 
 
-def _permute_fwd(x, idx, idx_inv):
-    return permute_rows(x, idx, idx_inv), (idx, idx_inv)
+def _permute_scalars_fwd(x, idx, idx_inv):
+    return permute_scalars(x, idx, idx_inv), (idx, idx_inv)
 
 
-def _permute_bwd(res, g):
+def _permute_scalars_bwd(res, g):
     idx, idx_inv = res
-    return g[idx_inv], _int_zero(idx), _int_zero(idx_inv)
+    return (permute_scalars(g, idx_inv, idx), _int_zero(idx),
+            _int_zero(idx_inv))
 
 
-permute_rows.defvjp(_permute_fwd, _permute_bwd)
+permute_scalars.defvjp(_permute_scalars_fwd, _permute_scalars_bwd)
+
+
+def _spread(rows, order, live):
+    """A row of ``rows`` [t, d] for each of the k*t sorted assignments,
+    ``rows[order % t]``; 0 where ``live`` (bool [k*t] in sorted order, or
+    None: all) says the assignment belongs to no group."""
+    out = rows[order % rows.shape[0]]
+    return out if live is None else jnp.where(live[:, None], out, 0)
+
+
+def _gather_sum(rows, inv, live, t: int):
+    """A token's k rows of the sorted ``rows`` [k*t, d], summed in float32
+    and rounded once: [t, d]. A row of no group (``live`` as in ``_spread``)
+    is whatever ``ragged_dot`` left there: taken out by a ``where``, never
+    by a product with 0."""
+    back = rows[inv]
+    if live is not None:
+        back = jnp.where(live[inv][:, None], back, 0)
+    return jnp.sum(back.reshape(-1, t, rows.shape[-1]), axis=0,
+                   dtype=jnp.float32).astype(rows.dtype)
+
+
+def _live_zero(live):
+    return None if live is None else _int_zero(live)
 
 
 @jax.custom_vjp
-def dispatch_rows(flat, order, inv):
+def dispatch_rows(flat, order, inv, live=None):
     """The rows of ``flat`` [t, d] in the order of their k*t choice-major
     assignments sorted by ``order``: ``flat[order % t]`` [k*t, d].
-    ``inv`` is ``order``'s inverse."""
-    return flat[order % flat.shape[0]]
+    ``inv`` is ``order``'s inverse; ``live`` (bool [k*t] in sorted order, or
+    None: all) marks the rows that belong to a group, whose cotangents
+    alone come back."""
+    return _spread(flat, order, None)
 
 
-def _dispatch_fwd(flat, order, inv):
-    return dispatch_rows(flat, order, inv), (order, inv, flat.shape[0])
+def _dispatch_fwd(flat, order, inv, live):
+    return (dispatch_rows(flat, order, inv, live),
+            (order, inv, live, flat.shape[0]))
 
 
 def _dispatch_bwd(res, g):
-    order, inv, t = res
-    # a token's k cotangents, summed in float32 and rounded once
-    dflat = jnp.sum(g[inv].reshape(-1, t, g.shape[-1]), axis=0,
-                    dtype=jnp.float32).astype(g.dtype)
-    return dflat, _int_zero(order), _int_zero(inv)
+    order, inv, live, t = res
+    return (_gather_sum(g, inv, live, t), _int_zero(order), _int_zero(inv),
+            _live_zero(live))
 
 
 dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def combine_rows(ys, order, inv, live, t: int):
+    """``dispatch_rows``' transpose as a forward: each of the ``t`` tokens'
+    k rows of the sorted ``ys`` [k*t, d], gathered through ``inv`` and
+    summed. ``live`` (bool [k*t] in sorted order, or None) marks the rows
+    that belong to a group: the others add nothing and get no cotangent.
+    Its own transpose is the dispatch's gather, from [t, d]."""
+    return _gather_sum(ys, inv, live, t)
+
+
+def _combine_fwd(ys, order, inv, live, t):
+    return combine_rows(ys, order, inv, live, t), (order, inv, live)
+
+
+def _combine_bwd(t, res, g):
+    order, inv, live = res
+    return (_spread(g, order, live), _int_zero(order), _int_zero(inv),
+            _live_zero(live))
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+@jax.custom_vjp
+def cotangents_together(rows, w):
+    """``(rows, w)`` as they are; their cotangents leave the backward
+    TOGETHER (an ``optimization_barrier``), so that nothing downstream of a
+    product's d rows starts before its d weights exist."""
+    return rows, w
+
+
+def _together_fwd(rows, w):
+    return (rows, w), None
+
+
+def _together_bwd(_, g):
+    return jax.lax.optimization_barrier(g)
+
+
+cotangents_together.defvjp(_together_fwd, _together_bwd)
+
+
+def _halves32(pre):
+    """A SwiGLU pre-activation's [gate | up] halves, float32 (split first:
+    XLA fuses a cast behind a slice, not a slice behind a cast)."""
+    g, u = jnp.split(pre, 2, axis=-1)
+    return g.astype(jnp.float32), u.astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def weighted_hidden(pre, gs, act: str):
+    """The hidden activation of the SORTED rows' pre-activation ``pre``
+    [rows, (2) f], each row times its router weight ``gs`` [rows] float32:
+    ``silu(g) * u * gs`` or ``relu(pre)^2 * gs``, computed in float32 and
+    rounded once to ``pre.dtype``. The down product is linear in its rows,
+    so ``down(hidden * gs) == gs * down(hidden)``: the weight applied here
+    leaves the way back a gather and a sum. The backward keeps ``pre`` and
+    ``gs`` alone and remakes the activation for d ``gs`` where it forms
+    d ``pre``."""
+    if act == "swiglu":
+        g, u = _halves32(pre)
+        hidden = jax.nn.silu(g) * u
+    elif act == "relu2":
+        hidden = jnp.square(jax.nn.relu(pre.astype(jnp.float32)))
+    else:
+        raise ValueError(f"expert_act must be one of {EXPERT_ACTS}, got "
+                         f"{act!r}")
+    return (hidden * gs[:, None]).astype(pre.dtype)
+
+
+def _weighted_hidden_fwd(pre, gs, act):
+    return weighted_hidden(pre, gs, act), (pre, gs)
+
+
+def _weighted_hidden_bwd(act, res, dh):
+    pre, gs = res
+    dh = dh.astype(jnp.float32)
+    da = dh * gs[:, None]                                 # d activation
+    if act == "swiglu":
+        g, u = _halves32(pre)
+        s = jax.nn.sigmoid(g)
+        dgs = jnp.sum(dh * (g * s * u), axis=-1)
+        dpre = jnp.concatenate([da * u * s * (1 + g * (1 - s)), da * g * s],
+                               axis=-1)
+    else:
+        r = jax.nn.relu(pre.astype(jnp.float32))
+        dgs = jnp.sum(dh * r * r, axis=-1)
+        dpre = da * 2 * r
+    return dpre.astype(pre.dtype), dgs
+
+
+weighted_hidden.defvjp(_weighted_hidden_fwd, _weighted_hidden_bwd)
+
+
+def sorted_assignments(flat_e, groups: int):
+    """(order, inv, group_sizes, live) of the assignments' experts
+    ``flat_e`` [n] int32, by sorts alone: ``order`` their stable argsort,
+    ``inv`` its inverse (the argsort of a permutation), ``group_sizes``
+    [groups] int32 the rows of each expert 0 .. groups - 1 read off the
+    SORTED keys' boundaries, ``live`` [n] bool the sorted rows that have a
+    group (an id of ``groups`` or more has none and sorts behind them all).
+    A scatter of n indices runs one index at a time on a TPU (``bincount``
+    2.29 ms and ``inverse_permutation`` 1.21 at OLMoE's 262,144, where the
+    sort of the same keys takes 0.23: chip run, PR 42)."""
+    n = flat_e.shape[0]
+    iota = jnp.arange(n, dtype=jnp.int32)
+    sorted_e, order = jax.lax.sort((flat_e.astype(jnp.int32), iota),
+                                   num_keys=1, is_stable=True)
+    _, inv = jax.lax.sort((order, iota), num_keys=1)
+    ends = jnp.searchsorted(sorted_e, jnp.arange(1, groups + 1,
+                                                 dtype=jnp.int32),
+                            method="compare_all").astype(jnp.int32)
+    sizes = jnp.diff(ends, prepend=jnp.zeros((1,), jnp.int32))
+    return order, inv, sizes, sorted_e < groups
 
 
 def _expert_ffn(xe, w_gu, w_dn):
@@ -906,32 +1069,50 @@ class MoELayer(Layer):
         ``grouped_matmul``: XLA's ``lax.ragged_dot`` forward and
         backward since PR 28. The rows go to their experts and come back
         by gathers, forward and backward (``dispatch_rows``,
-        ``permute_rows``). ``routing`` is ``_route``'s. Rows whose choice
-        is the skip sort behind every expert's run, belong to no group and
-        count as 0; the MLP router trains with no auxiliary term."""
+        ``combine_rows``), and the index work is two sorts
+        (``sorted_assignments``). Since PR 43 a row's router weight
+        multiplies its hidden activation BEFORE the down product
+        (``weighted_hidden``: float32, rounded once), so coming back is a
+        gather and a sum over k and nothing of [k, t, d] is kept for the
+        backward or built in it. ``routing`` is ``_route``'s. Rows whose
+        choice is the skip, or an expert held elsewhere, sort behind every
+        expert's run, belong to no group and count as 0 (``live``: by a
+        ``where``, their rows are whatever the products left); the MLP
+        router trains with no auxiliary term.
+
+        What the backward holds at once decides whether XLA recomputes a
+        1 GiB gather at OLMoE's size (PERF.md section 6, PR 43): each
+        product's two cotangents leave together
+        (``cotangents_together``: d weights is formed where its rows are
+        alive, not at the end of the step), and the weighted activation is
+        remade from the pre-activation (``jax.checkpoint``) instead of
+        kept from the forward."""
         t, d = flat.shape
-        e, k = self.num_experts, self.top_k
-        held = self.num_held
+        e, held = self.num_experts, self.num_held
+        act = self.experts.act
         probs, gates, ids = routing                           # [t, k]
-        ids = self._held(ids)
-        flat_e = ids.T.reshape(-1)                            # [k*t]
-        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
-        inv = inverse_permutation(order)
-        group_sizes = jnp.bincount(flat_e, length=held).astype(jnp.int32)
-        xs = dispatch_rows(flat, order, inv)                  # [k*t, d]
+        flat_e = self._held(ids).T.reshape(-1)                # [k*t]
+        order, inv, group_sizes, live = sorted_assignments(flat_e, held)
+        if not (self.skip_choice or held < e):
+            live = None                                   # every row has one
+        xs = dispatch_rows(flat, order, inv, live)            # [k*t, d]
+        # each sorted row's weight, float32: scalars through the same
+        # permutation, 0 for a row of no group
+        gs = permute_scalars(self._weights(gates.T, 0).reshape(-1), order,
+                             inv)
+        if live is not None:
+            gs = jnp.where(live, gs, 0)
 
         w_in = self.experts.w_in.astype(flat.dtype)       # [held, d, (2)f]
         w_dn = self.experts.w_down.astype(flat.dtype)     # [held, f, d2]
+        pre = grouped_matmul(*cotangents_together(xs, w_in), group_sizes)
 
-        gmm = lambda a, w: grouped_matmul(a, w, group_sizes)
-        ys = expert_ffn(xs, w_in, w_dn, self.experts.act, gmm, gmm)
-
-        # unsort to choice-major, weight, reduce over k
-        y_cm = permute_rows(ys, inv, order).reshape(k, t, d)
-        g_km = self._weights(gates.T, 0)                      # [k, t]
-        if self.skip_choice or held < e:
-            y_cm = jnp.where((ids.T < held)[..., None], y_cm, 0)
-        out = jnp.sum(g_km[..., None].astype(ys.dtype) * y_cm, axis=0)
+        @jax.checkpoint
+        def down(pre, gs, w_dn):
+            hidden = weighted_hidden(pre, gs, act)
+            return grouped_matmul(*cotangents_together(hidden, w_dn),
+                                  group_sizes)
+        out = combine_rows(down(pre, gs, w_dn), order, inv, live, t)
         return out, (jnp.zeros((), jnp.float32) if self.router == "mlp"
                      else _aux_loss(probs, e))
 

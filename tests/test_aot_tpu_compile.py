@@ -330,10 +330,18 @@ def test_the_olmoe_step_runs_flash_attention_in_two_bf16_calls(
     Mosaic calls, the forward and ONE backward, whose first operand and
     first result are 4-D bf16 (what ``flash_attn_roofline``'s pattern finds
     them by); no float32 ``[b, h, sq, d]`` buffer stands in for dq anywhere;
-    the program is no larger than before the backward became one pass
-    (14.493 GiB, at the compiler's rematerialization limit: PERF.md section
-    7) and rematerializes the one fusion it did; the build's row says how
-    the kernel runs."""
+    the build's row says how the kernel runs.
+
+    Since PR 43 the program recomputes NOTHING (until then one
+    ``fusion.remat``: the routed layer's first gather, 1 GiB, run a second
+    time in the backward because the step stood over the compiler's
+    rematerialization limit, 8.77 ms a step on the chip) and is no larger
+    than it was with that recomputation (14.493 GiB): the router's weight
+    is applied to the sorted rows before the down product, so the layer
+    keeps no ``[k, t, d]`` array for its backward and builds none there (no
+    ``bf16[8,32768,2048]`` broadcast of d out), each product's two
+    cotangents leave the backward together, and the weighted activation is
+    remade from the pre-activation (PERF.md section 6, PR 43)."""
     from benchmarks import program, run as bench, traffic
     from paddle_tpu import optimizer as opt_mod
     from paddle_tpu.core import compile_cache
@@ -384,7 +392,10 @@ def test_the_olmoe_step_runs_flash_attention_in_two_bf16_calls(
     # dq leaves its kernel in bf16: no float32 [b, h, sq, d] anywhere, in a
     # fusion or out of one
     assert f"f32[{rows},16,{seq},128]" not in text
-    assert len(set(re.findall(r"%(\S*\.remat\S*) = ", text))) == 1
+    assert len(set(re.findall(r"%(\S*\.remat\S*) = ", text))) == 0
+    ktd = "bf16\\[%d,%d,%d\\]" % (config["num_experts_per_tok"], rows * seq,
+                                  config["hidden_size"])
+    assert not re.search(r"= %s[^\n]* broadcast\(" % ktd, text)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -685,7 +696,11 @@ def test_a_prompts_routed_layer_keeps_one_copy_of_its_sorted_rows(
 def olmoe_routed_layer(topo):
     """OLMoE's routed layer as ``olmoe.pretrain-4k`` runs it (8 x 4096
     tokens, 64 experts of 2048 x 1024, top-8, dropless, bf16), forward and
-    backward, compiled for one described chip."""
+    backward, compiled for one described chip. The loss comes back with the
+    gradients, as a step's does: since PR 43 the way back from the experts
+    is linear (the router's weight is applied before the down product), so
+    the gradients of a linear loss alone would not need the forward's down
+    product at all."""
     from paddle_tpu.parallel.moe import MoELayer
     moe = MoELayer(hidden_size=2048, ffn_size=1024, num_experts=64, top_k=8,
                    capacity_factor=None, dtype="bfloat16")
@@ -698,7 +713,8 @@ def olmoe_routed_layer(topo):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
         moe.raw_parameters())
     x = jax.ShapeDtypeStruct((8, 4096, 2048), BF16, sharding=dev)
-    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
 
 
 def test_the_expert_products_compile_to_ragged_dots_alone(olmoe_routed_layer):
@@ -721,8 +737,9 @@ def test_the_routed_rows_move_by_gathers_in_the_activation_dtype(
     bf16. No scatter over them (on TPU a scatter whose indices XLA cannot
     know to be a permutation is two passes over a ``u32[262144,2048]``
     copy, a ``pred`` mask and an index sort), no float32 copy of them
-    around a backward product, and so under 6 GiB of temporaries (4.53;
-    9.52 with the scatter form and float32 cotangents)."""
+    around a backward product, and so under 6 GiB of temporaries (4.02
+    since PR 43 keeps no [k, t, d] array for the backward, 4.53 before; 9.52
+    with the scatter form and float32 cotangents)."""
     text = olmoe_routed_layer.as_text()
     # the entry computation's instructions are the buffers the program
     # holds; inside a fusion a float32 value lives in registers (v5e's

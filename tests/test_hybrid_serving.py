@@ -525,11 +525,22 @@ def test_capacity_and_expert_parallel_paths_refuse_both_by_name(kw, word):
 # commit before it.)
 PARENT_JAXPRS = {
     "gshard.infer4": "ceb4f45443e080c5",
-    "gshard.train": "8b10471cbf1c1896", "gshard.grad": "aa6b84a2d69af151",
     "glm.infer4": "4c1df47df0307047",
-    "glm.train": "129fe7319009d631", "glm.grad": "53a89d1ffdd80f3e",
-    "zaya.train": "ce7271d7d7478edc", "zaya.grad": "010f90f8331694ce",
     "capacity.train": "362adbfaf6c2cb73",
+}
+# The dropless TRAINING path is PR 43's: the router's weight multiplies the
+# sorted rows' hidden activation before the down product, the rows come back
+# by a gather and a sum alone, the index work is two sorts (name: (PR 43's,
+# PR 32's = the parent's)). Outputs and gradients against the formulation
+# it replaces: tests/test_moe_dropless_routing.py. The inference programs
+# and the capacity path are untouched.
+TRAINING_JAXPRS = {
+    "gshard.train": ("ebf8de4d24e0a0e3", "8b10471cbf1c1896"),
+    "gshard.grad": ("c89045330665293c", "aa6b84a2d69af151"),
+    "glm.train": ("4cccc584109c6acd", "129fe7319009d631"),
+    "glm.grad": ("c9238ca234a33932", "53a89d1ffdd80f3e"),
+    "zaya.train": ("f244ee126c3b33f8", "ce7271d7d7478edc"),
+    "zaya.grad": ("72afd7bca7ed69ff", "010f90f8331694ce"),
 }
 # The ONE program of the default layer that PR 33 did change: ``DENSE_ROWS``
 # went 128 -> 240, so a call of 129-240 rows whose choices outnumber the
@@ -545,8 +556,7 @@ CHANGED_JAXPRS = {
 # The sorted inference path is PR 37's: the loop over an expert's rows at
 # every width (the default layer's widths took ``ragged_dot`` before) and the
 # rows back by gathers (name: (PR 37's, PR 33's = the parent's)). The dense
-# path (4 and 192 rows: what a decode tick runs) and the training programs
-# above are untouched.
+# path (4 and 192 rows: what a decode tick runs) is untouched.
 SORTED_JAXPRS = {
     "gshard.infer320": ("af1260f576b70290", "3a8fb23ca48c1280"),
     "glm.infer320": ("54c1856b5997cf0e", "84954e4197067162"),
@@ -600,10 +610,11 @@ def test_the_default_layers_jaxpr_is_the_parents(name):
     assert "ragged_dot" not in text
     layer.train()
     x = jnp.ones((2, 8, 32))
-    assert _sha(lambda x: layer(x, state(x)), x) == \
-        PARENT_JAXPRS[f"{name}.train"]
+    ours, parents = TRAINING_JAXPRS[f"{name}.train"]
+    assert _sha(lambda x: layer(x, state(x)), x) == ours != parents
+    ours, parents = TRAINING_JAXPRS[f"{name}.grad"]
     assert _sha(jax.grad(lambda x: layer(x, state(x))[0].sum()), x) == \
-        PARENT_JAXPRS[f"{name}.grad"]
+        ours != parents
 
 
 def test_a_stage_builds_a_prefix_of_the_pattern():
