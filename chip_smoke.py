@@ -335,10 +335,10 @@ def phase_serve(sz, seed):
         bucket = eng._bucket(len(p))
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :len(p)] = p
-        logits, eng.pools, _ = eng._prefill_fn(bucket)(
+        logits, eng.pools, eng.slot_state = eng._prefill_fn(bucket)(
             eng._params, jnp.asarray(ids), eng.pools,
             jnp.zeros((1, eng.pages_per_seq), jnp.int32),
-            jnp.int32(len(p) - 1))
+            jnp.int32(len(p) - 1), eng.slot_state, np.int32(0))
         first[(w, i)] = np.asarray(logits.astype(jnp.float32))
 
     out, wave_s = {}, []
@@ -407,7 +407,7 @@ def phase_serve(sz, seed):
     programs[f"prefill_{bucket}"] = eng._prefill_cache[bucket].lower(
         eng._params, jnp.zeros((1, bucket), jnp.int32), eng.pools,
         jnp.zeros((1, eng.pages_per_seq), jnp.int32),
-        jnp.int32(0)).compile().as_text()
+        jnp.int32(0), eng.slot_state, np.int32(0)).compile().as_text()
     kernels = {k: kernel_census(v) for k, v in programs.items()}
     emit(phase="serve", kernels=kernels, peak_bytes=_peaks())
     if not REHEARSAL:

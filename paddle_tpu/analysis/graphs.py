@@ -133,9 +133,9 @@ def build_serving_tick() -> BuiltGraph:
     stop detection on device, zero host transfers."""
     import jax.numpy as jnp
     eng = _engine()
+    eng._tables_dev = jnp.asarray(eng.tables)
     fn = eng._build_decode(4, any_sample=False, attn_impl="paged")
-    args = (eng._params, eng.pools, jnp.asarray(eng.tables),
-            eng._base_key, eng._state, eng._knobs)
+    args = eng._decode_args(False)
     compiled = fn.lower(*args).compile()
     return BuiltGraph("serving_tick", compiled, GraphContract(
         "serving_tick", require_aliased=("pools",),
@@ -160,9 +160,9 @@ def build_serving_tick_quant() -> BuiltGraph:
     eng = ContinuousBatchingEngine(model, max_batch=2, page_size=8,
                                    max_len=64, num_pages=24)
     eng._init_state(jnp.zeros((_VOCAB,), jnp.float32))
+    eng._tables_dev = jnp.asarray(eng.tables)
     fn = eng._build_decode(4, any_sample=False, attn_impl="paged")
-    args = (eng._params, eng.pools, jnp.asarray(eng.tables),
-            eng._base_key, eng._state, eng._knobs)
+    args = eng._decode_args(False)
     compiled = fn.lower(*args).compile()
     hkv, npages, ps, hd = eng.pools[0][0].shape
     return BuiltGraph("serving_tick_quant", compiled, GraphContract(

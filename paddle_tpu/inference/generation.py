@@ -10,6 +10,7 @@ greedy, temperature, top-k, top-p — each a pure function over logits.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional
 
@@ -231,14 +232,21 @@ def _compiled_generate(model, cfg: GenerationConfig, b: int, prompt_len: int,
     def run(params, input_ids, key):
         # run under the layer's functional bridge so params are traced inputs
         with model._bind(params) if hasattr(model, "_bind") else \
-                _nullcontext():
+                contextlib.nullcontext():
             if kind == "paged":
+                # the ``ServingCore`` programs, every row's prompt at once
                 pools0, tables = core.alloc_paged_caches(b, max_len,
                                                          page_size)
-                hidden, caches = core.prefill_paged(input_ids, pools0,
-                                                    tables)
+                state = core.alloc_slot_state(b)
+                if jax.tree.leaves(state):
+                    raise ValueError(
+                        f"{type(core).__name__} keeps per-slot state, which "
+                        f"a prefill fills one sequence at a time: serve it "
+                        f"through ContinuousBatchingEngine")
+                hidden, caches, _ = core.prefill_paged(
+                    input_ids, pools0, tables, state, None, None)
                 decode = lambda tok, pos, c: core.decode_step_paged(
-                    tok, pos, c, tables)
+                    tok, pos, c, tables, state)[:2]
             else:
                 hidden, caches = core.prefill(input_ids, max_len)
                 decode = core.decode_step
@@ -308,11 +316,3 @@ def generate_paged(model, input_ids,
     compiled = _compiled_generate(model, cfg, b, prompt_len, "paged",
                                   page_size)
     return compiled(params, input_ids, jax.random.PRNGKey(cfg.seed))
-
-
-class _nullcontext:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False

@@ -113,6 +113,7 @@ tests/test_serving_spec.py, tests/test_serving_prefix.py).
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import itertools
 import time
@@ -125,6 +126,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import compile_cache
+from ..models.serving_core import ServingCore
 from ..observability.metrics import REGISTRY as _REG
 from ..observability.sentry import sentry as _sentry
 from ..observability.tracing import TRACER as _TRACE
@@ -384,9 +386,10 @@ class _PoolDry(Exception):
 
 
 class ContinuousBatchingEngine:
-    """vLLM-style continuous batching over a model exposing the paged-KV
-    trio (``alloc_paged_caches`` / ``prefill_paged`` / ``decode_step_paged``
-    on its core, e.g. ``LlamaForCausalLM``).
+    """vLLM-style continuous batching over a model whose core (the model
+    itself, or its ``.model``: ``LlamaForCausalLM``) is a
+    ``models.serving_core.ServingCore``: the cache and the programs declared
+    there are all the engine uses of it.
 
     ``async_depth``: bounded in-flight dispatch window. 1 = synchronous
     (dispatch → drain → bookkeep, the pre-async engine's schedule, kept
@@ -408,7 +411,6 @@ class ContinuousBatchingEngine:
                  prefix_cache: bool = False,
                  admission: Optional[AdmissionPolicy] = None,
                  name: Optional[str] = None):
-        self.model = model
         # replica identity (ISSUE 12 satellite): N engines in one process
         # (the in-proc serving fabric) must not merge their registry
         # series — every gauge/counter this engine publishes carries an
@@ -422,20 +424,28 @@ class ContinuousBatchingEngine:
         # replica's spans landing in the router's singleton
         self._tracer = None
         self.core = getattr(model, "model", model)
-        # per-slot state (``core.alloc_slot_state``): what a sequence
-        # carries from token to token OUTSIDE its pages (a convolution's
-        # last inputs, a recurrence's state): a pytree whose leaves lead
-        # with the slot. It lives beside the pools, is donated with them,
-        # written by the slot's prefill and rewritten by every tick; a
-        # freed, reused or preempted slot needs no copy, because its next
-        # prefill overwrites it. None for a model whose state is all in
-        # its pages: an empty pytree, which adds no input to a program,
-        # so the engine builds the programs it built. What would need a
-        # SNAPSHOT of the state at a position other than a sequence's
-        # end is refused here, by name
-        alloc_state = getattr(self.core, "alloc_slot_state", None)
-        self.slot_state = alloc_state(max_batch) if alloc_state else None
-        if self.slot_state is not None:
+        if not isinstance(self.core, ServingCore):
+            raise TypeError(
+                f"{type(self.core).__name__} is no ServingCore "
+                f"(models/serving_core.py declares what the engine serves)")
+        # the model around the core, resolved ONCE: its leaves, the context
+        # that binds a program's traced leaves, and its head
+        self._params = (model.raw_parameters()
+                        if hasattr(model, "raw_parameters") else {})
+        self._bind = (model._bind if hasattr(model, "_bind")
+                      else (lambda params: _NULL))
+        self._head = (model.logits if hasattr(model, "logits")
+                      else (lambda hidden: hidden))
+        # per-slot state (``ServingCore.alloc_slot_state``; empty where the
+        # state is all in the pages) lives beside the pools, is donated
+        # with them, written by the slot's prefill and rewritten by every
+        # tick; a freed, reused or preempted slot needs no copy, because
+        # its next prefill overwrites it. What would need a SNAPSHOT of it
+        # at a position other than a sequence's end is refused here, by name
+        self.slot_state = self.core.alloc_slot_state(max_batch)
+        self.slot_state_bytes = sum(
+            a.size * a.dtype.itemsize for a in jax.tree.leaves(self.slot_state))
+        if self.slot_state_bytes:
             for mode, on in (("chunked_prefill", chunked_prefill),
                              ("prefix_cache", prefix_cache),
                              ("spec_k", spec_k)):
@@ -445,21 +455,20 @@ class ContinuousBatchingEngine:
                         f"state {type(self.core).__name__} keeps beside "
                         f"its pages (alloc_slot_state), which the engine "
                         f"does not take yet")
-        self.slot_state_bytes = sum(
-            a.size * a.dtype.itemsize for a in jax.tree.leaves(self.slot_state))
-        if spec_k and not hasattr(self.core, "decode_verify_paged"):
+        # the modes built on an optional program (the prefill-extend, the
+        # multi-token verify: a latent-cache model has neither yet) are
+        # refused here, by name, not inside a trace
+        has = self.core.optional_programs
+        if spec_k and "decode_verify_paged" not in has:
             raise ValueError(
                 f"spec_k={spec_k} needs a model whose core implements "
                 f"decode_verify_paged (multi-token paged verify); "
                 f"{type(self.core).__name__} does not")
-        # a model that lacks the prefill-extend or the multi-token verify
-        # program (a latent-cache model has neither yet) cannot run the
-        # modes built on them: refused here, by name, not inside a trace
         for mode, on, needs in (
                 ("chunked_prefill", chunked_prefill, ("prefill_chunk_paged",)),
                 ("prefix_cache", prefix_cache, ("prefill_chunk_paged",
                                                 "decode_verify_paged"))):
-            lacks = [m for m in needs if not hasattr(self.core, m)]
+            lacks = [m for m in needs if m not in has]
             if on and lacks:
                 raise ValueError(
                     f"{mode}=True needs a model whose core implements "
@@ -499,7 +508,7 @@ class ContinuousBatchingEngine:
         # "gqa": K and V pages per KV head; "mla": one latent row a token.
         # Nothing below reads a pool entry's arrays as K and V except the
         # handoff (serialize_pages / adopt_pages), which refuses the rest
-        self.attention_kind = getattr(self.core, "attention_kind", "gqa")
+        self.attention_kind = self.core.attention_kind
         # bytes of cache a token takes over all layers, as ALLOCATED (a
         # gauge): the 4-D arrays of every entry, a token's share of a page,
         # a latent row's padding to whole lane tiles included
@@ -509,7 +518,7 @@ class ContinuousBatchingEngine:
         # counters a model's decode tick adds up on the device
         # (``core.tick_counters``: a routed model's expert load); they ride
         # to the host inside the block's token array
-        self._tick_counters = tuple(getattr(self.core, "tick_counters", ()))
+        self._tick_counters = tuple(self.core.tick_counters)
         self.tick_counts = dict.fromkeys(self._tick_counters, 0)
         self._total_pages = total - 1
         self._free: List[int] = list(range(total - 1, 0, -1))  # stack; 0 kept
@@ -533,8 +542,6 @@ class ContinuousBatchingEngine:
         self._queue: Deque[_Request] = deque()
         self._requests: Dict[int, _Request] = {}
         self._rid = itertools.count()
-        self._params = (model.raw_parameters()
-                        if hasattr(model, "raw_parameters") else {})
         self._base_key = jax.random.PRNGKey(self.cfg.seed)
         self._prefill_cache: Dict[int, object] = {}
         # decode_block = tokens generated per compiled scheduler tick. One
@@ -953,7 +960,7 @@ class ContinuousBatchingEngine:
     # -- KV-page handoff (serving-fabric disaggregation, ISSUE 12) -----------
 
     def _refuse_latent_handoff(self) -> None:
-        if self.slot_state is not None:
+        if self.slot_state_bytes:
             raise ValueError(
                 f"KV-page handoff ({HANDOFF_FMT}) carries pages alone; "
                 f"this engine's model keeps per-slot state beside them "
@@ -1171,14 +1178,11 @@ class ContinuousBatchingEngine:
             return _NULL
         self._built.add(key)
         rows = shape.get("bucket", shape.get("width"))   # a prefill's
-        how = getattr(self.core, "expert_path", None)
-        path, step = (rows and how and how(rows)) or (None, None)
+        path, step = (rows and self.core.expert_path(rows)) or (None, None)
         said = {"expert_path": path, "expert_step_rows": step}
-        state = getattr(self.core, "state_path", None)
-        if state and program in ("prefill_paged", "run"):
-            said["state_path"] = state(rows, self.max_batch)
-            if not self.paged_layers:
-                said["pages"] = "none"
+        if program in ("prefill_paged", "run"):
+            said["state_path"] = self.core.state_path(rows, self.max_batch)
+            said["pages"] = None if self.paged_layers else "none"
         return compile_cache.building(
             program, self.build_log, **shape,
             **{k: v for k, v in said.items() if v})
@@ -1492,18 +1496,13 @@ class ContinuousBatchingEngine:
         fn = self._prefill_cache.get(bucket)
         if fn is not None:
             return fn
-        core, model = self.core, self.model
-        head = model.logits if hasattr(model, "logits") else (lambda h: h)
+        core, bind, head = self.core, self._bind, self._head
 
-        def prefill_paged(params, ids, pools, tables1, last_idx,
-                          slot_state=None, slot=None):
-            ctx = model._bind(params) if hasattr(model, "_bind") else None
-            with ctx if ctx is not None else _null():
-                if slot_state is None:
-                    hidden, pools = core.prefill_paged(ids, pools, tables1)
-                else:
-                    hidden, pools, slot_state = core.prefill_paged(
-                        ids, pools, tables1, slot_state, slot, last_idx)
+        def prefill_paged(params, ids, pools, tables1, last_idx, slot_state,
+                          slot):
+            with bind(params):
+                hidden, pools, slot_state = core.prefill_paged(
+                    ids, pools, tables1, slot_state, slot, last_idx)
                 logits = head(hidden[0, last_idx, :])
             return logits, pools, slot_state
 
@@ -1539,7 +1538,7 @@ class ContinuousBatchingEngine:
         # rank — a request deferred every tick would otherwise keep its
         # prefix artificially hot and starve eviction of real traffic
         m = self._prefix.match(self._req_tokens(req), touch=False)
-        if m >= L and hasattr(self.core, "decode_verify_paged"):
+        if m >= L:
             price = 1
         else:
             price = L - (min(m, L - 1) // self.page_size) * self.page_size
@@ -1585,15 +1584,11 @@ class ContinuousBatchingEngine:
         write lands in the private copy and the returned logits row is
         what a full prefill would have produced."""
         if self._tail_fn is None:
-            core, model = self.core, self.model
-            head = model.logits if hasattr(model, "logits") else \
-                (lambda h: h)
+            core, bind, head = self.core, self._bind, self._head
 
             def tail_logits(params, tok, pos, pools, tables1, src, dst):
                 pools = [_entry_page_copy(e, src, dst) for e in pools]
-                ctx = model._bind(params) if hasattr(model, "_bind") \
-                    else None
-                with ctx if ctx is not None else _null():
+                with bind(params):
                     h, pools = core.decode_verify_paged(tok, pos, pools,
                                                         tables1)
                     logits = head(h[0, 0, :])
@@ -1644,8 +1639,7 @@ class ContinuousBatchingEngine:
             n_lock, fast, m = 0, False, 0
             if self._prefix is not None:
                 m = self._prefix.match(toks)
-                fast = (m >= L
-                        and hasattr(self.core, "decode_verify_paged"))
+                fast = m >= L
                 n_lock = (L - 1) // self.page_size if m >= L \
                     else m // self.page_size
             pages = self._alloc_pages(need - n_lock,
@@ -1814,12 +1808,10 @@ class ContinuousBatchingEngine:
         fn = self._chunk_fns.get(width)
         if fn is not None:
             return fn
-        core, model = self.core, self.model
-        head = model.logits if hasattr(model, "logits") else (lambda h: h)
+        core, bind, head = self.core, self._bind, self._head
 
         def prefill_chunk(params, ids, offset, pools, tables1, last_idx):
-            ctx = model._bind(params) if hasattr(model, "_bind") else None
-            with ctx if ctx is not None else _null():
+            with bind(params):
                 hidden, pools = core.prefill_chunk_paged(
                     ids, offset, pools, tables1)
                 # logits at the prompt's true last index — meaningful on
@@ -1881,19 +1873,15 @@ class ContinuousBatchingEngine:
         cost; the flag is host state, so at most two executables per K.
         ``attn_impl`` ('dense'|'paged') is baked in at TRACE time via
         force_decode_impl — the context-aware dispatch choice."""
-        core, model = self.core, self.model
-        head = model.logits if hasattr(model, "logits") else (lambda h: h)
+        core, bind, head = self.core, self._bind, self._head
         from ..ops.pallas.paged_attention import force_decode_impl
         n_counts = len(self._tick_counters)
 
         # ``run`` is the decode tick's name in the device trace, and the
         # only program of the engine with that name: the benchmark's
         # decode_tick_roofline finds the tick as ``^jit_run\(``
-        def run(params, pools, tables, base_key, state, knobs,
-                slot_state=None):
-            ctx = model._bind(params) if hasattr(model, "_bind") else None
-            with ctx if ctx is not None else _null(), \
-                    force_decode_impl(attn_impl):
+        def run(params, pools, tables, base_key, state, knobs, slot_state):
+            with bind(params), force_decode_impl(attn_impl):
                 def body(carry, _):
                     logits, pos, active, budget, gen = carry[0]
                     pools, slots = carry[1:]
@@ -1913,17 +1901,10 @@ class ContinuousBatchingEngine:
                     # slots HOLD real pages, stopped slots' speculative
                     # writes must be unreachable — one mask serves both
                     tbl = tables * active[:, None].astype(tables.dtype)
-                    # what the model returns follows what it is asked for:
-                    # (h, pools), then the tick's counters, then the
-                    # slots' next state
-                    kw = dict(counters=True) if n_counts else {}
-                    if slots is not None:
-                        kw["slot_state"] = slots
-                    h, pools, *more = core.decode_step_paged(
-                        tok, pos, pools, tbl, **kw)
-                    counts = more.pop(0) if n_counts else None
-                    if slots is not None:
-                        (slots,) = more
+                    # the rows' next state (empty for a core with none)
+                    # and the tick's counters (None where none is declared)
+                    h, pools, slots, counts = core.decode_step_paged(
+                        tok, pos, pools, tbl, slots)
                     new_logits = head(h[:, 0, :])
                     new_active, budget = decode_stop_update(
                         tok, active, budget, knobs["eos"])
@@ -1972,14 +1953,12 @@ class ContinuousBatchingEngine:
         to the garbage page (beyond the table span) — so a speculatively
         dispatched NEXT block self-masks what this block rejected and the
         depth-2 in-flight window is preserved."""
-        core, model = self.core, self.model
-        head = model.logits if hasattr(model, "logits") else (lambda h: h)
+        core, bind, head = self.core, self._bind, self._head
         provider = self._draft
 
         def spec_decode_block(params, pools, tables, base_key, state, knobs,
                               hist):
-            ctx = model._bind(params) if hasattr(model, "_bind") else None
-            with ctx if ctx is not None else _null():
+            with bind(params):
                 logits, pos, active, budget, gen = state
                 B = logits.shape[0]
                 H = hist.shape[1]
@@ -2522,15 +2501,7 @@ class ContinuousBatchingEngine:
         return out
 
 
-class _null:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _null()
+_NULL = contextlib.nullcontext()
 
 
 __all__ = ["ContinuousBatchingEngine", "HANDOFF_FMT", "HANDOFF_FMT_V1"]

@@ -39,7 +39,7 @@ kinds, by a pattern string with one character a block:
   a KV head, ``phi`` the D = d (d + 1) / 2 products of pairs, ``y = phi(q)^T
   S / (phi(q) . z + eps)``. A Brumby layer is ``p-``.
 
-Served through ``inference.ContinuousBatchingEngine`` by the interface it has
+Served through ``inference.ContinuousBatchingEngine`` as a ``ServingCore``
 (``alloc_paged_caches`` / ``alloc_slot_state`` / ``prefill_paged`` /
 ``decode_step_paged``): page pools for the attention layers ONLY, a per-slot
 state for the Mamba layers only: the convolution's last ``conv_kernel - 1``
@@ -72,6 +72,7 @@ from ..nn import initializer as I
 from ..parallel.moe import MoELayer, expert_ffn
 from .llama import (_kv_scatter_tokens, _kv_write_prompt, _normal,
                     _paged_decode_attention)
+from .serving_core import ServingCore
 
 
 @dataclass
@@ -810,7 +811,7 @@ class HybridBlock(nn.Layer):
         return x + self.mixer(u)[0] + self.shared_expert(u)
 
 
-class HybridForCausalLM(nn.Layer):
+class HybridForCausalLM(nn.Layer, ServingCore):
     """The hybrid decoder with its embedding, final norm and head (the
     embedding's transpose under ``tie_word_embeddings``, else a leaf of its
     own). ``forward`` returns logits, or (loss, logits) given labels."""
@@ -853,49 +854,33 @@ class HybridForCausalLM(nn.Layer):
     # -- serving path (inference.ContinuousBatchingEngine) -------------------
 
     def expert_path(self, rows: int):
-        """(path, rows of a step) by which the expert layers run a program
-        of ``rows`` rows (``MoELayer.inference_path``), None without an
-        expert layer: what the engine writes into the program's
-        ``build_log`` row."""
+        """``MoELayer.inference_path`` of the expert layers (all alike)."""
         routed = self._kinds("E")
         return routed[0].mixer.inference_path(rows) if routed else None
 
     def state_path(self, rows, slots: int):
-        """The form the state-space layers' recurrence takes in a prefill
-        program of ``rows`` positions ("kernel" or "xla") or, ``rows`` None,
-        in a tick of ``slots`` slots (also "fused": ``Mamba1Mixer.
-        state_path``), None without such a layer: ``build_log``'s
-        ``state_path``."""
+        """The stateful mixers' own ``state_path`` (all alike): "kernel" or
+        "xla", in a tick also "fused" (``Mamba1Mixer.state_path``)."""
         stateful = self._kinds(STATEFUL)
         return stateful[0].mixer.state_path(rows, slots) if stateful else None
 
-    def alloc_paged_caches(self, batch: int, max_len: int,
-                           page_size: int = 128):
-        """(pools, tables): one pool entry for each ATTENTION layer, in
-        order (the other layers keep nothing a page; a pattern without
-        ``*`` has NO pool, and the engine then holds no page), and the
-        shared block table."""
-        pages_per_seq = -(-max_len // page_size)
-        num_pages = batch * pages_per_seq
-        pools = [layer.mixer.alloc_pool(num_pages, page_size)
-                 for layer in self._kinds("*")]
-        return pools, jnp.arange(num_pages, dtype=jnp.int32).reshape(
-            batch, pages_per_seq)
+    def pool_layers(self):
+        """The ATTENTION layers alone keep pages: a pattern without ``*``
+        has NO pool, and the engine then holds no page."""
+        return [layer.mixer for layer in self._kinds("*")]
 
     def alloc_slot_state(self, slots: int):
         """One entry for each layer that carries a state (Mamba of either
         kind, power retention), in order, every leaf leading with the slot;
-        None for a pattern without one (the engine then keeps nothing)."""
-        return ([layer.mixer.alloc_slot_state(slots)
-                 for layer in self._kinds(STATEFUL)] or None)
+        empty for a pattern without one."""
+        return [layer.mixer.alloc_slot_state(slots)
+                for layer in self._kinds(STATEFUL)]
 
-    def prefill_paged(self, input_ids, pools, tables, slot_state=None,
-                      slot=None, last_idx=None):
-        """The prompt of one sequence: (hidden, pools) and, given
-        ``slot_state``, the state with slot ``slot`` set from the prompt's
-        position ``last_idx``."""
+    def prefill_paged(self, input_ids, pools, tables, slot_state, slot,
+                      last_idx):
+        """``ServingCore.prefill_paged``."""
         x = jnp.take(self.embed_tokens, input_ids, axis=0)
-        pools, state = list(pools), list(slot_state or ())
+        pools, state = list(pools), list(slot_state)
         n_attn = n_mamba = 0
         for layer in self.layers:
             u = layer.norm(x)
@@ -912,18 +897,12 @@ class HybridForCausalLM(nn.Layer):
             else:
                 y, _ = layer.experts(u)
             x = x + y
-        if slot_state is None:
-            return self.norm(x), pools
         return self.norm(x), pools, state
 
-    def decode_step_paged(self, token_ids, pos, pools, tables,
-                          counters: bool = False, slot_state=None):
-        """token_ids [b] -> (hidden [b, 1, d], pools), then, with
-        ``counters``, the tick's ``tick_counters`` as int32 and, given
-        ``slot_state`` (its leaves [b, ..]: row i is sequence i's), the
-        rows' next state."""
+    def decode_step_paged(self, token_ids, pos, pools, tables, slot_state):
+        """``ServingCore.decode_step_paged``."""
         x = jnp.take(self.embed_tokens, token_ids[:, None], axis=0)
-        pools, state = list(pools), list(slot_state or ())
+        pools, state = list(pools), list(slot_state)
         n_attn = n_mamba = 0
         routed = peak = held = 0
         for layer in self.layers:
@@ -945,11 +924,11 @@ class HybridForCausalLM(nn.Layer):
                 routed += x.shape[0] * self.cfg.num_experts_per_tok
                 peak, held = peak + jnp.max(load), held + jnp.sum(load)
             x = x + y
-        out = (self.norm(x), pools)
-        if counters:
-            out += (jnp.stack([jnp.int32(routed), peak, held]).astype(
-                jnp.int32),)
-        return out if slot_state is None else out + (state,)
+        hidden, counts = self.norm(x), None
+        if self.tick_counters:
+            counts = jnp.stack([jnp.int32(routed), peak, held]).astype(
+                jnp.int32)
+        return hidden, pools, state, counts
 
     def forward(self, input_ids, labels=None):
         x = jnp.take(self.embed_tokens, input_ids, axis=0)

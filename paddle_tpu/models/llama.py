@@ -29,6 +29,7 @@ from ..nn import functional as F
 from ..nn import initializer as I
 from ..ops import rope as rope_ops
 from ..ops import norm as norm_ops
+from .serving_core import ServingCore
 
 
 @dataclass
@@ -330,21 +331,6 @@ def _paged_decode_attention(q2, kv, tables, pos):
         return paged_decode_attention(q2, kv[0], kv[1], tables, pos,
                                       **scales)
     return paged_decode_xla(q2, kv[0], kv[1], tables, pos, **scales)
-
-
-def alloc_layer_pools(layers, batch: int, max_len: int, page_size: int):
-    """(pools, tables) of a paged model: one pool entry a layer, laid out
-    by the layer's attention (``self_attn.alloc_pool``), and the shared
-    block table with pages assigned contiguously per sequence (the
-    allocator is the caller's concern at serving scale; reference:
-    block_multi_head_attention's table-driven pool)."""
-    pages_per_seq = -(-max_len // page_size)
-    num_pages = batch * pages_per_seq
-    pools = [layer.self_attn.alloc_pool(num_pages, page_size)
-             for layer in layers]
-    tables = jnp.arange(num_pages, dtype=jnp.int32).reshape(
-        batch, pages_per_seq)
-    return pools, tables
 
 
 def _token_mean(nll, labels, ignore_index: int = -100):
@@ -750,7 +736,9 @@ class LlamaDecoderLayer(nn.Layer):
         return h + self.mlp(self.post_attention_layernorm(h)), cache
 
 
-class LlamaModel(nn.Layer):
+class LlamaModel(nn.Layer, ServingCore):
+    optional_programs = ("prefill_chunk_paged", "decode_verify_paged")
+
     def __init__(self, cfg: LlamaConfig):
         super().__init__()
         self.cfg = cfg
@@ -826,62 +814,43 @@ class LlamaModel(nn.Layer):
 
     # -- paged-KV (vLLM-style) inference paths ------------------------------
 
-    def alloc_paged_caches(self, batch: int, max_len: int,
-                           page_size: int = 128):
-        """Per-layer head-major page pools + the shared block table."""
-        return alloc_layer_pools(self.layers, batch, max_len, page_size)
-
-    def prefill_paged(self, input_ids, pools, tables):
-        x = jnp.take(self.embed_tokens, input_ids, axis=0)
+    def _paged_layers(self, attend, ids, at, pools, tables):
+        """The ONE paged layer loop. ``attend`` is the attention's paged
+        method and ``at`` its position arguments: all that the four
+        programs below differ in."""
+        x = jnp.take(self.embed_tokens, ids, axis=0)
         new_pools = []
         for layer, kv in zip(self.layers, pools):
-            a, kv = layer.self_attn.prefill_paged(
-                layer.input_layernorm(x), self.rope_cos, self.rope_sin,
-                kv, tables)
+            a, kv = attend(layer.self_attn, layer.input_layernorm(x),
+                           self.rope_cos, self.rope_sin, *at, kv, tables)
             h = x + a
             x = h + layer.mlp(layer.post_attention_layernorm(h))
             new_pools.append(kv)
         return self.norm(x), new_pools
+
+    # the ``ServingCore`` programs over head-major page pools, a layer's
+    # whole state: ``slot_state`` (empty) goes back as it came, and nothing
+    # is counted
+
+    def prefill_paged(self, input_ids, pools, tables, slot_state, slot,
+                      last_idx):
+        return self._paged_layers(LlamaAttention.prefill_paged, input_ids,
+                                  (), pools, tables) + (slot_state,)
 
     def prefill_chunk_paged(self, input_ids, offset, pools, tables):
-        x = jnp.take(self.embed_tokens, input_ids, axis=0)
-        new_pools = []
-        for layer, kv in zip(self.layers, pools):
-            a, kv = layer.self_attn.prefill_chunk_paged(
-                layer.input_layernorm(x), self.rope_cos, self.rope_sin,
-                offset, kv, tables)
-            h = x + a
-            x = h + layer.mlp(layer.post_attention_layernorm(h))
-            new_pools.append(kv)
-        return self.norm(x), new_pools
+        return self._paged_layers(LlamaAttention.prefill_chunk_paged,
+                                  input_ids, (offset,), pools, tables)
 
-    def decode_step_paged(self, token_ids, pos, pools, tables):
-        x = jnp.take(self.embed_tokens, token_ids[:, None], axis=0)
-        new_pools = []
-        for layer, kv in zip(self.layers, pools):
-            a, kv = layer.self_attn.decode_paged(
-                layer.input_layernorm(x), self.rope_cos, self.rope_sin,
-                pos, kv, tables)
-            h = x + a
-            x = h + layer.mlp(layer.post_attention_layernorm(h))
-            new_pools.append(kv)
-        return self.norm(x), new_pools
+    def decode_step_paged(self, token_ids, pos, pools, tables, slot_state):
+        return self._paged_layers(
+            LlamaAttention.decode_paged, token_ids[:, None], (pos,), pools,
+            tables) + (slot_state, None)
 
     def decode_verify_paged(self, token_ids, pos, pools, tables):
-        """Speculative verify: ``token_ids`` [b, T] at per-row positions
-        ``pos[b]..pos[b]+T-1`` → (hidden [b, T, d], pools). Hidden at
-        in-chunk index j scores the token AFTER input j — the engine
-        samples targets from every row to accept/reject drafts."""
-        x = jnp.take(self.embed_tokens, token_ids, axis=0)
-        new_pools = []
-        for layer, kv in zip(self.layers, pools):
-            a, kv = layer.self_attn.decode_verify_paged(
-                layer.input_layernorm(x), self.rope_cos, self.rope_sin,
-                pos, kv, tables)
-            h = x + a
-            x = h + layer.mlp(layer.post_attention_layernorm(h))
-            new_pools.append(kv)
-        return self.norm(x), new_pools
+        """Hidden at in-chunk index j scores the token AFTER input j: the
+        engine samples targets from every row to accept or reject drafts."""
+        return self._paged_layers(LlamaAttention.decode_verify_paged,
+                                  token_ids, (pos,), pools, tables)
 
 
 class LlamaForCausalLM(nn.Layer):

@@ -28,12 +28,12 @@ appears from GSPMD sharding — parallel/moe.py).
   whose state is handed from layer to layer, residual merges scaled per
   channel, the embedding tied to the head.
 
-Served through ``inference.ContinuousBatchingEngine`` by the paged trio
-(``alloc_paged_caches`` / ``prefill_paged`` / ``decode_step_paged``), whose
-loop takes each attention layer's own ``alloc_pool`` / ``prefill_paged`` /
+Served through ``inference.ContinuousBatchingEngine`` as a ``ServingCore``
+(``prefill_paged`` / ``decode_step_paged``), whose loops take each attention
+layer's own ``alloc_pool`` / ``alloc_slot_state`` / ``prefill_paged`` /
 ``decode_paged``: per-head K and V pages under GQA attention, one latent
-row under MLA, K and V pages plus a per-slot state (``alloc_slot_state``)
-under CCA.
+row under MLA, K and V pages plus a per-slot state under CCA (the other
+two hand their empty state entry back as it came).
 """
 
 from __future__ import annotations
@@ -52,7 +52,8 @@ from ..ops import rope as rope_ops
 from ..parallel.moe import ROUTER_NORM_EPS, MoELayer
 from .llama import (LlamaAttention, LlamaConfig, LlamaMLP,
                     _kv_scatter_tokens, _kv_write_prompt, _normal,
-                    _paged_decode_attention, alloc_layer_pools)
+                    _paged_decode_attention)
+from .serving_core import ServingCore
 
 
 @dataclass
@@ -297,12 +298,16 @@ class LatentAttention(nn.Layer):
         dt = jnp.bfloat16 if self.cfg.dtype == "bfloat16" else jnp.float32
         return (jnp.zeros((1, num_pages, page_size, self.row), dt),)
 
-    def prefill_paged(self, x, cos, sin, kv, tables):
+    def alloc_slot_state(self, slots: int):
+        """Nothing: the latent rows in the pages are the whole state."""
+        return ()
+
+    def prefill_paged(self, x, cos, sin, kv, tables, state, slot, last_idx):
         """Prompt pass: expanded attention, and the rows ``[c_kv | k_r]``
         written into the pool's pages in the pool's dtype. Rows past the
         prompt's own length (the engine pads ids to a bucket) lie beyond
         seq_len and are overwritten by decode steps before they are ever
-        unmasked."""
+        unmasked. ``state`` (empty) goes back as it came."""
         (pool,) = kv
         b, s, _ = x.shape
         page = pool.shape[2]
@@ -315,9 +320,9 @@ class LatentAttention(nn.Layer):
               + jnp.arange(s) % page)                         # [b, s]
         pool = pool.reshape(-1, pool.shape[3]).at[at.reshape(-1)].set(
             rows.reshape(b * s, -1).astype(pool.dtype)).reshape(pool.shape)
-        return out, (pool,)
+        return out, (pool,), state
 
-    def decode_paged(self, x, cos, sin, pos, kv, tables):
+    def decode_paged(self, x, cos, sin, pos, kv, tables, state):
         """One-token step, absorbed: the new row goes to its page slot and
         every head attends over the cached rows, read once in the dtype
         they are stored in (the Pallas kernel on a TPU, its XLA twin
@@ -349,7 +354,7 @@ class LatentAttention(nn.Layer):
             ctx = latent_decode_xla(q, pool, tables, pos, self.rank,
                                     self.scale)
         out = jnp.einsum("bhr,rhv->bhv", ctx.astype(x.dtype), w_uv)
-        return self._mm(out.reshape(b, 1, -1), "o_proj"), (pool,)
+        return self._mm(out.reshape(b, 1, -1), "o_proj"), (pool,), state
 
 
 def _prev_row(a):
@@ -585,6 +590,20 @@ class ResidualMerge(nn.Layer):
                 + self.out_bias).astype(x.dtype)
 
 
+class GQAttention(LlamaAttention):
+    """``LlamaAttention`` as a layer of this core: its state is all in its
+    pages, so it takes and hands back the layer's empty slot-state entry."""
+
+    def alloc_slot_state(self, slots: int):
+        return ()
+
+    def prefill_paged(self, x, cos, sin, kv, tables, state, slot, last_idx):
+        return super().prefill_paged(x, cos, sin, kv, tables) + (state,)
+
+    def decode_paged(self, x, cos, sin, pos, kv, tables, state):
+        return super().decode_paged(x, cos, sin, pos, kv, tables) + (state,)
+
+
 _ATTENTION = {"mla": LatentAttention, "cca": CompressedConvAttention}
 
 
@@ -598,7 +617,7 @@ class MoEDecoderLayer(nn.Layer):
                                           dtype="float32")
         self.self_attn = (_ATTENTION[cfg.attention](cfg)
                           if cfg.attention in _ATTENTION
-                          else LlamaAttention(lcfg))
+                          else GQAttention(lcfg))
         self.post_attention_layernorm = nn.RMSNorm(cfg.hidden_size,
                                                    cfg.rms_norm_eps,
                                                    dtype="float32")
@@ -671,7 +690,7 @@ class MoEDecoderLayer(nn.Layer):
         return routed, load, r
 
 
-class MoEForCausalLM(nn.Layer):
+class MoEForCausalLM(nn.Layer, ServingCore):
     """DeepSeekMoE/Qwen2-MoE-style causal LM. forward returns
     (loss, logits) with labels (loss = CE + aux_weight * load-balance aux),
     logits otherwise."""
@@ -728,81 +747,52 @@ class MoEForCausalLM(nn.Layer):
     # -- paged-KV serving path (inference.ContinuousBatchingEngine) ---------
 
     def expert_path(self, rows: int):
-        """(path, rows of a step) by which the routed layers run a program
-        of ``rows`` rows (``MoELayer.inference_path``: every routed layer
-        has the same shapes); None for a model whose experts the inference
-        path does not run (none routed, or capacity routing). The engine
-        writes it into the program's ``build_log`` row."""
+        """``MoELayer.inference_path`` of the routed layers (all alike);
+        None where the inference path does not run the experts (none
+        routed, or capacity routing)."""
         routed = [layer.moe for layer in self.layers if layer.moe is not None]
         if not routed or self.cfg.capacity_factor is not None:
             return None
         return routed[0].inference_path(rows)
 
-    def alloc_paged_caches(self, batch: int, max_len: int,
-                           page_size: int = 128):
-        """One pool entry a layer, laid out by the layer's attention
-        (``alloc_pool``), and the shared block table."""
-        return alloc_layer_pools(self.layers, batch, max_len, page_size)
-
     def alloc_slot_state(self, slots: int):
-        """What a sequence carries from token to token OUTSIDE its pages,
-        one entry a layer, every leaf leading with the slot: CCA's
-        conv/shift state. None for a model whose whole state is its pages
-        (the engine then keeps nothing and builds the programs it built)."""
-        if self.cfg.attention != "cca":
-            return None
+        """One entry a layer, every leaf leading with the slot: CCA's
+        conv/shift state; empty under an attention whose state is all in
+        its pages."""
         return [layer.self_attn.alloc_slot_state(slots)
                 for layer in self.layers]
 
-    def prefill_paged(self, input_ids, pools, tables, slot_state=None,
-                      slot=None, last_idx=None):
-        """The prompt of one sequence: (hidden, pools) and, given
-        ``slot_state``, the state with slot ``slot`` set from the prompt's
-        position ``last_idx``."""
+    def prefill_paged(self, input_ids, pools, tables, slot_state, slot,
+                      last_idx):
+        """``ServingCore.prefill_paged``."""
         x = jnp.take(self.embed_tokens, input_ids, axis=0)
         new_pools, new_state, r = [], [], None
-        for i, (layer, kv) in enumerate(zip(self.layers, pools)):
-            u = layer.input_layernorm(x)
-            if slot_state is None:
-                a, kv = layer.self_attn.prefill_paged(
-                    u, self.rope_cos, self.rope_sin, kv, tables)
-            else:
-                a, kv, st = layer.self_attn.prefill_paged(
-                    u, self.rope_cos, self.rope_sin, kv, tables,
-                    slot_state[i], slot, last_idx)
-                new_state.append(st)
+        for layer, kv, st in zip(self.layers, pools, slot_state):
+            a, kv, st = layer.self_attn.prefill_paged(
+                layer.input_layernorm(x), self.rope_cos, self.rope_sin, kv,
+                tables, st, slot, last_idx)
             h = layer.merge("attn", x, a)
             y, _, r = layer.mlp_inference(layer.post_attention_layernorm(h), r)
             x = layer.merge("mlp", h, y)
             new_pools.append(kv)
-        if slot_state is None:
-            return self.norm(x), new_pools
+            new_state.append(st)
         return self.norm(x), new_pools, new_state
 
-    def decode_step_paged(self, token_ids, pos, pools, tables,
-                          counters: bool = False, slot_state=None):
-        """token_ids [b] -> (hidden [b, 1, d], pools), then, with
-        ``counters``, the tick's ``tick_counters`` as int32 and, given
-        ``slot_state`` (its leaves [b, ..]: row i is sequence i's), the
-        rows' next state."""
+    def decode_step_paged(self, token_ids, pos, pools, tables, slot_state):
+        """``ServingCore.decode_step_paged``."""
         x = jnp.take(self.embed_tokens, token_ids[:, None], axis=0)
         new_pools, new_state, r = [], [], None
         routed, peak, skipped = 0, 0, 0
-        for i, (layer, kv) in enumerate(zip(self.layers, pools)):
-            u = layer.input_layernorm(x)
-            if slot_state is None:
-                a, kv = layer.self_attn.decode_paged(
-                    u, self.rope_cos, self.rope_sin, pos, kv, tables)
-            else:
-                a, kv, st = layer.self_attn.decode_paged(
-                    u, self.rope_cos, self.rope_sin, pos, kv, tables,
-                    slot_state[i])
-                new_state.append(st)
+        for layer, kv, st in zip(self.layers, pools, slot_state):
+            a, kv, st = layer.self_attn.decode_paged(
+                layer.input_layernorm(x), self.rope_cos, self.rope_sin, pos,
+                kv, tables, st)
             h = layer.merge("attn", x, a)
             y, load, r = layer.mlp_inference(
                 layer.post_attention_layernorm(h), r)
             x = layer.merge("mlp", h, y)
             new_pools.append(kv)
+            new_state.append(st)
             if load is not None:
                 routed, peak = routed + jnp.sum(load), peak + jnp.max(load)
                 if self.cfg.router_skip_choice:
@@ -810,12 +800,13 @@ class MoEForCausalLM(nn.Layer):
                     skipped = skipped + (load.dtype.type(
                         x.shape[0] * self.cfg.num_experts_per_tok)
                         - jnp.sum(load))
-        out = (self.norm(x), new_pools)
-        if counters:
-            counts = ([routed + skipped, peak, skipped]
-                      if self.cfg.router_skip_choice else [routed, peak])
-            out += (jnp.stack(counts).astype(jnp.int32),)
-        return out if slot_state is None else out + (new_state,)
+        hidden, counts = self.norm(x), None
+        if self.tick_counters:
+            counts = jnp.stack(
+                [routed + skipped, peak, skipped]
+                if self.cfg.router_skip_choice else [routed, peak]
+            ).astype(jnp.int32)
+        return hidden, new_pools, new_state, counts
 
     def forward(self, input_ids, labels=None):
         cfg = self.cfg

@@ -203,9 +203,9 @@ def test_prefill_then_ticks_through_the_slot_state_equal_the_full_forward(
         assert not np.asarray(a)[0].any()          # slot 0 was not written
     pos = jnp.asarray([5, 37], jnp.int32)
     for t in range(37, 57):
-        h, filled, state = model.decode_step_paged(
-            jnp.asarray([7, ids[t]], jnp.int32), pos, filled, tables,
-            slot_state=state)
+        h, filled, state, counts = model.decode_step_paged(
+            jnp.asarray([7, ids[t]], jnp.int32), pos, filled, tables, state)
+        assert counts is None                # it declares no counter
         assert np.abs(np.asarray(model.logits(h[1, 0]))
                       - want[t]).max() < TOL[gates]
         pos = pos + 1

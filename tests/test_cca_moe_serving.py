@@ -355,8 +355,8 @@ def test_handoff_refuses_a_model_with_slot_state_by_name(served, call):
     lambda: LlamaForCausalLM(LlamaConfig.tiny(dtype="float32")),
 ], ids=["routed-gqa", "dense-gqa"])
 def test_a_model_without_slot_state_builds_the_programs_it_built(build):
-    """No hook or a hook that gives None: the engine's state is the empty
-    pytree, so its prefill and tick have exactly the parent's inputs (one a
+    """A core whose state is all in its pages hands the engine an EMPTY
+    pytree (``ServingCore.alloc_slot_state``), so its prefill and tick have exactly the parent's inputs (one a
     leaf of params, pools, tables, key, state, knobs and nothing else: the
     unused slot index is pruned) and give back nothing more. (Lowered from
     the parent commit and from this one, the GLM, OLMoE and Llama engines'
@@ -366,11 +366,11 @@ def test_a_model_without_slot_state_builds_the_programs_it_built(build):
     eng = _engine(model)
     eng.submit(_ids(5) % 200, max_new_tokens=2)
     eng.run()
-    assert eng.slot_state is None
+    assert jax.tree.leaves(eng.slot_state) == []
     assert eng.stats()["slot_state_bytes"] == 0
     assert "moe_skipped" not in eng.stats()
     args = eng._decode_args(False)
-    assert args[6] is None
+    assert jax.tree.leaves(args[6]) == []
     (run,) = eng._decode_fns.values()
     assert len(jax.make_jaxpr(run)(*args).jaxpr.invars) == len(
         jax.tree.leaves(args[:6]))
@@ -378,10 +378,10 @@ def test_a_model_without_slot_state_builds_the_programs_it_built(build):
     pre = (eng._params, jnp.zeros((1, 16), jnp.int32), eng.pools,
            jnp.asarray(eng.tables[:1]), jnp.int32(4))
     (main,) = [line for line in prefill.lower(
-        *pre, None, np.int32(0)).as_text().splitlines() if "@main(" in line]
+        *pre, eng.slot_state, np.int32(0)).as_text().splitlines() if "@main(" in line]
     assert main.count("%arg") == len(jax.tree.leaves(pre))
     out = jax.eval_shape(run, *args)
-    assert len(out) == 5 and out[4] is None
+    assert len(out) == 5 and jax.tree.leaves(out[4]) == []
 
 
 def test_a_model_with_no_new_field_has_the_parameters_it_had():
@@ -398,6 +398,6 @@ def test_a_model_with_no_new_field_has_the_parameters_it_had():
         assert "lm_head" in names and cfg.head_dim == 32
         assert not [n for n in names if "merge" in n or "router_" in n
                     or "conv" in n]
-        assert model.alloc_slot_state(4) is None
+        assert jax.tree.leaves(model.alloc_slot_state(4)) == []
         assert "layers.1.moe.gate_weight" in names
         assert model.tick_counters == ("moe_assignments", "moe_peak_load")

@@ -294,7 +294,7 @@ def test_cost_watch_reobserves_on_executable_change():
 
 def test_serving_publishes_cost_gauges():
     import paddle_tpu as pt
-    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.inference import ContinuousBatchingEngine, serving
     from paddle_tpu.inference.generation import GenerationConfig
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
@@ -312,6 +312,17 @@ def test_serving_publishes_cost_gauges():
             eng.submit(rs.randint(0, 32, (L,)).astype(np.int32))
         out = eng.run()
         assert sum(len(v) for v in out.values()) > 0
+        # the gauges read the books' recent CLEAN tick: by the wall clock
+        # that takes a block the host WAITED for with no admission in front
+        # of it, which three short requests on a loaded host may never give.
+        # The books take a clock, so play them one tick of 1 ms a token
+        now = [0.0]
+        eng._books = serving._StreamBooks(clock=lambda: now[0])
+        block = eng._books.dispatched(busy=False, behind=False)
+        now[0] = eng.decode_block * 1e-3
+        eng._books.drained(K=eng.decode_block, **block, waited=True)
+        assert eng._books.recent_tick_s() == pytest.approx(1e-3)
+        eng.publish_metrics()
         mfu = REGISTRY.gauge("pt_model_flops_utilization").value(
             component="serving")
         assert math.isfinite(mfu) and mfu > 0

@@ -672,9 +672,8 @@ def test_prefill_then_ticks_through_the_slot_state_equal_the_full_forward(
         assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-6
     pos = jnp.array([0, 21], jnp.int32)
     for t in range(21, 36):
-        h, filled, counts, state = model.decode_step_paged(
-            jnp.array([0, ids[t]], jnp.int32), pos, filled, tables,
-            counters=True, slot_state=state)
+        h, filled, state, counts = model.decode_step_paged(
+            jnp.array([0, ids[t]], jnp.int32), pos, filled, tables, state)
         assert np.abs(np.asarray(model.logits(h[1, 0])) - want[t]).max() < TOL
         pos = pos + jnp.array([0, 1], jnp.int32)
     assert int(counts[0]) == 2 * 3 * 2 and 0 < int(counts[2]) <= int(counts[0])

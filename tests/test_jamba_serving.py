@@ -386,18 +386,20 @@ def test_mqa_20_on_1_through_the_paged_helpers_equals_the_reference():
                       attn_layer_offset=0)
     assert cfg["hybrid_pattern"] == "*-*-"
     model, reference = _build(cfg), _reference(cfg)
-    assert model.alloc_slot_state(2) is None
+    state = model.alloc_slot_state(2)
+    assert jax.tree.leaves(state) == []
     ids = _ids(30, 4)
     want = reference(ids)
     pools, tables = model.alloc_paged_caches(2, 64, 16)
     assert [a.shape for a in pools[0]] == [(1, 8, 16, 16)] * 2
     padded = jnp.zeros((1, 32), jnp.int32).at[0, :19].set(ids[:19])
-    h, pools = model.prefill_paged(padded, pools, tables[1:2])
+    h, pools, state = model.prefill_paged(padded, pools, tables[1:2], state,
+                                          1, jnp.int32(18))
     assert np.abs(np.asarray(model.logits(h[0, 18])) - want[18]).max() < TOL
     pos = jnp.array([0, 19], jnp.int32)
     for t in range(19, 30):
-        h, pools = model.decode_step_paged(
-            jnp.array([0, ids[t]], jnp.int32), pos, pools, tables)
+        h, pools, state, _ = model.decode_step_paged(
+            jnp.array([0, ids[t]], jnp.int32), pos, pools, tables, state)
         assert np.abs(np.asarray(model.logits(h[1, 0])) - want[t]).max() < TOL
         pos = pos + jnp.array([0, 1], jnp.int32)
 
@@ -444,9 +446,9 @@ def test_prefill_then_ticks_through_the_slot_state_equal_the_full_forward(
         assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-6
     pos = jnp.zeros((slots,), jnp.int32).at[1].set(21)
     for t in range(21, 36):
-        h, filled, state = model.decode_step_paged(
+        h, filled, state, _ = model.decode_step_paged(
             jnp.zeros((slots,), jnp.int32).at[1].set(ids[t]), pos, filled,
-            tables, slot_state=state)
+            tables, state)
         assert np.abs(np.asarray(model.logits(h[1, 0])) - want[t]).max() < TOL
         pos = pos.at[1].add(1)
 

@@ -105,14 +105,18 @@ def test_prefill_then_decode_through_the_latent_cache_matches_the_reference(glm)
     pools, tables = model.alloc_paged_caches(1, 48, page)
     padded = np.zeros((1, 32), np.int32)
     padded[0, :p] = ids[:p]
-    hidden, pools = model.prefill_paged(jnp.asarray(padded), pools, tables)
+    state = model.alloc_slot_state(1)
+    assert jax.tree.leaves(state) == []      # the latent rows are all of it
+    hidden, pools, state = model.prefill_paged(
+        jnp.asarray(padded), pools, tables, state, 0, jnp.int32(p - 1))
     want = reference(ids)
     assert np.abs(np.asarray(model.logits(hidden[0, p - 1])) - want[p - 1]
                   ).max() < TOL
     for pos in range(p, len(ids)):
-        hidden, pools = model.decode_step_paged(
+        hidden, pools, state, counts = model.decode_step_paged(
             jnp.asarray(ids[pos:pos + 1]), jnp.asarray([pos], jnp.int32),
-            pools, tables)
+            pools, tables, state)
+        assert counts.shape == (len(model.tick_counters),)
         got = np.asarray(model.logits(hidden[0, 0]))
         assert np.abs(got - want[pos]).max() < TOL, pos
 
@@ -129,11 +133,13 @@ def test_absorbed_attention_equals_expanded_attention_for_one_layer():
     x = jax.random.normal(jax.random.key(3), (2, 27, 64), jnp.float32)
     want = np.asarray(attn(x))
     (pool,), tables = attn.alloc_pool(2 * 2, 16), jnp.arange(4).reshape(2, 2)
-    got, kv = attn.prefill_paged(x[:, :11], None, None, (pool,), tables)
+    got, kv, _ = attn.prefill_paged(x[:, :11], None, None, (pool,), tables,
+                                    (), None, None)
     assert np.abs(np.asarray(got) - want[:, :11]).max() < TOL
     for pos in range(11, 27):
-        out, kv = attn.decode_paged(x[:, pos:pos + 1], None, None,
-                                    jnp.full((2,), pos, jnp.int32), kv, tables)
+        out, kv, _ = attn.decode_paged(
+            x[:, pos:pos + 1], None, None, jnp.full((2,), pos, jnp.int32),
+            kv, tables, ())
         assert np.abs(np.asarray(out)[:, 0] - want[:, pos]).max() < TOL, pos
 
 

@@ -132,13 +132,15 @@ def test_int8_kv_teacher_forced_step_parity(bf16, prompts, ref_streams):
         core = model.model
         pools, tables = core.alloc_paged_caches(1, len(full) + PAGE,
                                                 PAGE)
-        h, pools = core.prefill_paged(jnp.asarray(p)[None, :], pools,
-                                      tables)
+        h, pools, state = core.prefill_paged(
+            jnp.asarray(p)[None, :], pools, tables, core.alloc_slot_state(1),
+            0, len(p) - 1)
         logits = [np.asarray(model.logits(h[:, -1]), np.float32)]
         for i in range(len(p), len(full) - 1):
             tok = jnp.asarray(full[i:i + 1])
             pos = jnp.asarray([i], jnp.int32)
-            h, pools = core.decode_step_paged(tok, pos, pools, tables)
+            h, pools, state, _ = core.decode_step_paged(tok, pos, pools,
+                                                        tables, state)
             logits.append(np.asarray(model.logits(h[:, -1]),
                                      np.float32))
         per_model[label] = np.concatenate(logits, axis=0)

@@ -27,6 +27,7 @@ from paddle_tpu.inference import (ContinuousBatchingEngine, DraftProvider,
                                   GenerationConfig, NgramDraftProvider)
 from paddle_tpu.inference.generation import generate_scan
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.models.serving_core import ServingCore
 
 PAGE = 8
 
@@ -284,7 +285,7 @@ def test_custom_draft_provider_wrong_drafts_are_safe(model):
 
 
 def test_spec_rejects_model_without_verify(model):
-    class NoVerify:
+    class NoVerify(ServingCore):
         pass
 
     class M:

@@ -256,9 +256,9 @@ def test_every_engine_program_has_a_name_of_its_own(tiny_llama):
     assert names["chunk"] == f"prefill_chunk_{PAGE}"
     # the name a program is built under is the module the trace prints
     plain._init_state(jnp.zeros((vocab,), jnp.float32))
-    args = (plain._params, plain.pools, jnp.asarray(plain.tables),
-            plain._base_key, plain._state, plain._knobs)
-    assert _module(builders["decode"], *args) == "jit_run"
+    plain._tables_dev = jnp.asarray(plain.tables)
+    assert _module(builders["decode"],
+                   *plain._decode_args(False)) == "jit_run"
     assert _module(builders["cow"], eng.pools, jnp.int32(1),
                    jnp.int32(2)) == "jit_cow_page"
     # ...and every build_log row is of one of them
