@@ -1,8 +1,9 @@
 """Hybrid state-space / attention / expert causal LM (``nemotron_h``'s layout,
 as NVIDIA-Nemotron-3-Nano-30B-A3B publishes it, ``jamba``'s, as
-AI21-Jamba2-3B does, and ``brumby``'s, as Brumby-14B-Base does).
+AI21-Jamba2-3B does, ``brumby``'s, as Brumby-14B-Base does, and
+``qwen3_next``'s, as Qwen3-Next-80B-A3B-Instruct does).
 
-A decoder of blocks ``x + Mixer(RMSNorm(x))`` whose mixer is ONE of six
+A decoder of blocks ``x + Mixer(RMSNorm(x))`` whose mixer is ONE of nine
 kinds, by a pattern string with one character a block:
 
 - ``M``: a Mamba-2 layer (``Mamba2Mixer``, arXiv:2405.21060): one input
@@ -38,6 +39,12 @@ kinds, by a pattern string with one character a block:
   a float32 state ``S = g S + phi(k) v^T`` [D, d] and ``z = g z + phi(k)``
   a KV head, ``phi`` the D = d (d + 1) / 2 products of pairs, ``y = phi(q)^T
   S / (phi(q) . z + eps)``. A Brumby layer is ``p-``.
+- ``d``, ``a``, ``e``: a Gated DeltaNet layer (a float32 state that is READ
+  BACK before it is written: the gated delta rule), gated attention with a
+  partial rotary embedding, and softmax-routed SwiGLU experts beside a
+  gated shared expert, each behind a ZERO-CENTRED norm (``models/
+  hybrid_gated.py`` has the three and their equations). A Qwen3-Next layer
+  is ``de`` or ``ae``; a published period of four is ``dededeae``.
 
 Served through ``inference.ContinuousBatchingEngine`` as a ``ServingCore``
 (``alloc_paged_caches`` / ``alloc_slot_state`` / ``prefill_paged`` /
@@ -49,12 +56,14 @@ rewrites in place (``ops.pallas.ssm.ssm_state_update``, or for a Mamba-1
 layer ``ops.pallas.selective_ssm``'s ``conv_window_step`` and
 ``selective_state_update``, window and state each in one pass, on a TPU; for
 a power-retention layer ``ops.pallas.power_retention``'s
-``power_state_update``). A
+``power_state_update``; for a Gated DeltaNet layer ``ops.pallas.
+gated_delta``'s ``gated_delta_state_update``). A
 prompt runs the Mamba-2 recurrence in chunks (``ssd_chunked``: matrix
 products inside a chunk, the state carried from chunk to chunk), power
-retention in chunks too (``power_retention_chunked``) and the
+retention and the gated delta rule in chunks too
+(``power_retention_chunked``, ``gated_delta_chunked``) and the
 Mamba-1 recurrence, which has no such form, with time inside a kernel
-(``selective_scan``). A pattern without ``*`` keeps no page at all. Not trained: ``forward`` is the whole-sequence form for
+(``selective_scan``). A pattern without ``*`` or ``a`` keeps no page at all. Not trained: ``forward`` is the whole-sequence form for
 tests and evaluation; neither scan has a hand-written backward and no
 training cell runs one.
 """
@@ -96,7 +105,14 @@ class HybridConfig:
     num_attention_heads: int = 32
     num_key_value_heads: int = 2
     head_dim: int = 128
-    rope_theta: float = 10000.0            # power retention's q and k alone
+    rope_theta: float = 10000.0            # power retention's and gated
+    partial_rotary_factor: float = 1.0     # attention's q and k alone
+    # Gated DeltaNet (Hk key heads of K serve Hv value heads of V)
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    delta_chunk_size: int = 64             # of a prompt's chunked delta rule
     # experts
     num_experts: int = 128                 # the router's width
     first_expert_held: int = 0             # the share of them held here:
@@ -113,12 +129,14 @@ class HybridConfig:
     dtype: str = "float32"
 
     def __post_init__(self):
-        bad = set(self.pattern) - set("ME*m-p")
+        bad = set(self.pattern) - set("ME*m-pdae")
         if bad or not self.pattern:
             raise ValueError(f"pattern {self.pattern!r}: one of 'M' (Mamba-2)"
                              f", 'm' (Mamba-1), '*' (attention), 'E' "
                              f"(experts), '-' (dense MLP), 'p' (power "
-                             f"retention) a block")
+                             f"retention), 'd' (Gated DeltaNet), 'a' (gated "
+                             f"attention), 'e' (softmax-routed experts, a "
+                             f"gated shared one) a block")
         if (self.num_hidden_layers is not None
                 and not 0 < self.num_hidden_layers <= len(self.pattern)):
             raise ValueError(f"num_hidden_layers={self.num_hidden_layers}: "
@@ -771,7 +789,19 @@ class GatedMLP(nn.Layer):
                           jnp.matmul, jnp.matmul)
 
 
-STATEFUL = "Mmp"    # the kinds whose mixer keeps a per-slot state
+STATEFUL = "Mmpd"   # the kinds whose mixer keeps a per-slot state
+PAGED = "*a"        # the kinds whose mixer keeps K and V pages
+ROUTED = "Ee"       # the kinds that route rows to experts
+ZERO_CENTRED = "dae"    # the kinds behind a norm that scales by 1 + w
+
+
+def _block_norm(cfg: HybridConfig, kind: str):
+    """The norm in front of a block of ``kind``: float32, zero-centred for
+    the kinds that are."""
+    if kind in ZERO_CENTRED:
+        from .hybrid_gated import ZeroCentredRMSNorm
+        return ZeroCentredRMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+    return nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps, dtype="float32")
 
 
 class HybridBlock(nn.Layer):
@@ -780,12 +810,24 @@ class HybridBlock(nn.Layer):
     def __init__(self, cfg: HybridConfig, kind: str):
         super().__init__()
         self.kind = kind
-        self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
-                               dtype="float32")
+        self.norm = _block_norm(cfg, kind)
         if kind in "Mm-*p":
             self.mixer = {"M": Mamba2Mixer, "m": Mamba1Mixer, "-": GatedMLP,
                           "*": NoPEAttention,
                           "p": PowerRetentionMixer}[kind](cfg)
+        elif kind in ZERO_CENTRED:
+            from . import hybrid_gated as gated
+            if kind == "e":
+                self.mixer = MoELayer(
+                    cfg.hidden_size, cfg.moe_intermediate_size,
+                    cfg.num_experts, top_k=cfg.num_experts_per_tok,
+                    capacity_factor=None, dtype=cfg.dtype, scoring="softmax",
+                    norm_topk_prob=cfg.norm_topk_prob,
+                    experts_held=cfg.experts_held, expert_act="swiglu")
+                self.shared_expert = gated.GatedSharedExpert(cfg)
+            else:
+                self.mixer = {"d": gated.GatedDeltaNetMixer,
+                              "a": gated.GatedAttention}[kind](cfg)
         else:
             self.mixer = MoELayer(
                 cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
@@ -806,7 +848,7 @@ class HybridBlock(nn.Layer):
         """Whole sequences, no cache (the router's auxiliary loss is dropped:
         not trained)."""
         u = self.norm(x)
-        if self.kind != "E":
+        if self.kind not in ROUTED:
             return x + self.mixer(u)
         return x + self.mixer(u)[0] + self.shared_expert(u)
 
@@ -827,8 +869,8 @@ class HybridForCausalLM(nn.Layer, ServingCore):
             sharding=("tp", "fsdp"))
         self.layers = nn.LayerList([HybridBlock(cfg, kind)
                                     for kind in cfg.kinds])
-        self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
-                               dtype="float32")
+        # the final norm is of the last block's family
+        self.norm = _block_norm(cfg, cfg.kinds[-1])
         if not cfg.tie_word_embeddings:
             self.lm_head = self.create_parameter(
                 [cfg.hidden_size, cfg.vocab_size], dtype=cfg.dtype,
@@ -840,7 +882,7 @@ class HybridForCausalLM(nn.Layer, ServingCore):
         # on a held expert; each summed over the expert layers
         self.tick_counters = (("moe_assignments", "moe_peak_load",
                                "moe_assignments_held")
-                              if "E" in cfg.kinds else ())
+                              if set(ROUTED) & set(cfg.kinds) else ())
 
     def logits(self, hidden):
         if self.cfg.tie_word_embeddings:
@@ -855,7 +897,7 @@ class HybridForCausalLM(nn.Layer, ServingCore):
 
     def expert_path(self, rows: int):
         """``MoELayer.inference_path`` of the expert layers (all alike)."""
-        routed = self._kinds("E")
+        routed = self._kinds(ROUTED)
         return routed[0].mixer.inference_path(rows) if routed else None
 
     def state_path(self, rows, slots: int):
@@ -866,13 +908,13 @@ class HybridForCausalLM(nn.Layer, ServingCore):
 
     def pool_layers(self):
         """The ATTENTION layers alone keep pages: a pattern without ``*``
-        has NO pool, and the engine then holds no page."""
-        return [layer.mixer for layer in self._kinds("*")]
+        or ``a`` has NO pool, and the engine then holds no page."""
+        return [layer.mixer for layer in self._kinds(PAGED)]
 
     def alloc_slot_state(self, slots: int):
         """One entry for each layer that carries a state (Mamba of either
-        kind, power retention), in order, every leaf leading with the slot;
-        empty for a pattern without one."""
+        kind, power retention, Gated DeltaNet), in order, every leaf
+        leading with the slot; empty for a pattern without one."""
         return [layer.mixer.alloc_slot_state(slots)
                 for layer in self._kinds(STATEFUL)]
 
@@ -888,7 +930,7 @@ class HybridForCausalLM(nn.Layer, ServingCore):
                 y, state[n_mamba] = layer.mixer.prefill(
                     u, state[n_mamba], slot, last_idx)
                 n_mamba += 1
-            elif layer.kind == "*":
+            elif layer.kind in PAGED:
                 y, pools[n_attn] = layer.mixer.prefill(u, pools[n_attn],
                                                        tables)
                 n_attn += 1
@@ -913,7 +955,7 @@ class HybridForCausalLM(nn.Layer, ServingCore):
                 y, state[n_mamba] = layer.mixer.decode(
                     u, state[n_mamba], *((pos,) if layer.kind == "p" else ()))
                 n_mamba += 1
-            elif layer.kind == "*":
+            elif layer.kind in PAGED:
                 y, pools[n_attn] = layer.mixer.decode(u, pos, pools[n_attn],
                                                       tables)
                 n_attn += 1

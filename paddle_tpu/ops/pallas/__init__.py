@@ -4,6 +4,7 @@ the TPU-backend kernels with the op registry."""
 from . import flash_attention  # noqa: F401
 from . import fused_norm  # noqa: F401
 from . import fused_vocab_ce  # noqa: F401
+from . import gated_delta  # noqa: F401
 from . import latent_attention  # noqa: F401
 from . import paged_attention  # noqa: F401
 from . import power_retention  # noqa: F401
@@ -24,4 +25,5 @@ KERNEL_NAMES = (
     "latent_attention_decode", "ssm_state_update",
     "selective_state_update", "selective_scan", "conv_window_step",
     "power_state_update", "power_retention_chunked",
+    "gated_delta_state_update",
 )

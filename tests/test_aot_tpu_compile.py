@@ -97,7 +97,7 @@ def _tuned_flash_cases():
             id="flash_tuned[%s]" % key.split("|")[3])
 
 
-def _paged(page, dtype, rows=8, h=32, h_kv=8, per_seq=None):
+def _paged(page, dtype, rows=8, h=32, h_kv=8, per_seq=None, d=128):
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
     per_seq = per_seq or 2048 // page
     pages = rows * per_seq + 1
@@ -106,8 +106,8 @@ def _paged(page, dtype, rows=8, h=32, h_kv=8, per_seq=None):
     def fn(q, kp, vp, tables, lens, *scales):
         kw = dict(k_scales=scales[0], v_scales=scales[1]) if quant else {}
         return paged_decode_attention(q, kp, vp, tables, lens, **kw)
-    pool = ((h_kv, pages, page, 128), dtype)
-    args = [((rows, h, 128), BF16), pool, pool, ((rows, per_seq), I32),
+    pool = ((h_kv, pages, page, d), dtype)
+    args = [((rows, h, d), BF16), pool, pool, ((rows, per_seq), I32),
             ((rows,), I32)] + ([((pages,), F32)] * 2 if quant else [])
     return fn, args
 
@@ -164,6 +164,17 @@ def _selective_scan(length=1024, d=5120, n=16):
     return selective_scan, [((1, length, d), BF16), ((1, length, d), F32),
                             ((n, d), F32), ((1, length, n), F32),
                             ((1, length, n), F32)]
+
+
+def _delta_update(slots=192, hk=16, hv=32, d=128):
+    """Qwen3-Next's decode tick, one Gated DeltaNet layer: every slot's 32
+    value heads' [128, 128] float32 state, one slot's 2 MB a grid step (in
+    and out, double-buffered: 8 of the 32 MiB of VMEM asked for), two value
+    heads a key head."""
+    from paddle_tpu.ops.pallas.gated_delta import gated_delta_state_update
+    return gated_delta_state_update, [
+        ((slots, hv, d, d), F32), ((slots, hk, d), F32), ((slots, hk, d), F32),
+        ((slots, hv, d), BF16), ((slots, hv), F32), ((slots, hv), F32)]
 
 
 def _power_update(slots=32, hq=40, hkv=8, d=128):
@@ -289,6 +300,14 @@ ONE_CHIP = [
     pytest.param(_power_chunked, id="power_retention_chunked[4096x40/8x128]"),
     pytest.param(lambda: _power_chunked(768),
                  id="power_retention_chunked[768x40/8x128]"),
+    # qwen3-next.long-generation: 192 rows, 16 query heads on 2 KV heads of
+    # 256 (the first cell at that head size), 24-page tables; its tick kernel
+    pytest.param(lambda: _paged(128, BF16, rows=192, h=16, h_kv=2, per_seq=24,
+                                d=256),
+                 id="paged_decode[bf16,192x16/2,d256,24pages]"),
+    pytest.param(lambda: _flash_rule(1024, d=256, h=16, h_kv=2, grad=False),
+                 id="flash_fwd[s1024,16/2,d256]"),
+    pytest.param(_delta_update, id="gated_delta_state_update[192x32x128x128]"),
     pytest.param(_rms_norm, id="rms_norm[D4096]"),
     pytest.param(_rope, id="rope[s2048,32/8]"),
     pytest.param(lambda: _fused_ce(16384, 4096, 128256),
@@ -650,6 +669,54 @@ def test_the_brumby_tick_holds_one_copy_of_its_slot_state_and_no_page(
         total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                  + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
         assert total <= 12 * 2 ** 30, (kernel, total / 2 ** 30)
+
+
+def test_the_qwen3_next_tick_holds_one_copy_of_its_slot_state(topo,
+                                                              monkeypatch):
+    """``qwen3-next.long-generation``'s whole decode tick (12 layers, 192
+    slots, the cell's engine) compiled for one described chip: nine calls of
+    the tick's kernel beside three of the paged kernel at a head of 256, the
+    3,708,813,312 B of slot state and the pools aliased in place (ONE copy),
+    no copy or transpose of a state leaf, and the program under 13 GiB of
+    the chip's 15.75 (12.44; the widest prefill, whose chunked delta rule is
+    XLA's, counts 12.76: PERF.md section 4)."""
+    from benchmarks import program, run as bench
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.inference.generation import GenerationConfig
+    from paddle_tpu.ops import registry
+    monkeypatch.setattr(autotune, "_device_kind", lambda default="cpu": KIND)
+    monkeypatch.setattr(registry, "backend_kind", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = bench.resolve("qwen3-next.long-generation",
+                           os.path.join(root, "BENCHMARK.json"))[1]
+    model, _ = program.build_model(config)
+    eng = ContinuousBatchingEngine(
+        model.eval(), generation_config=GenerationConfig(do_sample=False),
+        **config["engine"])
+    assert len(eng.pools) == 3 and eng.stats()["paged_layers"] == 3
+    assert model.state_path(None, eng.max_batch) == "kernel"
+    assert model.state_path(1024, 1) == "xla"
+    assert eng.stats()["slot_state_bytes"] == 3_708_813_312
+    pool_bytes = 3 * 2 * 2 * 4609 * 128 * 256 * 2
+    eng._init_state(jax.ShapeDtypeStruct((config["vocab_size"],), BF16))
+    eng._tables_dev = jnp.asarray(eng.tables)
+    dev = SingleDeviceSharding(topo.devices[0])
+    abstract = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
+                                       if not hasattr(a, "dtype") else a.dtype,
+                                       sharding=dev), t)
+    compiled = eng._build_decode(1, False, "paged").lower(
+        *abstract(eng._decode_args(False))).compile()
+    text = compiled.as_text()
+    names = sorted(c.split(".")[0] for c in _mosaic_calls(text))
+    assert names == (["gated_delta_state_update"] * 9
+                     + ["paged_attention_decode"] * 3), names
+    assert not re.search(r"f32\[192,32,128,128\]\S* (copy|transpose)\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 3_708_813_312 + pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total <= 13 * 2 ** 30, total / 2 ** 30
 
 
 @pytest.mark.parametrize("tokens,step,temp_mib", [(1536, 192, 160),

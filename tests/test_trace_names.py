@@ -404,6 +404,14 @@ def _power_chunked():
         jnp.ones((1, 128, 5, 128)),)
 
 
+def _delta_update():
+    from paddle_tpu.ops.pallas.gated_delta import gated_delta_state_update
+    rest = (jnp.ones((1, 4, 16)), jnp.ones((1, 4, 16)), jnp.ones((1, 8, 128)),
+            jnp.zeros((1, 8)), jnp.ones((1, 8)))
+    return (lambda s: gated_delta_state_update(s, *rest, interpret=True)[0]), (
+        jnp.zeros((1, 8, 16, 128)),)
+
+
 @pytest.mark.parametrize("entry,expect", [
     (_flash, ["flash_attention_fwd", "flash_attention_bwd"]),
     (_flash_two_pass, ["flash_attention_fwd", "flash_attention_bwd_dq",
@@ -421,6 +429,7 @@ def _power_chunked():
     (_selective_scan, ["selective_scan"]),
     (_power_update, ["power_state_update"]),
     (_power_chunked, ["power_retention_chunked"]),
+    (_delta_update, ["gated_delta_state_update"]),
 ], ids=lambda v: v.__name__.strip("_") if callable(v) else None)
 def test_a_pallas_entry_point_names_its_kernels(entry, expect):
     """Forward and gradient: every pallas_call in the traced program carries
@@ -488,7 +497,8 @@ def test_xla_own_share_reads_what_is_neither_a_kernel_nor_an_expert_product():
                                  "ssm_state_update",
                                  "selective_state_update", "selective_scan",
                                  "conv_window_step", "power_state_update",
-                                 "power_retention_chunked")
+                                 "power_retention_chunked",
+                                 "gated_delta_state_update")
         assert bool(rx.search(f"%jvp_{kernel}_.1 = " + tail)) == served_only
 
 
@@ -519,7 +529,7 @@ def test_the_jamba_metrics_read_their_kernel_and_not_the_windows(metric,
 
 def test_kernel_names_are_all_documented_once():
     names = pallas_ops.KERNEL_NAMES
-    assert len(names) == len(set(names)) == 19
+    assert len(names) == len(set(names)) == 20
 
 
 # -- request timelines --------------------------------------------------------
